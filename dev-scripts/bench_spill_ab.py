@@ -1,7 +1,7 @@
 """A/B the spill-to-scatter hybrid kernel vs spill_cap=0 at the ads shape.
 
-Run on the real TPU (no timeout-kill — launch in background and let it
-exit). Protocol: in-jit fori_loop differencing (PERF_NOTES.md).
+Run on the real TPU, through the chip tool, as the only process on the
+chip. Protocol: in-jit fori_loop differencing.
 """
 
 import time

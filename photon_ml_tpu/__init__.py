@@ -15,35 +15,6 @@ The compute/communication layer is JAX on TPU instead of Spark RDDs:
   (``photon_ml_tpu.game``).
 """
 
-def _install_jax_compat() -> None:
-    """Bridge older jax releases where ``shard_map`` still lives in
-    ``jax.experimental`` under the pre-rename ``check_rep`` kwarg: the
-    codebase imports ``from jax import shard_map`` and passes
-    ``check_vma=...`` (the current API). No-op on current jax."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return
-    import functools
-    import inspect
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if "check_vma" in inspect.signature(_shard_map).parameters:
-        jax.shard_map = _shard_map
-        return
-
-    @functools.wraps(_shard_map)
-    def shard_map(f, /, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(f, **kwargs)
-
-    jax.shard_map = shard_map
-
-
-_install_jax_compat()
-
 from photon_ml_tpu.task import TaskType
 
 __version__ = "0.1.0"

@@ -14,6 +14,7 @@ from typing import Iterable, Iterator
 
 from photon_ml_tpu.io.avro_codec import read_avro_records
 from photon_ml_tpu.io.libsvm import read_libsvm
+from photon_ml_tpu.utils.backend import enable_compilation_cache
 from photon_ml_tpu.utils.index_map import feature_key, intercept_key
 from photon_ml_tpu.utils.native_index import build_partitioned_index
 
@@ -73,6 +74,7 @@ def main(argv=None) -> None:
     ap.add_argument("--add-intercept", default="true")
     ap.add_argument("--shard-name", default="global")
     ns = ap.parse_args(argv)
+    enable_compilation_cache()
     shard_dir = run_feature_indexing(
         ns.input_paths.split(","),
         ns.output_dir,

@@ -25,6 +25,7 @@ from photon_ml_tpu.io import schemas
 from photon_ml_tpu.io.avro_codec import write_container
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.task import TaskType
+from photon_ml_tpu.utils.backend import enable_compilation_cache
 from photon_ml_tpu.utils.logging_util import PhotonLogger, Timer
 
 
@@ -175,6 +176,7 @@ class GameScoringDriver:
     def __init__(self, params: GameScoringParams, logger=None):
         params.validate()
         self.params = params
+        enable_compilation_cache()
         if params.tile_cache_dir is not None:
             from photon_ml_tpu.ops.schedule_cache import configure
 

@@ -44,6 +44,7 @@ from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.optim.config import GLMOptimizationConfiguration
 from photon_ml_tpu.optim.problem import create_glm_problem
 from photon_ml_tpu.task import TaskType
+from photon_ml_tpu.utils.backend import enable_compilation_cache
 from photon_ml_tpu.utils.logging_util import PhotonLogger, Timer
 from photon_ml_tpu.utils.profiling import profile_trace
 
@@ -431,6 +432,7 @@ class GameTrainingDriver:
         initialize_multihost(
             params.coordinator_address, params.num_processes, params.process_id
         )
+        enable_compilation_cache()
         if params.tile_cache_dir is not None:
             # process-wide: every coordinate's tiled conversion (FE solves
             # across all combos) shares the persistent tier

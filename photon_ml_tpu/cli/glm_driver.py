@@ -49,6 +49,7 @@ from photon_ml_tpu.ops.normalization import (
 from photon_ml_tpu.optim import CONVERGENCE_REASON_NAMES, OptimizerType, RegularizationType
 from photon_ml_tpu.task import TaskType
 from photon_ml_tpu.training import train_generalized_linear_model
+from photon_ml_tpu.utils.backend import enable_compilation_cache
 from photon_ml_tpu.utils.index_map import split_feature_key
 from photon_ml_tpu.utils.logging_util import PhotonLogger, Timer
 
@@ -166,7 +167,7 @@ class GLMParams:
     # Diagnostics reservoir bounds for the streaming path: the sample is
     # rows x max_nnz dense (int32+float32), so wide-row datasets must not
     # blow the bounded-memory contract — rows are scaled down to fit the
-    # byte budget (ADVICE.md round 5).
+    # byte budget.
     diagnostic_reservoir_rows: int = 100_000
     diagnostic_reservoir_bytes: int = 256 << 20
     # λ-grid execution policy (training.resolve_grid_mode): "batched"
@@ -362,7 +363,7 @@ def budgeted_reservoir_rows(
     rows x max_nnz dense (int32 indices + float32 values = 8 B/slot, plus
     12 B/row of label/offset/weight), so wide-row datasets scale rows
     DOWN to fit instead of allocating multiple GB on the host — the
-    streaming path's bounded-memory contract (ADVICE.md round 5). The
+    streaming path's bounded-memory contract. The
     shared core lives in io.streaming.budgeted_rows; the GAME driver
     budgets its (multi-shard-wide) reservoir through the same helper."""
     from photon_ml_tpu.io.streaming import budgeted_rows, sparse_row_bytes
@@ -414,6 +415,7 @@ class GLMDriver:
         initialize_multihost(
             params.coordinator_address, params.num_processes, params.process_id
         )
+        enable_compilation_cache()
         if params.tile_cache_dir is not None:
             # process-wide so every stage's tiled conversion (train,
             # validation, diagnostics) shares the same persistent tier

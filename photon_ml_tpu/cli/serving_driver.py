@@ -47,6 +47,7 @@ import numpy as np
 from photon_ml_tpu.evaluation import EvaluatorType
 from photon_ml_tpu.game.config import FeatureShardConfiguration
 from photon_ml_tpu.task import TaskType
+from photon_ml_tpu.utils.backend import enable_compilation_cache
 from photon_ml_tpu.utils.logging_util import PhotonLogger, Timer
 
 DEFAULT_LADDER_TEXT = "1,8,64,256"
@@ -410,6 +411,7 @@ class ServingDriver:
     def __init__(self, params: ServingParams, logger=None):
         params.validate()
         self.params = params
+        enable_compilation_cache()
         if params.no_overlap:
             from photon_ml_tpu.parallel import overlap
 

@@ -919,8 +919,8 @@ class MatrixFactorizationCoordinate(Coordinate):
         )
         # Merge sparse cap-classes upward: every distinct (E_b, S) bucket
         # shape costs a multi-second trace + compile of the fused solver
-        # (measured ~5 s/program over the relay — 9 programs made the MF
-        # first step 63 s warm-cache), while padding a FEW entities to
+        # (9 programs made the MF first step 63 s in round 4; merging
+        # took them to 4), while padding a FEW entities to
         # the next power of two only squares their tiny share of the
         # Gram work. Keep a class only when it holds >= 25% of the
         # active entities; everything else pads up to the next kept

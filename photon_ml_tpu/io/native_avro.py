@@ -18,7 +18,6 @@ or the schema shape is unsupported.
 from __future__ import annotations
 
 import ctypes
-import os
 import subprocess
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -26,13 +25,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from photon_ml_tpu.io.avro_codec import read_container
+from photon_ml_tpu.utils.native_build import library_path
 
-_REPO_ROOT = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-)
-_SRC = os.path.join(_REPO_ROOT, "native", "avro_reader.cpp")
-_LIB_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_LIB = os.path.join(_LIB_DIR, "libavro_reader.so")
 _COMPILE_LOCK = threading.Lock()
 _lib_handle = None
 
@@ -57,20 +51,7 @@ def _lib():
     with _COMPILE_LOCK:
         if _lib_handle is not None:
             return _lib_handle
-        if not (
-            os.path.isfile(_LIB)
-            and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)
-        ):
-            os.makedirs(_LIB_DIR, exist_ok=True)
-            subprocess.run(
-                [
-                    "g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                    _SRC, "-o", _LIB, "-lz",
-                ],
-                check=True,
-                capture_output=True,
-            )
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(library_path("avro_reader", link=("-lz",)))
         lib.pavro_decode.restype = ctypes.c_void_p
         lib.pavro_decode.argtypes = [
             ctypes.c_char_p, ctypes.c_uint64,
@@ -113,7 +94,7 @@ def available() -> bool:
     try:
         _lib()
         return True
-    except Exception:
+    except (OSError, subprocess.CalledProcessError):
         return False
 
 

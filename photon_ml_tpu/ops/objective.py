@@ -205,8 +205,8 @@ jax.tree_util.register_dataclass(
 #
 # The streaming objectives (io/streaming.py, game/streaming.py) evaluate
 # l2=0 partials chunk by chunk and fold on device; these module-level jits
-# replace their constructor-time ``jit(lambda)``s (PERF_NOTES round 9's
-# "noted, not attempted" item): one compile cache for the whole process,
+# replace their constructor-time ``jit(lambda)``s: one compile cache for
+# the whole process,
 # keyed by jit on the objective's static structure + chunk shapes.
 
 

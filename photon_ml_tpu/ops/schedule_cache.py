@@ -1,8 +1,7 @@
 """Persistent content-addressed cache for tiled sparse schedules.
 
-WHY: the tiled Pallas kernels (ops/tiled_sparse.py) sit at ~0.99x their
-dispatched-step roofline (BENCH_r05), so the remaining cold-training host
-cost is the SCHEDULE BUILD — ~4.3 s per dataset at the ads shape, repaid
+WHY: beside the compile, the cold-training host cost of the tiled Pallas
+kernels (ops/tiled_sparse.py) is the SCHEDULE BUILD — ~4.3 s per dataset at the ads shape, repaid
 on every process start and every sweep whose in-memory cache missed. The
 schedule is a pure function of (entry coordinates/values, tile params,
 output-block count): exactly the static layout work Photon ML amortizes
@@ -458,8 +457,7 @@ class ScheduleLRU:
     """Small bounded LRU for converted batches: a hit refreshes recency,
     inserts evict the LEAST recently used entry. One instance each for
     the tiled and sharded conversions (ops/tiled_sparse.py), so the two
-    call sites can no longer thrash each other out of a shared dict
-    (ADVICE.md round 5)."""
+    call sites can no longer thrash each other out of a shared dict."""
 
     def __init__(self, maxsize: int):
         from collections import OrderedDict

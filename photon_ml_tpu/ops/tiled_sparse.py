@@ -1,7 +1,7 @@
 """Tiled sparse GLM kernels: gather/scatter-free margins and gradients.
 
 WHY: on TPU, XLA lowers random gather/scatter to ~7ns/element serial loops
-(measured — PERF_NOTES.md), so the reference's two hot loops (margin
+(measured on the chip, round 1), so the reference's two hot loops (margin
 accumulation and gradient axpy, ValueAndGradientAggregator.scala:133-154)
 are 100x slower than the hardware's streaming rate. This module replaces
 both with a STATIC TILED layout + two Pallas kernels whose only per-entry
@@ -47,7 +47,7 @@ Array = jnp.ndarray
 @dataclass(frozen=True)
 class TileParams:
     # Defaults from on-chip sweeps at the ads shape (262k x 64nnz x 1M,
-    # PERF_NOTES.md "tile sweep"): window-shape changes (s_hi=s_lo=128, or
+    # the round-2 tile sweep): window-shape changes (s_hi=s_lo=128, or
     # 64/128) were net losses. ``chunk=None`` sizes the grid-step width
     # from the dataset's average tile occupancy at build time (pow2 of the
     # mean entries per tile, clamped to [1024, 4096]) — at the ads shape
@@ -167,44 +167,22 @@ _tile_lib_handle = None  # None = untried, False = unavailable
 
 
 def _tile_lib():
-    """ctypes handle to native/tile_schedule.cpp (compiled on demand like
-    io/native_avro.py); False when the toolchain/library is unavailable —
-    callers fall back to the numpy builder."""
+    """ctypes handle to native/tile_schedule.cpp (compiled on demand, see
+    utils/native_build.py); False when the toolchain/library is
+    unavailable — callers fall back to the numpy builder."""
     global _tile_lib_handle
     if _tile_lib_handle is not None:
         return _tile_lib_handle
     import ctypes
-    import os
     import subprocess
+
+    from photon_ml_tpu.utils.native_build import library_path
 
     with _TILE_LIB_LOCK:
         if _tile_lib_handle is not None:
             return _tile_lib_handle
-        root = os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        )
-        src = os.path.join(root, "native", "tile_schedule.cpp")
-        lib_dir = os.path.join(root, "native", "build")
-        lib_path = os.path.join(lib_dir, "libtile_schedule.so")
         try:
-            if not (
-                os.path.isfile(lib_path)
-                and os.path.getmtime(lib_path) >= os.path.getmtime(src)
-            ):
-                os.makedirs(lib_dir, exist_ok=True)
-                # compile to a temp path + atomic rename so another
-                # process never dlopens a half-written .so
-                tmp_path = f"{lib_path}.{os.getpid()}.tmp"
-                subprocess.run(
-                    [
-                        "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                        src, "-o", tmp_path,
-                    ],
-                    check=True,
-                    capture_output=True,
-                )
-                os.replace(tmp_path, lib_path)
-            lib = ctypes.CDLL(lib_path)
+            lib = ctypes.CDLL(library_path("tile_schedule", opt="-O3"))
             i64 = ctypes.c_int64
             p_i64 = ctypes.POINTER(i64)
             p_i32 = ctypes.POINTER(ctypes.c_int32)
@@ -220,7 +198,7 @@ def _tile_lib():
                 p_i32, p_i32, p_f32,
             ]
             _tile_lib_handle = lib
-        except Exception:
+        except (OSError, subprocess.CalledProcessError):
             _tile_lib_handle = False
     return _tile_lib_handle
 
@@ -1169,7 +1147,7 @@ def _place_data_sharded(batch: TiledSparseBatch, mesh, axis: str):
 # offsets change between sweeps) must not pay the multi-second schedule
 # rebuild + host pull every call. Keyed by array identity; LRU-bounded
 # because each entry pins a tiled batch in HBM. TWO separate caches (one
-# per conversion flavor, ADVICE.md round 5): a process interleaving
+# per conversion flavor): a process interleaving
 # single-device and sharded conversions — GAME with several FE shards
 # plus a GLM grid — previously thrashed one shared 2-entry dict and
 # silently rebuilt every sweep. Both sit in front of the persistent disk
@@ -1357,9 +1335,12 @@ def _bilinear_pass_kernel(
         one-hot EXACTNESS, which the bf16 split relies on, survives.
         Trades the [s, width] compare chain for a matmul + one
         elementwise pass; whether Mosaic schedules it better than the
-        compare is the A/B bench.py carries (PERF_NOTES round 6)."""
+        compare is the A/B bench.py carries."""
         if onehot == "mxu":
-            i_col = jax.lax.broadcasted_iota(jnp.float32, (s, 1), 0)
+            # Mosaic's iota is integer-only: count in int32, then cast
+            i_col = jax.lax.broadcasted_iota(
+                jnp.int32, (s, 1), 0
+            ).astype(jnp.float32)
             lhs = jnp.concatenate(
                 [1.0 - i_col * i_col, 2.0 * i_col, -jnp.ones_like(i_col)],
                 axis=1,
@@ -1376,6 +1357,18 @@ def _bilinear_pass_kernel(
             return jnp.maximum(d, 0.0).astype(dt)
         iota = jax.lax.broadcasted_iota(jnp.int32, (s, width), 0)
         return (idx == iota).astype(dt)
+
+    def _bf16_dot(lhs, rhs, dims):
+        """Single-pass MXU product of bf16 operands, f32 accumulation.
+        The precision is pinned: under a global
+        ``jax.default_matmul_precision("highest")`` (a reference check's
+        setting) an unpinned bf16 dot asks Mosaic for an fp32 contraction
+        of bf16 operands, which it refuses to compile."""
+        return jax.lax.dot_general(
+            lhs, rhs, dims,
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT,
+        )
 
     def _chain(ip, op, v, width):
         """One independent gather->contrib->scatter chain over ``width``
@@ -1398,9 +1391,8 @@ def _bilinear_pass_kernel(
             # gather: pack [hi | lo] along the lane axis -> [S_HI, 2*S_LO]
             s1, s2 = _split(src_ref[0])
             src_cat = jnp.concatenate([s1, s2], axis=1)
-            a_cat = jax.lax.dot_general(
-                src_cat, oh_in_hi, dims_in,
-                preferred_element_type=jnp.float32,
+            a_cat = _bf16_dot(
+                src_cat, oh_in_hi, dims_in
             )  # [2*S_LO, w]: rows [0,S_LO) = hi terms, [S_LO,2*S_LO) = lo
             # fold the halves first (sublane slice at a multiple of 8) so
             # the mask-reduce runs at [S_LO, w] instead of [2*S_LO, w]
@@ -1421,9 +1413,8 @@ def _bilinear_pass_kernel(
             rhs = jnp.concatenate(
                 [oh_out_lo * c1, oh_out_lo * c2], axis=0
             )  # [2*S_LO, w]
-            update_wide = jax.lax.dot_general(
-                oh_out_hi, rhs, dims_out,
-                preferred_element_type=jnp.float32,
+            update_wide = _bf16_dot(
+                oh_out_hi, rhs, dims_out
             )  # [S_HI, 2*S_LO]
             return update_wide[:, :s_lo] + update_wide[:, s_lo:]
         elif mxu == "bf16x2":
@@ -1438,10 +1429,8 @@ def _bilinear_pass_kernel(
 
             # gather: src_g[p] = src2d[ih[p], il[p]]
             s1, s2 = _split(src_ref[0])
-            a = jax.lax.dot_general(
-                s1, oh_in_hi, dims_in, preferred_element_type=jnp.float32
-            ) + jax.lax.dot_general(
-                s2, oh_in_hi, dims_in, preferred_element_type=jnp.float32
+            a = _bf16_dot(s1, oh_in_hi, dims_in) + _bf16_dot(
+                s2, oh_in_hi, dims_in
             )  # [S_LO, w]
             src_g = jnp.sum(a * oh_in_lo, axis=0, keepdims=True)  # [1, w]
             contrib = v * src_g  # [1, w]
@@ -1451,12 +1440,10 @@ def _bilinear_pass_kernel(
             # A @ B^T via lane/entry contraction. oh_out_lo is 0/1 and the
             # contrib terms are already bf16, so each product is exact.
             c1, c2 = _split(contrib)
-            return jax.lax.dot_general(
-                oh_out_hi, oh_out_lo * c1, dims_out,
-                preferred_element_type=jnp.float32,
-            ) + jax.lax.dot_general(
-                oh_out_hi, oh_out_lo * c2, dims_out,
-                preferred_element_type=jnp.float32,
+            return _bf16_dot(
+                oh_out_hi, oh_out_lo * c1, dims_out
+            ) + _bf16_dot(
+                oh_out_hi, oh_out_lo * c2, dims_out
             )  # [S_HI, S_LO]
         else:  # "highest": full f32 emulation, ~3x slower, ~1e-7 rel error
             oh_in_hi = _expand(ih, s_hi, width, jnp.float32)

@@ -9,7 +9,7 @@ steepest-descent fallback, Armijo backtracking with the same constants,
 and the reference's convergence rules (Optimizer.scala:156-170 via
 optim.common.check_convergence).
 
-Readback discipline (PERF_NOTES round 10; the round-9 baseline debt):
+Readback discipline (round 10; the round-9 baseline debt):
 ONLY the scalars that gate host control flow come back, and they come
 back BATCHED through the counted ``overlap.device_get`` seam — one fetch
 for the direction setup, one per line-search trial (the trial's

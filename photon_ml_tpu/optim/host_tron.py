@@ -10,7 +10,7 @@ one-cluster-aggregate-per-CG-step loop,
 HessianVectorAggregator.scala:137-152 + TRON.scala:259-341), and the
 shared convergence rules (Optimizer.scala:156-170).
 
-Readback discipline (PERF_NOTES round 10): control scalars come back
+Readback discipline (round 10): control scalars come back
 BATCHED through the counted ``overlap.device_get`` seam — per CG step
 one residual-norm check plus one (d·Hd, d·d, s·d, s·s) batch (the
 boundary norm ‖s+αd‖ derives from those on host, so the old separate

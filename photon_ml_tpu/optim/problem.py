@@ -529,7 +529,7 @@ def resolve_kernel(kernel: str, batch=None) -> str:
     """Resolve the objective-kernel choice: "scatter" | "tiled" | "auto".
 
     "auto" picks the tiled Pallas kernel pair (7x the scatter throughput,
-    PERF_NOTES.md) when running on TPU with sparse data; the kernels are
+    round 2 on the chip) when running on TPU with sparse data; the kernels are
     Mosaic (TPU-only), so every other backend — CPU, GPU — gets scatter.
     """
     if kernel not in ("auto", "tiled", "scatter"):

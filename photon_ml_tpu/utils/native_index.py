@@ -16,37 +16,19 @@ from __future__ import annotations
 import ctypes
 import json
 import os
-import subprocess
-import threading
 from typing import Iterable, List, Optional, Sequence
 
 import numpy as np
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-_SRC = os.path.join(_REPO_ROOT, "native", "index_store.cpp")
-_LIB_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_LIB = os.path.join(_LIB_DIR, "libindex_store.so")
-_COMPILE_LOCK = threading.Lock()
+from photon_ml_tpu.utils.native_build import library_path
+
 _lib_handle = None
-
-
-def _compile_if_needed() -> str:
-    with _COMPILE_LOCK:
-        if os.path.isfile(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-            return _LIB
-        os.makedirs(_LIB_DIR, exist_ok=True)
-        cmd = [
-            "g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-            _SRC, "-o", _LIB,
-        ]
-        subprocess.run(cmd, check=True, capture_output=True)
-        return _LIB
 
 
 def _lib():
     global _lib_handle
     if _lib_handle is None:
-        lib = ctypes.CDLL(_compile_if_needed())
+        lib = ctypes.CDLL(library_path("index_store"))
         lib.pidx_build.restype = ctypes.c_int
         lib.pidx_build.argtypes = [
             ctypes.c_char_p,

@@ -731,7 +731,7 @@ class TestLargeScaleREBuild:
 
 @pytest.mark.slow
 class TestDeviceResidentResiduals:
-    """VERDICT r2 item 6: at steady state the coordinate-descent loop does
+    """At steady state the coordinate-descent loop does
     no implicit device->host transfer — residuals, offsets, and scores
     stay jnp end-to-end (SURVEY §7.9 device-resident KeyValueScore); the
     tracker/objective readbacks are single EXPLICIT device_get calls."""
@@ -787,7 +787,7 @@ class TestDeviceResidentResiduals:
 
 @pytest.mark.slow
 class TestFilePathScale:
-    """VERDICT r2 item 3, 'through the REAL path': Avro files -> native
+    """Through the REAL path: Avro files -> native
     column decode -> vectorized GAME dataset assembly -> vectorized RE
     build, at a volume where any per-record Python loop in the chain
     would visibly blow up."""
@@ -861,7 +861,7 @@ class TestFilePathScale:
 
 class TestBucketScanFold:
     """Same-shape bucket groups fold into ONE lax.scan dispatch
-    (round 5, PERF_NOTES RE-bank ceiling): the folded update must equal
+    (round 5, the RE-bank ceiling profile): the folded update must equal
     the per-bucket path exactly."""
 
     def _data(self, rng, n_buckets=4, E=64, S=8, K=6, D=32):

@@ -2,7 +2,7 @@
 warm-started sequential path.
 
 Pins the contract, not just the happy path:
-- per-λ parity with the sequential trainer within the PERF_NOTES fp32
+- per-λ parity with the sequential trainer within the measured fp32
   envelopes (rtol 2e-3 class for the LBFGS family, tighter for TRON),
   on both the scatter and tiled kernels;
 - active-mask freeze semantics — a converged member's state is
@@ -89,8 +89,8 @@ class TestGridParityScatter:
             batch, TaskType.LOGISTIC_REGRESSION, 48, **kw
         )
         # values effectively exact; coefficients see the fp32 reorder
-        # noise amplified through line-search branch points (PERF_NOTES
-        # r8 "~1e-4 relative" class — atol 1e-3 is the seed-safe margin)
+        # noise amplified through line-search branch points (the round-8
+        # "~1e-4 relative" class — atol 1e-3 is the seed-safe margin)
         _assert_grid_parity(
             r_seq, r_bat, value_rtol=1e-5, coef_atol=1e-3
         )
@@ -98,7 +98,7 @@ class TestGridParityScatter:
     def test_matches_warm_sequential_within_envelope(self, rng):
         """Against the DEFAULT warm-started sequential path both land on
         the same per-λ optimum, reached along different iterate paths —
-        the PERF_NOTES rtol-2e-3-class LBFGS envelope."""
+        the rtol-2e-3-class LBFGS envelope."""
         batch = _synth_batch(rng)
         kw = dict(
             regularization_type=RegularizationType.L2,

@@ -107,8 +107,8 @@ class TestRouter:
 @pytest.mark.slow
 class TestMeshSteadyState:
     def test_mesh_cd_no_implicit_d2h_at_steady_state(self, rng):
-        # VERDICT r2 items 5+6 done-criterion: CPU-mesh CoordinateDescent
-        # under the transfer guard once caches/routers are warm
+        # CPU-mesh CoordinateDescent under the transfer guard once
+        # caches/routers are warm
         recs, _, _ = make_records(rng, n=200, n_users=6)
         ds = build_game_dataset(recs, SHARDS, ["userId"])
         red = build_random_effect_dataset(

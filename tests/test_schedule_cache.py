@@ -187,7 +187,7 @@ class TestMemoryTiers:
         assert len(lru) == 2
 
     def test_interleaved_tiled_and_sharded_build_once(self, rng):
-        """Regression (ADVICE.md round 5): interleaving ensure_tiled and
+        """Regression (round 5): interleaving ensure_tiled and
         ensure_tiled_sharded must not evict each other's schedules — each
         layout is built exactly once per process."""
         from photon_ml_tpu.ops.tiled_sparse import (

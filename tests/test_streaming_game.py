@@ -9,7 +9,7 @@ reordering noise (~1e-7/evaluation) is amplified through optimizer
 iterates, so coefficient agreement lands at ~1e-4 relative after a full
 CD run (the TRON fixed effect is the tightest pairing — its host driver
 walks the in-jit iterate sequence step for step); the OBJECTIVE agrees
-much tighter. PERF_NOTES round 7 records the measured envelopes.
+much tighter (the round-7 measured envelopes).
 """
 
 import json
