@@ -2586,7 +2586,7 @@ def _obs_config(name, *, seed=0):
     recorder), alternating passes, median-of-passes per arm.
 
     The contract being priced: tracing must stay affordable enough to
-    leave on in production. Gates in dev-scripts/bench_obs.sh:
+    leave on in production. Gates its caller applied (CPU-era):
     <2% request-path overhead on this host class (multi-core/chip; the
     1-core container number is recorded honestly), 0 request-path
     lowerings in BOTH arms, readbacks == dispatches unchanged, and
@@ -2741,7 +2741,7 @@ def _obs_config(name, *, seed=0):
     # tuple per traced request); measure that call in isolation and
     # divide by the measured per-request wall. On hosts whose
     # scheduling noise exceeds the effect (this 1-core container
-    # swings +-20% pass to pass), bench_obs.sh gates THIS number —
+    # swings +-20% pass to pass), THIS number was the gated one —
     # the A/B stays recorded honestly either way.
     span_record_us = min(span_record_us, span_record_micro())
     per_request_us = off_s / n_req * 1e6
@@ -2791,8 +2791,8 @@ def _fleet_obs_config(name, *, seed=0):
     attribution), alternating passes.
 
     The contract being priced: the collector must stay affordable
-    enough to leave on against a production fleet. Gates in
-    dev-scripts/bench_fleet_obs.sh: <2% request-path overhead on
+    enough to leave on against a production fleet. Gates its caller
+    applied (CPU-era): <2% request-path overhead on
     multi-core/chip hosts (the 1-core container number is recorded
     honestly under a noise ceiling), 0 request-path lowerings in BOTH
     arms, fleet conservation balanced (router admitted == Σ
@@ -4101,15 +4101,14 @@ def suite(only=None):
         print(json.dumps(results[-1]), flush=True)
 
     # 15: unified telemetry (ISSUE 13): tracing/metrics on-vs-off
-    # request-path overhead A/B + trace completeness + conservation;
-    # gates in dev-scripts/bench_obs.sh.
+    # request-path overhead A/B + trace completeness + conservation.
     if want("15_observability"):
         results.append(_obs_config("15_observability"))
         print(json.dumps(results[-1]), flush=True)
 
     # 16: fleet observability (ISSUE 15): collector/tracing/attribution
     # on-vs-off over a real 2-shard TCP fleet + merge completeness +
-    # fleet conservation; gates in dev-scripts/bench_fleet_obs.sh.
+    # fleet conservation.
     if want("16_fleet_observability"):
         results.append(_fleet_obs_config("16_fleet_observability"))
         print(json.dumps(results[-1]), flush=True)
@@ -4208,12 +4207,10 @@ if __name__ == "__main__":
         # as one JSON line (gates applied by the script)
         print(json.dumps(_wire_config("wire")))
     elif "--fleet-obs" in sys.argv:
-        # dev-scripts/bench_fleet_obs.sh entry: the fleet-collector
-        # overhead A/B as one JSON line (gates applied by the script)
+        # the fleet-collector overhead A/B as one JSON line
         print(json.dumps(_fleet_obs_config("fleet_obs")))
     elif "--obs" in sys.argv:
-        # dev-scripts/bench_obs.sh entry: the telemetry overhead A/B
-        # as one JSON line (gates applied by the script)
+        # the telemetry overhead A/B as one JSON line
         print(json.dumps(_obs_config("obs")))
     elif "--suite" in sys.argv:
         only = None

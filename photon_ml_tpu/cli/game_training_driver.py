@@ -40,6 +40,7 @@ from photon_ml_tpu.game.model import GameModel
 from photon_ml_tpu.game.model_io import save_game_model
 from photon_ml_tpu.game.random_effect import RandomEffectOptimizationProblem
 from photon_ml_tpu.game.random_effect_data import build_random_effect_dataset
+from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.optim.config import GLMOptimizationConfiguration
 from photon_ml_tpu.optim.problem import create_glm_problem
@@ -1245,8 +1246,10 @@ class GameTrainingDriver:
                     combo_ckpt_dir = os.path.join(
                         p.checkpoint_dir, f"combo-{fp}"
                     )
-                with self.timer.time(f"train-combo-{ci}"), profile_trace(
-                    p.profile_dir if ci == 0 else None
+                with (
+                    self.timer.time(f"train-combo-{ci}"),
+                    profile_trace(p.profile_dir if ci == 0 else None),
+                    obs_span("game.train_combo", combo=ci),
                 ):
                     result, extras = train_streaming_game(
                         train_paths,
@@ -1495,10 +1498,12 @@ class GameTrainingDriver:
                             len(combos),
                         )
                         break
-                    with self.timer.time(f"train-combo-{ci}"), profile_trace(
+                    with (
+                        self.timer.time(f"train-combo-{ci}"),
                         # trace the FIRST combo actually trained (combos run
                         # in warm-start order, not grid order)
-                        p.profile_dir if ti == 0 else None
+                        profile_trace(p.profile_dir if ti == 0 else None),
+                        obs_span("game.train_combo", combo=ci),
                     ):
                         from photon_ml_tpu.parallel import overlap
 

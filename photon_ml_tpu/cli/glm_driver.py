@@ -1024,12 +1024,17 @@ class GLMDriver:
     def train(self) -> None:
         p = self.params
         self.emitter.send(TrainingStartEvent(p.job_name))
+        from photon_ml_tpu.obs.trace import span as obs_span
         from photon_ml_tpu.utils.profiling import profile_trace
 
         self._load_parent()
         grid_ckpt, guard = self._grid_checkpoint_setup()
         self._preempted = False
-        with self.timer.time("train"), profile_trace(p.profile_dir):
+        with (
+            self.timer.time("train"),
+            profile_trace(p.profile_dir),
+            obs_span("glm.train"),
+        ):
             data = self._data
             mesh = self._mesh()
             retrain_initial = self._retrain_initial()
@@ -1314,7 +1319,7 @@ class GLMDriver:
         # parallel/overlap.py via training.grid_result_scalars).
         from photon_ml_tpu.training import grid_result_scalars
 
-        for lam, (iters, value, reason) in grid_result_scalars(
+        for lam, (iters, value, reason, evaluations) in grid_result_scalars(
             self.results
         ).items():
             self.emitter.send(
@@ -1328,9 +1333,10 @@ class GLMDriver:
                 )
             )
             self.logger.info(
-                "lambda=%g: %d iters, f=%g, reason=%s",
+                "lambda=%g: %d iters, %d evaluations, f=%g, reason=%s",
                 lam,
                 iters,
+                evaluations,
                 value,
                 CONVERGENCE_REASON_NAMES.get(reason, "?"),
             )

@@ -32,11 +32,12 @@ from photon_ml_tpu.game.model import (
 )
 from photon_ml_tpu.game.random_effect import (
     RandomEffectOptimizationProblem,
+    device_row_view,
     score_random_effect,
 )
 from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
 from photon_ml_tpu.models.coefficients import Coefficients
-from photon_ml_tpu.models.glm import create_model
+from photon_ml_tpu.models.glm import compute_scores, create_model
 from photon_ml_tpu.optim.problem import GLMOptimizationProblem
 
 Array = jnp.ndarray
@@ -74,6 +75,14 @@ class Coordinate:
         transfers, layout builds, AOT warming) — idempotent, and safe to
         run on a background thread while another coordinate's solves
         occupy the device (overlap prefetched dispatch). Default: no-op."""
+
+
+@jax.jit
+def fe_score(means: Array, batch) -> Array:
+    """The fixed effect's scoring pass as one named program (module
+    ``fe_score``, scope ``cd.score``) a device trace can place."""
+    with jax.named_scope("cd.score"):
+        return compute_scores(means, batch)
 
 
 @dataclass
@@ -377,7 +386,7 @@ class FixedEffectCoordinate(Coordinate):
         if self._is_feature_sharded():
             return self._update_model_grid_feature_sharded(reg_weights)
         from photon_ml_tpu.models.coefficients import Coefficients
-        from photon_ml_tpu.optim.common import OptResult, Tracker
+        from photon_ml_tpu.optim.common import grid_member
 
         batch = self._batch(None)
         if self.down_sampling_rate < 1.0:
@@ -391,7 +400,6 @@ class FixedEffectCoordinate(Coordinate):
             batch, [float(w) for w in reg_weights], mesh=self.mesh
         )
         out = []
-        tracker = result.tracker
         for i in range(len(reg_weights)):
             var_i = variances[i] if variances is not None else None
             coefficients = Coefficients(result.coefficients[i], var_i)
@@ -400,22 +408,7 @@ class FixedEffectCoordinate(Coordinate):
                     self.problem.create_model(coefficients),
                     self.feature_shard_id,
                 ),
-                OptResult(
-                    coefficients=result.coefficients[i],
-                    value=result.value[i],
-                    grad_norm=result.grad_norm[i],
-                    iterations=result.iterations[i],
-                    reason=result.reason[i],
-                    tracker=Tracker(
-                        values=tracker.values[i],
-                        grad_norms=tracker.grad_norms[i],
-                        count=tracker.count[i],
-                        coefs=(
-                            tracker.coefs[i]
-                            if tracker.coefs is not None else None
-                        ),
-                    ),
-                ),
+                grid_member(result, i),
             ))
         return out
 
@@ -426,7 +419,7 @@ class FixedEffectCoordinate(Coordinate):
         blocks sharded over "model"), [G] l1/l2 vectors, and the cached
         tile/entry layout walked once per data pass for the whole grid."""
         from photon_ml_tpu.models.coefficients import Coefficients
-        from photon_ml_tpu.optim.common import OptResult, Tracker
+        from photon_ml_tpu.optim.common import grid_member
         from photon_ml_tpu.optim.config import OptimizerType
         from photon_ml_tpu.optim.problem import _VARIANCE_EPSILON
         from photon_ml_tpu.parallel.distributed import (
@@ -463,7 +456,6 @@ class FixedEffectCoordinate(Coordinate):
         ) + st["extras_tail"]
         result = grid_fit(w0_bank, sharded, l2_vec, *extras)
         out = []
-        tracker = result.tracker
         norm_extras = st["extras_tail"][:2] if st["with_norm"] else []
         for i in range(G):
             var_i = None
@@ -480,27 +472,15 @@ class FixedEffectCoordinate(Coordinate):
                     problem.create_model(coefficients),
                     self.feature_shard_id,
                 ),
-                OptResult(
-                    coefficients=coef_i,
-                    value=result.value[i],
-                    grad_norm=result.grad_norm[i],
-                    iterations=result.iterations[i],
-                    reason=result.reason[i],
-                    tracker=Tracker(
-                        values=tracker.values[i],
-                        grad_norms=tracker.grad_norms[i],
-                        count=tracker.count[i],
-                        coefs=(
-                            tracker.coefs[i]
-                            if tracker.coefs is not None else None
-                        ),
-                    ),
-                ),
+                grid_member(result, i)._replace(coefficients=coef_i),
             ))
         return out
 
     def score(self, model: FixedEffectModel) -> Array:
-        return model.score(self.dataset)
+        return fe_score(
+            model.model.means,
+            self.dataset.batch_for_shard(self.feature_shard_id),
+        )
 
     def regularization_term(self, model: FixedEffectModel) -> float:
         from photon_ml_tpu.parallel import overlap
@@ -577,8 +557,6 @@ class RandomEffectCoordinate(Coordinate):
     def prepare(self, model=None) -> None:
         """Stage bucket device transfers / stacked group args / AOT
         programs + the row view the score pass reads."""
-        from photon_ml_tpu.game.random_effect import device_row_view
-
         bank = (
             model.bank
             if model is not None
@@ -717,8 +695,6 @@ class FactoredRandomEffectCoordinate(Coordinate):
     def _latent_rows(self, projection: Array) -> Tuple[Array, Array]:
         """Project every row into latent space: dense [n, L] values with
         identity local indices."""
-        from photon_ml_tpu.game.random_effect import device_row_view
-
         _, _, ix, v = device_row_view(self.re_dataset)
         # x_lat = sum_s v_s * B[ix_s]  -> [n, L]
         return jnp.einsum("nk,nkl->nl", v, jnp.take(projection, ix, axis=0))
@@ -748,8 +724,6 @@ class FactoredRandomEffectCoordinate(Coordinate):
     def _update_projection(
         self, bank: Array, projection: Array, offsets_np: np.ndarray
     ) -> Array:
-        from photon_ml_tpu.game.random_effect import device_row_view
-
         d = self.re_dataset.local_dim
         L = self.config.latent_space_dimension
         codes, valid, ix, v = device_row_view(self.re_dataset)
@@ -773,8 +747,6 @@ class FactoredRandomEffectCoordinate(Coordinate):
         return coefficients.means.reshape(d, L)
 
     def score(self, model) -> Array:
-        from photon_ml_tpu.game.random_effect import device_row_view
-
         x_lat = self._latent_rows(model.projection)  # [n, L]
         codes, valid, _, _ = device_row_view(self.re_dataset)
         w_rows = jnp.take(model.bank, codes, axis=0)
@@ -796,8 +768,6 @@ class FactoredRandomEffectModel(DatumScoringModel):
     feature_shard_id: str
 
     def score(self, dataset: GameDataset) -> Array:
-        from photon_ml_tpu.game.random_effect import device_row_view
-
         codes, valid, ix, v = device_row_view(self.re_dataset)
         x_lat = jnp.einsum("nk,nkl->nl", v, jnp.take(self.projection, ix, axis=0))
         w_rows = jnp.take(self.bank, codes, axis=0)

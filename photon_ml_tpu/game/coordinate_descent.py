@@ -17,20 +17,55 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+from functools import partial
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu.game.coordinate import Coordinate
 from photon_ml_tpu.game.data import GameDataset
 from photon_ml_tpu.game.model import GameModel
+from photon_ml_tpu.obs.registry import default_registry
 from photon_ml_tpu.obs.trace import span as obs_span
-from photon_ml_tpu.obs.trace import start_span
 from photon_ml_tpu.ops.losses import loss_for_task
+from photon_ml_tpu.optim.common import CONVERGENCE_REASON_NAMES, OptResult
 from photon_ml_tpu.parallel import overlap
 from photon_ml_tpu.task import TaskType
+from photon_ml_tpu.utils import profiling  # noqa: F401  obs spans -> profiler
 from photon_ml_tpu.utils.logging_util import PhotonLogger
 
 Array = jnp.ndarray
+
+
+# The CD loop's own device arithmetic, as named programs (the function
+# handed to jax.jit names the XLA module) with a scope each, so that a
+# device trace places it in this layer.
+
+
+@jax.jit
+def cd_residual(total: Array, own_score: Array) -> Array:
+    """What the OTHER coordinates leave: total - own score (the
+    KeyValueScore `-` of the reference)."""
+    with jax.named_scope("cd.residual"):
+        return total - own_score
+
+
+@jax.jit
+def cd_total(partial_total: Array, score: Array) -> Array:
+    with jax.named_scope("cd.residual"):
+        return partial_total + score
+
+
+@partial(jax.jit, static_argnums=0)
+def cd_objective(loss, total_score, offsets, labels, weights, reg_terms):
+    """The rows' weighted loss at (sum of scores + offsets) + the
+    coordinates' reg terms."""
+    with jax.named_scope("cd.objective"):
+        value = jnp.sum(weights * loss.value(total_score + offsets, labels))
+        for term in reg_terms:
+            value = value + term
+        return value
 
 
 @dataclass
@@ -123,11 +158,13 @@ class CoordinateDescent:
             )
             self._device_cols = cached
         off, lab, w = cached
-        z = total_score + off
-        value = jnp.sum(w * loss.value(z, lab))
-        for name, coord in self.coordinates.items():
-            value = value + coord.regularization_term_device(models[name])
-        return overlap.Deferred(value, float)
+        reg_terms = [
+            coord.regularization_term_device(models[name])
+            for name, coord in self.coordinates.items()
+        ]
+        return overlap.Deferred(
+            cd_objective(loss, total_score, off, lab, w, reg_terms), float
+        )
 
     def run(
         self,
@@ -156,7 +193,8 @@ class CoordinateDescent:
                     "resumed coordinate descent from checkpoint step %d", latest
                 )
         for name in seq:
-            scores[name] = self.coordinates[name].score(models[name])
+            with obs_span("cd.score", coordinate=name):
+                scores[name] = self.coordinates[name].score(models[name])
 
         objective_history: List[float] = []
         trackers: Dict[str, List[object]] = {name: [] for name in seq}
@@ -197,71 +235,110 @@ class CoordinateDescent:
                     step,
                 )
 
-        for it in range(start_iteration, num_iterations):
-            # obs/trace.py training span: one per CD iteration, with
-            # per-coordinate children below — host wall-clock only (the
-            # async dispatch window, not device time; --profile-dir
-            # carries the device side)
-            it_span = start_span("cd.iteration", iteration=it + 1)
-            # Fresh O(C) score sum once per iteration; inside the sweep the
-            # residual for each coordinate is total - own score (the
-            # KeyValueScore `-` of the reference) and the total is patched
-            # incrementally — O(1) adds per coordinate instead of the
-            # O(C^2) sum-of-others join chain.
-            total = jnp.zeros((self.dataset.num_rows,), jnp.float32)
-            for name in seq:
-                total = total + scores[name]
-            # Prefetched dispatch (overlap lever 3): coordinate k+1's
-            # host prep — bucket stacking/device transfer, layout builds,
-            # AOT warming — runs on the background worker UNDER coordinate
-            # k's device solves instead of as a serial gap between their
-            # dispatches. The worker only ever touches the coordinate
-            # being prefetched; the main thread wait()s before updating
-            # it, so cache mutations never race.
-            prefetched: Dict[str, object] = {}
-            for j, name in enumerate(seq):
-                coord = self.coordinates[name]
-                overlap.wait(prefetched.pop(name, None))
-                if overlap.overlap_enabled() and j + 1 < len(seq):
-                    nxt = seq[j + 1]
-                    if nxt != name and nxt not in prefetched:
-                        prefetched[nxt] = overlap.submit(
-                            self.coordinates[nxt].prepare, models[nxt]
-                        )
-                residual = total - scores[name] if len(seq) > 1 else None
-                with obs_span(
-                    "cd.update", parent_id=it_span.span_id,
-                    trace_id=it_span.trace_id, coordinate=name,
-                ):
-                    models[name], tracker = coord.update_model(
-                        models[name], residual
-                    )
-                    trackers[name].append(tracker)
-                    new_score = coord.score(models[name])
-                total = (
-                    residual + new_score
-                    if residual is not None
-                    else new_score
-                )
-                scores[name] = new_score
-            for fut in prefetched.values():  # surface prep failures
-                overlap.wait(fut)
-
-            # Deferred-readback discipline: the objective (loss + every
-            # reg term) and every coordinate's tracker stats come back in
-            # ONE batched device_get per iteration — not per-bucket, not
-            # per-coordinate (each pull is a synchronous round trip that
-            # stalls the dispatches queued behind it).
-            objective_d = self._objective_deferred(total, models)
-            overlap.fetch_all(
-                [objective_d]
-                + [
-                    getattr(trackers[name][-1], "deferred", None)
-                    for name in seq
-                ]
+        counters = {
+            what: default_registry().counter(
+                f"photon_optim_{what}_total",
+                f"optimizer {what} of the coordinate solves, by coordinate",
             )
-            objective = objective_d.result()
-            it_span.end(objective=objective)
+            for what in ("solves", "iterations", "evaluations")
+        }
+        for it in range(start_iteration, num_iterations):
+            # one span per CD iteration, its children below (cd.update ->
+            # fit.dispatch / bank.dispatch, cd.score, ...): host wall of
+            # the async dispatch, filed in the ring when tracing is on
+            # and written beside the device lines under --profile-dir
+            with obs_span("cd.iteration", iteration=it + 1) as it_span:
+                # Fresh O(C) score sum once per iteration; inside the sweep
+                # the residual for each coordinate is total - own score
+                # (the KeyValueScore `-` of the reference) and the total is
+                # patched incrementally — O(1) adds per coordinate instead
+                # of the O(C^2) sum-of-others join chain.
+                total = jnp.zeros((self.dataset.num_rows,), jnp.float32)
+                for name in seq:
+                    total = cd_total(total, scores[name])
+                # Prefetched dispatch (overlap lever 3): coordinate k+1's
+                # host prep — bucket stacking/device transfer, layout
+                # builds, AOT warming — runs on the background worker UNDER
+                # coordinate k's device solves instead of as a serial gap
+                # between their dispatches. The worker only ever touches
+                # the coordinate being prefetched; the main thread wait()s
+                # before updating it, so cache mutations never race.
+                prefetched: Dict[str, object] = {}
+                for j, name in enumerate(seq):
+                    coord = self.coordinates[name]
+                    if name in prefetched:
+                        with obs_span("cd.prefetch_wait", coordinate=name):
+                            overlap.wait(prefetched.pop(name))
+                    if overlap.overlap_enabled() and j + 1 < len(seq):
+                        nxt = seq[j + 1]
+                        if nxt != name and nxt not in prefetched:
+                            prefetched[nxt] = overlap.submit(
+                                self.coordinates[nxt].prepare, models[nxt]
+                            )
+                    residual = (
+                        cd_residual(total, scores[name])
+                        if len(seq) > 1 else None
+                    )
+                    with obs_span("cd.update", coordinate=name):
+                        models[name], tracker = coord.update_model(
+                            models[name], residual
+                        )
+                    trackers[name].append(tracker)
+                    with obs_span("cd.score", coordinate=name):
+                        new_score = coord.score(models[name])
+                    total = (
+                        cd_total(residual, new_score)
+                        if residual is not None
+                        else new_score
+                    )
+                    scores[name] = new_score
+                for fut in prefetched.values():  # surface prep failures
+                    overlap.wait(fut)
+
+                # Deferred-readback discipline: the objective (loss + every
+                # reg term), every coordinate's tracker stats and the
+                # optimizer counts of every solve come back in ONE batched
+                # device_get per iteration — not per-bucket, not
+                # per-coordinate (each pull is a synchronous round trip
+                # that stalls the dispatches queued behind it).
+                with obs_span("cd.objective"):
+                    objective_d = self._objective_deferred(total, models)
+                solved = {
+                    name: overlap.Deferred(
+                        (r.iterations, r.evaluations, r.reason)
+                    )
+                    for name, r in ((n, trackers[n][-1]) for n in seq)
+                    if isinstance(r, OptResult)
+                }
+                with obs_span("cd.readback"):
+                    overlap.fetch_all(
+                        [objective_d]
+                        + [
+                            getattr(trackers[name][-1], "deferred", None)
+                            for name in seq
+                        ]
+                        + list(solved.values())
+                    )
+                objective = objective_d.result()
+                it_span.set(objective=objective)
+                for name, fetched in solved.items():
+                    iterations, evaluations, reason = (
+                        int(v) for v in fetched.result()
+                    )
+                    it_span.set(**{
+                        f"{name}.iterations": iterations,
+                        f"{name}.evaluations": evaluations,
+                    })
+                    counters["solves"].inc(1, coordinate=name)
+                    counters["iterations"].inc(iterations, coordinate=name)
+                    counters["evaluations"].inc(
+                        evaluations, coordinate=name
+                    )
+                    self.logger.info(
+                        "coordinate %s: %d iterations, %d evaluations, %s",
+                        name, iterations, evaluations,
+                        CONVERGENCE_REASON_NAMES.get(reason, "?"),
+                    )
             objective_history.append(objective)
             self.logger.info(
                 "coordinate descent iter %d: objective=%g", it + 1, objective

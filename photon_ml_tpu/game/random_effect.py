@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
+from photon_ml_tpu.obs.trace import span as obs_span
+from photon_ml_tpu.obs.trace import traced as obs_traced
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.optim.common import (
     CONVERGENCE_REASON_NAMES,
@@ -173,8 +175,10 @@ def _bucket_solver(
             history=config.lbfgs_history,
         )
 
+    # the functions handed to jax.jit name the XLA modules: bank_sparse,
+    # bank_dense, bank_newton inside bank_fused / bank_fused_scan
     @jax.jit
-    def solve(bank, ix, v, lab, off, w, l1, l2):
+    def bank_sparse(bank, ix, v, lab, off, w, l1, l2):
         def one(coef0, ix_e, v_e, lab_e, off_e, w_e):
             vg_raw, hvp_raw = entity_objective(ix_e, v_e, lab_e, off_e, w_e)
 
@@ -197,11 +201,13 @@ def _bucket_solver(
         (measured 132 ms at E=20k, S=16, k=32, D=1000) while the VPU eats
         the k-reduction whole (33 ms, exact same result). XLA fuses the
         [E, S, k, D] broadcast; it is never materialized."""
-        d = jnp.arange(d_local, dtype=ix.dtype)
-        return jnp.sum(
-            v[..., :, None] * (ix[..., :, None] == d[None, None, None, :]),
-            axis=2,
-        )
+        with jax.named_scope("bank.densify"):
+            d = jnp.arange(d_local, dtype=ix.dtype)
+            return jnp.sum(
+                v[..., :, None]
+                * (ix[..., :, None] == d[None, None, None, :]),
+                axis=2,
+            )
 
     def _make_dense(identity):
         """DENSE per-entity layout: one compare-and-reduce densification
@@ -214,7 +220,7 @@ def _bucket_solver(
         view) and X IS values — no densify broadcast at all."""
 
         @jax.jit
-        def solve_dense(bank, ix, v, lab, off, w, l1, l2):
+        def bank_dense(bank, ix, v, lab, off, w, l1, l2):
             X = v if identity else _densify(ix, v, bank.shape[1])
 
             def one(coef0, X_e, lab_e, off_e, w_e):
@@ -236,11 +242,11 @@ def _bucket_solver(
             res = jax.vmap(one)(bank, X, lab, off, w)
             return res.coefficients, res.iterations, res.reason
 
-        return solve_dense
+        return bank_dense
 
     def _make_newton(identity):
         @jax.jit
-        def solve_dense_newton(bank, ix, v, lab, off, w, l1, l2):
+        def bank_newton(bank, ix, v, lab, off, w, l1, l2):
             """Damped Newton in the DUAL (sample) space — the TPU-first
             redesign of the per-entity solve.
 
@@ -268,7 +274,8 @@ def _bucket_solver(
             tol = config.tolerance
 
             def one(coef0, X_e, lab_e, off_e, w_e):
-                G = X_e @ X_e.T  # [S, S] sample Gram, one-time
+                with jax.named_scope("bank.newton.gram"):
+                    G = X_e @ X_e.T  # [S, S] sample Gram, one-time
 
                 def value(c, z):
                     return jnp.sum(w_e * loss.value(z, lab_e)) + 0.5 * l2 * jnp.vdot(c, c)
@@ -327,10 +334,11 @@ def _bucket_solver(
                         return x_c, r_c, p_c, rs2
 
                     y0 = jnp.zeros_like(rhs)
-                    y, _, _, _ = jax.lax.fori_loop(
-                        0, s_b, cg_body,
-                        (y0, rhs, rhs, jnp.vdot(rhs, rhs)),
-                    )
+                    with jax.named_scope("bank.newton.cg"):
+                        y, _, _, _ = jax.lax.fori_loop(
+                            0, s_b, cg_body,
+                            (y0, rhs, rhs, jnp.vdot(rhs, rhs)),
+                        )
                     t = dh * y
                     r = cd - t
                     step = -(X_e.T @ r) / l2 - c  # = -H^-1 g, ONE X pass
@@ -378,10 +386,12 @@ def _bucket_solver(
                         f_t = jnp.where(k < 16, f_t, jnp.inf)
                         return k, a, f_t, jnp.minimum(f_min, f_t)
 
-                    a0, f0_t = trial(jnp.int32(0))
-                    k, alpha, f_t, f_min = jax.lax.while_loop(
-                        ls_cond, ls_body, (jnp.int32(0), a0, f0_t, f0_t)
-                    )
+                    with jax.named_scope("bank.newton.line_search"):
+                        a0, f0_t = trial(jnp.int32(0))
+                        k, alpha, f_t, f_min = jax.lax.while_loop(
+                            ls_cond, ls_body,
+                            (jnp.int32(0), a0, f0_t, f0_t),
+                        )
                     # Strict decrease moves the iterate (monotone invariant);
                     # when NO trial decreases but the best trial was a float32
                     # near-tie, the entity is sitting on its optimum's noise
@@ -427,7 +437,7 @@ def _bucket_solver(
             coefs, iters, reasons = jax.vmap(one)(bank, X, lab, off, w)
             return coefs, iters, reasons
 
-        return solve_dense_newton
+        return bank_newton
 
     n_reasons = max(CONVERGENCE_REASON_NAMES) + 1
 
@@ -450,10 +460,11 @@ def _bucket_solver(
 
         # photon: sharding(axes=[], donates=[0])
         @partial(jax.jit, donate_argnums=donate)
-        def fused(bank_full, codes, ix, v, lab, off, w, l1, l2):
+        def bank_fused(bank_full, codes, ix, v, lab, off, w, l1, l2):
             sl = jnp.take(bank_full, codes, axis=0)
             new_sl, iters, reasons = core(sl, ix, v, lab, off, w, l1, l2)
-            bank_full = bank_full.at[codes].set(new_sl)
+            with jax.named_scope("bank.scatter_back"):
+                bank_full = bank_full.at[codes].set(new_sl)
             return (
                 bank_full,
                 jnp.sum(iters),
@@ -461,7 +472,7 @@ def _bucket_solver(
                 jnp.bincount(reasons, length=n_reasons),
             )
 
-        return fused
+        return bank_fused
 
     def _fused_scan(core):
         """The fused bucket update folded over a STACK of same-shape
@@ -477,13 +488,14 @@ def _bucket_solver(
 
         # photon: sharding(axes=[], donates=[0])
         @partial(jax.jit, donate_argnums=donate)
-        def fused_scan(bank_full, codes_s, ix_s, v_s, lab_s, off_s, w_s,
-                       l1, l2):
+        def bank_fused_scan(bank_full, codes_s, ix_s, v_s, lab_s, off_s,
+                            w_s, l1, l2):
             def body(bank, args):
                 codes, ix, v, lab, off, w = args
                 sl = jnp.take(bank, codes, axis=0)
                 new_sl, iters, reasons = core(sl, ix, v, lab, off, w, l1, l2)
-                bank = bank.at[codes].set(new_sl)
+                with jax.named_scope("bank.scatter_back"):
+                    bank = bank.at[codes].set(new_sl)
                 return bank, (
                     jnp.sum(iters),
                     jnp.max(iters),
@@ -500,7 +512,7 @@ def _bucket_solver(
                 jnp.sum(counts, axis=0),
             )
 
-        return fused_scan
+        return bank_fused_scan
 
     @jax.jit
     def hdiag(sl, ix, v, lab, off, w, l2):
@@ -527,17 +539,17 @@ def _bucket_solver(
     solve_newton = _make_newton(False)
     solve_newton_id = _make_newton(True)
     return SimpleNamespace(
-        sparse=solve,
+        sparse=bank_sparse,
         dense=solve_dense,
         dense_id=solve_dense_id,
         newton=solve_newton,
         newton_id=solve_newton_id,
-        fused_sparse=_fused(solve),
+        fused_sparse=_fused(bank_sparse),
         fused_dense=_fused(solve_dense),
         fused_dense_id=_fused(solve_dense_id),
         fused_newton=_fused(solve_newton),
         fused_newton_id=_fused(solve_newton_id),
-        fused_scan_sparse=_fused_scan(solve),
+        fused_scan_sparse=_fused_scan(bank_sparse),
         fused_scan_dense=_fused_scan(solve_dense),
         fused_scan_dense_id=_fused_scan(solve_dense_id),
         fused_scan_newton=_fused_scan(solve_newton),
@@ -975,7 +987,10 @@ class RandomEffectOptimizationProblem:
         ]
         if not fresh:
             return
-        with ThreadPoolExecutor(min(8, len(fresh))) as pool:
+        with (
+            obs_span("bank.warm_solvers", programs=len(fresh)),
+            ThreadPoolExecutor(min(8, len(fresh))) as pool,
+        ):
             compiled = list(pool.map(lambda item: item[1](), fresh))
         for (sig, _), exe in zip(fresh, compiled):
             # FIFO-bounded: the cache lives on the SHARED solver
@@ -986,6 +1001,7 @@ class RandomEffectOptimizationProblem:
                 self._aot_cache.pop(next(iter(self._aot_cache)))
             self._aot_cache[sig] = exe
 
+    @obs_traced("bank.update")
     def update_bank(
         self,
         bank: Array,  # [E, D]
@@ -1026,9 +1042,10 @@ class RandomEffectOptimizationProblem:
             # (in-place scatter per bucket) while the caller's reference
             # stays valid
             bank = jnp.array(bank, copy=True)
-        residual_offsets, routed, router = self._route_residuals(
-            dataset, residual_offsets
-        )
+        with obs_span("bank.route_residuals"):
+            residual_offsets, routed, router = self._route_residuals(
+                dataset, residual_offsets
+            )
         var_bank = jnp.zeros_like(bank) if with_variances else None
         if with_variances:
             from photon_ml_tpu.optim.problem import _VARIANCE_EPSILON
@@ -1071,12 +1088,18 @@ class RandomEffectOptimizationProblem:
                 fused_scan = self._aot_cache.get(
                     ("scan", kind, bank.shape, ix_s.shape)
                 ) or getattr(self._solvers, f"fused_scan_{kind}")
-                bank, it_sum, it_max, counts = fused_scan(
-                    bank, codes_s, ix_s, v_s, lab_s, off_s, w_s, l1_d, l2_d
+                n_group = sum(
+                    dataset.buckets[bi].num_entities for bi in members
                 )
-                n_reals.append(
-                    sum(dataset.buckets[bi].num_entities for bi in members)
-                )
+                with obs_span(
+                    "bank.dispatch", kind=kind, entities=n_group,
+                    capacity=dataset.buckets[members[0]].capacity,
+                ):
+                    bank, it_sum, it_max, counts = fused_scan(
+                        bank, codes_s, ix_s, v_s, lab_s, off_s, w_s,
+                        l1_d, l2_d,
+                    )
+                n_reals.append(n_group)
                 stat_vecs.append(
                     jnp.concatenate([jnp.stack([it_sum, it_max]), counts])
                 )
@@ -1103,6 +1126,10 @@ class RandomEffectOptimizationProblem:
                 )
             n_real = bucket.num_entities
             kind = self._bucket_kind(bucket, bank.shape[1])
+            dispatch_span = obs_span(
+                "bank.dispatch", kind=kind, entities=n_real,
+                capacity=bucket.capacity,
+            )
             if self.mesh is None:
                 # fused path: gather + solve + scatter + tracker reductions
                 # in one dispatch; AOT-warmed programs run their compiled
@@ -1110,18 +1137,21 @@ class RandomEffectOptimizationProblem:
                 fused = self._aot_cache.get(
                     (kind, bank.shape, bucket.indices.shape)
                 ) or getattr(self._solvers, f"fused_{kind}")
-                bank, it_sum, it_max, counts = fused(
-                    bank, codes_d, ix_d, v_d, lab_d, off_d, w_d, l1_d, l2_d
-                )
+                with dispatch_span:
+                    bank, it_sum, it_max, counts = fused(
+                        bank, codes_d, ix_d, v_d, lab_d, off_d, w_d,
+                        l1_d, l2_d,
+                    )
             else:
                 # padded entities carry zero data: their solve converges at
                 # iteration 0 on a zero gradient — inert and cheap
                 sl = bank[codes_d]
                 (sl,), _ = self._shard_entity_axis([sl])
                 solver = getattr(self._solvers, kind)
-                new_sl, iters, reasons = solver(
-                    sl, ix_d, v_d, lab_d, off_d, w_d, l1_d, l2_d
-                )
+                with dispatch_span:
+                    new_sl, iters, reasons = solver(
+                        sl, ix_d, v_d, lab_d, off_d, w_d, l1_d, l2_d
+                    )
                 new_sl = new_sl[:n_real]
                 iters = iters[:n_real]
                 reasons = reasons[:n_real]
@@ -1262,10 +1292,17 @@ def score_random_effect(
     features is equivalent to the reference's back-projected model scoring:
     features unseen in the entity's active data have zero coefficients,
     RandomEffectCoordinate.scala:178-199)."""
-    codes, valid, ix, v = device_row_view(dataset)
-    w_rows = jnp.take(bank, codes, axis=0)  # [n, D]
-    score = jnp.sum(v * jnp.take_along_axis(w_rows, ix, axis=1), axis=-1)
-    return jnp.where(valid, score, 0.0)
+    return re_score(bank, *device_row_view(dataset))
+
+
+@jax.jit
+def re_score(bank, codes, valid, ix, v):
+    """One named program (module ``re_score``, scope ``cd.score``) a
+    device trace can place; the [n, D] rows are never written."""
+    with jax.named_scope("cd.score"):
+        w_rows = jnp.take(bank, codes, axis=0)  # [n, D]
+        score = jnp.sum(v * jnp.take_along_axis(w_rows, ix, axis=1), axis=-1)
+        return jnp.where(valid, score, 0.0)
 
 
 def dryrun_entity_bank(mesh) -> None:

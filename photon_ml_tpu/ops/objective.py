@@ -74,26 +74,29 @@ class GLMObjective:
 
     def margins(self, coef: Array, batch: Batch) -> Array:
         """z_i = x_eff_i . w_eff + offset_i (normalized-space margin)."""
-        w_eff = self.norm.effective_coefficients(coef)
-        if isinstance(batch, SparseBatch):
-            raw = sparse_dot(batch, w_eff)
-        else:
-            raw = batch.features @ w_eff
-        return raw - self.norm.shift_dot(w_eff) + batch.offsets
+        with jax.named_scope("objective.margins"):
+            w_eff = self.norm.effective_coefficients(coef)
+            if isinstance(batch, SparseBatch):
+                raw = sparse_dot(batch, w_eff)
+            else:
+                raw = batch.features @ w_eff
+            return raw - self.norm.shift_dot(w_eff) + batch.offsets
 
     # -- scatter helpers ---------------------------------------------------
 
     def _weighted_feature_sum(self, batch: Batch, row_coef: Array) -> Array:
         """sum_i row_coef[i] * x_i  as a dense [dim] vector."""
-        if isinstance(batch, SparseBatch):
-            return sparse_scatter_add(batch, row_coef, self.dim)
-        return batch.features.T @ row_coef
+        with jax.named_scope("objective.gradient"):
+            if isinstance(batch, SparseBatch):
+                return sparse_scatter_add(batch, row_coef, self.dim)
+            return batch.features.T @ row_coef
 
     # -- value / gradient --------------------------------------------------
 
     def value(self, coef: Array, batch: Batch, l2_weight=0.0) -> Array:
         z = self.margins(coef, batch)
-        val = jnp.sum(batch.weights * self.loss.value(z, batch.labels))
+        with jax.named_scope("objective.loss"):
+            val = jnp.sum(batch.weights * self.loss.value(z, batch.labels))
         val = self._psum(val)
         return val + 0.5 * l2_weight * jnp.dot(coef, coef)
 
@@ -107,18 +110,20 @@ class GLMObjective:
         grad = factor * (vectorSum - shift * prefactorSum) + lambda * w.
         """
         z = self.margins(coef, batch)
-        lv = self.loss.value(z, batch.labels)
-        ld = self.loss.d1(z, batch.labels)
-        c = batch.weights * ld
-        value_sum = jnp.sum(batch.weights * lv)
+        with jax.named_scope("objective.loss"):
+            lv = self.loss.value(z, batch.labels)
+            ld = self.loss.d1(z, batch.labels)
+            c = batch.weights * ld
+            value_sum = jnp.sum(batch.weights * lv)
+            prefactor_sum = jnp.sum(c)
         vector_sum = self._weighted_feature_sum(batch, c)
-        prefactor_sum = jnp.sum(c)
         value_sum, vector_sum, prefactor_sum = self._psum(
             (value_sum, vector_sum, prefactor_sum)
         )
-        grad = self.norm.unshift_gradient(vector_sum, prefactor_sum)
-        value = value_sum + 0.5 * l2_weight * jnp.dot(coef, coef)
-        grad = grad + l2_weight * coef
+        with jax.named_scope("objective.gradient"):
+            grad = self.norm.unshift_gradient(vector_sum, prefactor_sum)
+            value = value_sum + 0.5 * l2_weight * jnp.dot(coef, coef)
+            grad = grad + l2_weight * coef
         return value, grad
 
     def gradient(self, coef: Array, batch: Batch, l2_weight=0.0) -> Array:

@@ -548,11 +548,18 @@ def _build_schedule(
     num_out_blocks: int,
     digest: Optional[str] = None,
 ) -> _Schedule:
-    return _Schedule(*map(jnp.asarray, _build_schedule_np(
-        rows, feats, vals, params=params,
-        sort_by_feature_block=sort_by_feature_block,
-        num_out_blocks=num_out_blocks, digest=digest,
-    )))
+    from photon_ml_tpu.obs.trace import span
+    from photon_ml_tpu.ops import schedule_cache as _sc
+
+    builds = _sc.stats().builds
+    with span("tiled.schedule_build", entries=int(len(vals))) as sp:
+        arrays = _build_schedule_np(
+            rows, feats, vals, params=params,
+            sort_by_feature_block=sort_by_feature_block,
+            num_out_blocks=num_out_blocks, digest=digest,
+        )
+        sp.set(cache="miss" if _sc.stats().builds > builds else "hit")
+        return _Schedule(*map(jnp.asarray, arrays))
 
 
 class TiledSparseBatch(NamedTuple):
@@ -988,6 +995,7 @@ def tiled_block_local_vg(loss, batch: FeatureShardedTiledBatch,
         z_partial = _bilinear_pass_auto(
             batch.z_sched, w2d, meta.rows_per_shard // win, p,
             interpret=interpret, mxu=mxu,
+            name=MARGIN_KERNEL,
         ).reshape(-1)
         z_partial = batch.z_sched.apply_spill(z_partial, w_eff)
         if shift is not None:
@@ -1001,6 +1009,7 @@ def tiled_block_local_vg(loss, batch: FeatureShardedTiledBatch,
         g_local = _bilinear_pass_auto(
             batch.g_sched, c2d, meta.block_dim // win, p,
             interpret=interpret, mxu=mxu,
+            name=GRADIENT_KERNEL,
         ).reshape(-1)
         g_local = batch.g_sched.apply_spill(g_local, c)
         grad_block = jax.lax.psum(g_local, data_axis)
@@ -1044,6 +1053,7 @@ def tiled_block_local_hvp_factory(
         part = _bilinear_pass_auto(
             batch.z_sched, x2d, meta.rows_per_shard // win, p,
             interpret=interpret, mxu=mxu,
+            name=MARGIN_KERNEL,
         ).reshape(-1)
         part = batch.z_sched.apply_spill(part, x_block)
         if shift is not None:
@@ -1064,6 +1074,7 @@ def tiled_block_local_hvp_factory(
             h_local = _bilinear_pass_auto(
                 batch.g_sched, c2d, meta.block_dim // win, p,
                 interpret=interpret, mxu=mxu,
+                name=GRADIENT_KERNEL,
             ).reshape(-1)
             h_local = batch.g_sched.apply_spill(h_local, c)
             h_block = jax.lax.psum(h_local, data_axis)
@@ -1102,6 +1113,7 @@ def tiled_block_local_hdiag(
         z_partial = _bilinear_pass_auto(
             batch.z_sched, w2d, meta.rows_per_shard // win, p,
             interpret=interpret, mxu=mxu,
+            name=MARGIN_KERNEL,
         ).reshape(-1)
         z_partial = batch.z_sched.apply_spill(z_partial, w_eff)
         if shift is not None:
@@ -1114,6 +1126,7 @@ def tiled_block_local_hdiag(
             out = _bilinear_pass_auto(
                 batch.g_sched, c2d, meta.block_dim // win, p,
                 vals=vals, interpret=interpret, mxu=mxu,
+                name=GRADIENT_KERNEL,
             ).reshape(-1)
             return batch.g_sched.apply_spill(out, c, vals=spill_vals)
 
@@ -1487,6 +1500,10 @@ def _bilinear_pass_kernel(
         out_ref[0] = out_ref[0] + update
 
 
+# The two directions of the bilinear pass, as the device trace names them.
+MARGIN_KERNEL = "photon_tiled_margin"  # rows <- coefficients
+GRADIENT_KERNEL = "photon_tiled_gradient"  # coefficients <- rows
+
 # Mosaic compiler-params experiment hook (None = defaults). Sweeps set
 # this to probe e.g. dimension_semantics / vmem_limit_bytes; production
 # leaves it None.
@@ -1499,6 +1516,8 @@ def _grid_bilinear_pass(
     num_out_blocks: int,
     params: TileParams,
     vals: Optional[Array] = None,
+    *,
+    name: str,
 ) -> Array:
     """Grid-batched schedule application: ONE fused data pass serves every
     grid member (the λ-grid batching lever, ISSUE 5 / Podracer-style
@@ -1522,18 +1541,20 @@ def _grid_bilinear_pass(
     win = params.window
     S = sched.num_steps
     G = src_bank.shape[0]
-    flat_in = (
-        sched.step_in[:, None] * win + sched.in_pos[:S]
-    ).reshape(-1)
-    flat_out = (
-        sched.step_out[:, None] * win + sched.out_pos[:S]
-    ).reshape(-1)
-    v = (sched.vals if vals is None else vals)[:S].reshape(-1)
-    src_flat = src_bank.reshape(G, -1).T  # [num_in_blocks * win, G]
-    contrib = v[:, None] * jnp.take(src_flat, flat_in, axis=0)
-    out = jnp.zeros((num_out_blocks * win, G), src_flat.dtype)
-    out = out.at[flat_out].add(contrib)
-    return out.T.reshape(G, num_out_blocks, params.s_hi, params.s_lo)
+    # the grid variant of the kernel's direction, under its own name
+    with jax.named_scope(name + "_grid"):
+        flat_in = (
+            sched.step_in[:, None] * win + sched.in_pos[:S]
+        ).reshape(-1)
+        flat_out = (
+            sched.step_out[:, None] * win + sched.out_pos[:S]
+        ).reshape(-1)
+        v = (sched.vals if vals is None else vals)[:S].reshape(-1)
+        src_flat = src_bank.reshape(G, -1).T  # [num_in_blocks * win, G]
+        contrib = v[:, None] * jnp.take(src_flat, flat_in, axis=0)
+        out = jnp.zeros((num_out_blocks * win, G), src_flat.dtype)
+        out = out.at[flat_out].add(contrib)
+        return out.T.reshape(G, num_out_blocks, params.s_hi, params.s_lo)
 
 
 def _bilinear_pass_auto(
@@ -1546,6 +1567,7 @@ def _bilinear_pass_auto(
     interpret: bool = False,
     mxu: str = "bf16x2w",
     onehot: str = "compare",
+    name: str,
 ) -> Array:
     """:func:`_run_bilinear_pass` that stays ``jax.vmap``-able.
 
@@ -1564,7 +1586,7 @@ def _bilinear_pass_auto(
     def run(sched_, src_, vals_):
         return _run_bilinear_pass(
             sched_, src_, num_out_blocks, params, vals=vals_,
-            interpret=interpret, mxu=mxu, onehot=onehot,
+            interpret=interpret, mxu=mxu, onehot=onehot, name=name,
         )
 
     @run.def_vmap
@@ -1582,7 +1604,8 @@ def _bilinear_pass_auto(
             )
         return (
             _grid_bilinear_pass(
-                sched_, src_, num_out_blocks, params, vals=vals_
+                sched_, src_, num_out_blocks, params, vals=vals_,
+                name=name,
             ),
             True,
         )
@@ -1600,8 +1623,11 @@ def _run_bilinear_pass(
     interpret: bool = False,
     mxu: str = "bf16x2w",
     onehot: str = "compare",
+    name: str,
 ) -> Array:
-    """-> [num_out_blocks, S_HI, S_LO] accumulated output."""
+    """-> [num_out_blocks, S_HI, S_LO] accumulated output. ``name`` is
+    the kernel's name on the device (the pass's direction: its callers
+    say ``photon_tiled_margin`` or ``photon_tiled_gradient``)."""
     G = sched.num_steps
     L = params.chunk
     if L % max(params.split, 1) != 0:
@@ -1646,6 +1672,7 @@ def _run_bilinear_pass(
         ),
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
+        name=name,
     )(*operands)
     return out
 
@@ -1705,8 +1732,10 @@ class TiledGLMObjective:
         raw = _bilinear_pass_auto(
             b.z_sched, w2d, b.num_row_blocks, p,
             interpret=self.interpret, mxu=self.mxu, onehot=self.onehot,
+            name=MARGIN_KERNEL,
         ).reshape(-1)
-        return b.z_sched.apply_spill(raw, w_padded)
+        with jax.named_scope("objective.spill"):
+            return b.z_sched.apply_spill(raw, w_padded)
 
     def _grad_pass(
         self, c_rows: Array, batch: TiledSparseBatch,
@@ -1719,16 +1748,19 @@ class TiledGLMObjective:
         g = _bilinear_pass_auto(
             b.g_sched, c2d, b.num_feat_blocks, p,
             vals=vals, interpret=self.interpret, mxu=self.mxu, onehot=self.onehot,
+            name=GRADIENT_KERNEL,
         ).reshape(-1)
-        return b.g_sched.apply_spill(g, c_rows, vals=spill_vals)
+        with jax.named_scope("objective.spill"):
+            return b.g_sched.apply_spill(g, c_rows, vals=spill_vals)
 
     # -- margins -----------------------------------------------------------
 
     def margins(self, coef: Array, batch: TiledSparseBatch) -> Array:
         """z_i = x_eff_i . w_eff + offset_i in padded row space."""
-        w_eff = self.norm.effective_coefficients(coef)
-        raw = self._z_pass(self._pad(w_eff, batch), batch)
-        return raw - self.norm.shift_dot(w_eff) + batch.offsets
+        with jax.named_scope("objective.margins"):
+            w_eff = self.norm.effective_coefficients(coef)
+            raw = self._z_pass(self._pad(w_eff, batch), batch)
+            return raw - self.norm.shift_dot(w_eff) + batch.offsets
 
     # -- value / gradient --------------------------------------------------
 
@@ -1743,18 +1775,21 @@ class TiledGLMObjective:
     ) -> Tuple[Array, Array]:
         d_in = coef.shape[0]
         z = self.margins(coef, batch)
-        lv = self.loss.value(z, batch.labels)
-        ld = self.loss.d1(z, batch.labels)
-        c = batch.weights * ld
-        value_sum = jnp.sum(batch.weights * lv)
-        vector_sum = self._grad_pass(c, batch)[:d_in]
-        prefactor_sum = jnp.sum(c)
+        with jax.named_scope("objective.loss"):
+            lv = self.loss.value(z, batch.labels)
+            ld = self.loss.d1(z, batch.labels)
+            c = batch.weights * ld
+            value_sum = jnp.sum(batch.weights * lv)
+            prefactor_sum = jnp.sum(c)
+        with jax.named_scope("objective.gradient"):
+            vector_sum = self._grad_pass(c, batch)[:d_in]
         value_sum, vector_sum, prefactor_sum = self._psum(
             (value_sum, vector_sum, prefactor_sum)
         )
-        grad = self.norm.unshift_gradient(vector_sum, prefactor_sum)
-        value = value_sum + 0.5 * l2_weight * jnp.dot(coef, coef)
-        return value, grad + l2_weight * coef
+        with jax.named_scope("objective.gradient"):
+            grad = self.norm.unshift_gradient(vector_sum, prefactor_sum)
+            value = value_sum + 0.5 * l2_weight * jnp.dot(coef, coef)
+            return value, grad + l2_weight * coef
 
     def gradient(self, coef: Array, batch: TiledSparseBatch, l2_weight=0.0) -> Array:
         return self.value_and_gradient(coef, batch, l2_weight)[1]

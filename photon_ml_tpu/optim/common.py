@@ -116,6 +116,10 @@ class OptResult(NamedTuple):
     iterations: Array  # int32
     reason: Array  # int32 convergence reason code
     tracker: Tracker
+    # int32: value+gradient calls the solve made, the one at w0 included
+    # (what the line search adds to ``iterations``); -1 = not counted (a
+    # result restored from a snapshot written before the count existed)
+    evaluations: Array = -1
 
     @property
     def reason_name(self) -> str:  # host-side convenience
@@ -153,12 +157,21 @@ def check_convergence(
 ValueAndGrad = Callable[[Array], Tuple[Array, Array]]
 
 
+def grid_member(result: OptResult, i: int) -> OptResult:
+    """Member ``i`` of a grid-batched result (every field carries a
+    leading grid axis)."""
+    import jax
+
+    return jax.tree.map(lambda a: a[i], result)
+
+
 class LineSearchResult(NamedTuple):
     step: Array
     w: Array
     f: Array
     g: Array
     ok: Array  # bool: sufficient decrease achieved
+    evaluations: Array  # int32: trial points evaluated (>= 1)
 
 
 def backtracking_line_search(
@@ -212,7 +225,7 @@ def backtracking_line_search(
         return (t_next, w_n, f_n, g_n, k + 1)
 
     w1, f1, g1 = trial(t0)
-    t, w_t, f_t, g_t, _ = lax.while_loop(
+    t, w_t, f_t, g_t, k = lax.while_loop(
         cond, body, (t0, w1, f1, g1, jnp.zeros((), jnp.int32))
     )
     ok = armijo_ok(w_t, f_t)
@@ -220,4 +233,6 @@ def backtracking_line_search(
     w_out = jnp.where(ok, w_t, w)
     f_out = jnp.where(ok, f_t, f)
     g_out = jnp.where(ok, g_t, g)
-    return LineSearchResult(step=t, w=w_out, f=f_out, g=g_out, ok=ok)
+    return LineSearchResult(
+        step=t, w=w_out, f=f_out, g=g_out, ok=ok, evaluations=k + 1
+    )

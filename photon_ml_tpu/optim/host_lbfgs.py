@@ -89,6 +89,7 @@ def minimize_lbfgs_host(
     if box is not None:
         w = box.project(w)
     f_dev, g = value_and_grad_fn(w)
+    evaluations = 1  # the one at w0
     # one batched fetch for the initial state's control scalars
     f, g0_norm = (
         float(v) for v in overlap.device_get((f_dev, jnp.linalg.norm(g)))
@@ -127,6 +128,7 @@ def minimize_lbfgs_host(
             if box is not None:
                 w_t = box.project(w_t)
             f_t, g_t = value_and_grad_fn(w_t)
+            evaluations += 1
             # one fetch per trial: the Armijo accept flag and the trial
             # value together (the decision is inherently sequential —
             # each trial's step size depends on the previous verdict)
@@ -176,6 +178,7 @@ def minimize_lbfgs_host(
         iterations=jnp.int32(it),
         reason=jnp.int32(reason),
         tracker=tracker,
+        evaluations=jnp.int32(evaluations),
     )
 
 
@@ -214,6 +217,7 @@ def minimize_owlqn_host(
         return f_smooth + jnp.sum(l1_vec * jnp.abs(w_t))
 
     f_s, g = value_and_grad_fn(w)
+    evaluations = 1  # the one at w0
     pg = _pseudo_gradient(w, g, l1_vec)
     # one batched fetch for the initial control scalars
     f_tot, g0_norm = (
@@ -263,6 +267,7 @@ def minimize_owlqn_host(
                 # minimize_owlqn)
                 w_t = box.project(w_t)
             f_t_s, g_t = value_and_grad_fn(w_t)
+            evaluations += 1
             f_t_tot_dev = total_dev(w_t, f_t_s)
             # Armijo on the projected point against the pseudo-gradient:
             # one fetch per trial (flag + total value together)
@@ -314,4 +319,5 @@ def minimize_owlqn_host(
         iterations=jnp.int32(it),
         reason=jnp.int32(reason),
         tracker=tracker,
+        evaluations=jnp.int32(evaluations),
     )

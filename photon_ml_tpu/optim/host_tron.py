@@ -113,6 +113,7 @@ def minimize_tron_host(
     if box is not None:
         w = box.project(w)
     f_dev, g = value_and_grad_fn(w)
+    evaluations = 1  # the one at w0
     # one batched fetch for the initial control scalars
     f, g0_norm = (
         float(v) for v in overlap.device_get((f_dev, jnp.linalg.norm(g)))
@@ -144,6 +145,7 @@ def minimize_tron_host(
             w_trial = box.project(w_trial)
             s = w_trial - w
         f_new_dev, g_new = value_and_grad_fn(w_trial)
+        evaluations += 1
         # the OUTER iteration's batch: every step/model control scalar
         # plus the device-computed convergence reason, in ONE fetch
         gs, s_r, f_new, snorm, g_norm_new, projected_any, reason_new = (
@@ -210,4 +212,5 @@ def minimize_tron_host(
         iterations=jnp.int32(it),
         reason=jnp.int32(reason),
         tracker=tracker,
+        evaluations=jnp.int32(evaluations),
     )

@@ -139,6 +139,7 @@ class _LoopState(NamedTuple):
     iteration: Array
     reason: Array
     tracker: Tracker
+    evaluations: Array  # int32 value+gradient calls so far
 
 
 def minimize_lbfgs(
@@ -181,20 +182,23 @@ def minimize_lbfgs(
         return st.reason == NOT_CONVERGED
 
     def body(st: _LoopState):
-        d = _two_loop_direction(st.g, st.mem, vdot)
-        # Fall back to steepest descent if d is not a descent direction.
-        descent = vdot(d, st.g) < 0
-        d = jnp.where(descent, d, -st.g)
-        t0 = jnp.where(
-            st.mem.length > 0,
-            jnp.ones((), st.f.dtype),
-            1.0 / jnp.maximum(norm(d), 1.0),
-        )
-        ls = backtracking_line_search(
-            value_and_grad_fn, st.w, st.f, st.g, d, t0,
-            max_steps=ls_max_steps, project=project, vdot=vdot,
-        )
-        mem = _update_memory(st.mem, ls.w - st.w, ls.g - st.g, vdot)
+        with jax.named_scope("lbfgs.direction"):
+            d = _two_loop_direction(st.g, st.mem, vdot)
+            # Fall back to steepest descent if d is not a descent direction.
+            descent = vdot(d, st.g) < 0
+            d = jnp.where(descent, d, -st.g)
+            t0 = jnp.where(
+                st.mem.length > 0,
+                jnp.ones((), st.f.dtype),
+                1.0 / jnp.maximum(norm(d), 1.0),
+            )
+        with jax.named_scope("lbfgs.line_search"):
+            ls = backtracking_line_search(
+                value_and_grad_fn, st.w, st.f, st.g, d, t0,
+                max_steps=ls_max_steps, project=project, vdot=vdot,
+            )
+        with jax.named_scope("lbfgs.memory"):
+            mem = _update_memory(st.mem, ls.w - st.w, ls.g - st.g, vdot)
         it = st.iteration + 1
         g_norm = norm(ls.g)
         # A failed line search means no further progress is possible; check
@@ -212,6 +216,7 @@ def minimize_lbfgs(
             tracker=st.tracker.record(
                 ls.f, g_norm, ls.w if track_coefficients else None
             ),
+            evaluations=st.evaluations + ls.evaluations,
         )
 
     init = _LoopState(
@@ -227,6 +232,7 @@ def minimize_lbfgs(
             max_iter + 1, w0.dtype,
             coef_dim=w0.shape[0] if track_coefficients else None,
         ).record(f0, g0_norm, w0 if track_coefficients else None),
+        evaluations=jnp.ones((), jnp.int32),  # the one at w0
     )
     final = lax.while_loop(cond, body, init)
     return OptResult(
@@ -236,6 +242,7 @@ def minimize_lbfgs(
         iterations=final.iteration,
         reason=final.reason,
         tracker=final.tracker,
+        evaluations=final.evaluations,
     )
 
 
@@ -299,11 +306,13 @@ def minimize_owlqn(
         return st.reason == NOT_CONVERGED
 
     def body(st: _LoopState):
-        pg = _pseudo_gradient(st.w, st.g, l1_vec)
-        d = _two_loop_direction(pg, st.mem, vdot)
-        # Constrain direction to the descent orthant of the pseudo-gradient.
-        d = jnp.where(d * pg < 0, d, 0.0)
-        orthant = jnp.where(st.w != 0, jnp.sign(st.w), jnp.sign(-pg))
+        with jax.named_scope("lbfgs.direction"):
+            pg = _pseudo_gradient(st.w, st.g, l1_vec)
+            d = _two_loop_direction(pg, st.mem, vdot)
+            # Constrain direction to the descent orthant of the
+            # pseudo-gradient.
+            d = jnp.where(d * pg < 0, d, 0.0)
+            orthant = jnp.where(st.w != 0, jnp.sign(st.w), jnp.sign(-pg))
 
         def project_orthant(w_t):
             w_t = jnp.where(jnp.sign(w_t) == orthant, w_t, 0.0)
@@ -319,13 +328,15 @@ def minimize_owlqn(
             jnp.ones((), st.f.dtype),
             1.0 / jnp.maximum(norm(d), 1.0),
         )
-        ls = backtracking_line_search(
-            vg_total, st.w, f_cur_total, pg, d, t0,
-            max_steps=ls_max_steps, project=project_orthant, vdot=vdot,
-        )
+        with jax.named_scope("lbfgs.line_search"):
+            ls = backtracking_line_search(
+                vg_total, st.w, f_cur_total, pg, d, t0,
+                max_steps=ls_max_steps, project=project_orthant, vdot=vdot,
+            )
         # ls.f is the total value; recover smooth value for state/memory.
         f_smooth_new = ls.f - vsum(l1_vec * jnp.abs(ls.w))
-        mem = _update_memory(st.mem, ls.w - st.w, ls.g - st.g, vdot)
+        with jax.named_scope("lbfgs.memory"):
+            mem = _update_memory(st.mem, ls.w - st.w, ls.g - st.g, vdot)
         it = st.iteration + 1
         pg_new = _pseudo_gradient(ls.w, ls.g, l1_vec)
         pg_norm = norm(pg_new)
@@ -343,6 +354,7 @@ def minimize_owlqn(
             reason=reason, tracker=st.tracker.record(
                 ls.f, pg_norm, ls.w if track_coefficients else None
             ),
+            evaluations=st.evaluations + ls.evaluations,
         )
 
     init = _LoopState(
@@ -358,6 +370,7 @@ def minimize_owlqn(
             max_iter + 1, w0.dtype,
             coef_dim=w0.shape[0] if track_coefficients else None,
         ).record(f0, g0_norm, w0 if track_coefficients else None),
+        evaluations=jnp.ones((), jnp.int32),  # the one at w0
     )
     final = lax.while_loop(cond, body, init)
     pg_final = _pseudo_gradient(final.w, final.g, l1_vec)
@@ -368,4 +381,5 @@ def minimize_owlqn(
         iterations=final.iteration,
         reason=final.reason,
         tracker=final.tracker,
+        evaluations=final.evaluations,
     )

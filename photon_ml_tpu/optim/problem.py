@@ -87,9 +87,9 @@ class GLMOptimizationProblem:
     # photon: entropy(id(mesh)-keyed jit-program memo; in-memory only)
     def _get_fit(self, track_models: bool, mesh=None, axis: str = "",
                  grid: bool = False, with_offsets: bool = False):
-        """Jitted fit program (optionally shard_mapped over ``mesh``),
-        cached so repeat `run`/`run_grid` calls skip re-tracing the
-        optimizer while_loop.
+        """``(fit, cache_hit)``: the jitted fit program (optionally
+        shard_mapped over ``mesh``), cached so repeat `run`/`run_grid`
+        calls skip re-tracing the optimizer while_loop.
 
         Tracing the L-BFGS while_loop over the tiled objective costs
         seconds of host time (the schedules are ~16.7M-entry pytrees);
@@ -149,7 +149,7 @@ class GLMOptimizationProblem:
             )
         hit = cache.get(key)
         if hit is not None:
-            return hit[0]
+            return hit[0], True
         optimize = make_optimizer(
             self.config,
             self.regularization,
@@ -211,12 +211,14 @@ class GLMOptimizationProblem:
                 out_specs=P(),
                 check_vma=False,
             )(fit)
+        # the name of the function handed to jax.jit names the XLA module
+        fit.__name__ = "glm_fit_grid" if grid else "glm_fit"
         fit = jax.jit(fit)
 
         while len(cache) >= _FIT_CACHE_MAX:
             cache.pop(next(iter(cache)))
         cache[key] = (fit, mesh)
-        return fit
+        return fit, False
 
     # photon: entropy(id(mesh)-keyed jit-program memo; in-memory only)
     def _get_hdiag(self, mesh=None, axis: str = "", grid: bool = False,
@@ -360,7 +362,7 @@ class GLMOptimizationProblem:
                 batch, SparseBatch
             ):
                 batch = ensure_tiled(batch, self.objective.dim)
-            fit = self._get_fit(
+            fit, _ = self._get_fit(
                 track_models, grid=True, with_offsets=with_offsets
             )
             extras = (
@@ -388,7 +390,7 @@ class GLMOptimizationProblem:
             sharded = ensure_tiled_sharded(batch, self.objective.dim, mesh, axis)
         else:
             sharded = ensure_data_sharded(batch, mesh, axis)
-        fit = self._get_fit(
+        fit, _ = self._get_fit(
             track_models, mesh=mesh, axis=axis, grid=True,
             with_offsets=with_offsets,
         )
@@ -435,56 +437,57 @@ class GLMOptimizationProblem:
         reductions riding ICI instead of one cluster round-trip per Breeze
         evaluation.
         """
-        w0 = (
-            jnp.zeros((self.objective.dim,), jnp.float32)
-            if initial is None
-            else jnp.asarray(initial)
+        from photon_ml_tpu.obs.trace import span
+        from photon_ml_tpu.ops.tiled_sparse import (
+            TiledGLMObjective,
+            ensure_tiled,
+            ensure_tiled_sharded,
         )
+
+        tiled = isinstance(self.objective, TiledGLMObjective)
         l1, l2 = self.regularization.split(reg_weight)
-
-        if mesh is None:
-            from photon_ml_tpu.data.batch import SparseBatch
-            from photon_ml_tpu.ops.tiled_sparse import (
-                TiledGLMObjective,
-                ensure_tiled,
+        axis = _row_axis(mesh) if mesh is not None else ""
+        with span("fit.prepare"):
+            w0 = (
+                jnp.zeros((self.objective.dim,), jnp.float32)
+                if initial is None
+                else jnp.asarray(initial)
             )
+            if mesh is None:
+                from photon_ml_tpu.data.batch import SparseBatch
 
-            if isinstance(self.objective, TiledGLMObjective) and isinstance(
-                batch, SparseBatch
-            ):
-                # identity-cached conversion: a CD loop re-wrapping the
-                # same columns with fresh offsets reuses the schedules
-                batch = ensure_tiled(batch, self.objective.dim)
-            fit = self._get_fit(track_models)
-            result = fit(w0, batch, jnp.float32(l1), jnp.float32(l2))
-            variances = None
-            if self.compute_variances:
-                hdiag = self.objective.hessian_diagonal(
-                    result.coefficients, batch, l2
+                if tiled and isinstance(batch, SparseBatch):
+                    # identity-cached conversion: a CD loop re-wrapping the
+                    # same columns with fresh offsets reuses the schedules
+                    batch = ensure_tiled(batch, self.objective.dim)
+            elif tiled:
+                # fast kernel AND mesh together: per-shard tiled schedules
+                # (ValueAndGradientAggregator.scala:235-250 runs distributed
+                # at full speed; so do we — no scatter fallback)
+                batch = ensure_tiled_sharded(
+                    batch, self.objective.dim, mesh, axis
                 )
-                variances = 1.0 / (hdiag + _VARIANCE_EPSILON)
-            return Coefficients(result.coefficients, variances), result
+            else:
+                from photon_ml_tpu.parallel.mesh import ensure_data_sharded
 
-        from photon_ml_tpu.parallel.mesh import ensure_data_sharded
-
-        axis = _row_axis(mesh)
-        from photon_ml_tpu.ops.tiled_sparse import TiledGLMObjective, ensure_tiled_sharded
-
-        if isinstance(self.objective, TiledGLMObjective):
-            # fast kernel AND mesh together: per-shard tiled schedules
-            # (ValueAndGradientAggregator.scala:235-250 runs distributed at
-            # full speed; so do we — no scatter fallback)
-            sharded = ensure_tiled_sharded(batch, self.objective.dim, mesh, axis)
-        else:
-            sharded = ensure_data_sharded(batch, mesh, axis)
-        _fit = self._get_fit(track_models, mesh=mesh, axis=axis)
-        result = _fit(w0, sharded, jnp.float32(l1), jnp.float32(l2))
+                batch = ensure_data_sharded(batch, mesh, axis)
+        with span(
+            "fit.dispatch", kernel="tiled" if tiled else "scatter"
+        ) as sp:
+            fit, hit = self._get_fit(track_models, mesh=mesh, axis=axis)
+            sp.set(fit_cache_hit=hit)
+            result = fit(w0, batch, jnp.float32(l1), jnp.float32(l2))
 
         variances = None
         if self.compute_variances:
-            hdiag = self._get_hdiag(mesh=mesh, axis=axis)(
-                result.coefficients, sharded, jnp.float32(l2)
-            )
+            if mesh is None:
+                hdiag = self.objective.hessian_diagonal(
+                    result.coefficients, batch, l2
+                )
+            else:
+                hdiag = self._get_hdiag(mesh=mesh, axis=axis)(
+                    result.coefficients, batch, jnp.float32(l2)
+                )
             variances = 1.0 / (hdiag + _VARIANCE_EPSILON)
         return Coefficients(result.coefficients, variances), result
 

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 from typing import Callable, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -120,6 +121,7 @@ class _TronState(NamedTuple):
     reason: Array
     failures: Array  # consecutive improvement failures
     tracker: Tracker
+    evaluations: Array  # int32 value+gradient calls so far
 
 
 def minimize_tron(
@@ -175,15 +177,17 @@ def minimize_tron(
             if hvp_factory is not None
             else (lambda d: hvp_fn(st.w, d))
         )
-        s, r = _truncated_cg(
-            hvp_local, st.g, st.delta, max_cg=max_cg,
-            vdot=vdot, norm=norm,
-        )
+        with jax.named_scope("tron.cg"):
+            s, r = _truncated_cg(
+                hvp_local, st.g, st.delta, max_cg=max_cg,
+                vdot=vdot, norm=norm,
+            )
         w_trial = st.w + s
         if box is not None:
             w_trial = box.project(w_trial)
             s = w_trial - st.w
-        f_new, g_new = value_and_grad_fn(w_trial)
+        with jax.named_scope("tron.step"):
+            f_new, g_new = value_and_grad_fn(w_trial)
         gs = vdot(st.g, s)
         # r = -g - H s from CG, so s.Hs = -s.(g + r) and
         # prered = -(g.s + 0.5 s.Hs) = -0.5 (g.s - s.r).
@@ -236,6 +240,7 @@ def minimize_tron(
             failures=failures, tracker=st.tracker.record(
                 f2, g_norm, w2 if track_coefficients else None
             ),
+            evaluations=st.evaluations + 1,
         )
 
     init = _TronState(
@@ -252,6 +257,7 @@ def minimize_tron(
             max_iter + 1, w0.dtype,
             coef_dim=w0.shape[0] if track_coefficients else None,
         ).record(f0, g0_norm, w0 if track_coefficients else None),
+        evaluations=jnp.ones((), jnp.int32),  # the one at w0
     )
     final = lax.while_loop(cond, body, init)
     return OptResult(
@@ -261,4 +267,5 @@ def minimize_tron(
         iterations=final.iteration,
         reason=final.reason,
         tracker=final.tracker,
+        evaluations=final.evaluations,
     )

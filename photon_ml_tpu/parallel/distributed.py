@@ -158,6 +158,7 @@ def _opt_result_specs(model_axis: str, track_models: bool = False) -> OptResult:
             values=P(), grad_norms=P(), count=P(),
             coefs=P(None, model_axis) if track_models else None,
         ),
+        evaluations=P(),
     )
 
 
@@ -180,6 +181,7 @@ def _opt_result_grid_specs(
             values=P(), grad_norms=P(), count=P(),
             coefs=P(None, None, model_axis) if track_models else None,
         ),
+        evaluations=P(),
     )
 
 

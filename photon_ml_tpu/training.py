@@ -19,7 +19,7 @@ from photon_ml_tpu.data.batch import Batch
 from photon_ml_tpu.models.glm import GeneralizedLinearModel
 from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.ops.normalization import NormalizationContext
-from photon_ml_tpu.optim.common import BoxConstraints, OptResult
+from photon_ml_tpu.optim.common import BoxConstraints, OptResult, grid_member
 from photon_ml_tpu.optim.config import (
     OptimizerConfig,
     OptimizerType,
@@ -28,6 +28,7 @@ from photon_ml_tpu.optim.config import (
 )
 from photon_ml_tpu.optim.problem import create_glm_problem, resolve_kernel
 from photon_ml_tpu.task import TaskType
+from photon_ml_tpu.utils import profiling  # noqa: F401  obs spans -> profiler
 
 Array = jnp.ndarray
 
@@ -51,6 +52,7 @@ def _snapshot_result_arrays(result: OptResult) -> Dict[str, object]:
         "grad_norm": np.asarray(result.grad_norm),
         "iterations": np.asarray(result.iterations),
         "reason": np.asarray(result.reason),
+        "evaluations": np.asarray(result.evaluations),
         "tracker_values": np.asarray(t.values),
         "tracker_grad_norms": np.asarray(t.grad_norms),
         "tracker_count": np.asarray(t.count),
@@ -76,6 +78,9 @@ def _result_from_snapshot(d: Dict[str, object]) -> OptResult:
             count=jnp.asarray(d["tracker_count"]),
             coefs=jnp.asarray(coefs) if coefs is not None else None,
         ),
+        # a snapshot written before the count existed restores as
+        # "not counted", not as an error
+        evaluations=jnp.asarray(d.get("evaluations", -1), jnp.int32),
     )
 
 
@@ -423,8 +428,6 @@ def train_grid_batched(
     ({lambda: model}, {lambda: OptResult}) contract as the sequential
     trainer; result scalars stay device-resident for the batched fetch.
     """
-    from photon_ml_tpu.optim.common import Tracker
-
     base = OptimizerConfig.default_for(optimizer_type)
     config = OptimizerConfig(
         optimizer_type=optimizer_type,
@@ -511,22 +514,7 @@ def train_grid_batched(
         var_i = variances[i] if variances is not None else None
         coefficients = Coefficients(result.coefficients[i], var_i)
         models[lam] = problem.create_model(coefficients, normalization)
-        tracker = result.tracker
-        results[lam] = OptResult(
-            coefficients=result.coefficients[i],
-            value=result.value[i],
-            grad_norm=result.grad_norm[i],
-            iterations=result.iterations[i],
-            reason=result.reason[i],
-            tracker=Tracker(
-                values=tracker.values[i],
-                grad_norms=tracker.grad_norms[i],
-                count=tracker.count[i],
-                coefs=(
-                    tracker.coefs[i] if tracker.coefs is not None else None
-                ),
-            ),
-        )
+        results[lam] = grid_member(result, i)
         if grid_checkpointer is not None:
             _save_lambda_snapshot(
                 grid_checkpointer, lam, result.coefficients[i],
@@ -753,7 +741,6 @@ def train_grid_batched_feature_sharded(
     from photon_ml_tpu.models.glm import create_model
     from photon_ml_tpu.ops.losses import loss_for_task
     from photon_ml_tpu.ops.objective import GLMObjective
-    from photon_ml_tpu.optim.common import Tracker
     from photon_ml_tpu.optim.factory import validate_optimizer_choice
     from photon_ml_tpu.parallel.distributed import (
         feature_shard_sparse_batch,
@@ -862,16 +849,10 @@ def train_grid_batched_feature_sharded(
             task,
             Coefficients(_to_original_space(coefs_pad[:dim]), variances),
         )
-        results[lam] = OptResult(
+        member = grid_member(result, i)
+        results[lam] = member._replace(
             coefficients=coefs_pad[:dim],
-            value=result.value[i],
-            grad_norm=result.grad_norm[i],
-            iterations=result.iterations[i],
-            reason=result.reason[i],
-            tracker=Tracker(
-                values=tracker.values[i],
-                grad_norms=tracker.grad_norms[i],
-                count=tracker.count[i],
+            tracker=member.tracker._replace(
                 coefs=(
                     tracker.coefs[i][:, :dim]
                     if tracker.coefs is not None else None
@@ -1297,9 +1278,11 @@ def train_streaming_feature_sharded(
 
 def grid_result_scalars(
     results: Dict[float, OptResult],
-) -> Dict[float, Tuple[int, float, int]]:
-    """{lambda: (iterations, value, reason)} with ONE batched readback
-    for the whole grid (parallel/overlap deferred-readback discipline).
+) -> Dict[float, Tuple[int, float, int, int]]:
+    """{lambda: (iterations, value, reason, evaluations)} with ONE
+    batched readback for the whole grid (parallel/overlap
+    deferred-readback discipline); ``evaluations`` is -1 for a result
+    restored from a snapshot that did not count them.
 
     Every OptResult's scalars are device-resident futures until someone
     forces them; the pre-overlap consumers pulled three scalars per
@@ -1309,11 +1292,14 @@ def grid_result_scalars(
 
     items = list(results.items())
     fetched = overlap.device_get(
-        [(res.iterations, res.value, res.reason) for _, res in items]
+        [
+            (res.iterations, res.value, res.reason, res.evaluations)
+            for _, res in items
+        ]
     )
     return {
-        lam: (int(it), float(value), int(reason))
-        for (lam, _), (it, value, reason) in zip(items, fetched)
+        lam: (int(it), float(value), int(reason), int(evaluations))
+        for (lam, _), (it, value, reason, evaluations) in zip(items, fetched)
     }
 
 

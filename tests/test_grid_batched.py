@@ -284,8 +284,9 @@ class TestCompileAndReadbackContract:
         scalars = training.grid_result_scalars(results)
         assert overlap.readback_stats() == 1
         assert set(scalars) == set(LAMBDAS)
-        for lam, (iters, value, reason) in scalars.items():
+        for lam, (iters, value, reason, evaluations) in scalars.items():
             assert iters >= 1 and np.isfinite(value) and reason != 0
+            assert evaluations >= iters + 1  # the one at w0 included
 
 
 class TestGridModePolicy:
