@@ -15,7 +15,7 @@ import argparse
 import hashlib
 import json
 import os
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from itertools import product
 from typing import Dict, List, Optional, Sequence
 
@@ -43,7 +43,7 @@ from photon_ml_tpu.game.random_effect_data import build_random_effect_dataset
 from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.optim.config import GLMOptimizationConfiguration
-from photon_ml_tpu.optim.problem import create_glm_problem
+from photon_ml_tpu.optim.problem import create_glm_problem, resolve_kernel
 from photon_ml_tpu.task import TaskType
 from photon_ml_tpu.utils.backend import enable_compilation_cache
 from photon_ml_tpu.utils.logging_util import PhotonLogger, Timer
@@ -148,6 +148,15 @@ def expand_config_grid(
         ]
         alternatives.append(opts)
     return [dict(zip(names, combo)) for combo in product(*alternatives)]
+
+
+# The MXU variant of the tiled kernel under the driver's fixed effect. Every
+# other tiled user takes the objective's default, "bf16x2w"; here the
+# solve's reported value has to agree with a float32 evaluation at the same
+# coefficients to 5e-7 (the cd cell's `fixed_value_gap`), and "bf16x2w"
+# read 5.06e-7 and 8.09e-7 on two of three row orders at 524,288 rows x 65
+# (PERF.md section 6, PR 28). Same kernel, 3.3x the time a pass there.
+_FIXED_EFFECT_MXU = "highest"
 
 
 @dataclass
@@ -576,26 +585,47 @@ class GameTrainingDriver:
         dataset: GameDataset,
         re_datasets,
         opt_combo: Dict[str, GLMOptimizationConfiguration],
+        fe_kernel: str = "auto",
     ):
+        """One coordinate per configured effect. A fixed effect's objective
+        is resolved as ``glm_driver`` resolves its own: the tiled Pallas
+        pair on a TPU, the scatter objective elsewhere. The feature-sharded
+        (data, model) mesh keeps the scatter layout until its tiled one has
+        run on the chip through this driver (PERF.md section 7), and
+        ``fe_kernel`` is the batched lambda grid's seam to do the same."""
+        from photon_ml_tpu.parallel.mesh import MODEL_AXIS
+
         p = self.params
         mesh = self._mesh()
         fe_mesh = self._fe_mesh()
         pod_mesh = self._entity_mesh()
+        if MODEL_AXIS in getattr(fe_mesh, "axis_names", ()):
+            fe_kernel = "scatter"
         coords = {}
         for name, dcfg in p.fixed_effect_data_configs.items():
             ocfg = opt_combo[name]
-            dim = dataset.shards[dcfg.feature_shard_id].dim
+            shard = dataset.shards[dcfg.feature_shard_id]
+            kernel = resolve_kernel(
+                fe_kernel, dataset.batch_for_shard(dcfg.feature_shard_id)
+            )
+            problem = create_glm_problem(
+                p.task_type,
+                shard.dim,
+                config=ocfg.optimizer_config,
+                regularization=ocfg.regularization,
+                compute_variances=p.compute_variance,
+                intercept_index=shard.intercept_index,
+                kernel=kernel,
+            )
+            if kernel == "tiled":
+                problem = replace(
+                    problem,
+                    objective=replace(problem.objective, mxu=_FIXED_EFFECT_MXU),
+                )
             coords[name] = FixedEffectCoordinate(
                 name=name,
                 dataset=dataset,
-                problem=create_glm_problem(
-                    p.task_type,
-                    dim,
-                    config=ocfg.optimizer_config,
-                    regularization=ocfg.regularization,
-                    compute_variances=p.compute_variance,
-                    intercept_index=dataset.shards[dcfg.feature_shard_id].intercept_index,
-                ),
+                problem=problem,
                 feature_shard_id=dcfg.feature_shard_id,
                 reg_weight=ocfg.reg_weight,
                 down_sampling_rate=ocfg.down_sampling_rate,
@@ -753,7 +783,11 @@ class GameTrainingDriver:
             "program; no cross-combo warm starts)", len(combos),
         )
         with self.timer.time("train-fe-grid-batched"):
-            coords = self._build_coordinates(dataset, re_datasets, combos[0])
+            # under vmap the tiled objective falls to _grid_bilinear_pass,
+            # the slow pass of ROADMAP S4: the grid keeps the scatter one
+            coords = self._build_coordinates(
+                dataset, re_datasets, combos[0], fe_kernel="scatter"
+            )
             coord = coords[name]
             fitted = coord.update_model_grid(lambdas)
 

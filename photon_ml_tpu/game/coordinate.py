@@ -99,6 +99,13 @@ class FixedEffectCoordinate(Coordinate):
     built once and reused across CD iterations — only the row vectors a
     sweep changes (offsets, the residual currency, and the down-sampling
     draw's weights) are re-placed per update.
+
+    A problem built on the tiled objective (``kernel == "tiled"``: what
+    the GAME driver resolves on a TPU) follows the same rule on one
+    device and on the data-parallel mesh: the coordinate holds the shard
+    as a ``TiledSparseBatch``, its schedules built once (in ``prepare()``
+    or the first update), and every update swaps in the row vectors on
+    the device.
     """
 
     name: str
@@ -136,14 +143,110 @@ class FixedEffectCoordinate(Coordinate):
             and MODEL_AXIS in getattr(self.mesh, "axis_names", ())
         )
 
+    @property
+    def kernel(self) -> str:
+        """Which objective the solves run, "tiled" | "scatter": what
+        ``fit.dispatch`` reports, for ``cd.update`` and the CD log line."""
+        from photon_ml_tpu.ops.tiled_sparse import TiledGLMObjective
+
+        tiled = isinstance(self.problem.objective, TiledGLMObjective)
+        return "tiled" if tiled else "scatter"
+
+    def _tiled_base(self):
+        """The shard as a ``TiledSparseBatch`` (mesh layout under a data
+        mesh), held on the coordinate: a driver with more fixed-effect
+        shards than ``ensure_tiled``'s LRU has entries must not rebuild
+        its schedules every sweep. The LRU behind it is keyed on the
+        dataset's cached device columns, so the coordinates a combo grid
+        builds afresh share one build."""
+        base = self.__dict__.get("_tiled")
+        if base is None:
+            from photon_ml_tpu.ops.tiled_sparse import (
+                bucket_spill,
+                ensure_tiled,
+                ensure_tiled_sharded,
+            )
+            from photon_ml_tpu.optim.problem import _row_axis
+
+            sparse = self.dataset.batch_for_shard(self.feature_shard_id)
+            dim = self.problem.objective.dim
+            if self.mesh is None:
+                # (bucketed: a refit on the same rows in another order
+                # finds its compiled solve in the persistent cache)
+                base = bucket_spill(ensure_tiled(sparse, dim))
+            else:
+                base = ensure_tiled_sharded(
+                    sparse, dim, self.mesh, _row_axis(self.mesh)
+                )
+            self.__dict__["_tiled"] = base
+        return base
+
+    def _refresh_rows(self, cached, row_sharding, residual):
+        """``cached`` (a tiled or feature-sharded layout) with this
+        update's row vectors: offsets, the residual currency, and, when
+        down-sampling, the draw's weights, padded to the layout's rows and
+        placed ON THE DEVICE (the residual never visits the host). The
+        schedules and entry routing only depend on indices, values and the
+        BUILD-time weight mask; a sampled weight only ever ZEROES a row
+        that was live at build time (inert through c = w * l'(z)), never
+        revives a built-out one, so the cached layout stays exact under
+        every draw."""
+        from photon_ml_tpu.ops.tiled_sparse import pad_row_vector
+
+        rows_total = cached.labels.shape[0]
+
+        def _place_rows(vec):
+            vec = pad_row_vector(vec, rows_total)
+            if row_sharding is None:
+                return vec
+            return jax.device_put(vec, row_sharding)
+
+        # the dataset's cached device copies of its row columns
+        rows = self.dataset.batch_for_shard(self.feature_shard_id)
+        offsets = rows.offsets
+        if residual is not None:
+            offsets = offsets + residual
+        out = cached._replace(offsets=_place_rows(offsets))
+        if self.down_sampling_rate < 1.0:
+            # Down-sampling is pure row re-weighting (data/sampler.py):
+            # the per-draw weights ride the SAME re-pad-and-place path as
+            # the residual offsets — traced arguments against the cached
+            # layout, so the entry routing, tile schedules and compiled
+            # fit all survive sampling (padding rows keep weight 0 and
+            # stay inert). Same PRNG key and row count as the scatter
+            # path's ``down_sample``, so the draws agree row for row.
+            from photon_ml_tpu.data.sampler import down_sample_weights
+
+            w_new = down_sample_weights(
+                jax.random.PRNGKey(self.sampler_seed),
+                rows.labels,
+                rows.weights,
+                self.down_sampling_rate,
+                self.problem.task,
+            )
+            out = out._replace(weights=_place_rows(w_new))
+        return out
+
+    def _tiled_batch(self, residual):
+        base = self._tiled_base()
+        return self._refresh_rows(
+            base, base.labels.sharding if self.mesh is not None else None,
+            residual,
+        )
+
     def update_model(self, model, residual=None):
         if self._is_feature_sharded():
             return self._update_model_feature_sharded(model, residual)
-        batch = self._batch(residual)
         initial = model.model.means if model is not None else None
-        if self.down_sampling_rate < 1.0:
+        if self.kernel == "tiled":
+            # the draw, if any, is already in the batch's row weights
+            coefficients, result = self.problem.run(
+                self._tiled_batch(residual), initial=initial,
+                reg_weight=self.reg_weight, mesh=self.mesh,
+            )
+        elif self.down_sampling_rate < 1.0:
             coefficients, result = self.problem.run_with_sampling(
-                batch,
+                self._batch(residual),
                 jax.random.PRNGKey(self.sampler_seed),
                 self.down_sampling_rate,
                 initial=initial,
@@ -152,8 +255,8 @@ class FixedEffectCoordinate(Coordinate):
             )
         else:
             coefficients, result = self.problem.run(
-                batch, initial=initial, reg_weight=self.reg_weight,
-                mesh=self.mesh,
+                self._batch(residual), initial=initial,
+                reg_weight=self.reg_weight, mesh=self.mesh,
             )
         return (
             FixedEffectModel(
@@ -225,7 +328,7 @@ class FixedEffectCoordinate(Coordinate):
         )
         hit = layout_cache.get(layout_key)
         if hit is not None:
-            sharded, block_dim, meta, layout, rows_total = hit
+            sharded, block_dim, meta, layout = hit
         else:
             base = self.dataset.batch_for_shard(self.feature_shard_id)
             # counted seam: a one-time layout-build fetch, but still a
@@ -236,20 +339,16 @@ class FixedEffectCoordinate(Coordinate):
                     host, dim, data_shards, model_shards, mesh=self.mesh
                 )
                 meta, layout = sharded.meta, "tiled"
-                rows_total = meta.data_shards * meta.rows_per_shard
             else:
                 sharded, block_dim = feature_shard_sparse_batch(
                     host, dim, model_shards, rows_multiple=data_shards
                 )
                 meta, layout = None, "sparse"
-                rows_total = sharded.labels.shape[0]
             for k in [
                 k for k in layout_cache if k[0] == self.feature_shard_id
             ]:
                 del layout_cache[k]
-            layout_cache[layout_key] = (
-                sharded, block_dim, meta, layout, rows_total
-            )
+            layout_cache[layout_key] = (sharded, block_dim, meta, layout)
         use_tron = problem.config.optimizer_type == OptimizerType.TRON
         use_owlqn = problem.regularization.has_l1
         norm = problem.objective.norm
@@ -281,7 +380,7 @@ class FixedEffectCoordinate(Coordinate):
             )
         state = dict(
             sharded=sharded, fit=fit, hdiag=hdiag, dim=dim, d_pad=d_pad,
-            rows_total=rows_total, use_owlqn=use_owlqn, l1_mask=l1_mask,
+            use_owlqn=use_owlqn, l1_mask=l1_mask,
             extras_tail=extras_tail, with_norm=with_norm,
             meta=meta, layout=layout,
         )
@@ -289,48 +388,17 @@ class FixedEffectCoordinate(Coordinate):
         return state
 
     def _refresh_sharded_rows(self, residual):
-        """Re-pad and re-place the per-update row vectors (offsets — the
-        residual currency — and, when down-sampling, the draw's weights)
-        against the cached sharded layout. Shared by the sequential
-        update and the λ-grid solve so the two row paths cannot
-        diverge."""
+        """The cached feature-sharded layout under this update's row
+        vectors (``_refresh_rows``). Shared by the sequential update and
+        the λ-grid solve so the two row paths cannot diverge."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from photon_ml_tpu.parallel.mesh import DATA_AXIS
 
         st = self._feature_sharded_state()
-        offsets = jnp.asarray(self.dataset.offsets)
-        if residual is not None:
-            offsets = offsets + residual
-        n = offsets.shape[0]
-        row_sharding = NamedSharding(self.mesh, P(DATA_AXIS))
-
-        def _place_rows(vec):
-            if st["rows_total"] != n:
-                vec = jnp.concatenate(
-                    [vec, jnp.zeros((st["rows_total"] - n,), jnp.float32)]
-                )
-            return jax.device_put(vec, row_sharding)
-
-        sharded = st["sharded"]._replace(offsets=_place_rows(offsets))
-        if self.down_sampling_rate < 1.0:
-            # Down-sampling is pure row re-weighting (data/sampler.py):
-            # the per-draw weights ride the SAME re-pad-and-place path as
-            # the residual offsets — traced arguments against the cached
-            # layout, so the entry routing, tile schedules and compiled
-            # fit all survive sampling (padding rows keep weight 0 and
-            # stay inert). Same PRNG key as the replicated path, so
-            # sampled-sharded == sampled-replicated draw-for-draw.
-            from photon_ml_tpu.data.sampler import down_sample_weights
-
-            w_new = down_sample_weights(
-                jax.random.PRNGKey(self.sampler_seed),
-                jnp.asarray(self.dataset.labels),
-                jnp.asarray(self.dataset.weights),
-                self.down_sampling_rate,
-                self.problem.task,
-            )
-            sharded = sharded._replace(weights=_place_rows(w_new))
+        sharded = self._refresh_rows(
+            st["sharded"], NamedSharding(self.mesh, P(DATA_AXIS)), residual
+        )
         st["sharded"] = sharded  # keep the freshest placement cached
         return sharded
 
@@ -499,10 +567,13 @@ class FixedEffectCoordinate(Coordinate):
 
     def prepare(self, model=None) -> None:
         """Stage the solve's static inputs ahead of update_model: the
-        feature-sharded layout (built once, multi-second cold) or the
-        replicated path's device copies of the shard columns."""
+        feature-sharded layout or the tiled schedules (each built once,
+        multi-second cold), else the scatter path's device copies of the
+        shard columns."""
         if self._is_feature_sharded():
             self._feature_sharded_state()
+        elif self.kernel == "tiled":
+            self._tiled_base()
         else:
             self.dataset.batch_for_shard(self.feature_shard_id)
 

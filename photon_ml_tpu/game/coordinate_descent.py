@@ -192,9 +192,23 @@ class CoordinateDescent:
                 self.logger.info(
                     "resumed coordinate descent from checkpoint step %d", latest
                 )
-        for name in seq:
+        # Prefetched dispatch (overlap lever 3) starts before the first
+        # update: once the first coordinate's starting model is scored, its
+        # host prep (a fixed effect's tile schedules: seconds when cold)
+        # runs on the worker UNDER the other coordinates' scoring passes
+        # and their compiles. As below, the worker only touches the
+        # coordinate the main thread is not working on.
+        pending: Dict[str, object] = {}
+        for j, name in enumerate(seq):
             with obs_span("cd.score", coordinate=name):
                 scores[name] = self.coordinates[name].score(models[name])
+            if (
+                j == 0 and len(seq) > 1 and overlap.overlap_enabled()
+                and start_iteration < num_iterations
+            ):
+                pending[name] = overlap.submit(
+                    self.coordinates[name].prepare, models[name]
+                )
 
         objective_history: List[float] = []
         trackers: Dict[str, List[object]] = {name: [] for name in seq}
@@ -263,7 +277,7 @@ class CoordinateDescent:
                 # between their dispatches. The worker only ever touches
                 # the coordinate being prefetched; the main thread wait()s
                 # before updating it, so cache mutations never race.
-                prefetched: Dict[str, object] = {}
+                prefetched, pending = pending, {}
                 for j, name in enumerate(seq):
                     coord = self.coordinates[name]
                     if name in prefetched:
@@ -279,7 +293,12 @@ class CoordinateDescent:
                         cd_residual(total, scores[name])
                         if len(seq) > 1 else None
                     )
-                    with obs_span("cd.update", coordinate=name):
+                    # which objective a fixed effect runs (tiled | scatter)
+                    kernel = getattr(coord, "kernel", None)
+                    with obs_span(
+                        "cd.update", coordinate=name,
+                        **({"kernel": kernel} if kernel else {}),
+                    ):
                         models[name], tracker = coord.update_model(
                             models[name], residual
                         )
@@ -334,10 +353,12 @@ class CoordinateDescent:
                     counters["evaluations"].inc(
                         evaluations, coordinate=name
                     )
+                    kernel = getattr(self.coordinates[name], "kernel", None)
                     self.logger.info(
-                        "coordinate %s: %d iterations, %d evaluations, %s",
+                        "coordinate %s: %d iterations, %d evaluations, %s%s",
                         name, iterations, evaluations,
                         CONVERGENCE_REASON_NAMES.get(reason, "?"),
+                        f", kernel={kernel}" if kernel else "",
                     )
             objective_history.append(objective)
             self.logger.info(

@@ -646,7 +646,8 @@ def build_tiled_batch(
     """COO triples + per-row arrays -> tiled batch. Entries with zero value
     are dropped (they contribute nothing)."""
     nz = vals != 0
-    rows, feats, vals = rows[nz], feats[nz], vals[nz]
+    if not nz.all():
+        rows, feats, vals = rows[nz], feats[nz], vals[nz]
     win = params.window
     n = labels.shape[0]
     n_pad = max(((n + win - 1) // win) * win, win)
@@ -700,6 +701,39 @@ def build_tiled_batch(
     )
 
 
+def bucket_spill(batch: TiledSparseBatch) -> TiledSparseBatch:
+    """``batch`` with each schedule's spilled tail zero-padded from its
+    lane multiple up to a multiple of a 16th–32nd of its own length
+    (padding slots carry val 0 at coordinate 0: inert adds, < 7% more of a
+    pass that is itself a few percent of an evaluation).
+
+    The tail's length is part of the compiled fit's shape, and it follows
+    the ROW ORDER: rows that land in other 8,192-row blocks (input files
+    read in another order) move tile counts across the spill rule by a
+    few hundred entries, and every such run compiled the fit anew (4.6 s
+    at 524,288 x 65) with the persistent compile cache warm. Bucketed,
+    near-equal tails share one program. Single-device layout only: a mesh
+    layout's tail is one padded segment a shard."""
+    if batch.meta.data_shards != 1:
+        return batch
+
+    def padded(sched: _Schedule) -> _Schedule:
+        s = int(sched.spill_vals.shape[0])
+        step = max(128, 1 << max(s.bit_length() - 5, 0))
+        extra = -s % step
+        if not extra:
+            return sched
+        return sched._replace(
+            spill_out=jnp.pad(sched.spill_out, (0, extra)),
+            spill_in=jnp.pad(sched.spill_in, (0, extra)),
+            spill_vals=jnp.pad(sched.spill_vals, (0, extra)),
+        )
+
+    return batch._replace(
+        z_sched=padded(batch.z_sched), g_sched=padded(batch.g_sched)
+    )
+
+
 def tiled_batch_from_sparse(batch, dim: int, *, params: TileParams = TileParams()):
     """Convenience: SparseBatch (padded ELL) -> TiledSparseBatch."""
     rows, feats, vals, _ = _sparse_coo(batch)
@@ -713,27 +747,51 @@ def tiled_batch_from_sparse(batch, dim: int, *, params: TileParams = TileParams(
 
 def _sparse_coo(batch) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
     """SparseBatch -> filtered COO triples (+ real row count): zero values
-    and weight-0 (padding) rows dropped."""
+    and weight-0 (padding) rows dropped. Row-major order. Where nothing is
+    dropped (no padding, no explicit zeros) no entry is gathered: at 34M
+    entries the masked copies were most of a schedule build's host time."""
     indices = np.asarray(batch.indices)
     values = np.asarray(batch.values)
     weights = np.asarray(batch.weights)
     n, k = indices.shape
-    rows = np.repeat(np.arange(n, dtype=np.int64), k)
-    feats = indices.reshape(-1).astype(np.int64)
-    vals = values.reshape(-1).astype(np.float32)
-    vals = np.where(np.repeat(weights > 0, k), vals, 0.0)
-    nz = vals != 0
-    return rows[nz], feats[nz], vals[nz], n
+    keep = values != 0
+    live = weights > 0
+    if not live.all():
+        keep &= live[:, None]
+    if keep.all():
+        rows = np.repeat(np.arange(n, dtype=np.int64), k)
+        return (
+            rows, indices.reshape(-1).astype(np.int64),
+            values.reshape(-1).astype(np.float32, copy=False), n,
+        )
+    return (
+        np.nonzero(keep)[0].astype(np.int64, copy=False),
+        indices[keep].astype(np.int64),
+        values[keep].astype(np.float32, copy=False), n,
+    )
 
 
-def _padded_row_meta(batch, total: int, n: int):
-    lab = np.zeros(total, np.float32)
-    lab[:n] = np.asarray(batch.labels)
-    off = np.zeros(total, np.float32)
-    off[:n] = np.asarray(batch.offsets)
-    wgt = np.zeros(total, np.float32)
-    wgt[:n] = np.asarray(batch.weights)
-    return jnp.asarray(lab), jnp.asarray(off), jnp.asarray(wgt)
+def pad_row_vector(vec, total: int) -> Array:
+    """One row vector zero-padded to ``total`` float32 rows. A vector that
+    is already on the device is padded THERE: in coordinate descent the
+    offsets are the device-resident residual, and pulling them to the host
+    would block on the scoring queued before the solve and move the vector
+    both ways, a synchronous readback outside the counted
+    ``overlap.device_get`` seam."""
+    if isinstance(vec, jax.Array):
+        vec = vec.astype(jnp.float32)
+        return jnp.pad(vec, (0, total - vec.shape[0]))
+    out = np.zeros(total, np.float32)
+    out[: len(vec)] = np.asarray(vec)
+    return jnp.asarray(out)
+
+
+def _padded_row_meta(batch, total: int):
+    return (
+        pad_row_vector(batch.labels, total),
+        pad_row_vector(batch.offsets, total),
+        pad_row_vector(batch.weights, total),
+    )
 
 
 def _concat_cell_schedules(
@@ -836,7 +894,7 @@ def build_sharded_tiled_batch(
         params=params, z_out_blocks=R // win, g_out_blocks=d_pad // win,
     )
     g_vals_sq = jnp.asarray(g_vals**2)
-    lab, off, wgt = _padded_row_meta(batch, n_shards * R, n)
+    lab, off, wgt = _padded_row_meta(batch, n_shards * R)
     out = TiledSparseBatch(
         meta=_TiledMeta(
             params=params, num_rows=R, dim=d_pad, num_real_rows=n,
@@ -939,7 +997,7 @@ def feature_shard_tiled_batch(
         data_shards * model_shards, params=params,
         z_out_blocks=R // win, g_out_blocks=block_dim // win,
     )
-    lab, off, wgt = _padded_row_meta(batch, data_shards * R, n)
+    lab, off, wgt = _padded_row_meta(batch, data_shards * R)
     out = FeatureShardedTiledBatch(
         meta=_FeatureShardedTiledMeta(
             params=params, rows_per_shard=R, block_dim=block_dim,
@@ -1202,9 +1260,7 @@ def ensure_tiled(  # photon: entropy(id-keyed tiling memo; weakref-pinned, never
             and w_ref is batch.weights
         ):
             meta = cached.meta
-            lab, off, wgt = _padded_row_meta(
-                batch, meta.num_rows, meta.num_real_rows
-            )
+            lab, off, wgt = _padded_row_meta(batch, meta.num_rows)
             return cached._replace(labels=lab, offsets=off, weights=wgt)
         _TILED_CACHE.pop(key)  # stale id collision
     out = tiled_batch_from_sparse(
@@ -1259,7 +1315,7 @@ def ensure_tiled_sharded(  # photon: entropy(id-keyed tiling memo; weakref-pinne
         ):
             meta = cached.meta
             lab, off, wgt = _padded_row_meta(
-                batch, meta.data_shards * meta.num_rows, meta.num_real_rows
+                batch, meta.data_shards * meta.num_rows
             )
             row_sh = NamedSharding(mesh, P(axis))
             return cached._replace(

@@ -399,6 +399,313 @@ class TestCoordinateDescent:
             )
 
 
+class TestFixedEffectCoordinateTiled:
+    """A fixed effect whose problem is built on the tiled objective (what
+    the GAME driver resolves on a TPU; here the kernels run in interpret
+    mode): same answers as the scatter one, schedules built once."""
+
+    MAX_ITER = 10
+
+    def _coord(self, ds, kernel, *, variances=False, rate=1.0, mesh=None):
+        return FixedEffectCoordinate(
+            name="global",
+            dataset=ds,
+            problem=create_glm_problem(
+                TaskType.LOGISTIC_REGRESSION, ds.shards["globalShard"].dim,
+                config=OptimizerConfig(max_iter=self.MAX_ITER),
+                regularization=RegularizationContext(RegularizationType.L2),
+                compute_variances=variances, kernel=kernel,
+            ),
+            feature_shard_id="globalShard",
+            reg_weight=0.1,
+            down_sampling_rate=rate,
+            mesh=mesh,
+        )
+
+    @staticmethod
+    def _builds(spans):
+        return [s for s in spans if s.name == "tiled.schedule_build"]
+
+    @pytest.mark.parametrize(
+        "case", ["plain", "variances", "down_sampled", "data_mesh"]
+    )
+    def test_matches_scatter_and_builds_its_schedules_once(self, rng, case):
+        from photon_ml_tpu.obs import trace as obs_trace
+        from photon_ml_tpu.ops import schedule_cache
+        from photon_ml_tpu.ops.tiled_sparse import TiledSparseBatch
+        from photon_ml_tpu.parallel.mesh import make_mesh
+
+        recs, _, _ = make_records(rng, n=300, n_users=8)
+        ds = build_game_dataset(recs, SHARDS, ["userId"])
+        kw = dict(
+            variances=case == "variances",
+            rate=0.5 if case == "down_sampled" else 1.0,
+            mesh=(
+                make_mesh((2,), devices=jax.devices()[:2])
+                if case == "data_mesh" else None
+            ),
+        )
+        residuals = [
+            jnp.asarray(0.3 * rng.normal(size=ds.num_rows), jnp.float32)
+            for _ in range(2)
+        ]
+        fitted, spans, builds = {}, {}, {}
+        for kernel in ("scatter", "tiled"):
+            coord = self._coord(ds, kernel, **kw)
+            assert coord.kernel == kernel
+            model, models = coord.initialize_model(), []
+            with obs_trace.tracing_scope(True):
+                obs_trace.tracer().clear()
+                for residual in residuals:
+                    before = schedule_cache.stats().builds
+                    model, result = coord.update_model(model, residual)
+                    models.append(model)
+                    spans.setdefault(kernel, []).append(
+                        obs_trace.tracer().drain()
+                    )
+                    builds.setdefault(kernel, []).append(
+                        schedule_cache.stats().builds - before
+                    )
+            assert int(result.iterations) >= 2
+            fitted[kernel] = models
+        # the z and the g schedule, in the first update and never again
+        # (the residual, the draw's weights: row vectors, no cache key)
+        shards = 2 if case == "data_mesh" else 1
+        assert builds == {"scatter": [0, 0], "tiled": [2 * shards, 0]}
+        first, second = (self._builds(s) for s in spans["tiled"])
+        if case != "data_mesh":  # (the mesh layout's build files no span)
+            assert [s.attrs["cache"] for s in first] == ["miss", "miss"]
+        assert second == [] and not any(map(self._builds, spans["scatter"]))
+        for per_update in spans["tiled"]:
+            dispatch, = [s for s in per_update if s.name == "fit.dispatch"]
+            assert dispatch.attrs["kernel"] == "tiled"
+        assert isinstance(coord.__dict__["_tiled"], TiledSparseBatch)
+        for scatter, tiled in zip(fitted["scatter"], fitted["tiled"]):
+            np.testing.assert_allclose(
+                np.asarray(tiled.model.means),
+                np.asarray(scatter.model.means), atol=2e-3,
+            )
+            if case == "variances":
+                np.testing.assert_allclose(
+                    np.asarray(tiled.model.coefficients.variances),
+                    np.asarray(scatter.model.coefficients.variances),
+                    rtol=1e-3,
+                )
+        # two residuals, two different solves
+        assert not np.allclose(
+            np.asarray(fitted["tiled"][0].model.means),
+            np.asarray(fitted["tiled"][1].model.means), atol=1e-3,
+        )
+
+    def test_coordinate_descent_prefetches_the_build_and_names_the_kernel(
+        self, rng
+    ):
+        """Through CoordinateDescent.run: the first coordinate's prepare
+        is prefetched under the other coordinates' starting scores, so the
+        schedules are built on the worker, once, outside cd.update; the
+        span and the log line say which objective ran."""
+        from photon_ml_tpu.obs import trace as obs_trace
+        from photon_ml_tpu.parallel import overlap
+        from photon_ml_tpu.utils.logging_util import PhotonLogger
+
+        class Lines(PhotonLogger):
+            def __init__(self):
+                super().__init__()
+                self.lines = []
+
+            def info(self, msg, *args):
+                self.lines.append(msg % args)
+
+        recs, _, _ = make_records(rng, n=300, n_users=8)
+        ds = build_game_dataset(recs, SHARDS, ["userId"])
+        red = build_random_effect_dataset(
+            ds, RandomEffectDataConfiguration("userId", "userShard")
+        )
+        coords = {
+            "global": self._coord(ds, "tiled"),
+            "per-user": RandomEffectCoordinate(
+                name="per-user", dataset=ds, re_dataset=red,
+                problem=RandomEffectOptimizationProblem(
+                    LOGISTIC, OptimizerConfig(max_iter=5),
+                    RegularizationContext(RegularizationType.L2),
+                    reg_weight=1.0,
+                ),
+            ),
+        }
+        cd = CoordinateDescent(
+            coords, ds, TaskType.LOGISTIC_REGRESSION, logger=Lines()
+        )
+        runs = []
+        with overlap.overlap_scope(True), obs_trace.tracing_scope(True):
+            for _ in range(2):
+                obs_trace.tracer().clear()
+                result = cd.run(num_iterations=1)
+                runs.append(obs_trace.tracer().drain())
+        assert np.isfinite(result.objective_history[-1])
+        first, second = runs
+        by_id = {s.span_id: s for s in first}
+        builds = self._builds(first)
+        assert len(builds) == 2 and self._builds(second) == []
+        for s in builds:  # on the worker: under no span of the main thread
+            assert s.parent_id is None or by_id[s.parent_id].name != "cd.update"
+        waits = [s for s in first if s.name == "cd.prefetch_wait"]
+        assert "global" in {s.attrs["coordinate"] for s in waits}
+        updates = {
+            s.attrs["coordinate"]: s.attrs.get("kernel")
+            for s in first if s.name == "cd.update"
+        }
+        assert updates == {"global": "tiled", "per-user": None}
+        assert any(
+            line.startswith("coordinate global: ")
+            and line.endswith(", kernel=tiled") for line in cd.logger.lines
+        )
+
+    def test_prepare_alone_builds_the_schedules(self, rng):
+        from photon_ml_tpu.obs import trace as obs_trace
+
+        recs, _, _ = make_records(rng, n=300, n_users=8)
+        ds = build_game_dataset(recs, SHARDS, ["userId"])
+        coord = self._coord(ds, "tiled")
+        with obs_trace.tracing_scope(True):
+            obs_trace.tracer().clear()
+            coord.prepare()
+            prepared = obs_trace.tracer().drain()
+            coord.update_model(coord.initialize_model(), None)
+            updated = obs_trace.tracer().drain()
+        assert len(self._builds(prepared)) == 2
+        assert self._builds(updated) == []
+
+    @pytest.mark.parametrize("rate", [1.0, 0.5])
+    def test_the_row_vectors_are_refreshed_on_the_device(self, rng, rate):
+        """The hit path moves no row vector through the host: it traces
+        under jit, where a host pull of the residual would raise."""
+        recs, _, _ = make_records(rng, n=300, n_users=8)
+        ds = build_game_dataset(recs, SHARDS, ["userId"])
+        coord = self._coord(ds, "tiled", rate=rate)
+        coord.prepare()
+        base = coord.__dict__["_tiled"]
+        residual = jnp.asarray(rng.normal(size=ds.num_rows), jnp.float32)
+        offsets, weights = jax.jit(
+            lambda r: coord._tiled_batch(r)[-2:]
+        )(residual)
+        n, total = ds.num_rows, base.labels.shape[0]
+        assert offsets.shape == weights.shape == (total,) and total > n
+        np.testing.assert_array_equal(
+            np.asarray(offsets), np.pad(ds.offsets + np.asarray(residual),
+                                        (0, total - n)),
+        )
+        kept = np.asarray(weights) > 0
+        assert not kept[n:].any() and kept.sum() <= (ds.weights > 0).sum()
+        assert (kept.sum() < (ds.weights > 0).sum()) == (rate < 1.0)
+        # the coordinate's own copy keeps the build-time weights
+        np.testing.assert_array_equal(
+            np.asarray(base.weights)[:n], ds.weights
+        )
+
+
+class TestDriverResolvesTheFixedEffectKernel:
+    """GameTrainingDriver._build_coordinates picks the fixed effect's
+    objective as glm_driver does: resolve_kernel("auto", batch)."""
+
+    def _coords(self, tmp_path, rng, *, rate=1, factored=False, **params):
+        from photon_ml_tpu.cli.game_training_driver import (
+            GameTrainingDriver,
+            GameTrainingParams,
+            expand_config_grid,
+        )
+        from photon_ml_tpu.game.config import FixedEffectDataConfiguration
+
+        recs, _, _ = make_records(rng, n=120, n_users=6)
+        ds = build_game_dataset(recs, SHARDS, ["userId"])
+        re_cfg = RandomEffectDataConfiguration(
+            "userId", "userShard", projector_type=ProjectorType.IDENTITY
+        )
+        p = GameTrainingParams(
+            train_input_dirs=[str(tmp_path / "unused")],
+            output_dir=str(tmp_path / "out"),
+            task_type=TaskType.LOGISTIC_REGRESSION,
+            feature_shards=SHARDS,
+            fixed_effect_data_configs={
+                "global": FixedEffectDataConfiguration("globalShard")
+            },
+            fixed_effect_opt_configs={
+                "global": f"5,1e-6,0.1,{rate},LBFGS,L2"
+            },
+            random_effect_data_configs={"per-user": re_cfg},
+            random_effect_opt_configs={"per-user": "5,1e-6,1.0,1,LBFGS,L2"},
+            factored_re_configs=(
+                {"per-user": FactoredRandomEffectConfiguration(
+                    latent_space_dimension=2, num_inner_iterations=1)}
+                if factored else {}
+            ),
+            **params,
+        )
+        driver = GameTrainingDriver(p)
+        combo = expand_config_grid(
+            {**p.fixed_effect_opt_configs, **p.random_effect_opt_configs}
+        )[0]
+        reds = {"per-user": build_random_effect_dataset(ds, re_cfg)}
+        return driver, lambda **kw: driver._build_coordinates(
+            ds, reds, combo, **kw
+        )
+
+    @pytest.fixture
+    def on_tpu(self, monkeypatch):
+        from photon_ml_tpu.utils import backend
+
+        monkeypatch.setattr(backend, "effective_platform", lambda: "tpu")
+
+    def test_scatter_on_the_cpu(self, tmp_path, rng):
+        from photon_ml_tpu.ops.objective import GLMObjective
+
+        _, build = self._coords(tmp_path, rng)
+        fe = build()["global"]
+        assert type(fe.problem.objective) is GLMObjective
+        assert fe.kernel == "scatter"
+
+    @pytest.mark.parametrize("rate", [1, 0.5])
+    def test_tiled_on_a_tpu_whatever_the_sampling_rate(
+        self, tmp_path, rng, on_tpu, rate
+    ):
+        """Down-sampling keeps the kernel: the draw's weights are row
+        metadata of the schedules built once (TestFixedEffectCoordinate
+        Tiled's down_sampled case), not part of any cache key."""
+        from photon_ml_tpu.ops.tiled_sparse import TiledGLMObjective
+
+        _, build = self._coords(tmp_path, rng, rate=rate)
+        fe = build()["global"]
+        assert isinstance(fe.problem.objective, TiledGLMObjective)
+        # the cd cell's fixed_value_gap holds the solve's value to 5e-7 of
+        # a float32 evaluation; "bf16x2w" read 8.1e-7 there (PERF.md, PR 28)
+        assert fe.problem.objective.mxu == "highest"
+        assert fe.kernel == "tiled" and fe.down_sampling_rate == rate
+
+    def test_the_projection_problem_stays_scatter(self, tmp_path, rng, on_tpu):
+        from photon_ml_tpu.ops.objective import GLMObjective
+
+        _, build = self._coords(tmp_path, rng, factored=True)
+        coords = build()
+        assert coords["global"].kernel == "tiled"
+        projection = coords["per-user"].projection_problem
+        assert type(projection.objective) is GLMObjective
+
+    def test_the_batched_grid_and_the_feature_mesh_keep_scatter(
+        self, tmp_path, rng, on_tpu
+    ):
+        _, build = self._coords(tmp_path, rng)
+        assert build(fe_kernel="scatter")["global"].kernel == "scatter"
+        driver, build = self._coords(
+            tmp_path, rng, distributed="feature",
+            delete_output_dir_if_exists=True,
+        )
+        fe = build()["global"]
+        assert fe._is_feature_sharded() and fe.kernel == "scatter"
+        # the data-parallel mesh follows the rule
+        driver.params.distributed = "auto"
+        fe = build()["global"]
+        assert fe.mesh is not None and fe.kernel == "tiled"
+
+
 class TestFactoredRandomEffect:
     def test_trains_and_scores(self, rng):
         recs, _, _ = make_records(rng, n=200, n_users=6)

@@ -181,7 +181,8 @@ def test_a_cd_run_leaves_one_readback_counters_and_the_log_line(rng):
     last = solves[-1]
     assert (
         f"coordinate global: {int(last.iterations)} iterations, "
-        f"{int(last.evaluations)} evaluations, {last.reason_name}"
+        f"{int(last.evaluations)} evaluations, {last.reason_name}, "
+        "kernel=scatter"
     ) in cd.logger.lines
 
 
@@ -234,6 +235,10 @@ def test_a_cd_iteration_files_every_span_once_under_its_parent(rng):
     assert len(named["cd.objective"]) == 1
     assert sorted(s.attrs["coordinate"] for s in named["cd.update"]) == [
         "global", "per-user"]
+    # the fixed effect's update says which objective ran; a bank has none
+    assert {
+        s.attrs["coordinate"]: s.attrs.get("kernel") for s in named["cd.update"]
+    } == {"global": "scatter", "per-user": None}
     in_iteration = [s for s in named["cd.score"] if s.parent_id is not None]
     assert sorted(s.attrs["coordinate"] for s in in_iteration) == [
         "global", "per-user"]

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from photon_ml_tpu.data.batch import make_sparse_batch
 from photon_ml_tpu.ops import schedule_cache as sc
@@ -206,6 +207,37 @@ class TestMemoryTiers:
             ensure_tiled(batch, d, params=PARAMS)
             ensure_tiled_sharded(batch, d, mesh, params=PARAMS)
         assert sc.stats().builds == builds_after_first
+
+    @pytest.mark.parametrize("layout", ["tiled", "sharded"])
+    def test_hit_path_pads_device_rows_on_the_device(self, rng, layout):
+        """A cache hit re-pads labels, offsets and weights only; offsets
+        that are already on the device (coordinate descent's residual)
+        are padded there: the hit path traces under jit, where a pull to
+        the host would raise."""
+        from photon_ml_tpu.ops.tiled_sparse import (
+            ensure_tiled,
+            ensure_tiled_sharded,
+        )
+        from photon_ml_tpu.parallel.mesh import DATA_AXIS, make_mesh
+
+        batch, d = random_problem(rng, n=64)
+        if layout == "tiled":
+            ensure = lambda b: ensure_tiled(b, d, params=PARAMS)
+        else:
+            mesh = make_mesh((2,), (DATA_AXIS,), devices=jax.devices()[:2])
+            ensure = lambda b: ensure_tiled_sharded(b, d, mesh, params=PARAMS)
+        built = ensure(batch)
+        builds = sc.stats().builds
+        offsets = jnp.asarray(rng.normal(size=64), jnp.float32)
+        padded = jax.jit(
+            lambda off: ensure(batch._replace(offsets=off)).offsets
+        )(offsets)
+        assert sc.stats().builds == builds
+        assert padded.shape == built.offsets.shape
+        got = np.asarray(padded)
+        # (the sharded layout pads each shard's rows to the window)
+        assert np.isin(np.asarray(offsets), got).all()
+        assert np.count_nonzero(got) == 64
 
     def test_sharded_pressure_does_not_evict_tiled(self, rng):
         """Several sharded conversions (> the sharded LRU bound) while a

@@ -7,6 +7,7 @@ multi-window shapes.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from photon_ml_tpu.data.batch import make_sparse_batch
@@ -33,6 +34,40 @@ def random_problem(rng, n=100, d=150, k=6, intercept=True):
         labels.append(float(rng.uniform() > 0.5))
         rows.append((ix, vs))
     return make_sparse_batch(rows, labels, weights=rng.uniform(0.5, 2.0, n)), d
+
+
+class TestSparseCoo:
+    """SparseBatch -> COO triples, the schedule builders' input."""
+
+    @pytest.mark.parametrize(
+        "case", ["nothing_dropped", "explicit_zeros", "padding_rows", "both"]
+    )
+    def test_matches_the_entry_by_entry_filter(self, rng, case):
+        from photon_ml_tpu.data.batch import SparseBatch
+        from photon_ml_tpu.ops.tiled_sparse import _sparse_coo
+
+        n, k, d = 40, 5, 90
+        indices = rng.integers(0, d, (n, k)).astype(np.int32)
+        values = rng.normal(size=(n, k)).astype(np.float32)
+        weights = rng.uniform(0.5, 2.0, n).astype(np.float32)
+        if case in ("explicit_zeros", "both"):
+            values[rng.uniform(size=(n, k)) < 0.3] = 0.0
+        if case in ("padding_rows", "both"):
+            weights[n - 7:] = 0.0
+        batch = SparseBatch(
+            indices=jnp.asarray(indices), values=jnp.asarray(values),
+            labels=jnp.zeros(n), offsets=jnp.zeros(n),
+            weights=jnp.asarray(weights),
+        )
+        want = [
+            (i, int(indices[i, j]), values[i, j])
+            for i in range(n) for j in range(k)
+            if values[i, j] != 0 and weights[i] > 0
+        ]
+        rows, feats, vals, rows_total = _sparse_coo(batch)
+        assert rows_total == n and rows.dtype == feats.dtype == np.int64
+        assert vals.dtype == np.float32
+        assert list(zip(rows.tolist(), feats.tolist(), vals)) == want
 
 
 class TestSchedule:
@@ -307,6 +342,47 @@ class TestSpill:
             np.asarray(obj.hessian_vector(w, w * 0.5, batch, 0.1)),
             atol=2e-4,
         )
+
+    def test_bucketed_tail_is_exact_and_shared_by_near_equal_tails(self, rng):
+        """bucket_spill pads each tail to a 16th-32nd of its length: tails
+        a few hundred entries apart (the cd cell's 208,000 and 208,768,
+        same rows in another order) get one shape, and the objective reads
+        the same to the bit (padding slots add val 0 at coordinate 0)."""
+        from photon_ml_tpu.ops.tiled_sparse import bucket_spill
+
+        batch, tb, d = self._spilly(rng)
+
+        def with_tail(length):
+            def grown(sched):
+                extra = length - sched.spill_vals.shape[0]
+                return sched._replace(
+                    spill_out=jnp.pad(sched.spill_out, (0, extra)),
+                    spill_in=jnp.pad(sched.spill_in, (0, extra)),
+                    spill_vals=jnp.pad(sched.spill_vals, (0, extra)),
+                )
+            return tb._replace(
+                z_sched=grown(tb.z_sched), g_sched=grown(tb.g_sched)
+            )
+
+        bucketed = [bucket_spill(with_tail(n)) for n in (208000, 208768)]
+        for sched in ("z_sched", "g_sched"):
+            shapes = {
+                tuple(a.shape for a in getattr(b, sched)[-3:])
+                for b in bucketed
+            }
+            assert shapes == {((212992,),) * 3}
+        # short tails keep their lane multiple; a mesh layout is left alone
+        assert bucket_spill(tb).z_sched.spill_vals.shape == (
+            tb.z_sched.spill_vals.shape
+        )
+        tobj = TiledGLMObjective(LOGISTIC, d, interpret=True, mxu="highest")
+        w = jnp.asarray(rng.normal(size=d).astype(np.float32))
+        for f in (tobj.value_and_gradient, tobj.hessian_diagonal):
+            for want, got in zip(
+                jax.tree.leaves(f(w, tb, 0.2)),
+                jax.tree.leaves(f(w, bucketed[0], 0.2)),
+            ):
+                np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_spill_reduces_steps(self, rng):
         batch, d = random_problem(rng, n=160, d=90, k=5)

@@ -23,6 +23,9 @@ Sections (SURVEY §4: test on the real execution target):
   8. full GAME coordinate-descent step on chip vs the CPU oracle (the
      whole composition: FE solve + RE bank + residuals + objective,
      through the overlap layer's deferred readbacks)
+  9. the GAME driver's own fixed-effect coordinate at chip_smoke.py's
+     leg-B width: dispatches kernel=tiled, builds its schedules once,
+     and lands where the scatter objective does
 
 Run with:  PHOTON_TPU_TESTS=1 python -m pytest tests/test_tiled_tpu.py -v
 """
@@ -379,6 +382,84 @@ def game_cd_step():
     np.testing.assert_allclose(hist_t, hist_c, atol=1e-3)
 
 
+# ---- 9. the GAME driver's fixed effect at leg-B width runs the kernel ---
+def game_driver_fixed_effect_is_tiled():
+    from photon_ml_tpu.cli import game_training_driver as gtd
+    from photon_ml_tpu.game.data import ShardData
+    from photon_ml_tpu.obs import trace as obs_trace
+    from photon_ml_tpu.utils.index_map import IdentityIndexMap
+
+    r = np.random.default_rng(9)
+    n, k, d = 262144, 64, 1 << 20  # chip_smoke.py leg B: 16,384 users x 16
+    indices = np.concatenate(
+        [r.integers(0, d, size=(n, k)), np.full((n, 1), d)], axis=1
+    ).astype(np.int32)
+    values = np.concatenate(
+        [r.normal(size=(n, k)) / 8.0, np.ones((n, 1))], axis=1
+    ).astype(np.float32)
+    ds = GameDataset(
+        uids=[str(i) for i in range(n)],
+        labels=(r.uniform(size=n) > 0.5).astype(np.float32),
+        offsets=np.zeros(n, np.float32), weights=np.ones(n, np.float32),
+        shards={"globalShard": ShardData(
+            indices, values, IdentityIndexMap(d, add_intercept=True), d)},
+        entity_codes={}, entity_indexes={}, num_real_rows=n,
+    )
+    tmp = tempfile.mkdtemp()
+    try:
+        driver = gtd.GameTrainingDriver(gtd.params_from_args([
+            "--train-input-dirs", tmp + "/unused",
+            "--output-dir", tmp + "/out",
+            "--task-type", "LOGISTIC_REGRESSION",
+            "--feature-shard-id-to-feature-section-keys-map",
+            "globalShard:features",
+            "--fixed-effect-data-configurations", "global:globalShard,1",
+            "--fixed-effect-optimization-configurations",
+            "global:10,1e-7,1.0,1,LBFGS,L2",
+            "--updating-sequence", "global", "--distributed", "off",
+        ]))
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+    combo = gtd.expand_config_grid(driver.params.fixed_effect_opt_configs)[0]
+    residuals = [
+        jnp.asarray(0.1 * r.normal(size=n), jnp.float32) for _ in range(2)
+    ]
+
+    l2 = combo["global"].reg_weight
+    oracle = jax.jit(GLMObjective(LOGISTIC, d + 1).value)
+
+    def two_updates(coord):
+        model, out = coord.initialize_model(), []
+        for residual in residuals:
+            obs_trace.tracer().clear()
+            model, result = coord.update_model(model, residual)
+            w = model.model.means
+            # the float32 scatter objective AT the coefficients reached
+            at_w = float(oracle(w, ds.batch_for_shard("globalShard", residual), l2))
+            out.append((float(result.value), at_w, obs_trace.tracer().drain()))
+        return out
+
+    with obs_trace.tracing_scope(True):
+        fe = driver._build_coordinates(ds, {}, combo)["global"]
+        assert fe.kernel == "tiled", fe.kernel
+        tiled = two_updates(fe)
+        scatter = two_updates(driver._build_coordinates(
+            ds, {}, combo, fe_kernel="scatter")["global"])
+    for i, (_, _, spans) in enumerate(tiled):
+        kernels = [s.attrs["kernel"] for s in spans if s.name == "fit.dispatch"]
+        assert kernels == ["tiled"], kernels
+        builds = [s for s in spans if s.name == "tiled.schedule_build"]
+        # both schedules in the first update, none in the second
+        assert len(builds) == (2 if i == 0 else 0), (i, len(builds))
+    # Ten L-BFGS iterations on two kernels part ways from the fourth on
+    # (PERF.md section 7), so not coefficient by coordinate: the value the
+    # solve reports is the float32 objective where it stands (the cd cell's
+    # fixed_value_gap), and it got as far down as the scatter solve did.
+    for (said, at_w, _), (_, scatter_at_w, _) in zip(tiled, scatter):
+        assert abs(said - at_w) <= 1e-6 * abs(at_w), (said, at_w)
+        assert at_w <= scatter_at_w * (1 + 2e-2), (at_w, scatter_at_w)
+
+
 # Every section runs even when an earlier one fails, so one call to the
 # chip reports all of them; the exit status says whether any failed.
 failed = []
@@ -403,6 +484,7 @@ _MARKERS = {
     "one_device_mesh_fit": "TPU_MESH_FIT_OK",
     "feature_sharded_1x1_mesh_fit": "TPU_FEATURE_SHARDED_OK",
     "game_cd_step": "TPU_GAME_CD_OK",
+    "game_driver_fixed_effect_is_tiled": "TPU_GAME_DRIVER_FE_TILED_OK",
 }
 
 pytestmark = pytest.mark.skipif(
