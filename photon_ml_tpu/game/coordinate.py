@@ -38,6 +38,7 @@ from photon_ml_tpu.game.random_effect import (
 from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
 from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.models.glm import compute_scores, create_model
+from photon_ml_tpu.obs.registry import default_registry
 from photon_ml_tpu.optim.problem import GLMOptimizationProblem
 
 Array = jnp.ndarray
@@ -663,6 +664,23 @@ class PodRandomEffectCoordinate(Coordinate):
             raise ValueError("PodRandomEffectCoordinate requires an entity mesh")
         self.pod = PodRandomEffectProblem(self.problem, self.mesh)
 
+    def _count_hop(self, hop: str):
+        """One exchange hop of this coordinate's rows, into the registry:
+        the rows routed, and those whose owner is another device than the
+        one that holds the row (host arithmetic on the router's static
+        tables; nothing is read from the device). Returns the pod view."""
+        view = self.pod.pod_view(self.re_dataset)
+        registry = default_registry()
+        registry.counter(
+            "photon_pod_routed_rows_total",
+            "rows the pod exchange carried, by coordinate and hop (in | out)",
+        ).inc(view.router.num_routed_rows, coordinate=self.name, hop=hop)
+        registry.counter(
+            "photon_pod_cross_shard_rows_total",
+            "routed rows whose owner is another device than the row's",
+        ).inc(view.router.cross_shard_rows, coordinate=self.name)
+        return view
+
     def initialize_model(self):
         from photon_ml_tpu.game.pod import PodRandomEffectModel
 
@@ -693,6 +711,14 @@ class PodRandomEffectCoordinate(Coordinate):
                 bank, self.re_dataset, residual_offsets=offsets,
                 defer_tracker=True,
             )
+        view = self._count_hop("in")
+        solved = default_registry().counter(
+            "photon_pod_entities_total",
+            "entities the pod bank updates solved, by coordinate and "
+            "solver kind",
+        )
+        for kind, entities in view.entities_by_kind().items():
+            solved.inc(entities, coordinate=self.name, kind=kind)
         return (
             PodRandomEffectModel(
                 bank,
@@ -708,6 +734,7 @@ class PodRandomEffectCoordinate(Coordinate):
         bank = getattr(model, "sharded_bank", None)
         if bank is None:
             return score_random_effect(model.bank, self.re_dataset)
+        self._count_hop("out")
         return self.pod.score(bank, self.re_dataset)
 
     def regularization_term(self, model) -> float:

@@ -64,6 +64,7 @@ from photon_ml_tpu.game.random_effect import (
 )
 from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
 from photon_ml_tpu.game.residual_routing import PodResidualRouter
+from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.optim.common import CONVERGENCE_REASON_NAMES
 from photon_ml_tpu.parallel import overlap
 from photon_ml_tpu.parallel.mesh import ENTITY_AXIS
@@ -148,13 +149,16 @@ def _zeros_sharded(mesh, rows: int, d: int) -> Array:
     fn = _ZEROS_CACHE.get(key)
     if fn is None:
 
-        def _make(rows=rows, d=d):
+        # (the functions handed to jax.jit name the XLA modules: pod_zeros,
+        # pod_replicate, pod_update, pod_variance, pod_score,
+        # pod_chunk_score here, pod_route_in / pod_route_out in the router)
+        def pod_zeros(rows=rows, d=d):
             return jnp.zeros((rows, d), jnp.float32)
 
         fn = _bounded_put(
             _ZEROS_CACHE, key,
             # photon: sharding(axes=[entity], out=[entity])
-            jax.jit(_make, out_shardings=_entity_sharding(mesh)),
+            jax.jit(pod_zeros, out_shardings=_entity_sharding(mesh)),
         )
     return fn()
 
@@ -164,13 +168,13 @@ def _replicate(mesh, value: Array) -> Array:
     fn = _REPL_CACHE.get(key)
     if fn is None:
 
-        def _ident(a):
+        def pod_replicate(a):
             return a
 
         fn = _bounded_put(
             _REPL_CACHE, key,
             # photon: sharding(axes=[entity], in=[entity], out=[r])
-            jax.jit(_ident, out_shardings=NamedSharding(mesh, P())),
+            jax.jit(pod_replicate, out_shardings=NamedSharding(mesh, P())),
         )
     return fn(value)
 
@@ -305,7 +309,7 @@ def _build_update_program(solvers, kind: str, mesh, axis: str,
         out_specs=(P(ax), P(), P(), P()),
         check_vma=False,
     )
-    def fused(bank_l, lrow, valid, ix, v, lab, w, *rest):
+    def pod_update(bank_l, lrow, valid, ix, v, lab, w, *rest):
         if with_slots:
             offslot, slots, l1, l2 = rest
             off = jnp.where(
@@ -328,7 +332,7 @@ def _build_update_program(solvers, kind: str, mesh, axis: str,
         )
         return bank_l, it_sum, it_max, counts
 
-    return fused
+    return pod_update
 
 
 def _build_variance_program(solvers, mesh, axis: str,
@@ -353,7 +357,7 @@ def _build_variance_program(solvers, mesh, axis: str,
         out_specs=P(ax),
         check_vma=False,
     )
-    def fused_var(var_l, bank_l, lrow, valid, ix, v, lab, w, *rest):
+    def pod_variance(var_l, bank_l, lrow, valid, ix, v, lab, w, *rest):
         if with_slots:
             offslot, slots, l2 = rest
             off = jnp.where(
@@ -370,7 +374,7 @@ def _build_variance_program(solvers, mesh, axis: str,
             1.0 / (hd + _VARIANCE_EPSILON), mode="drop"
         )
 
-    return fused_var
+    return pod_variance
 
 
 def _build_chunk_score_program(mesh, axis: str, n_dev: int):
@@ -390,7 +394,7 @@ def _build_chunk_score_program(mesh, axis: str, n_dev: int):
         out_specs=P(),
         check_vma=False,
     )
-    def score_chunk(bank_l, codes, ix, v, valid):
+    def pod_chunk_score(bank_l, codes, ix, v, valid):
         e_loc = bank_l.shape[0]
         me = lax.axis_index(ax)
         mine = valid & (ownership.owner_of(codes, n_dev) == me)
@@ -401,7 +405,7 @@ def _build_chunk_score_program(mesh, axis: str, n_dev: int):
         s = jnp.sum(v * jnp.take_along_axis(w_rows, ix, axis=1), axis=-1)
         return lax.psum(jnp.where(mine, s, 0.0), ax)
 
-    return score_chunk
+    return pod_chunk_score
 
 
 def _build_score_program(mesh, axis: str, n_dev: int, cap: int):
@@ -421,7 +425,7 @@ def _build_score_program(mesh, axis: str, n_dev: int, cap: int):
         out_specs=P(ax),
         check_vma=False,
     )
-    def score(bank_l, slot_lrow, slot_ix, slot_v, slot_valid, send_pos):
+    def pod_score(bank_l, slot_lrow, slot_ix, slot_v, slot_valid, send_pos):
         e_loc = bank_l.shape[0]
         safe = jnp.minimum(slot_lrow, e_loc - 1)
         w_rows = jnp.take(bank_l, safe, axis=0)
@@ -436,7 +440,7 @@ def _build_score_program(mesh, axis: str, n_dev: int, cap: int):
         safe_p = jnp.minimum(send_pos, n_dev * cap - 1)
         return jnp.where(send_pos < n_dev * cap, back[safe_p], 0.0)
 
-    return score
+    return pod_score
 
 
 # ---------------------------------------------------------------------------
@@ -444,16 +448,34 @@ def _build_score_program(mesh, axis: str, n_dev: int, cap: int):
 # ---------------------------------------------------------------------------
 
 
+def _owner_positions(codes: np.ndarray, spec: EntityShardSpec):
+    """Where each entity of a block goes: (owner shard, local bank row,
+    rank among the block's entities of the same owner, the fullest
+    owner's count, at least 1)."""
+    sh = entity_shard_of(codes, spec.num_shards)
+    counts = np.bincount(sh, minlength=spec.num_shards)
+    order = np.argsort(sh, kind="stable")
+    pos = np.empty(len(codes), np.int64)
+    pos[order] = np.arange(len(codes)) - np.repeat(
+        np.cumsum(counts) - counts, counts
+    )
+    return sh, spec.local_of(codes), pos, max(1, int(counts.max()))
+
+
 @dataclass
 class _PodBlock:
-    """One capacity class, split by entity hash into per-shard padded
-    blocks [n_dev * E_blk, S(, k)] (leading dim sharded). ``kind`` is
-    the SAME solver-family selection the replicated path would make for
-    the global bucket, so sharded-vs-replicated parity compares like
-    solvers."""
+    """One solver block: a capacity class split by entity hash into
+    per-shard padded blocks [n_dev * E_blk, S(, k)] (leading dim sharded),
+    or, where a shard's share of the class is over ``dense_bytes_budget``,
+    one of its ``sub_blocks`` equal sub-blocks, which share one compiled
+    program. ``kind`` is decided from the block a DEVICE holds
+    (``RandomEffectOptimizationProblem.dense_block_plan``): a class too
+    large for the dense solvers as one replicated bucket still runs them
+    on each device's share."""
 
     kind: str
     num_real: int  # real entities across all shards (tracker accounting)
+    sub_blocks: int  # how many blocks its capacity class was split into
     lrow: Array
     valid: Array
     ix: Array
@@ -483,13 +505,27 @@ class _PodView:
         self.n_dev = n_dev
         self.num_rows = int(dataset.row_entity_codes.shape[0])
         self.spec = EntityShardSpec(n_dev, dataset.num_entities)
+        self.blocks: List[_PodBlock] = []
+        with obs_span("pod.view_build", shards=n_dev) as build_span:
+            self._build(mesh, dataset, base_problem)
+            build_span.set(
+                blocks=len(dataset.buckets), sub_blocks=len(self.blocks),
+                slots=self.router.num_slots,
+            )
+
+    def _build(self, mesh, dataset: RandomEffectDataset, base_problem):
+        axis, n_dev = self.axis, self.n_dev
         e_loc = self.spec.rows_per_shard
         sharding = NamedSharding(mesh, P(axis))
+
+        def put(a):
+            # host array -> its shards, each straight to its device (never
+            # the whole array on the default device first)
+            return jax.device_put(a, sharding)
 
         codes = np.asarray(dataset.row_entity_codes, np.int64)
         self.router = PodResidualRouter(mesh, codes, axis=axis)
         cap = self.router.cap
-        n_slots = self.router.num_slots
 
         # -- scoring slots: every valid row's features staged at its
         # owner's (source, rank) slot — covers active AND passive rows,
@@ -498,7 +534,6 @@ class _PodView:
         flat_gid = slot_row.reshape(-1)
         s_valid = flat_gid >= 0
         safe_gid = np.maximum(flat_gid, 0)
-        k = dataset.row_local_indices.shape[1]
         slot_ix = np.where(
             s_valid[:, None], dataset.row_local_indices[safe_gid], 0
         ).astype(np.int32)
@@ -509,10 +544,10 @@ class _PodView:
         slot_lrow = np.where(
             s_valid, self.spec.local_of(slot_codes), e_loc
         ).astype(np.int32)
-        self.slot_ix = jax.device_put(jnp.asarray(slot_ix), sharding)
-        self.slot_v = jax.device_put(jnp.asarray(slot_v), sharding)
-        self.slot_lrow = jax.device_put(jnp.asarray(slot_lrow), sharding)
-        self.slot_valid = jax.device_put(jnp.asarray(s_valid), sharding)
+        self.slot_ix = put(slot_ix)
+        self.slot_v = put(slot_v)
+        self.slot_lrow = put(slot_lrow)
+        self.slot_valid = put(s_valid)
         self._score = _cached_program(
             ("score", _mesh_key(mesh), n_dev, cap),
             lambda: _build_score_program(mesh, axis, n_dev, cap),
@@ -523,51 +558,60 @@ class _PodView:
         # (same owner device by construction: a sample's entity IS the
         # slot's owner), so the solve needs no second exchange
         slot_of_row = self.router.slot_of_row
-        self.blocks: List[_PodBlock] = []
         d_local = dataset.local_dim
         for bucket in dataset.buckets:
-            kind = base_problem._bucket_kind(bucket, d_local)
             b_codes = np.asarray(bucket.entity_codes, np.int64)
-            sh = entity_shard_of(b_codes, n_dev)
-            lo = self.spec.local_of(b_codes)
-            counts = np.bincount(sh, minlength=n_dev)
-            e_blk = max(1, int(counts.max()))
-            pos = np.zeros(len(b_codes), np.int64)
-            for s in range(n_dev):
-                m = sh == s
-                pos[m] = np.arange(int(m.sum()))
-            dest = sh * e_blk + pos
+            sh, lo, pos, e_blk = _owner_positions(b_codes, self.spec)
             S = bucket.capacity
             kk = bucket.indices.shape[2]
-            rows_total = n_dev * e_blk
-            b_lrow = np.full(rows_total, e_loc, np.int32)
-            b_valid = np.zeros(rows_total, bool)
-            b_ix = np.zeros((rows_total, S, kk), np.int32)
-            b_v = np.zeros((rows_total, S, kk), np.float32)
-            b_lab = np.zeros((rows_total, S), np.float32)
-            b_w = np.zeros((rows_total, S), np.float32)
-            b_offslot = np.full((rows_total, S), -1, np.int32)
-            b_lrow[dest] = lo
-            b_valid[dest] = True
-            b_ix[dest] = bucket.indices
-            b_v[dest] = bucket.values
-            b_lab[dest] = bucket.labels
-            b_w[dest] = bucket.weights
+            # the block a device holds decides the solver, and how many
+            # equal sub-blocks keep each dense program under the budget
+            kind, e_cap = base_problem.dense_block_plan(
+                e_blk, S, d_local, bucket.identity_indices
+            )
+            n_sub = -(-e_blk // e_cap)
+            e_sub = -(-e_blk // n_sub)
             gids = bucket.row_index
-            b_offslot[dest] = np.where(
+            offslot = np.where(
                 gids >= 0, slot_of_row[np.maximum(gids, 0)], -1
             ).astype(np.int32)
-            self.blocks.append(_PodBlock(
-                kind=kind,
-                num_real=bucket.num_entities,
-                lrow=jax.device_put(jnp.asarray(b_lrow), sharding),
-                valid=jax.device_put(jnp.asarray(b_valid), sharding),
-                ix=jax.device_put(jnp.asarray(b_ix), sharding),
-                v=jax.device_put(jnp.asarray(b_v), sharding),
-                lab=jax.device_put(jnp.asarray(b_lab), sharding),
-                w=jax.device_put(jnp.asarray(b_w), sharding),
-                offslot=jax.device_put(jnp.asarray(b_offslot), sharding),
-            ))
+            rows_total = n_dev * e_sub
+            for j in range(n_sub):
+                m = (pos // e_sub) == j
+                dest = sh[m] * e_sub + pos[m] % e_sub
+                b_lrow = np.full(rows_total, e_loc, np.int32)
+                b_valid = np.zeros(rows_total, bool)
+                b_ix = np.zeros((rows_total, S, kk), np.int32)
+                b_v = np.zeros((rows_total, S, kk), np.float32)
+                b_lab = np.zeros((rows_total, S), np.float32)
+                b_w = np.zeros((rows_total, S), np.float32)
+                b_offslot = np.full((rows_total, S), -1, np.int32)
+                b_lrow[dest] = lo[m]
+                b_valid[dest] = True
+                b_ix[dest] = bucket.indices[m]
+                b_v[dest] = bucket.values[m]
+                b_lab[dest] = bucket.labels[m]
+                b_w[dest] = bucket.weights[m]
+                b_offslot[dest] = offslot[m]
+                self.blocks.append(_PodBlock(
+                    kind=kind,
+                    num_real=int(m.sum()),
+                    sub_blocks=n_sub,
+                    lrow=put(b_lrow),
+                    valid=put(b_valid),
+                    ix=put(b_ix),
+                    v=put(b_v),
+                    lab=put(b_lab),
+                    w=put(b_w),
+                    offslot=put(b_offslot),
+                ))
+
+    def entities_by_kind(self) -> Dict[str, int]:
+        """Real entities a bank update solves, by solver kind."""
+        out: Dict[str, int] = {}
+        for b in self.blocks:
+            out[b.kind] = out.get(b.kind, 0) + b.num_real
+        return out
 
     def per_device_data_bytes(self) -> int:
         """Per-device bytes of the staged solver blocks + scoring slots
@@ -670,7 +714,8 @@ class PodRandomEffectProblem:
         bank = self._coerce_bank(bank, dataset)
         l1, l2 = self.base.regularization.split(self.base.reg_weight)
         l1_d, l2_d = jnp.float32(l1), jnp.float32(l2)
-        slots = view.router.route_in(residual_offsets)  # hop 1
+        with obs_span("pod.route_in"):
+            slots = view.router.route_in(residual_offsets)  # hop 1
         solvers = self.base._solvers
         data = bank.data
         if _donate_args():
@@ -686,16 +731,22 @@ class PodRandomEffectProblem:
                 self.mesh, bank.spec.bank_rows, bank.dim
             )
         for blk in view.blocks:
+            # (a class's sub-blocks share one program and hand the donated
+            # bank from one to the next)
             fused = _cached_program(
                 ("update", _mesh_key(self.mesh), blk.kind, True),
                 lambda kind=blk.kind: _build_update_program(
                     solvers, kind, self.mesh, self.axis, with_slots=True
                 ),
             )
-            data, it_sum, it_max, counts = fused(
-                data, blk.lrow, blk.valid, blk.ix, blk.v, blk.lab, blk.w,
-                blk.offslot, slots, l1_d, l2_d,
-            )
+            with obs_span(
+                "pod.update", kind=blk.kind, sub_blocks=blk.sub_blocks,
+                entities=blk.num_real,
+            ):
+                data, it_sum, it_max, counts = fused(
+                    data, blk.lrow, blk.valid, blk.ix, blk.v, blk.lab, blk.w,
+                    blk.offslot, slots, l1_d, l2_d,
+                )
             if with_variances:
                 fused_var = _cached_program(
                     ("variance", _mesh_key(self.mesh), True),
@@ -767,14 +818,7 @@ class PodRandomEffectProblem:
         e_loc = spec.rows_per_shard
         sharding = _entity_sharding(self.mesh)
         codes = np.asarray(entity_codes, np.int64)
-        sh = entity_shard_of(codes, n_dev)
-        lo = spec.local_of(codes)
-        counts = np.bincount(sh, minlength=n_dev)
-        e_blk = max(1, int(counts.max()))
-        pos = np.zeros(len(codes), np.int64)
-        for s in range(n_dev):
-            m = sh == s
-            pos[m] = np.arange(int(m.sum()))
+        sh, lo, pos, e_blk = _owner_positions(codes, spec)
         dest = sh * e_blk + pos
         rows_total = n_dev * e_blk
         S = arrays["lab"].shape[1]
@@ -864,11 +908,13 @@ class PodRandomEffectProblem:
         currency) — an O(n) row vector, never anything [E]-sized."""
         view = self.pod_view(dataset)
         bank = self._coerce_bank(bank, dataset)
-        rows = view._score(
-            bank.data, view.slot_lrow, view.slot_ix, view.slot_v,
-            view.slot_valid, view.router._send_pos,
-        )
-        return _replicate(self.mesh, rows)[: view.num_rows]
+        with obs_span("pod.score"):
+            rows = view._score(
+                bank.data, view.slot_lrow, view.slot_ix, view.slot_v,
+                view.slot_valid, view.router._send_pos,
+            )
+        with obs_span("pod.replicate"):
+            return _replicate(self.mesh, rows)[: view.num_rows]
 
     def regularization_term_device(self, bank) -> Array:
         """Reg term over the SHARDED bank — the sum reduces device-side

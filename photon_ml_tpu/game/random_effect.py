@@ -108,6 +108,9 @@ class LazyRandomEffectTracker:
 # fresh build.
 _SOLVER_CACHE: dict = {}
 _SOLVER_CACHE_MAX = 16
+# bytes an element of a solver block: buckets, and the X and Gram blocks
+# densified from them, are float32
+_BLOCK_ITEMSIZE = 4
 
 
 def _cached_bucket_solver(
@@ -632,20 +635,49 @@ class RandomEffectOptimizationProblem:
         cache[key] = (ref, router)
         return router
 
+    def dense_block_plan(
+        self, num_entities: int, capacity: int, d_local: int, identity: bool
+    ) -> Tuple[str, int]:
+        """The one rule for a block of ``num_entities`` entities at
+        ``capacity`` samples and ``d_local`` features each: the solver
+        kind a dense staging of it runs, and how many of its entities ONE
+        dense program may hold under ``dense_bytes_budget``. A cap of at
+        least ``num_entities`` means the block runs whole; a smaller one
+        leaves the caller to run the sparse solver (a replicated bucket,
+        :meth:`_bucket_kind`) or to split the block into sub-blocks of at
+        most that many entities (a device's share on the pod path,
+        game/pod.py). ``("sparse", num_entities)`` where nothing dense
+        may run: the layout says so, or not one entity fits."""
+        if self.layout == "sparse":
+            return "sparse", num_entities
+        newton = self._newton_eligible()
+        # "_id": indices that are the tiled arange (k == local_dim, the MF
+        # latent view): X IS values, no [E, S, k, D] densify broadcast
+        kind = ("newton" if newton else "dense") + ("_id" if identity else "")
+        if self.layout == "dense":
+            return kind, num_entities
+        # X [E, S, D], plus the Newton path's Gram G [E, S, S] when that
+        # solver would actually run (the CG solve is matrix-free — no
+        # second S x S block) — when S > D the Grams, not X, dominate the
+        # footprint, but charging them to a bucket that can only take the
+        # plain dense solver would wrongly force the slow sparse path.
+        # Identity-indices buckets pay no X at all (X IS values).
+        floats = 0 if identity else capacity * d_local
+        if newton:
+            floats += capacity * capacity
+        if floats == 0:
+            return kind, num_entities
+        cap = self.dense_bytes_budget // (floats * _BLOCK_ITEMSIZE)
+        return (kind, int(cap)) if cap > 0 else ("sparse", num_entities)
+
     def _bucket_kind(self, bucket, d_local: int) -> str:
-        """Which solver program this bucket runs (host-side selection)."""
-        use_dense = self._use_dense(bucket, d_local)
-        kind = (
-            ("newton" if self._newton_eligible() else "dense")
-            if use_dense
-            else "sparse"
+        """Which solver program this bucket runs (host-side selection):
+        the dense kind where the WHOLE bucket fits the budget."""
+        e_b, s_b, _ = bucket.indices.shape
+        kind, cap = self.dense_block_plan(
+            e_b, s_b, d_local, bucket.identity_indices
         )
-        if use_dense and bucket.identity_indices:
-            # indices are the tiled arange (k == local_dim, the MF
-            # latent view): X IS values — skip the [E, S, k, D]
-            # densify broadcast
-            kind += "_id"
-        return kind
+        return kind if cap >= e_b else "sparse"
 
     def _newton_eligible(self) -> bool:
         """The dual-space Newton solver needs l2 > 0 (Woodbury ridge), a
@@ -659,20 +691,7 @@ class RandomEffectOptimizationProblem:
         )
 
     def _use_dense(self, bucket, d_local: int) -> bool:
-        if self.layout != "auto":
-            return self.layout == "dense"
-        e_b, s_b, _ = bucket.indices.shape
-        itemsize = np.dtype(bucket.values.dtype).itemsize
-        # X [E, S, D], plus the Newton path's Gram G [E, S, S] when that
-        # solver would actually run (the CG solve is matrix-free — no
-        # second S x S block) — when S > D the Grams, not X, dominate the
-        # footprint, but charging them to a bucket that can only take the
-        # plain dense solver would wrongly force the slow sparse path.
-        # Identity-indices buckets pay no X at all (X IS values).
-        floats = 0 if bucket.identity_indices else e_b * s_b * d_local
-        if self._newton_eligible():
-            floats += e_b * s_b * s_b
-        return floats * itemsize <= self.dense_bytes_budget
+        return self._bucket_kind(bucket, d_local) != "sparse"
 
     def _bucket_device_args(self, bucket, with_values=True) -> List[Array]:  # photon: entropy(id-keyed device-array memo; weakref-pinned, never serialized)
         """Device-resident (mesh-sharded if configured) static arrays for a

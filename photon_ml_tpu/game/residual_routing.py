@@ -202,6 +202,13 @@ class PodResidualRouter:
         self.cap = cap
         self.num_slots = n_dev * cap  # per-owner received slot count
 
+        # what one hop moves: every owned row, and of those the rows whose
+        # owner is another device than the one that holds the row
+        self.num_routed_rows = int(np.count_nonzero(owner >= 0))
+        self.cross_shard_rows = int(np.count_nonzero(
+            (owner >= 0) & (owner != np.arange(n_pad) // per_src)
+        ))
+
         # send position == return position: owner * cap + rank; invalid
         # rows point at the trash slot (num_slots)
         send_pos = np.where(
@@ -230,6 +237,7 @@ class PodResidualRouter:
         n_dev_ = n_dev
         axis_ = self.axis
 
+        # (the functions handed to jax.jit name the XLA modules)
         # photon: sharding(axes=[entity], in=[entity,entity], out=[entity])
         @jax.jit
         @partial(
@@ -239,7 +247,7 @@ class PodResidualRouter:
             out_specs=P(axis_),
             check_vma=False,
         )
-        def _route_in(vals, pos):
+        def pod_route_in(vals, pos):
             buf = jnp.zeros((n_dev_ * cap_ + 1,), jnp.float32)
             buf = buf.at[pos].set(vals, mode="drop")[:-1]
             blocks = buf.reshape(n_dev_, cap_)
@@ -248,7 +256,7 @@ class PodResidualRouter:
             )
             return out.reshape(-1)
 
-        self._route_in = _route_in
+        self._route_in = pod_route_in
 
         # photon: sharding(axes=[entity], in=[entity,entity], out=[entity])
         @jax.jit
@@ -259,7 +267,7 @@ class PodResidualRouter:
             out_specs=P(axis_),
             check_vma=False,
         )
-        def _route_out(slot_vals, pos):
+        def pod_route_out(slot_vals, pos):
             blocks = slot_vals.reshape(n_dev_, cap_)
             back = lax.all_to_all(
                 blocks, axis_, split_axis=0, concat_axis=0, tiled=False
@@ -267,7 +275,7 @@ class PodResidualRouter:
             safe = jnp.minimum(pos, n_dev_ * cap_ - 1)
             return jnp.where(pos < n_dev_ * cap_, back[safe], 0.0)
 
-        self._route_out = _route_out
+        self._route_out = pod_route_out
 
     def _pad_rows(self, vec: Array) -> Array:
         vec = jnp.asarray(vec, jnp.float32)
