@@ -14,7 +14,9 @@ The KeyValueScore residual currency is a row-aligned [n] array here; every
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, replace
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -78,12 +80,49 @@ class Coordinate:
         occupy the device (overlap prefetched dispatch). Default: no-op."""
 
 
-@jax.jit
-def fe_score(means: Array, batch) -> Array:
+@partial(jax.jit, static_argnames=("mesh", "axis"))
+def fe_score(means: Array, batch, objective=None, *, mesh=None, axis=None) -> Array:
     """The fixed effect's scoring pass as one named program (module
-    ``fe_score``, scope ``cd.score``) a device trace can place."""
+    ``fe_score``, scope ``cd.score``) a device trace can place:
+    ``compute_scores``' numbers in dataset row order, by one of two
+    implementations.
+
+    Given a ``SparseBatch`` it is the gather (``compute_scores``). Given
+    the coordinate's ``TiledSparseBatch`` and its ``TiledGLMObjective`` it
+    rides the margin pass (``objective.scores``: kernel
+    ``photon_tiled_margin`` plus the spilled entries) and cuts the padded
+    row space back to the dataset's rows; both tiled layouts keep global
+    row r at position r and pad only at the end. Under ``mesh`` the pass
+    runs under ``shard_map`` over ``axis``, each device over its own
+    schedule with no ``psum``, and the vector leaves this program
+    replicated over the mesh: the layout the CD score algebra carries
+    (what the pod's scores have and its ``route_in`` takes)."""
     with jax.named_scope("cd.score"):
-        return compute_scores(means, batch)
+        if objective is None:
+            return compute_scores(means, batch)
+        rows = batch.meta.num_real_rows
+        if mesh is None:
+            return objective.scores(means, batch)[:rows]
+        from jax.sharding import PartitionSpec as P
+
+        from photon_ml_tpu.parallel.mesh import replicated
+
+        # photon: sharding(axes=[data], in=[r,data], out=[r])
+        padded = jax.shard_map(
+            objective.scores, mesh=mesh, in_specs=(P(), P(axis)),
+            out_specs=P(axis), check_vma=False,
+        )(means, batch)
+        # (gathered whole, then cut: the cut of a row-sharded vector at a
+        # row count the mesh does not divide is a halo exchange)
+        return jax.lax.with_sharding_constraint(
+            padded, replicated(mesh)
+        )[:rows]
+
+
+# One build of a coordinate's tile schedules at a time: ``score()`` on the
+# main thread and a prefetched ``prepare()`` on the worker may both ask for
+# them; the first builds, the other waits and finds them.
+_TILED_BUILD_LOCK = threading.Lock()
 
 
 @dataclass
@@ -104,9 +143,15 @@ class FixedEffectCoordinate(Coordinate):
     A problem built on the tiled objective (``kernel == "tiled"``: what
     the GAME driver resolves on a TPU) follows the same rule on one
     device and on the data-parallel mesh: the coordinate holds the shard
-    as a ``TiledSparseBatch``, its schedules built once (in ``prepare()``
-    or the first update), and every update swaps in the row vectors on
-    the device.
+    as a ``TiledSparseBatch``, its schedules built once (by whichever of
+    ``prepare()``, ``score()`` and the first update comes first), and
+    every update swaps in the row vectors on the device. Such a coordinate
+    SCORES on the margin kernel too (``fe_score`` over the same tiled
+    batch, row-sharded under the mesh), as long as the schedules hold
+    every entry that scores: ``_sparse_coo`` builds weight-0 rows out, so
+    a shard with features on such a row keeps the gather over the
+    dataset's ``SparseBatch``, as the scatter objective and the
+    feature-sharded (data, model) mesh do. ``score_kernel`` says which.
     """
 
     name: str
@@ -161,14 +206,19 @@ class FixedEffectCoordinate(Coordinate):
         dataset's cached device columns, so the coordinates a combo grid
         builds afresh share one build."""
         base = self.__dict__.get("_tiled")
-        if base is None:
-            from photon_ml_tpu.ops.tiled_sparse import (
-                bucket_spill,
-                ensure_tiled,
-                ensure_tiled_sharded,
-            )
-            from photon_ml_tpu.optim.problem import _row_axis
+        if base is not None:
+            return base
+        from photon_ml_tpu.ops.tiled_sparse import (
+            bucket_spill,
+            ensure_tiled,
+            ensure_tiled_sharded,
+        )
+        from photon_ml_tpu.optim.problem import _row_axis
 
+        with _TILED_BUILD_LOCK:
+            base = self.__dict__.get("_tiled")
+            if base is not None:  # built while this thread waited
+                return base
             sparse = self.dataset.batch_for_shard(self.feature_shard_id)
             dim = self.problem.objective.dim
             if self.mesh is None:
@@ -179,8 +229,31 @@ class FixedEffectCoordinate(Coordinate):
                 base = ensure_tiled_sharded(
                     sparse, dim, self.mesh, _row_axis(self.mesh)
                 )
+            # noted once, with the build: do the schedules hold every
+            # entry that scores? (the build drops weight-0 rows; one with
+            # no feature scores 0 either way, as the row padding does)
+            dead = ~(np.asarray(self.dataset.weights) > 0)
+            values = self.dataset.shards[self.feature_shard_id].values
+            self.__dict__["_tiled_scores"] = not (
+                dead.any() and np.any(np.asarray(values)[dead])
+            )
             self.__dict__["_tiled"] = base
         return base
+
+    def _scoring_batch(self):
+        """The tiled batch when scoring rides the margin kernel, None when
+        it takes the gather: decided on the objective's type, the mesh's
+        axes and whether the schedules hold every entry that scores."""
+        if self.kernel != "tiled" or self._is_feature_sharded():
+            return None
+        base = self._tiled_base()
+        return base if self.__dict__["_tiled_scores"] else None
+
+    @property
+    def score_kernel(self) -> str:
+        """How ``score()`` computes, "tiled" | "gather": for ``cd.score``
+        and ``photon_fe_scores_total``."""
+        return "gather" if self._scoring_batch() is None else "tiled"
 
     def _refresh_rows(self, cached, row_sharding, residual):
         """``cached`` (a tiled or feature-sharded layout) with this
@@ -546,9 +619,31 @@ class FixedEffectCoordinate(Coordinate):
         return out
 
     def score(self, model: FixedEffectModel) -> Array:
+        means = model.model.means
+        tiled = self._scoring_batch()
+        default_registry().counter(
+            "photon_fe_scores_total",
+            "scoring passes of the fixed effects, by coordinate and "
+            "kernel (tiled | gather)",
+        ).inc(
+            1, coordinate=self.name,
+            kernel="gather" if tiled is None else "tiled",
+        )
+        if tiled is None:
+            return fe_score(
+                means, self.dataset.batch_for_shard(self.feature_shard_id)
+            )
+        axis = None
+        if self.mesh is not None:
+            from photon_ml_tpu.optim.problem import _row_axis
+            from photon_ml_tpu.parallel.mesh import replicated
+
+            axis = _row_axis(self.mesh)
+            # (the zero model is one device's array, a solved one the
+            # mesh's: placed alike, they share one compiled program)
+            means = jax.device_put(means, replicated(self.mesh))
         return fe_score(
-            model.model.means,
-            self.dataset.batch_for_shard(self.feature_shard_id),
+            means, tiled, self.problem.objective, mesh=self.mesh, axis=axis
         )
 
     def regularization_term(self, model: FixedEffectModel) -> float:
@@ -569,8 +664,9 @@ class FixedEffectCoordinate(Coordinate):
     def prepare(self, model=None) -> None:
         """Stage the solve's static inputs ahead of update_model: the
         feature-sharded layout or the tiled schedules (each built once,
-        multi-second cold), else the scatter path's device copies of the
-        shard columns."""
+        multi-second cold; ``score()`` rides the schedules too and waits
+        for a build under way here), else the scatter path's device
+        copies of the shard columns."""
         if self._is_feature_sharded():
             self._feature_sharded_state()
         elif self.kernel == "tiled":

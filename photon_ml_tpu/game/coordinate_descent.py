@@ -166,6 +166,17 @@ class CoordinateDescent:
             cd_objective(loss, total_score, off, lab, w, reg_terms), float
         )
 
+    def _score(self, name: str, model) -> Array:
+        """One scoring pass under its ``cd.score`` span, which says how a
+        fixed effect scored (``kernel``: tiled | gather)."""
+        coord = self.coordinates[name]
+        with obs_span("cd.score", coordinate=name) as sp:
+            score = coord.score(model)
+            kernel = getattr(coord, "score_kernel", None)
+            if kernel:
+                sp.set(kernel=kernel)
+        return score
+
     def run(
         self,
         num_iterations: int,
@@ -193,22 +204,29 @@ class CoordinateDescent:
                     "resumed coordinate descent from checkpoint step %d", latest
                 )
         # Prefetched dispatch (overlap lever 3) starts before the first
-        # update: once the first coordinate's starting model is scored, its
-        # host prep (a fixed effect's tile schedules: seconds when cold)
-        # runs on the worker UNDER the other coordinates' scoring passes
-        # and their compiles. As below, the worker only touches the
-        # coordinate the main thread is not working on.
+        # update: the first coordinate's host prep (a fixed effect's tile
+        # schedules: seconds when cold) runs on the worker UNDER the other
+        # coordinates' scoring passes and their compiles, and its own
+        # starting model is scored last, once the prep is done: a fixed
+        # effect on the tiled objective scores on the schedules too. As
+        # below, the worker only touches the coordinate the main thread
+        # is not working on.
         pending: Dict[str, object] = {}
-        for j, name in enumerate(seq):
-            with obs_span("cd.score", coordinate=name):
-                scores[name] = self.coordinates[name].score(models[name])
-            if (
-                j == 0 and len(seq) > 1 and overlap.overlap_enabled()
-                and start_iteration < num_iterations
-            ):
-                pending[name] = overlap.submit(
-                    self.coordinates[name].prepare, models[name]
-                )
+        order = list(seq)
+        if (
+            len(seq) > 1 and overlap.overlap_enabled()
+            and start_iteration < num_iterations
+        ):
+            first = seq[0]
+            pending[first] = overlap.submit(
+                self.coordinates[first].prepare, models[first]
+            )
+            order = [n for n in seq if n != first] + [first]
+        for name in order:
+            if name in pending:
+                with obs_span("cd.prefetch_wait", coordinate=name):
+                    overlap.wait(pending.pop(name))
+            scores[name] = self._score(name, models[name])
 
         objective_history: List[float] = []
         trackers: Dict[str, List[object]] = {name: [] for name in seq}
@@ -303,8 +321,7 @@ class CoordinateDescent:
                             models[name], residual
                         )
                     trackers[name].append(tracker)
-                    with obs_span("cd.score", coordinate=name):
-                        new_score = coord.score(models[name])
+                    new_score = self._score(name, models[name])
                     total = (
                         cd_total(residual, new_score)
                         if residual is not None

@@ -1743,6 +1743,13 @@ class TiledGLMObjective:
     with the margins/gradient passes running the tiled Pallas kernels
     instead of gather/scatter. Methods take the batch as an argument (pass
     it through jit — it is a pytree).
+
+    Scoring rides the margin pass too: :meth:`scores` is the same kernel
+    launch for ORIGINAL-space coefficients, with no normalisation and no
+    offsets (``models.glm.compute_scores``' numbers). It sees what the
+    schedule holds: an entry of a row that ``_sparse_coo`` built out
+    (weight 0) scores 0 here, so a caller whose rows were not all live at
+    build time keeps the gather for them.
     """
 
     loss: object
@@ -1817,6 +1824,16 @@ class TiledGLMObjective:
             w_eff = self.norm.effective_coefficients(coef)
             raw = self._z_pass(self._pad(w_eff, batch), batch)
             return raw - self.norm.shift_dot(w_eff) + batch.offsets
+
+    def scores(self, coef: Array, batch: TiledSparseBatch) -> Array:
+        """x_i . coef in padded row space, for ORIGINAL-space ``coef``: the
+        raw row sums of the margin pass (kernel ``photon_tiled_margin`` +
+        the spilled entries), what ``models.glm.compute_scores`` returns
+        for the rows the schedule holds. No normalisation, no offsets and
+        no ``psum``: under ``shard_map`` each device scores its own rows.
+        (:meth:`margins` takes NORMALISED-space coefficients.)"""
+        with jax.named_scope("objective.scores"):
+            return self._z_pass(self._pad(coef, batch), batch)
 
     # -- value / gradient --------------------------------------------------
 
