@@ -133,9 +133,9 @@ def _user_id(u: int) -> str:
 
 def _planted_model(size: SmokeSize):
     """(w_fixed [fixed_dim], w_user [users, user_dim]) from SEED: normal
-    weights on a random fifth of the coordinates (bench.py's planted
-    recipe), scaled so the fixed part of a row's margin has standard
-    deviation 3.5 and the per-user part 1.5. At 16 rows per feature that
+    weights on a random fifth of the coordinates, scaled so the fixed
+    part of a row's margin has standard deviation 3.5 and the per-user
+    part 1.5. At 16 rows per feature that
     is what leaves a fixed-effect-only fit a validation AUC near 0.62."""
     rng = np.random.default_rng([SEED, 0])
     density = 0.2

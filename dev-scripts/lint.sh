@@ -31,21 +31,21 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-python -m photon_ml_tpu.lint photon_ml_tpu bench.py "$@"
+python -m photon_ml_tpu.lint photon_ml_tpu "$@"
 
 skip_spmd=0
 for arg in "$@"; do
     [ "$arg" = "--no-spmd" ] && skip_spmd=1
 done
 if [ "$skip_spmd" = 0 ]; then
-    python -m photon_ml_tpu.lint photon_ml_tpu bench.py \
+    python -m photon_ml_tpu.lint photon_ml_tpu \
         --check-sharding-md SHARDING.md
 fi
 
 if command -v ruff >/dev/null 2>&1; then
-    ruff check photon_ml_tpu bench.py tests dev-scripts
+    ruff check photon_ml_tpu tests dev-scripts
 elif python -c "import ruff" >/dev/null 2>&1; then
-    python -m ruff check photon_ml_tpu bench.py tests dev-scripts
+    python -m ruff check photon_ml_tpu tests dev-scripts
 else
     echo "lint.sh: ruff not installed — skipping ruff check" >&2
 fi

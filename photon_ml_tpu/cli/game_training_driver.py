@@ -253,8 +253,7 @@ class GameTrainingParams:
     tile_cache_dir: Optional[str] = None
     # Escape hatch for the host-device overlap layer (parallel/overlap.py):
     # True runs fully serial — eager readbacks, inline host prep,
-    # synchronous checkpoint/metrics writes (the pre-overlap behavior and
-    # the dev-scripts/bench_overlap.sh A/B baseline).
+    # synchronous checkpoint/metrics writes (the pre-overlap behavior).
     no_overlap: bool = False
     # Out-of-core GAME training (game/streaming.py): the train set streams
     # once per CD pass through spilled fixed-shape chunks, random effects

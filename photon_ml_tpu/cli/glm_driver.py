@@ -161,8 +161,7 @@ class GLMParams:
     tile_cache_dir: Optional[str] = None
     # Escape hatch for the host-device overlap layer (parallel/overlap.py):
     # True runs fully serial — eager readbacks, inline host prep,
-    # synchronous artifact writes (the pre-overlap behavior, and the A/B
-    # baseline for dev-scripts/bench_overlap.sh).
+    # synchronous artifact writes (the pre-overlap behavior).
     no_overlap: bool = False
     # Diagnostics reservoir bounds for the streaming path: the sample is
     # rows x max_nnz dense (int32+float32), so wide-row datasets must not

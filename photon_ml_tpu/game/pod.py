@@ -36,8 +36,7 @@ The streamed path (game/streaming.py) reuses the same fused sharded
 segment solve: each ``SpilledREBuckets`` segment is split by the same
 entity hash so a device only ever stages its own shard of a segment.
 
-Weak-scaling contract (pinned by tests/test_pod_game.py and bench.py's
-``12_pod_game``): at N shards, per-device bank + optimizer-state bytes
+Weak-scaling contract (pinned by tests/test_pod_game.py): at N shards, per-device bank + optimizer-state bytes
 are ~1/N of the replicated path for the same model, with CD parity
 inside the established fp32 envelopes.
 """
@@ -242,8 +241,8 @@ class ShardedREBank:
 
 def per_device_bytes(*values) -> int:
     """Max bytes any single device holds across the given arrays /
-    ShardedREBanks — the weak-scaling accounting the tests and bench
-    pin (per-device bank + optimizer-state bytes ~flat as total
+    ShardedREBanks — the weak-scaling accounting the tests pin
+    (per-device bank + optimizer-state bytes ~flat as total
     coefficients grow with the shard count)."""
     per: Dict[object, int] = {}
     for v in values:

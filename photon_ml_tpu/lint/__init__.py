@@ -78,7 +78,7 @@ Opt out per-invocation with ``--no-concurrency`` / ``--no-spmd`` /
 
 Usage::
 
-    python -m photon_ml_tpu.lint photon_ml_tpu bench.py
+    python -m photon_ml_tpu.lint photon_ml_tpu
     python -m photon_ml_tpu.lint --json photon_ml_tpu
     dev-scripts/lint.sh            # photon-lint + ruff (when installed)
 

@@ -21,7 +21,7 @@ from photon_ml_tpu.lint.baseline import (
 from photon_ml_tpu.lint.core import all_rules, analyze_paths
 
 DEFAULT_BASELINE = ".photon-lint-baseline.json"
-DEFAULT_PATHS = ("photon_ml_tpu", "bench.py")
+DEFAULT_PATHS = ("photon_ml_tpu",)
 
 
 def _default_paths() -> List[str]:
@@ -49,7 +49,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "paths", nargs="*",
-        help="files or directories (default: photon_ml_tpu bench.py)",
+        help="files or directories (default: photon_ml_tpu)",
     )
     p.add_argument(
         "--json", action="store_true", dest="as_json",

@@ -53,7 +53,7 @@ logger = logging.getLogger(__name__)
 # Bump whenever the schedule array layout or builder semantics change:
 # the version is part of both the artifact path and meta.json, so old
 # artifacts simply miss and are rebuilt.
-SCHEDULE_CACHE_VERSION = 1
+SCHEDULE_CACHE_VERSION = 2
 
 ENV_CACHE_DIR = "PHOTON_TILE_CACHE_DIR"
 ENV_WAIT_S = "PHOTON_TILE_CACHE_WAIT_S"

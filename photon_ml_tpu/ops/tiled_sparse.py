@@ -1,11 +1,13 @@
 """Tiled sparse GLM kernels: gather/scatter-free margins and gradients.
 
-WHY: on TPU, XLA lowers random gather/scatter to ~7ns/element serial loops
-(measured on the chip, round 1), so the reference's two hot loops (margin
-accumulation and gradient axpy, ValueAndGradientAggregator.scala:133-154)
-are 100x slower than the hardware's streaming rate. This module replaces
-both with a STATIC TILED layout + two Pallas kernels whose only per-entry
-operations are VPU compares and MXU matmuls:
+WHY: on TPU, XLA lowers random gather/scatter to serial per-element
+loops, so the reference's two hot loops (margin accumulation and gradient
+axpy, ValueAndGradientAggregator.scala:133-154) run far below the
+hardware's streaming rate (PERF_LEDGER.jsonl, PR 28, `glmix-ads-100m.cd`:
+`cd_fe_eval_ms` 589.13 on the scatter objective, 115.33 on these kernels
+at `highest`). This module replaces both with a STATIC TILED layout + two
+Pallas kernels whose only per-entry operations are VPU compares and MXU
+matmuls:
 
 - Entries are binned into (row-window x feature-window) tiles; windows are
   R_WIN = F_WIN = S_HI * S_LO positions wide.
@@ -46,31 +48,20 @@ Array = jnp.ndarray
 
 @dataclass(frozen=True)
 class TileParams:
-    # Defaults from on-chip sweeps at the ads shape (262k x 64nnz x 1M,
-    # the round-2 tile sweep): window-shape changes (s_hi=s_lo=128, or
-    # 64/128) were net losses. ``chunk=None`` sizes the grid-step width
-    # from the dataset's average tile occupancy at build time (pow2 of the
-    # mean entries per tile, clamped to [1024, 4096]) — at the ads shape
-    # that picks 4096, which with the bf16x2w full-width matmuls measured
-    # 23.9 ms vs 25.8 ms for the old fixed 2048 (fewer grid steps, ~99.5%
-    # slot fill because the mean tile holds ~4078 entries).
+    # ``chunk=None`` sizes the grid-step width from the dataset's average
+    # tile occupancy at build time (see ``resolved``): fewer, fuller grid
+    # steps. What a step and a launch cost at the benchmark's shapes is
+    # in PERF.md section 5.
     s_hi: int = 128
     s_lo: int = 64
     chunk: Optional[int] = None  # entries per grid step; None = auto
-    # Independent compute chains per grid step (chunk lane-sliced into
-    # `split` sub-chunks with no data dependency). Measured on-chip:
-    # Mosaic does NOT overlap the chains (split=2 cost ~1.3-1.7 ms at
-    # every chunk size), so the default stays 1; the knob remains for
-    # kernel experiments. chunk must be divisible by split * 128.
-    split: int = 1
     # Spill-to-scatter threshold: a tile whose entry count modulo the
     # chunk leaves a remainder <= spill_cap routes that remainder to a
     # small XLA gather/scatter path instead of paying a nearly-empty
     # grid step (and a tile with <= spill_cap entries total spills
-    # entirely). Break-even (measured, ads shape): one grid step costs
-    # ~3.9 us while a spilled entry costs ~15 ns of serialized
-    # gather+scatter, so the cap defaults to chunk // 16 (~260 at chunk
-    # 4096). None = default; 0 disables spilling.
+    # entirely). A grid step costs a few hundred spilled entries'
+    # serialized gather+scatter, so the cap defaults to chunk // 16 (~260
+    # at chunk 4096). None = default; 0 disables spilling.
     spill_cap: Optional[int] = None
 
     @property
@@ -91,11 +82,10 @@ class TileParams:
         chunk is mean + 2*sqrt(mean) rounded up to a lane multiple: tile
         occupancy concentrates around the mean (Poisson-ish), so a chunk
         just past the +2-sigma tail holds ~98% of tiles in ONE ~97%-full
-        step and spills only the far tail. Measured at the ads shape
-        (mean 4078): chunk 4224 -> 16.5 ms/eval vs 18.6 at pow2 4096
-        (104k spills -> 2.3k) vs 23.1 without spilling. Multi-chunk
-        tiles (mean > 4096) keep the pow2 rule — the remainder logic
-        already spills or pads their tails."""
+        step and spills only the far tail (at a mean of 4078 entries a
+        tile: chunk 4224, 2.3k spilled entries where pow2 4096 spills
+        104k). Multi-chunk tiles (mean > 4096) keep the pow2 rule — the
+        remainder logic already spills or pads their tails."""
         if self.chunk is not None:
             return self
         import dataclasses
@@ -103,12 +93,10 @@ class TileParams:
         avg = max(1, n_entries // max(n_tiles_hint, 1))
         lo = min(1024, self.window)
         spilling = self.spill_cap is None or self.spill_cap > 0
-        # lane slices in the kernel are chunk // split wide, so the
-        # resolved chunk must divide by split * 128
-        align = 128 * max(1, self.split)
         if spilling and avg <= 4096:
-            c = int(-(-int(avg + 2.0 * np.sqrt(avg)) // align) * align)
-            c = max(lo, min(-(-4608 // align) * align, c))
+            # entries live on lanes: round up to a multiple of 128
+            c = -(-int(avg + 2.0 * np.sqrt(avg)) // 128) * 128
+            c = max(lo, min(4608, c))
         else:
             c = 1 << int(np.round(np.log2(avg)))
             c = max(lo, min(4096, c))
@@ -296,8 +284,7 @@ def _build_schedule_np(
     (ops/schedule_cache.py — a hit returns mmap-backed arrays and skips
     the build entirely), then the native counting-sort builder; the numpy
     path below is the fallback oracle (vectorized repeat/cumsum/scatter —
-    no per-entry Python loops; the round-2 loop version cost 17-77 s at the
-    ads shape, this is ~8 s, the native builder ~0.3 s).
+    no per-entry Python loops).
 
     ``digest``: precomputed content digest of (rows, feats, vals) so
     callers building BOTH passes from one triple hash it once."""
@@ -936,8 +923,8 @@ class FeatureShardedTiledBatch(NamedTuple):
     for its row shard (psum over "model" completes them); its g-schedule
     produces the block-local gradient (psum over "data" completes it) —
     same collective pattern as parallel.distributed's scatter-based sparse
-    layout, but running the Pallas bilinear kernels instead of
-    ~7ns/element gather/scatter loops.
+    layout, but running the Pallas bilinear kernels instead of XLA's
+    gather/scatter loops.
 
     Schedule leaves concatenate cells along axis 0 in data-major,
     model-minor order, all cells padded to one static shape, so shard_map
@@ -1352,7 +1339,6 @@ def _bilinear_pass_kernel(
     s_lo: int,
     chunk: int,
     mxu: str,
-    split: int = 1,
     onehot: str = "compare",
 ):
     """One grid step: expand src at in_pos, multiply by vals,
@@ -1382,8 +1368,7 @@ def _bilinear_pass_kernel(
     )  # [1, L] float32
 
     def _split(x):
-        # hi + lo bf16 terms of an f32 array (~16 mantissa bits kept);
-        # shared by both bf16 variants — keep their numerics identical
+        # hi + lo bf16 terms of an f32 array (~16 mantissa bits kept)
         hi_part = x.astype(jnp.bfloat16)
         lo_part = (x - hi_part.astype(jnp.float32)).astype(jnp.bfloat16)
         return hi_part, lo_part
@@ -1404,7 +1389,7 @@ def _bilinear_pass_kernel(
         one-hot EXACTNESS, which the bf16 split relies on, survives.
         Trades the [s, width] compare chain for a matmul + one
         elementwise pass; whether Mosaic schedules it better than the
-        compare is the A/B bench.py carries."""
+        compare has no ledger row yet (ROADMAP S1b)."""
         if onehot == "mxu":
             # Mosaic's iota is integer-only: count in int32, then cast
             i_col = jax.lax.broadcasted_iota(
@@ -1439,112 +1424,71 @@ def _bilinear_pass_kernel(
             precision=jax.lax.Precision.DEFAULT,
         )
 
-    def _chain(ip, op, v, width):
-        """One independent gather->contrib->scatter chain over ``width``
-        entry lanes -> update [S_HI, S_LO]."""
-        ih = ip // s_lo
-        il = ip - ih * s_lo
-        oh = op // s_lo
-        ol = op - oh * s_lo
-        dims_in = (((0,), (0,)), ((), ()))
-        dims_out = (((1,), (1,)), ((), ()))
+    # gather -> contrib -> scatter over the L entry lanes ->
+    # update [S_HI, S_LO]
+    ih = ip_full // s_lo
+    il = ip_full - ih * s_lo
+    oh = op_full // s_lo
+    ol = op_full - oh * s_lo
+    dims_in = (((0,), (0,)), ((), ()))
+    dims_out = (((1,), (1,)), ((), ()))
 
-        if mxu == "bf16x2w":
-            # Same hi+lo bf16 data split as "bf16x2", but each pass's TWO
-            # half-width matmuls fuse into ONE full-width matmul by packing
-            # the hi and lo terms into the otherwise idle half of the MXU
-            # tile (s_lo = 64 uses 64 of 128 sublanes/lanes): identical MAC
-            # count at ~2x the effective utilization.
-            oh_in_hi = _expand(ih, s_hi, width, jnp.bfloat16)  # [S_HI, w]
+    if mxu == "bf16x2w":
+        # One-hot matrices are 0/1 — EXACT in bf16. Only the data
+        # operand carries mantissa, so instead of Precision.HIGHEST (6
+        # bf16 MXU passes for f32 x f32) the data side is split into
+        # two bf16 terms (hi + lo, ~16 mantissa bits, ~1e-5 rel error),
+        # and the TWO half-width matmuls that would take fuse into ONE
+        # full-width matmul by packing the hi and lo terms into the
+        # otherwise idle half of the MXU tile (s_lo = 64 uses 64 of 128
+        # sublanes/lanes).
+        oh_in_hi = _expand(ih, s_hi, L, jnp.bfloat16)  # [S_HI, L]
 
-            # gather: pack [hi | lo] along the lane axis -> [S_HI, 2*S_LO]
-            s1, s2 = _split(src_ref[0])
-            src_cat = jnp.concatenate([s1, s2], axis=1)
-            a_cat = _bf16_dot(
-                src_cat, oh_in_hi, dims_in
-            )  # [2*S_LO, w]: rows [0,S_LO) = hi terms, [S_LO,2*S_LO) = lo
-            # fold the halves first (sublane slice at a multiple of 8) so
-            # the mask-reduce runs at [S_LO, w] instead of [2*S_LO, w]
-            a = a_cat[:s_lo] + a_cat[s_lo:]
-            oh_in_lo = _expand(il, s_lo, width, jnp.float32)
-            src_g = jnp.sum(a * oh_in_lo, axis=0, keepdims=True)  # [1, w]
-            contrib = v * src_g
+        # gather: pack [hi | lo] along the lane axis -> [S_HI, 2*S_LO]
+        s1, s2 = _split(src_ref[0])
+        src_cat = jnp.concatenate([s1, s2], axis=1)
+        a_cat = _bf16_dot(
+            src_cat, oh_in_hi, dims_in
+        )  # [2*S_LO, L]: rows [0,S_LO) = hi terms, [S_LO,2*S_LO) = lo
+        # fold the halves first (sublane slice at a multiple of 8) so
+        # the mask-reduce runs at [S_LO, L] instead of [2*S_LO, L]
+        a = a_cat[:s_lo] + a_cat[s_lo:]
+        oh_in_lo = _expand(il, s_lo, L, jnp.float32)
+        src_g = jnp.sum(a * oh_in_lo, axis=0, keepdims=True)  # [1, L]
+        contrib = v_full * src_g
 
-            # scatter: RHS rows [0,S_LO) carry onehot*c_hi, [S_LO,2*S_LO)
-            # carry onehot*c_lo -> one [S_HI, 2*S_LO] product; the two lane
-            # halves fold with an exact VPU add. The RHS is built from ONE
-            # [S_LO, w] one-hot compare + a sublane concat (round 2 used a
-            # [2*S_LO, w] compare + arithmetic 0/1 blend — twice the VPU
-            # compare work for the same matrix).
-            c1, c2 = _split(contrib)
-            oh_out_hi = _expand(oh, s_hi, width, jnp.bfloat16)
-            oh_out_lo = _expand(ol, s_lo, width, jnp.bfloat16)
-            rhs = jnp.concatenate(
-                [oh_out_lo * c1, oh_out_lo * c2], axis=0
-            )  # [2*S_LO, w]
-            update_wide = _bf16_dot(
-                oh_out_hi, rhs, dims_out
-            )  # [S_HI, 2*S_LO]
-            return update_wide[:, :s_lo] + update_wide[:, s_lo:]
-        elif mxu == "bf16x2":
-            # One-hot matrices are 0/1 — EXACT in bf16. Only the data
-            # operand carries mantissa, so instead of Precision.HIGHEST (6
-            # bf16 MXU passes for f32 x f32) we split the data side into
-            # two bf16 terms (hi + lo, ~16 mantissa bits, ~1e-5 rel error)
-            # and run 2 single-pass bf16 matmuls — 3x the MXU throughput
-            # at GLM-sufficient precision.
-            oh_in_hi = _expand(ih, s_hi, width, jnp.bfloat16)  # [S_HI, w]
-            oh_in_lo = _expand(il, s_lo, width, jnp.float32)  # [S_LO, w]
-
-            # gather: src_g[p] = src2d[ih[p], il[p]]
-            s1, s2 = _split(src_ref[0])
-            a = _bf16_dot(s1, oh_in_hi, dims_in) + _bf16_dot(
-                s2, oh_in_hi, dims_in
-            )  # [S_LO, w]
-            src_g = jnp.sum(a * oh_in_lo, axis=0, keepdims=True)  # [1, w]
-            contrib = v * src_g  # [1, w]
-
-            oh_out_hi = _expand(oh, s_hi, width, jnp.bfloat16)
-            oh_out_lo = _expand(ol, s_lo, width, jnp.bfloat16)
-            # A @ B^T via lane/entry contraction. oh_out_lo is 0/1 and the
-            # contrib terms are already bf16, so each product is exact.
-            c1, c2 = _split(contrib)
-            return _bf16_dot(
-                oh_out_hi, oh_out_lo * c1, dims_out
-            ) + _bf16_dot(
-                oh_out_hi, oh_out_lo * c2, dims_out
-            )  # [S_HI, S_LO]
-        else:  # "highest": full f32 emulation, ~3x slower, ~1e-7 rel error
-            oh_in_hi = _expand(ih, s_hi, width, jnp.float32)
-            oh_in_lo = _expand(il, s_lo, width, jnp.float32)
-            a = jax.lax.dot_general(
-                src_ref[0], oh_in_hi, dims_in,
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.HIGHEST,
-            )
-            src_g = jnp.sum(a * oh_in_lo, axis=0, keepdims=True)
-            contrib = v * src_g
-            oh_out_hi = _expand(oh, s_hi, width, jnp.float32)
-            oh_out_lo = _expand(ol, s_lo, width, jnp.float32)
-            return jax.lax.dot_general(
-                oh_out_hi, oh_out_lo * contrib, dims_out,
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.HIGHEST,
-            )
-
-    # `split` independent chains over lane slices of the chunk: no data
-    # dependency between them, so the scheduler can overlap one chain's
-    # VPU one-hot build with another's MXU passes.
-    w = L // split
-    update = _chain(
-        ip_full[:, :w], op_full[:, :w], v_full[:, :w], w
-    )
-    for h in range(1, split):
-        update = update + _chain(
-            ip_full[:, h * w:(h + 1) * w],
-            op_full[:, h * w:(h + 1) * w],
-            v_full[:, h * w:(h + 1) * w],
-            w,
+        # scatter: RHS rows [0,S_LO) carry onehot*c_hi, [S_LO,2*S_LO)
+        # carry onehot*c_lo -> one [S_HI, 2*S_LO] product; the two lane
+        # halves fold with an exact VPU add. The RHS is built from ONE
+        # [S_LO, L] one-hot compare + a sublane concat (round 2 used a
+        # [2*S_LO, L] compare + arithmetic 0/1 blend — twice the VPU
+        # compare work for the same matrix).
+        c1, c2 = _split(contrib)
+        oh_out_hi = _expand(oh, s_hi, L, jnp.bfloat16)
+        oh_out_lo = _expand(ol, s_lo, L, jnp.bfloat16)
+        rhs = jnp.concatenate(
+            [oh_out_lo * c1, oh_out_lo * c2], axis=0
+        )  # [2*S_LO, L]
+        update_wide = _bf16_dot(
+            oh_out_hi, rhs, dims_out
+        )  # [S_HI, 2*S_LO]
+        update = update_wide[:, :s_lo] + update_wide[:, s_lo:]
+    else:  # "highest": full f32 emulation, slower, ~1e-7 rel error
+        oh_in_hi = _expand(ih, s_hi, L, jnp.float32)
+        oh_in_lo = _expand(il, s_lo, L, jnp.float32)
+        a = jax.lax.dot_general(
+            src_ref[0], oh_in_hi, dims_in,
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        src_g = jnp.sum(a * oh_in_lo, axis=0, keepdims=True)
+        contrib = v_full * src_g
+        oh_out_hi = _expand(oh, s_hi, L, jnp.float32)
+        oh_out_lo = _expand(ol, s_lo, L, jnp.float32)
+        update = jax.lax.dot_general(
+            oh_out_hi, oh_out_lo * contrib, dims_out,
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
 
     @pl.when(step_init_ref[g] == 1)
@@ -1559,11 +1503,6 @@ def _bilinear_pass_kernel(
 # The two directions of the bilinear pass, as the device trace names them.
 MARGIN_KERNEL = "photon_tiled_margin"  # rows <- coefficients
 GRADIENT_KERNEL = "photon_tiled_gradient"  # coefficients <- rows
-
-# Mosaic compiler-params experiment hook (None = defaults). Sweeps set
-# this to probe e.g. dimension_semantics / vmem_limit_bytes; production
-# leaves it None.
-_COMPILER_PARAMS = None
 
 
 def _grid_bilinear_pass(
@@ -1686,11 +1625,6 @@ def _run_bilinear_pass(
     say ``photon_tiled_margin`` or ``photon_tiled_gradient``)."""
     G = sched.num_steps
     L = params.chunk
-    if L % max(params.split, 1) != 0:
-        # a non-dividing split would silently drop the remainder lanes
-        raise ValueError(
-            f"chunk {L} is not divisible by split {params.split}"
-        )
     entry_spec = pl.BlockSpec((8, L), lambda g, so, si, st: (g // 8, 0))
     src_spec = pl.BlockSpec(
         (1, params.s_hi, params.s_lo), lambda g, so, si, st: (si[g], 0, 0)
@@ -1704,7 +1638,6 @@ def _run_bilinear_pass(
         s_lo=params.s_lo,
         chunk=L,
         mxu=mxu,
-        split=params.split,
         onehot=onehot,
     )
     in_specs = [entry_spec, entry_spec, entry_spec, src_spec]
@@ -1727,7 +1660,6 @@ def _run_bilinear_pass(
             (num_out_blocks, params.s_hi, params.s_lo), jnp.float32
         ),
         interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
         name=name,
     )(*operands)
     return out
@@ -1759,7 +1691,7 @@ class TiledGLMObjective:
     interpret: bool = False
     # "bf16x2w" (default): hi+lo bf16 data split with both half-width
     # matmuls fused into one full-width MXU tile (~1e-5 rel err, fastest);
-    # "bf16x2": the two-matmul variant; "highest" (~1e-7, 2.5x slower).
+    # "highest": full f32 emulation (~1e-7).
     mxu: str = "bf16x2w"
     # Positional-expansion algorithm: "compare" (sublane-iota equality,
     # the round-2 build) or "mxu" (squared-distance matmul + relu — the
@@ -1770,9 +1702,9 @@ class TiledGLMObjective:
     def __post_init__(self):
         if self.norm is None:
             object.__setattr__(self, "norm", identity_context())
-        if self.mxu not in ("bf16x2w", "bf16x2", "highest"):
+        if self.mxu not in ("bf16x2w", "highest"):
             # a typo must not silently fall through to the "highest"
-            # branch (2.5x slower, different numerics)
+            # branch (slower, different numerics)
             raise ValueError(f"unknown mxu variant {self.mxu!r}")
         if self.onehot not in ("compare", "mxu"):
             raise ValueError(f"unknown onehot variant {self.onehot!r}")

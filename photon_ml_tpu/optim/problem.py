@@ -531,9 +531,13 @@ class GLMOptimizationProblem:
 def resolve_kernel(kernel: str, batch=None) -> str:
     """Resolve the objective-kernel choice: "scatter" | "tiled" | "auto".
 
-    "auto" picks the tiled Pallas kernel pair (7x the scatter throughput,
-    round 2 on the chip) when running on TPU with sparse data; the kernels are
-    Mosaic (TPU-only), so every other backend — CPU, GPU — gets scatter.
+    "auto" picks the tiled Pallas kernel pair when running on TPU with
+    sparse data (PERF_LEDGER.jsonl, PR 28, `glmix-ads-100m.cd`:
+    `cd_fe_eval_ms` 589.13 on the scatter objective, 115.33 on the
+    kernels); the kernels are Mosaic (TPU-only), so every other backend —
+    CPU, GPU — gets scatter. An already tiled batch is "tiled" on any
+    platform: only the tiled objective can read one (on the CPU it
+    interprets the kernels).
     """
     if kernel not in ("auto", "tiled", "scatter"):
         raise ValueError(
@@ -542,8 +546,11 @@ def resolve_kernel(kernel: str, batch=None) -> str:
     if kernel != "auto":
         return kernel
     from photon_ml_tpu.data.batch import SparseBatch
+    from photon_ml_tpu.ops.tiled_sparse import TiledSparseBatch
     from photon_ml_tpu.utils.backend import effective_platform
 
+    if isinstance(batch, TiledSparseBatch):
+        return "tiled"
     on_tpu = effective_platform() == "tpu"
     sparse_ok = batch is None or isinstance(batch, SparseBatch)
     return "tiled" if (on_tpu and sparse_ok) else "scatter"

@@ -29,13 +29,7 @@ from photon_ml_tpu.parallel.shuffle import (
 )
 from photon_ml_tpu.parallel.distributed import (
     FeatureShardedSparseBatch,
-    data_parallel_fit_lbfgs,
-    data_parallel_value_and_grad,
     feature_shard_sparse_batch,
-    feature_sharded_fit,
-    feature_sharded_sparse_fit,
-    feature_sharded_sparse_fit_owlqn,
-    feature_sharded_value_and_grad,
 )
 
 __all__ = [
@@ -56,11 +50,5 @@ __all__ = [
     "entity_all_to_all",
     "reshard_capacity",
     "FeatureShardedSparseBatch",
-    "data_parallel_fit_lbfgs",
-    "data_parallel_value_and_grad",
     "feature_shard_sparse_batch",
-    "feature_sharded_fit",
-    "feature_sharded_sparse_fit",
-    "feature_sharded_sparse_fit_owlqn",
-    "feature_sharded_value_and_grad",
 ]

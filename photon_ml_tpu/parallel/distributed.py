@@ -1,10 +1,11 @@
-"""Distributed GLM objectives and training steps under shard_map.
+"""Feature-sharded GLM objectives and training steps under shard_map.
 
 Reference mapping (SURVEY §2.3/§2.4):
-- P1 data parallelism: examples sharded over the "data" axis, coefficients
-  replicated, (value, grad, Hv) psum'ed — replaces
-  DistributedGLMLossFunction + ValueAndGradientAggregator.treeAggregate
-  (ValueAndGradientAggregator.scala:235-250).
+- P1 data parallelism (examples sharded over the "data" axis, coefficients
+  replicated, (value, grad, Hv) psum'ed — DistributedGLMLossFunction +
+  ValueAndGradientAggregator.treeAggregate,
+  ValueAndGradientAggregator.scala:235-250) lives in
+  ``optim.problem.GLMOptimizationProblem.run(mesh=)``, not here.
 - Feature/coefficient parallelism ("model" axis): for coefficient vectors
   too big to replicate, margins decompose over feature blocks
   (z = sum_blocks x_b . w_b -> psum over "model"), and each device keeps
@@ -28,116 +29,12 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
-from photon_ml_tpu.data.batch import Batch
 from photon_ml_tpu.ops.objective import GLMObjective
 from photon_ml_tpu.optim.common import OptResult
 from photon_ml_tpu.optim.lbfgs import minimize_lbfgs
 from photon_ml_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 Array = jnp.ndarray
-
-
-def data_parallel_value_and_grad(
-    objective: GLMObjective,
-    mesh: Mesh,
-    *,
-    data_axis: str = DATA_AXIS,
-) -> Callable:
-    """(w, batch, l2) -> (value, grad), batch sharded over ``data_axis``,
-    coefficients replicated. One psum per evaluation (the treeAggregate)."""
-    obj = objective.with_axis(data_axis)
-
-    # photon: sharding(axes=[data], in=[r,data,r], out=[r,r])
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(), P(data_axis), P()),
-        out_specs=(P(), P()),
-        check_vma=False,
-    )
-    def vg(w, batch, l2):
-        return obj.value_and_gradient(w, batch, l2)
-
-    return jax.jit(vg)
-
-
-def data_parallel_fit_lbfgs(
-    objective: GLMObjective,
-    mesh: Mesh,
-    *,
-    data_axis: str = DATA_AXIS,
-    max_iter: int = 100,
-    tol: float = 1e-7,
-    history: int = 10,
-) -> Callable[[Array, Batch, Array], OptResult]:
-    """Whole L-BFGS fit inside ONE shard_map program: per-iteration psums
-    ride ICI with no host round-trips (vs one treeAggregate round-trip per
-    Breeze iteration in the reference, SURVEY §3.1)."""
-    obj = objective.with_axis(data_axis)
-
-    # photon: sharding(axes=[data], in=[r,data,r], out=[r])
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(), P(data_axis), P()),
-        out_specs=P(),
-        check_vma=False,
-    )
-    def fit(w0, batch, l2):
-        vg = lambda w: obj.value_and_gradient(w, batch, l2)
-        return minimize_lbfgs(
-            vg, w0, max_iter=max_iter, tol=tol, history=history
-        )
-
-    return jax.jit(fit)
-
-
-# ---------------------------------------------------------------------------
-# Feature-axis ("model") sharding for >HBM coefficient vectors
-# ---------------------------------------------------------------------------
-
-
-def feature_sharded_value_and_grad(
-    objective: GLMObjective,
-    mesh: Mesh,
-    *,
-    data_axis: str = DATA_AXIS,
-    model_axis: str = MODEL_AXIS,
-) -> Callable:
-    """2-D sharded objective over DENSE feature blocks.
-
-    Layout: features [n, d] sharded P(data, model); w [d] sharded P(model);
-    per-device partial margins psum over ``model_axis``; loss row-reductions
-    psum over ``data_axis``; gradient blocks stay device-local (each device
-    owns grad[d_block] — reduce-scatter-free by construction). Returns
-    (value replicated, grad sharded P(model)).
-    """
-    loss = objective.loss
-
-    # photon: sharding(axes=[data,model], in=[model,data+model,data,data,data,r], out=[r,model])
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(model_axis), P(data_axis, model_axis), P(data_axis), P(data_axis), P(data_axis), P()),
-        out_specs=(P(), P(model_axis)),
-        check_vma=False,
-    )
-    def vg(w_block, x_block, labels, offsets, weights, l2):
-        # partial margins from this feature block, summed across blocks
-        z = jax.lax.psum(x_block @ w_block, model_axis) + offsets
-        lv = loss.value(z, labels)
-        ld = loss.d1(z, labels)
-        c = weights * ld
-        value = jax.lax.psum(jnp.sum(weights * lv), data_axis)
-        # gradient for THIS feature block only; reduce over examples
-        grad_block = jax.lax.psum(x_block.T @ c, data_axis)
-        # L2 term: w stays sharded; psum the squared-norm contributions
-        w_sq = jax.lax.psum(jnp.vdot(w_block, w_block), model_axis)
-        value = value + 0.5 * l2 * w_sq
-        grad_block = grad_block + l2 * w_block
-        return value, grad_block
-
-    return jax.jit(vg)
 
 
 def _opt_result_specs(model_axis: str, track_models: bool = False) -> OptResult:
@@ -183,53 +80,6 @@ def _opt_result_grid_specs(
         ),
         evaluations=P(),
     )
-
-
-def feature_sharded_fit(
-    objective: GLMObjective,
-    mesh: Mesh,
-    *,
-    data_axis: str = DATA_AXIS,
-    model_axis: str = MODEL_AXIS,
-    max_iter: int = 50,
-    tol: float = 1e-7,
-    history: int = 10,
-) -> Callable:
-    """L-BFGS over a feature-sharded coefficient vector: optimizer state
-    ([m, d_block] memories, w block) lives SHARDED on every device; the only
-    cross-block traffic per iteration is the margin psum and the scalar
-    reductions inside the two-loop recursion (vdots psum over model axis).
-
-    Runs the UNMODIFIED ``minimize_lbfgs`` with ``axis_name=model_axis`` —
-    the same program as the replicated/single-chip path, so convergence
-    rules, trackers, and cautious updates cannot diverge. Returns a full
-    OptResult (coefficients sharded over ``model_axis``).
-    """
-    loss = objective.loss
-
-    # photon: sharding(axes=[data,model], in=[model,data+model,data,data,data,r], out=?)
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(model_axis), P(data_axis, model_axis), P(data_axis), P(data_axis), P(data_axis), P()),
-        out_specs=_opt_result_specs(model_axis),
-        check_vma=False,
-    )
-    def fit(w0_block, x_block, labels, offsets, weights, l2):
-        def vg(w_block):
-            z = jax.lax.psum(x_block @ w_block, model_axis) + offsets
-            c = weights * loss.d1(z, labels)
-            value = jax.lax.psum(jnp.sum(weights * loss.value(z, labels)), data_axis)
-            grad_block = jax.lax.psum(x_block.T @ c, data_axis)
-            w_sq = jax.lax.psum(jnp.vdot(w_block, w_block), model_axis)
-            return value + 0.5 * l2 * w_sq, grad_block + l2 * w_block
-
-        return minimize_lbfgs(
-            vg, w0_block, max_iter=max_iter, tol=tol, history=history,
-            axis_name=model_axis,
-        )
-
-    return jax.jit(fit)
 
 
 # ---------------------------------------------------------------------------
@@ -481,31 +331,6 @@ def _sparse_block_hdiag(loss, b, l2, model_axis: str, data_axis: str,
     return hdiag
 
 
-def feature_sharded_sparse_fit_tron(
-    objective: GLMObjective,
-    mesh: Mesh,
-    *,
-    data_axis: str = DATA_AXIS,
-    model_axis: str = MODEL_AXIS,
-    max_iter: int = 15,
-    tol: float = 1e-5,
-    max_cg: int = 20,
-) -> Callable:
-    """TRON over a feature-sharded coefficient vector with sparse data:
-    the reference's hottest distributed loop (one treeAggregate round-trip
-    per CG iteration, SURVEY §3.2) becomes a while_loop whose every CG
-    step is two psums over ICI. L2/none only (TRON+L1 is rejected by the
-    optimizer factory, matching OptimizerFactory.scala:49-86).
-
-    Thin wrapper over :func:`feature_sharded_glm_fit` (the one sharded
-    program family) preserving this entry point's historical defaults."""
-    return feature_sharded_glm_fit(
-        objective, mesh, layout="sparse", optimizer="tron",
-        data_axis=data_axis, model_axis=model_axis,
-        max_iter=max_iter, tol=tol, max_cg=max_cg,
-    )
-
-
 def feature_sharded_sparse_value_and_grad(
     objective: GLMObjective,
     mesh: Mesh,
@@ -563,102 +388,6 @@ def feature_sharded_sparse_hessian_vector(
         return factory(w_block)(d_block)
 
     return jax.jit(hv)
-
-
-def feature_sharded_sparse_fit(
-    objective: GLMObjective,
-    mesh: Mesh,
-    *,
-    data_axis: str = DATA_AXIS,
-    model_axis: str = MODEL_AXIS,
-    max_iter: int = 50,
-    tol: float = 1e-7,
-    history: int = 10,
-) -> Callable:
-    """L-BFGS over a feature-sharded coefficient vector with SPARSE data.
-
-    ``fit(w0, sharded_batch, l2) -> OptResult``; ``w0`` is the full
-    [num_blocks * block_dim] vector (sharded over ``model_axis`` by
-    shard_map), the batch comes from :func:`feature_shard_sparse_batch`.
-    Per evaluation: one psum of partial margins over the model axis + one
-    psum of the block gradient over the data axis; gradient and optimizer
-    state never leave their block's devices.
-
-    Thin wrapper over :func:`feature_sharded_glm_fit` (the one sharded
-    program family) preserving this entry point's historical defaults.
-    """
-    return feature_sharded_glm_fit(
-        objective, mesh, layout="sparse", optimizer="lbfgs",
-        data_axis=data_axis, model_axis=model_axis,
-        max_iter=max_iter, tol=tol, history=history,
-    )
-
-
-def feature_sharded_tiled_fit(
-    objective: GLMObjective,
-    mesh: Mesh,
-    meta,
-    *,
-    data_axis: str = DATA_AXIS,
-    model_axis: str = MODEL_AXIS,
-    max_iter: int = 50,
-    tol: float = 1e-7,
-    history: int = 10,
-    interpret: Optional[bool] = None,
-    owlqn: bool = False,
-) -> Callable:
-    """L-BFGS (or OWL-QN with ``owlqn=True``) over a feature-sharded
-    coefficient vector with the TILED Pallas kernels — the 10B-coefficient
-    layout at full kernel speed (round 2 ran this path on ~7ns/element
-    scatters).
-
-    ``fit(w0, batch, l2[, l1, l1_mask]) -> OptResult`` with ``batch`` a
-    FeatureShardedTiledBatch built by
-    ops.tiled_sparse.feature_shard_tiled_batch for this mesh's
-    (data, model) shape; ``meta`` is that batch's static meta. Collective
-    pattern per evaluation: one psum of partial margins over "model", one
-    psum of the block gradient over "data" — identical to the scatter
-    layout, so the optimizer and convergence rules are unchanged.
-
-    Thin wrapper over :func:`feature_sharded_glm_fit` (the one sharded
-    program family) preserving this entry point's historical defaults.
-    """
-    return feature_sharded_glm_fit(
-        objective, mesh, meta, layout="tiled",
-        optimizer="owlqn" if owlqn else "lbfgs",
-        data_axis=data_axis, model_axis=model_axis,
-        max_iter=max_iter, tol=tol, history=history, interpret=interpret,
-    )
-
-
-def feature_sharded_tiled_fit_tron(
-    objective: GLMObjective,
-    mesh: Mesh,
-    meta,
-    *,
-    data_axis: str = DATA_AXIS,
-    model_axis: str = MODEL_AXIS,
-    max_iter: int = 15,
-    tol: float = 1e-5,
-    max_cg: int = 20,
-    interpret: Optional[bool] = None,
-) -> Callable:
-    """TRON over a feature-sharded coefficient vector with the TILED
-    Pallas kernels: the reference's hottest distributed loop (one
-    treeAggregate Hv per CG iteration, TRON.scala:259-341 +
-    HessianVectorAggregator.scala:137-152) at full kernel speed on the
-    10B-coefficient layout. Collective pattern per CG step: one psum of
-    the direction's partial margins over "model" + one psum of the block
-    Hv over "data" — identical to the scatter TRON, so convergence rules
-    are unchanged. L2/none only (TRON+L1 rejected by the factory).
-
-    Thin wrapper over :func:`feature_sharded_glm_fit` (the one sharded
-    program family) preserving this entry point's historical defaults."""
-    return feature_sharded_glm_fit(
-        objective, mesh, meta, layout="tiled", optimizer="tron",
-        data_axis=data_axis, model_axis=model_axis,
-        max_iter=max_iter, tol=tol, max_cg=max_cg, interpret=interpret,
-    )
 
 
 # Jitted feature-sharded fit programs shared across builder calls: a
@@ -1074,30 +803,3 @@ def feature_sharded_hessian_diagonal(
             return _hdiag(w, batch, l2, tuple(extras))
 
     return jax.jit(hdiag)
-
-
-def feature_sharded_sparse_fit_owlqn(
-    objective: GLMObjective,
-    mesh: Mesh,
-    *,
-    data_axis: str = DATA_AXIS,
-    model_axis: str = MODEL_AXIS,
-    max_iter: int = 50,
-    tol: float = 1e-7,
-    history: int = 10,
-) -> Callable:
-    """OWL-QN over the sparse feature-sharded layout: the L1/elastic-net
-    path for >HBM coefficient vectors. ``fit(w0, sharded_batch, l2, l1,
-    l1_mask)`` (L2 first, matching the smooth objective; ``l1_mask`` a
-    full [d_pad] 0/1 vector — 0 exempts a slot, e.g. the intercept — split
-    over the model axis like w); the L1 term lives in the optimizer
-    (pseudo-gradient/orthant rules are elementwise over the local block,
-    scalars psum — same recipe as L-BFGS).
-
-    Thin wrapper over :func:`feature_sharded_glm_fit` (the one sharded
-    program family) preserving this entry point's historical defaults."""
-    return feature_sharded_glm_fit(
-        objective, mesh, layout="sparse", optimizer="owlqn",
-        data_axis=data_axis, model_axis=model_axis,
-        max_iter=max_iter, tol=tol, history=history,
-    )

@@ -36,8 +36,7 @@ per-bucket readbacks).
 
 Overlap is ON by default; ``--no-overlap`` on the drivers (or
 ``PHOTON_NO_OVERLAP=1``, or :func:`set_overlap`) falls back to fully
-serial execution — the escape hatch, and the A/B baseline for
-``dev-scripts/bench_overlap.sh``. With overlap off, ``submit`` runs
+serial execution — the escape hatch. With overlap off, ``submit`` runs
 inline, ``submit_io`` writes synchronously and :class:`Deferred` values
 fetch eagerly, so the serial path is byte-identical to the pre-overlap
 code.

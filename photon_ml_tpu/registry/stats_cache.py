@@ -12,8 +12,8 @@ holding exactly the per-partition reductions the scan needs. A cached
 scan then touches only partitions without a valid entry — which for an
 append-only directory is precisely the new ones. The ``scanned`` /
 ``cached`` counters (and the ``registry.stats_cache`` fault seam) make
-"touches only new partitions" a COUNTED claim the bench gates and the
-tier-1 tests assert, not a hope.
+"touches only new partitions" a COUNTED claim the tier-1 tests assert,
+not a hope.
 
 Exactness: the per-partition reductions are integers (rows, max live
 nnz), a key SET, and float64 moment partials.

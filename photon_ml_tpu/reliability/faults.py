@@ -260,8 +260,7 @@ def active_plan() -> Optional[FaultPlan]:
 
 def inject(seam: str, detail: str = "") -> None:
     """The injection point: every reliability seam calls this once per
-    attempt. No plan installed -> a counter bump and nothing else (the
-    disabled-path cost the bench overhead gate prices)."""
+    attempt. No plan installed -> a counter bump and nothing else."""
     if seam not in SEAMS:
         raise ValueError(f"unknown fault seam {seam!r}")
     plan = active_plan()

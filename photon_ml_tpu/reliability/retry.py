@@ -214,7 +214,7 @@ def io_call(
     The wrapped ``fn`` must be idempotent per attempt (seek-then-write,
     whole-file decode, tmp+rename) — every seam in the package is.
     """
-    if _bypassed():  # the bench A/B's "layer off" arm — never set in prod
+    if _bypassed():  # an A/B's "layer off" arm — never set in prod
         return fn(*args, **kwargs)
     policy = policy or policy_for(seam)
     attempt = 0

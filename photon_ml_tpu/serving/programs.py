@@ -318,8 +318,7 @@ class ServingPrograms:
         exe = self.executable(bank.spec, B)
         if exe is None:
             # an unwarmed shape reached the hot path: compile it now and
-            # count the miss — the bench/test gates pin this at zero
-            # after warmup
+            # count the miss — the tests pin this at zero after warmup
             with self._lock:
                 self.cold_dispatch_compiles += 1
             exe, _ = self._get_or_compile(bank.spec, bank.arrays, B)

@@ -106,7 +106,7 @@ def reset_host_timings() -> None:
 def peak_rss_bytes() -> int:
     """Host peak-RSS high-water of this process in BYTES (ru_maxrss is
     KiB on Linux, bytes on macOS) — the out-of-core layer's reported
-    memory ceiling (metrics.json / bench.py streaming sections)."""
+    memory ceiling (``streaming.peak_rss_bytes`` in metrics.json)."""
     import resource
     import sys
 

@@ -1,8 +1,8 @@
-"""Tier-1 gate: the whole package + bench.py are photon-lint clean.
+"""Tier-1 gate: the whole package is photon-lint clean.
 
 This is what turns the PR 1-3 perf invariants from tribal knowledge into
 CI: a new raw readback, jit-of-lambda, unswept spill dir or undrained
-submit_io anywhere in photon_ml_tpu/ (or bench.py) fails this test
+submit_io anywhere in photon_ml_tpu/ fails this test
 unless it is explicitly allow()-ed or baselined. The flip-side tests pin
 that the enforcement is real: removing a baseline entry or a suppression
 comment makes the analyzer report again."""
@@ -25,7 +25,7 @@ from photon_ml_tpu.lint import (
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BASELINE = os.path.join(REPO, ".photon-lint-baseline.json")
-TARGETS = ["photon_ml_tpu", "bench.py"]
+TARGETS = ["photon_ml_tpu"]
 
 
 @pytest.fixture()
@@ -51,7 +51,7 @@ def _fmt(violations):
 
 
 class TestLintClean:
-    def test_package_and_bench_are_clean(self, full_report):
+    def test_package_is_clean(self, full_report):
         report = Report(
             files=full_report.files,
             violations=list(full_report.violations),
@@ -63,6 +63,19 @@ class TestLintClean:
             "non-baselined photon-lint violations:\n"
             + _fmt(report.violations)
         )
+
+    def test_default_paths_exist(self):
+        """`python -m photon_ml_tpu.lint` with no argument checks what
+        DEFAULT_PATHS names, silently skipping a path that is gone: a
+        deleted default would shrink the gate without failing it."""
+        from photon_ml_tpu.lint.cli import DEFAULT_PATHS
+
+        assert list(DEFAULT_PATHS) == TARGETS
+        missing = [
+            p for p in DEFAULT_PATHS
+            if not os.path.exists(os.path.join(REPO, p))
+        ]
+        assert missing == [], missing
 
     def test_baseline_has_no_stale_entries(self, full_report):
         report = Report(violations=list(full_report.violations))
@@ -437,8 +450,10 @@ class TestLintClean:
         # distributed fit builders collapsed into feature_sharded_glm_fit
         # wrappers and the problem.py hdiag variants merged, while the
         # unified-mesh grid programs (game/unified.py) added six
-        # declared entries
-        assert len(rows) == 36, [
+        # declared entries; ISSUE 31 deleted the four entry points no
+        # driver reached (data_parallel_*, dense feature_sharded_*):
+        # 36 -> 32
+        assert len(rows) == 32, [
             (r["module"], r["entry"]) for r in rows
         ]
         assert all(r["declared"] == "yes" for r in rows), [
@@ -603,24 +618,28 @@ class TestLintClean:
             if v.rule == "PL016" and "seeds Random" in v.message
         ], _fmt(dirty.violations)
 
-    def test_reverting_bench_flood_seed_resurfaces_pl016(self):
-        """Same pin for the flood-payload generator: hash(key)-seeded
-        default_rng meant parent and relaunched child processes built
-        DIFFERENT payloads for the same key, drifting cache-hit
-        accounting."""
-        path = "bench.py"
-        src = open(path).read()
-        fixed = ("seed = zlib.crc32(\n"
-                 '                f"{key[0]}:{key[1]}:{key[2]}"'
-                 '.encode("utf-8")\n'
-                 "            )")
-        assert fixed in src, "bench flood seed changed; update me"
-        # no clean-half re-analysis of bench.py here (it is the largest
-        # file in the run): test_determinism_rules_land_at_zero already
-        # proves the fixed tree carries zero PL016
-        dirty = analyze_source(
-            path, src.replace(fixed, "seed = hash(key)")
+    def test_hash_seeded_default_rng_is_pl016(self):
+        """The same pin on a payload generator, as an inline source:
+        a hash(key)-seeded default_rng builds DIFFERENT payloads for
+        the same key in a parent and a relaunched child process
+        (PYTHONHASHSEED differs); the crc32 seed does not."""
+        template = (
+            "import zlib\n"
+            "import numpy as np\n"
+            "\n"
+            "def payload(key, pool):\n"
+            "    seed = {seed}\n"
+            "    prng = np.random.default_rng(seed & 0x7FFFFFFF)\n"
+            "    pool[key] = float(prng.standard_normal())\n"
+            "    return pool[key]\n"
         )
+        path = "photon_ml_tpu/serving/_flood_payload.py"
+        clean = analyze_source(path, template.format(
+            seed='zlib.crc32(f"{key[0]}:{key[1]}".encode("utf-8"))'
+        ))
+        assert not [v for v in clean.violations if v.rule == "PL016"], \
+            _fmt(clean.violations)
+        dirty = analyze_source(path, template.format(seed="hash(key)"))
         assert [
             v for v in dirty.violations
             if v.rule == "PL016" and "default_rng" in v.message

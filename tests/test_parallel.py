@@ -1,6 +1,7 @@
 """Distributed-path tests on the virtual 8-device CPU mesh: data-parallel
-objective == single-device objective, whole-fit-in-shard_map, feature-axis
-sharding exactness (the multi-chip paths the driver dry-runs).
+fit == single-device fit (``GLMOptimizationProblem.run(mesh=)``),
+feature-axis sharding exactness (``feature_sharded_glm_fit``): the
+multi-chip paths the drivers run and ``__graft_entry__`` dry-runs.
 """
 
 import numpy as np
@@ -9,20 +10,22 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from photon_ml_tpu.data.batch import make_dense_batch, make_sparse_batch
+from photon_ml_tpu.data.batch import make_sparse_batch
 from photon_ml_tpu.ops.losses import LOGISTIC
 from photon_ml_tpu.ops.objective import GLMObjective
 from photon_ml_tpu.optim import minimize_lbfgs
+from photon_ml_tpu.optim.config import (
+    OptimizerConfig,
+    RegularizationContext,
+    RegularizationType,
+)
+from photon_ml_tpu.optim.problem import create_glm_problem
 from photon_ml_tpu.parallel import (
     DATA_AXIS,
     MODEL_AXIS,
-    data_parallel_fit_lbfgs,
-    data_parallel_value_and_grad,
-    feature_sharded_fit,
-    feature_sharded_value_and_grad,
     make_mesh,
-    shard_batch,
 )
+from photon_ml_tpu.task import TaskType
 
 
 @pytest.fixture(scope="module")
@@ -49,27 +52,51 @@ def sparse_problem(rng, n=256, d=32, k=8):
     return make_sparse_batch(rows, labels, pad_rows_to=8), w_true
 
 
+def l2_problem(d, max_iter):
+    """The problem ``glm_driver`` builds: logistic, L-BFGS, L2."""
+    return create_glm_problem(
+        TaskType.LOGISTIC_REGRESSION, d,
+        config=OptimizerConfig(max_iter=max_iter),
+        regularization=RegularizationContext(RegularizationType.L2),
+    )
+
+
 class TestDataParallel:
+    """``GLMOptimizationProblem.run(mesh=)``: the whole fit inside one
+    shard_map program over the data axis, the path ``glm_driver`` runs."""
+
     def test_matches_single_device(self, mesh8, rng):
+        # one iteration from a non-zero start: the value at the start is
+        # the objective's value there, and the step taken is along its
+        # gradient, so both are compared coordinate by coordinate
         batch, _ = sparse_problem(rng)
         d = 32
-        obj = GLMObjective(LOGISTIC, d)
+        problem = l2_problem(d, max_iter=1)
         w = jnp.asarray(rng.normal(size=d).astype(np.float32))
-        v_local, g_local = obj.value_and_gradient(w, batch, 0.1)
-        sharded = shard_batch(batch, mesh8)
-        vg = data_parallel_value_and_grad(obj, mesh8)
-        v_dist, g_dist = vg(w, sharded, jnp.float32(0.1))
-        np.testing.assert_allclose(float(v_dist), float(v_local), rtol=1e-5)
+        _, local = problem.run(batch, initial=w, reg_weight=0.1)
+        _, dist = problem.run(batch, initial=w, reg_weight=0.1, mesh=mesh8)
+        assert int(dist.iterations) == int(local.iterations) == 1
         np.testing.assert_allclose(
-            np.asarray(g_dist), np.asarray(g_local), atol=1e-4
+            np.asarray(dist.tracker.values), np.asarray(local.tracker.values),
+            rtol=1e-5,
         )
+        np.testing.assert_allclose(
+            np.asarray(dist.tracker.grad_norms),
+            np.asarray(local.tracker.grad_norms), rtol=1e-4,
+        )
+        np.testing.assert_allclose(
+            np.asarray(dist.coefficients - w),
+            np.asarray(local.coefficients - w), atol=1e-4,
+        )
+        assert float(jnp.linalg.norm(local.coefficients - w)) > 1e-3
 
     def test_whole_fit_in_shard_map(self, mesh8, rng):
         batch, _ = sparse_problem(rng)
         d = 32
         obj = GLMObjective(LOGISTIC, d)
-        fit = data_parallel_fit_lbfgs(obj, mesh8, max_iter=50)
-        res = fit(jnp.zeros(d), shard_batch(batch, mesh8), jnp.float32(0.1))
+        _, res = l2_problem(d, max_iter=50).run(
+            batch, reg_weight=0.1, mesh=mesh8
+        )
         local = minimize_lbfgs(
             lambda w: obj.value_and_gradient(w, batch, 0.1),
             jnp.zeros(d), max_iter=50,
@@ -154,47 +181,10 @@ class TestEntityAllToAll:
 
 
 class TestFeatureSharded:
-    def test_value_and_grad_exact(self, mesh4x2, rng):
-        n, d = 64, 16
-        x = rng.normal(size=(n, d)).astype(np.float32)
-        y = (rng.uniform(size=n) > 0.5).astype(np.float32)
-        batch = make_dense_batch(x, y)
-        obj = GLMObjective(LOGISTIC, d)
-        w = jnp.asarray(rng.normal(size=d).astype(np.float32))
-        v_local, g_local = obj.value_and_gradient(w, batch, 0.2)
-        vg = feature_sharded_value_and_grad(obj, mesh4x2)
-        v, g = vg(w, batch.features, batch.labels, batch.offsets,
-                  batch.weights, jnp.float32(0.2))
-        np.testing.assert_allclose(float(v), float(v_local), rtol=1e-5)
-        np.testing.assert_allclose(np.asarray(g), np.asarray(g_local), atol=1e-4)
-
-    def test_sharded_fit_matches_replicated(self, mesh4x2, rng):
-        n, d = 128, 16
-        x = rng.normal(size=(n, d)).astype(np.float32)
-        y = (rng.uniform(size=n) > 0.5).astype(np.float32)
-        batch = make_dense_batch(x, y)
-        obj = GLMObjective(LOGISTIC, d)
-        fit = feature_sharded_fit(obj, mesh4x2, max_iter=50)
-        res = fit(jnp.zeros(d), batch.features, batch.labels, batch.offsets,
-                  batch.weights, jnp.float32(0.1))
-        local = minimize_lbfgs(
-            lambda w_: obj.value_and_gradient(w_, batch, 0.1),
-            jnp.zeros(d), max_iter=50,
-        )
-        np.testing.assert_allclose(
-            np.asarray(res.coefficients), np.asarray(local.coefficients),
-            atol=5e-3,
-        )
-        # Shared optimizer => identical convergence bookkeeping shape.
-        np.testing.assert_allclose(
-            float(res.value), float(local.value), rtol=1e-5
-        )
-        assert int(res.iterations) > 0
-
     def test_sparse_sharded_fit_matches_replicated(self, mesh4x2, rng):
-        from photon_ml_tpu.parallel import (
-            feature_shard_sparse_batch,
-            feature_sharded_sparse_fit,
+        from photon_ml_tpu.parallel import feature_shard_sparse_batch
+        from photon_ml_tpu.parallel.distributed import (
+            feature_sharded_glm_fit,
         )
 
         # d chosen NOT to divide into equal blocks so d_pad > d and the
@@ -207,7 +197,9 @@ class TestFeatureSharded:
         )
         d_pad = 2 * block_dim
         assert d_pad > d
-        fit = feature_sharded_sparse_fit(obj, mesh4x2, max_iter=50)
+        fit = feature_sharded_glm_fit(
+            obj, mesh4x2, layout="sparse", optimizer="lbfgs", max_iter=50
+        )
         res = fit(jnp.zeros(d_pad), sharded, jnp.float32(0.1))
         local = minimize_lbfgs(
             lambda w_: obj.value_and_gradient(w_, batch, 0.1),
@@ -224,7 +216,7 @@ class TestFeatureSharded:
         from photon_ml_tpu.optim.lbfgs import minimize_owlqn
         from photon_ml_tpu.parallel import feature_shard_sparse_batch
         from photon_ml_tpu.parallel.distributed import (
-            feature_sharded_sparse_fit_owlqn,
+            feature_sharded_glm_fit,
         )
 
         batch, _ = sparse_problem(rng, n=128, d=45, k=8)
@@ -233,7 +225,9 @@ class TestFeatureSharded:
         sharded, block_dim = feature_shard_sparse_batch(
             batch, d, num_blocks=2, rows_multiple=4
         )
-        fit = feature_sharded_sparse_fit_owlqn(obj, mesh4x2, max_iter=50)
+        fit = feature_sharded_glm_fit(
+            obj, mesh4x2, layout="sparse", optimizer="owlqn", max_iter=50
+        )
         res = fit(
             jnp.zeros(2 * block_dim), sharded,
             jnp.float32(0.05), jnp.float32(0.2),
@@ -253,10 +247,7 @@ class TestFeatureSharded:
         ).sum()
 
     def test_sparse_sharded_value_and_grad_exact(self, mesh4x2, rng):
-        from photon_ml_tpu.parallel import (
-            feature_shard_sparse_batch,
-            feature_sharded_sparse_fit,  # noqa: F401 (import check)
-        )
+        from photon_ml_tpu.parallel import feature_shard_sparse_batch
         from photon_ml_tpu.parallel.distributed import (
             feature_sharded_sparse_value_and_grad,
         )

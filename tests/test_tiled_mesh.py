@@ -173,7 +173,7 @@ class TestFeatureShardedTiled:
         # tiled block-local schedules vs the plain replicated fit
         from photon_ml_tpu.ops.tiled_sparse import feature_shard_tiled_batch
         from photon_ml_tpu.parallel.distributed import (
-            feature_sharded_tiled_fit,
+            feature_sharded_glm_fit,
         )
         from photon_ml_tpu.parallel.mesh import MODEL_AXIS
 
@@ -192,8 +192,9 @@ class TestFeatureShardedTiled:
             batch, d, 4, 2, params=PARAMS, mesh=mesh
         )
         obj = GLMObjective(LOGISTIC, d)
-        fit = feature_sharded_tiled_fit(
-            obj, mesh, sharded.meta, max_iter=25, interpret=True
+        fit = feature_sharded_glm_fit(
+            obj, mesh, sharded.meta, layout="tiled", optimizer="lbfgs",
+            max_iter=25, interpret=True,
         )
         res = fit(
             jnp.zeros(2 * block_dim, jnp.float32), sharded, jnp.float32(0.5)

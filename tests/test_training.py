@@ -236,3 +236,38 @@ class TestKernelSwitch:
         assert resolve_kernel("auto") == "scatter"  # tests run on CPU
         assert resolve_kernel("tiled") == "tiled"
         assert resolve_kernel("scatter") == "scatter"
+
+    def test_auto_resolves_tiled_for_a_tiled_batch(self, rng):
+        """Only the tiled objective can read a TiledSparseBatch, so
+        "auto" picks it on any platform (interpreted on the CPU) and the
+        fit runs; "auto" used to pick scatter here and the fit failed."""
+        import jax.numpy as jnp
+        from photon_ml_tpu.data.batch import make_sparse_batch
+        from photon_ml_tpu.ops.tiled_sparse import TileParams, ensure_tiled
+        from photon_ml_tpu.optim.config import OptimizerConfig
+        from photon_ml_tpu.optim.problem import (
+            create_glm_problem,
+            resolve_kernel,
+        )
+        from photon_ml_tpu.task import TaskType
+
+        d = 24
+        rows = [
+            (rng.choice(d, size=3, replace=False).tolist(), [1.0, -1.0, 0.5])
+            for _ in range(32)
+        ]
+        labels = (rng.uniform(size=32) > 0.5).astype(float).tolist()
+        sparse = make_sparse_batch(rows, labels)
+        tiled = ensure_tiled(
+            sparse, d, params=TileParams(s_hi=8, s_lo=8, chunk=128)
+        )
+        assert resolve_kernel("auto", sparse) == "scatter"  # CPU
+        assert resolve_kernel("auto", tiled) == "tiled"
+        problem = create_glm_problem(
+            TaskType.LOGISTIC_REGRESSION, d,
+            config=OptimizerConfig(max_iter=2),
+            kernel=resolve_kernel("auto", tiled),
+        )
+        coef, result = problem.run(tiled)
+        assert int(result.iterations) >= 1
+        assert bool(jnp.all(jnp.isfinite(coef.means)))
