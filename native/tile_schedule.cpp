@@ -20,6 +20,11 @@
 //
 // The pass is role-symmetric: the z-pass calls with (out=rows, in=feats),
 // the gradient pass with (out=feats, in=rows) — same code path.
+//
+// An entry whose value is 0 is no entry: both calls pass over it (it takes
+// no slot and no spill place). That is how a caller takes entries out
+// without compacting three arrays: the batch builders zero a dense
+// column's values (tiled_sparse._split_dense_columns).
 
 #include <cstdint>
 #include <cstring>
@@ -98,13 +103,14 @@ extern "C" {
 // Returns 0, or -1 when the tile space is too large for a counting sort
 // (caller falls back to the numpy builder).
 int64_t ts_plan(const int64_t* out_coord, const int64_t* in_coord,
-                int64_t n, int64_t win, int64_t chunk, int64_t cap,
-                int64_t num_out_blocks, int64_t* steps_out,
+                const float* vals, int64_t n, int64_t win, int64_t chunk,
+                int64_t cap, int64_t num_out_blocks, int64_t* steps_out,
                 int64_t* spilled_out) try {
   TileDims d = tile_dims(out_coord, in_coord, n, win, num_out_blocks);
   if (d.n_tiles <= 0 || d.n_tiles > max_tiles(n)) return -1;
   std::vector<int64_t> counts(static_cast<size_t>(d.n_tiles), 0);
   for (int64_t i = 0; i < n; ++i) {
+    if (vals[i] == 0.0f) continue;
     int64_t t = (out_coord[i] / win) * d.n_in_blocks + in_coord[i] / win;
     ++counts[static_cast<size_t>(t)];
   }
@@ -148,6 +154,7 @@ int64_t ts_fill(const int64_t* out_coord, const int64_t* in_coord,
   if (d.n_tiles <= 0 || d.n_tiles > max_tiles(n)) return -1;
   std::vector<int64_t> counts(static_cast<size_t>(d.n_tiles), 0);
   for (int64_t i = 0; i < n; ++i) {
+    if (vals[i] == 0.0f) continue;
     int64_t t = (out_coord[i] / win) * d.n_in_blocks + in_coord[i] / win;
     ++counts[static_cast<size_t>(t)];
   }
@@ -198,6 +205,7 @@ int64_t ts_fill(const int64_t* out_coord, const int64_t* in_coord,
   // orderings match the numpy builder exactly).
   std::vector<int64_t> cursor(static_cast<size_t>(d.n_tiles), 0);
   for (int64_t i = 0; i < n; ++i) {
+    if (vals[i] == 0.0f) continue;
     int64_t ob = out_coord[i] / win;
     int64_t ib = in_coord[i] / win;
     size_t t = static_cast<size_t>(ob * d.n_in_blocks + ib);

@@ -15,7 +15,7 @@ import argparse
 import hashlib
 import json
 import os
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from itertools import product
 from typing import Dict, List, Optional, Sequence
 
@@ -148,15 +148,6 @@ def expand_config_grid(
         ]
         alternatives.append(opts)
     return [dict(zip(names, combo)) for combo in product(*alternatives)]
-
-
-# The MXU variant of the tiled kernel under the driver's fixed effect. Every
-# other tiled user takes the objective's default, "bf16x2w"; here the
-# solve's reported value has to agree with a float32 evaluation at the same
-# coefficients to 5e-7 (the cd cell's `fixed_value_gap`), and "bf16x2w"
-# read 5.06e-7 and 8.09e-7 on two of three row orders at 524,288 rows x 65
-# (PERF.md section 6, PR 28). Same kernel, 3.3x the time a pass there.
-_FIXED_EFFECT_MXU = "highest"
 
 
 @dataclass
@@ -616,11 +607,6 @@ class GameTrainingDriver:
                 intercept_index=shard.intercept_index,
                 kernel=kernel,
             )
-            if kernel == "tiled":
-                problem = replace(
-                    problem,
-                    objective=replace(problem.objective, mxu=_FIXED_EFFECT_MXU),
-                )
             coords[name] = FixedEffectCoordinate(
                 name=name,
                 dataset=dataset,

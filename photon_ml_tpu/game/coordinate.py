@@ -90,8 +90,9 @@ def fe_score(means: Array, batch, objective=None, *, mesh=None, axis=None) -> Ar
     Given a ``SparseBatch`` it is the gather (``compute_scores``). Given
     the coordinate's ``TiledSparseBatch`` and its ``TiledGLMObjective`` it
     rides the margin pass (``objective.scores``: kernel
-    ``photon_tiled_margin`` plus the spilled entries) and cuts the padded
-    row space back to the dataset's rows; both tiled layouts keep global
+    ``photon_tiled_margin`` plus the spilled entries and a dense column's
+    side term) and cuts the padded row space back to the dataset's rows;
+    both tiled layouts keep global
     row r at position r and pad only at the end. Under ``mesh`` the pass
     runs under ``shard_map`` over ``axis``, each device over its own
     schedule with no ``psum``, and the vector leaves this program
@@ -198,6 +199,13 @@ class FixedEffectCoordinate(Coordinate):
         tiled = isinstance(self.problem.objective, TiledGLMObjective)
         return "tiled" if tiled else "scatter"
 
+    @property
+    def mxu(self) -> Optional[str]:
+        """The tiled kernel's MXU variant (``TiledGLMObjective.mxu``), None
+        on the scatter objective: beside ``kernel`` on ``cd.update`` and
+        ``cd.score``."""
+        return getattr(self.problem.objective, "mxu", None)
+
     def _tiled_base(self):
         """The shard as a ``TiledSparseBatch`` (mesh layout under a data
         mesh), held on the coordinate: a driver with more fixed-effect
@@ -229,9 +237,10 @@ class FixedEffectCoordinate(Coordinate):
                 base = ensure_tiled_sharded(
                     sparse, dim, self.mesh, _row_axis(self.mesh)
                 )
-            # noted once, with the build: do the schedules hold every
+            # noted once, with the build: does the tiled batch hold every
             # entry that scores? (the build drops weight-0 rows; one with
-            # no feature scores 0 either way, as the row padding does)
+            # no feature scores 0 either way, as the row padding does. A
+            # dense column's entries are held: beside the schedules)
             dead = ~(np.asarray(self.dataset.weights) > 0)
             values = self.dataset.shards[self.feature_shard_id].values
             self.__dict__["_tiled_scores"] = not (

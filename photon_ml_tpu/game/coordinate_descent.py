@@ -168,13 +168,16 @@ class CoordinateDescent:
 
     def _score(self, name: str, model) -> Array:
         """One scoring pass under its ``cd.score`` span, which says how a
-        fixed effect scored (``kernel``: tiled | gather)."""
+        fixed effect scored (``kernel``: tiled | gather; on the kernel,
+        its ``mxu`` variant)."""
         coord = self.coordinates[name]
         with obs_span("cd.score", coordinate=name) as sp:
             score = coord.score(model)
             kernel = getattr(coord, "score_kernel", None)
             if kernel:
                 sp.set(kernel=kernel)
+            if kernel == "tiled":
+                sp.set(mxu=coord.mxu)
         return score
 
     def run(
@@ -312,11 +315,12 @@ class CoordinateDescent:
                         if len(seq) > 1 else None
                     )
                     # which objective a fixed effect runs (tiled | scatter)
+                    # and, on the kernel, its MXU variant
                     kernel = getattr(coord, "kernel", None)
-                    with obs_span(
-                        "cd.update", coordinate=name,
-                        **({"kernel": kernel} if kernel else {}),
-                    ):
+                    ran = {"kernel": kernel} if kernel else {}
+                    if kernel == "tiled":
+                        ran["mxu"] = coord.mxu
+                    with obs_span("cd.update", coordinate=name, **ran):
                         models[name], tracker = coord.update_model(
                             models[name], residual
                         )

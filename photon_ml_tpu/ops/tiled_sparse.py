@@ -18,6 +18,18 @@ matmuls:
 - The z-pass streams chunks sorted by row-block (output revisiting is
   monotone -> pallas accumulates the z window in VMEM); the grad-pass
   streams the same entries sorted by feature-block.
+- A DENSE column (an entry in every live row: an intercept) stays out of
+  the tiles. It is not sparse: it fills whole tiles with one-hot
+  expansions that read the same coefficient thousands of times, and the
+  kernel's bfloat16 hi+lo split rounds that ONE coefficient the same way
+  in every row, so all margins shift together and the value moves by the
+  shift times sum l'(z) (5e-7-8e-7 of it at 524,288 rows x 65; PERF.md
+  section 6, PR 28, 32), where every other column's rounding averages
+  out over the rows. The batch builders take such columns out of the
+  entries they schedule (``_split_dense_columns``: from the data alone,
+  at most ``MAX_DENSE_COLUMNS``) and keep them as a float32 [K, rows]
+  array beside the schedules; the objective's two passes apply it
+  exactly, one fused multiply-add a row.
 
 The schedule (tile assignment, chunking, window-local index packing) is
 computed ONCE on host per dataset — full-batch GLM training re-evaluates
@@ -177,7 +189,7 @@ def _tile_lib():
             p_f32 = ctypes.POINTER(ctypes.c_float)
             lib.ts_plan.restype = i64
             lib.ts_plan.argtypes = [
-                p_i64, p_i64, i64, i64, i64, i64, i64, p_i64, p_i64,
+                p_i64, p_i64, p_f32, i64, i64, i64, i64, i64, p_i64, p_i64,
             ]
             lib.ts_fill.restype = i64
             lib.ts_fill.argtypes = [
@@ -236,7 +248,7 @@ def _build_schedule_native(
     steps_out = ctypes.c_int64()
     spilled_out = ctypes.c_int64()
     rc = lib.ts_plan(
-        p(oc, i64), p(ic, i64), n, win, L, cap, num_out_blocks,
+        p(oc, i64), p(ic, i64), p(v, f32), n, win, L, cap, num_out_blocks,
         ctypes.byref(steps_out), ctypes.byref(spilled_out),
     )
     if rc != 0:
@@ -286,6 +298,10 @@ def _build_schedule_np(
     path below is the fallback oracle (vectorized repeat/cumsum/scatter —
     no per-entry Python loops).
 
+    An entry whose value is 0 is no entry (it takes no slot): how the
+    batch builders take a dense column's entries out without compacting
+    three arrays (``_split_dense_columns``).
+
     ``digest``: precomputed content digest of (rows, feats, vals) so
     callers building BOTH passes from one triple hash it once."""
     from photon_ml_tpu.ops import schedule_cache as _sc
@@ -313,8 +329,14 @@ def _build_schedule_np(
         sort_by_feature_block=sort_by_feature_block,
         num_out_blocks=num_out_blocks,
     )
+    entries = int(np.count_nonzero(vals))
     if native is not None:
-        return _finish_schedule_build(native, t_build, cache_dir, cache_key)
+        return _finish_schedule_build(
+            native, t_build, cache_dir, cache_key, entries
+        )
+    if entries != len(vals):
+        live = np.flatnonzero(vals)
+        rows, feats, vals = rows[live], feats[live], vals[live]
     win = params.window
     L = params.chunk
     # int32 entry coordinates when they fit (half the sort/gather traffic);
@@ -452,18 +474,24 @@ def _build_schedule_np(
             step_out, step_in, step_init, o_pos, i_pos, sv,
             sp_out, sp_in, sp_vals,
         ),
-        t_build, cache_dir, cache_key,
+        t_build, cache_dir, cache_key, entries,
     )
 
 
-def _finish_schedule_build(arrays, t0, cache_dir, key):
-    """Record the build in the cache stats/profiling stream and persist
-    the artifact (writer process only) when the disk tier is active."""
+def _finish_schedule_build(arrays, t0, cache_dir, key, entries):
+    """Record the build in the cache stats/profiling stream, count its
+    ``entries`` by the path that applies them and persist the artifact
+    (writer process only) when the disk tier is active."""
     import time as _time
 
     from photon_ml_tpu.ops import schedule_cache as _sc
 
     _sc.record_build_seconds(_time.perf_counter() - t0)
+    # (the spilled tail is zero-padded; an entry that spilled is not 0)
+    spilled = int(np.count_nonzero(arrays[8]))
+    counter = _entries_counter()
+    counter.inc(entries - spilled, path="kernel")
+    counter.inc(spilled, path="spill")
     if key is not None and _sc.is_cache_writer():
         _sc.store_schedule(cache_dir, key, arrays)
     return arrays
@@ -525,6 +553,107 @@ def _pad_schedule_np(
     )
 
 
+# How many dense columns a batch keeps beside its schedules: one sublane
+# tile of the [K, rows] side array. A further one stays in the tiles.
+MAX_DENSE_COLUMNS = 8
+
+
+def _split_dense_columns(
+    rows: np.ndarray,
+    feats: np.ndarray,
+    vals: np.ndarray,
+    num_rows: int,
+    num_cols: int,
+    limit: int,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """COO triple -> (vals, dense_cols, dense_vals): the values with the
+    dense columns' entries set to 0, which to the schedule builders is no
+    entry (the 168M-entry triple is not compacted), those columns' ids
+    (int32 [K], K <= ``limit``) and their values a row (float32 [K,
+    num_rows], 0 where a row holds no entry at all). With K = 0 ``vals``
+    comes back as it went in (the same object), else as a copy.
+
+    A column is dense when it holds exactly one entry in every LIVE row (a
+    row with at least one entry; the callers have dropped weight-0 rows
+    and zero values), so its entry count equals the live-row count: read
+    off ``np.bincount``, from the entries alone.
+
+    Host work ahead of every schedule build, so it is a few memory-bound
+    passes, each split over threads (numpy releases the GIL in them; at
+    most 256 MB of per-thread column counts)."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    total = len(vals)
+    none = vals, np.zeros(0, np.int32), np.zeros((0, num_rows), np.float32)
+    if limit <= 0 or not total:
+        return none
+    threads = max(1, min(8, (1 << 25) // max(num_cols, 1)))
+    edges = np.linspace(0, total, threads + 1).astype(np.int64)
+    parts = [slice(int(a), int(b)) for a, b in zip(edges[:-1], edges[1:])]
+    with ThreadPoolExecutor(threads) as pool:
+
+        def counted(ids, length):
+            return sum(pool.map(
+                lambda part: np.bincount(ids[part], minlength=length), parts
+            ))
+
+        counts = counted(feats, num_cols)
+        top = int(counts.max())
+        # no column outnumbers the live rows short of a repeated (row,
+        # column) pair, so a column in all ``num_rows`` rows says every row
+        # is live and saves the pass over ``rows``; the distinct-row check
+        # below holds either reading to account
+        live = top if top == num_rows else int(
+            np.count_nonzero(counted(rows, num_rows))
+        )
+        cols, sides, taken = [], [], []
+        for col in np.flatnonzero(counts == live):
+            at = np.concatenate(list(pool.map(
+                lambda part: part.start + np.flatnonzero(feats[part] == col),
+                parts,
+            )))
+            side = np.zeros(num_rows, np.float32)
+            side[rows[at]] = vals[at]
+            if int(np.count_nonzero(side)) != live:
+                continue  # a row holds it twice, so another row lacks it
+            cols.append(col)
+            sides.append(side)
+            taken.append(at)
+            if len(cols) == limit:
+                break
+    if not cols:
+        return none
+    vals = vals.copy()
+    vals[np.concatenate(taken)] = 0.0
+    return vals, np.asarray(cols, np.int32), np.stack(sides)
+
+
+def _count_dense_entries(
+    dense_vals: np.ndarray, builds_before: int, cells: int
+) -> None:
+    """``photon_tiled_entries_total{path="dense"}``, beside what
+    ``_finish_schedule_build`` counts: each schedule BUILT since
+    ``builds_before`` (two a cell of rows when nothing came from the disk
+    cache) adds the dense entries of its rows, as it added its own."""
+    from photon_ml_tpu.ops import schedule_cache as _sc
+
+    built = min(_sc.stats().builds - builds_before, 2 * cells)
+    entries = int(np.count_nonzero(dense_vals))
+    if built > 0 and entries:
+        _entries_counter().inc(entries * built // cells, path="dense")
+
+
+def _entries_counter():
+    from photon_ml_tpu.obs.registry import default_registry
+
+    return default_registry().counter(
+        "photon_tiled_entries_total",
+        "entries of built tile schedules by the path that applies them: "
+        "kernel (grid steps), spill (the scatter beside them), dense (a "
+        "dense column's float32 side term)",
+    )
+
+
 def _build_schedule(
     rows: np.ndarray,
     feats: np.ndarray,
@@ -534,12 +663,19 @@ def _build_schedule(
     sort_by_feature_block: bool,
     num_out_blocks: int,
     digest: Optional[str] = None,
+    entries: int,
+    dense_columns: int,
 ) -> _Schedule:
+    """``_build_schedule_np`` onto the device, under its span, which says
+    what the batch builder scheduled (``entries``) and what it kept beside
+    the schedules (``dense_columns``)."""
     from photon_ml_tpu.obs.trace import span
     from photon_ml_tpu.ops import schedule_cache as _sc
 
     builds = _sc.stats().builds
-    with span("tiled.schedule_build", entries=int(len(vals))) as sp:
+    with span(
+        "tiled.schedule_build", entries=entries, dense_columns=dense_columns
+    ) as sp:
         arrays = _build_schedule_np(
             rows, feats, vals, params=params,
             sort_by_feature_block=sort_by_feature_block,
@@ -567,6 +703,8 @@ class TiledSparseBatch(NamedTuple):
     labels: Array
     offsets: Array
     weights: Array
+    dense_cols: Optional[Array] = None  # int32 [K]
+    dense_vals: Optional[Array] = None  # float32 [K, num_rows]
 
     # convenience passthroughs (static python ints)
     @property
@@ -629,9 +767,12 @@ def build_tiled_batch(
     dim: int,
     *,
     params: TileParams = TileParams(),
+    max_dense_columns: int = MAX_DENSE_COLUMNS,
 ) -> TiledSparseBatch:
     """COO triples + per-row arrays -> tiled batch. Entries with zero value
-    are dropped (they contribute nothing)."""
+    are dropped (they contribute nothing); up to ``max_dense_columns``
+    dense columns go beside the schedules (``_split_dense_columns``), the
+    tile parameters resolved from what is left."""
     nz = vals != 0
     if not nz.all():
         rows, feats, vals = rows[nz], feats[nz], vals[nz]
@@ -639,7 +780,11 @@ def build_tiled_batch(
     n = labels.shape[0]
     n_pad = max(((n + win - 1) // win) * win, win)
     d_pad = max(((dim + win - 1) // win) * win, win)
-    params = params.resolved(len(vals), (n_pad // win) * (d_pad // win))
+    vals, dense_cols, dense_vals = _split_dense_columns(
+        rows, feats, vals, n, dim, max_dense_columns
+    )
+    entries = len(vals) - int(np.count_nonzero(dense_vals))
+    params = params.resolved(entries, (n_pad // win) * (d_pad // win))
 
     # the two passes are independent and numpy's sorts/gathers release the
     # GIL — overlap them (halves the dominant host cost of cold training)
@@ -652,19 +797,21 @@ def build_tiled_batch(
         _sc.content_digest(rows, feats, vals)
         if _sc.resolve_cache_dir() is not None else None
     )
+    builds = _sc.stats().builds
     with ThreadPoolExecutor(2) as pool:
         fz = pool.submit(
             _build_schedule, rows, feats, vals, params=params,
             sort_by_feature_block=False, num_out_blocks=n_pad // win,
-            digest=digest,
+            digest=digest, entries=entries, dense_columns=len(dense_cols),
         )
         fg = pool.submit(
             _build_schedule, rows, feats, vals, params=params,
             sort_by_feature_block=True, num_out_blocks=d_pad // win,
-            digest=digest,
+            digest=digest, entries=entries, dense_columns=len(dense_cols),
         )
         z_sched = fz.result()
         g_sched = fg.result()
+    _count_dense_entries(dense_vals, builds, 1)
     lab = np.zeros(n_pad, np.float32)
     lab[:n] = labels
     off = np.zeros(n_pad, np.float32)
@@ -685,6 +832,27 @@ def build_tiled_batch(
         labels=jnp.asarray(lab),
         offsets=jnp.asarray(off),
         weights=jnp.asarray(wgt),
+        **_dense_leaves(dense_cols, dense_vals, n_pad, 1),
+    )
+
+
+def _dense_leaves(
+    dense_cols: np.ndarray, dense_vals: np.ndarray, rows_a_shard: int,
+    shards: int,
+) -> dict:
+    """``_split_dense_columns``' K ids and [K, n] values as the batch's two
+    leaves: rows zero-padded to ``shards * rows_a_shard``, then one [K,
+    rows_a_shard] segment a shard along axis 0 (global row r sits at
+    position r in both layouts). Nothing where K = 0."""
+    k, n = dense_vals.shape
+    if not k:
+        return {}
+    padded = np.zeros((k, shards * rows_a_shard), np.float32)
+    padded[:, :n] = dense_vals
+    by_shard = padded.reshape(k, shards, rows_a_shard).transpose(1, 0, 2)
+    return dict(
+        dense_cols=jnp.asarray(np.tile(dense_cols, shards)),
+        dense_vals=jnp.asarray(by_shard.reshape(shards * k, rows_a_shard)),
     )
 
 
@@ -721,14 +889,17 @@ def bucket_spill(batch: TiledSparseBatch) -> TiledSparseBatch:
     )
 
 
-def tiled_batch_from_sparse(batch, dim: int, *, params: TileParams = TileParams()):
+def tiled_batch_from_sparse(
+    batch, dim: int, *, params: TileParams = TileParams(),
+    max_dense_columns: int = MAX_DENSE_COLUMNS,
+):
     """Convenience: SparseBatch (padded ELL) -> TiledSparseBatch."""
     rows, feats, vals, _ = _sparse_coo(batch)
     return build_tiled_batch(
         rows, feats, vals,
         np.asarray(batch.labels), np.asarray(batch.offsets),
         np.asarray(batch.weights),
-        dim, params=params,
+        dim, params=params, max_dense_columns=max_dense_columns,
     )
 
 
@@ -850,6 +1021,7 @@ def build_sharded_tiled_batch(
     params: TileParams = TileParams(),
     mesh=None,
     axis: Optional[str] = None,
+    max_dense_columns: int = MAX_DENSE_COLUMNS,
 ) -> TiledSparseBatch:
     """SparseBatch -> mesh-layout TiledSparseBatch: the fast kernel AND
     data parallelism simultaneously (the reference's hot loop property,
@@ -863,23 +1035,40 @@ def build_sharded_tiled_batch(
     TiledSparseBatch (the meta describes the per-shard view) and runs the
     unmodified Pallas kernels; the objective's ``axis_name`` psums do the
     cross-device reduction. With ``mesh`` given, leaves are placed with
-    rows/steps sharded over ``axis`` (default "data").
+    rows/steps sharded over ``axis`` (default "data"). A dense column of
+    the WHOLE batch goes beside the schedules as in ``build_tiled_batch``,
+    each device holding its own rows' segment.
     """
+    from photon_ml_tpu.obs.trace import span
+    from photon_ml_tpu.ops import schedule_cache as _sc
+
     win = params.window
     rows, feats, vals, n = _sparse_coo(batch)
     rows_per = -(-n // n_shards)
     R = max(((rows_per + win - 1) // win) * win, win)
     d_pad = max(((dim + win - 1) // win) * win, win)
+    vals, dense_cols, dense_vals = _split_dense_columns(
+        rows, feats, vals, n, dim, max_dense_columns
+    )
+    entries = len(vals) - int(np.count_nonzero(dense_vals))
     params = params.resolved(
-        len(vals), n_shards * (R // win) * (d_pad // win)
+        entries, n_shards * (R // win) * (d_pad // win)
     )
     shard_of = rows // R
     local_rows = rows - shard_of * R
 
-    z_sched, g_sched, g_vals = _concat_cell_schedules(
-        local_rows, feats, vals, shard_of, n_shards,
-        params=params, z_out_blocks=R // win, g_out_blocks=d_pad // win,
-    )
+    builds = _sc.stats().builds
+    with span(
+        "tiled.schedule_build", entries=entries,
+        dense_columns=len(dense_cols), shards=n_shards,
+    ) as sp:
+        z_sched, g_sched, g_vals = _concat_cell_schedules(
+            local_rows, feats, vals, shard_of, n_shards,
+            params=params, z_out_blocks=R // win,
+            g_out_blocks=d_pad // win,
+        )
+        sp.set(cache="miss" if _sc.stats().builds > builds else "hit")
+    _count_dense_entries(dense_vals, builds, n_shards)
     g_vals_sq = jnp.asarray(g_vals**2)
     lab, off, wgt = _padded_row_meta(batch, n_shards * R)
     out = TiledSparseBatch(
@@ -893,6 +1082,7 @@ def build_sharded_tiled_batch(
         labels=lab,
         offsets=off,
         weights=wgt,
+        **_dense_leaves(dense_cols, dense_vals, R, n_shards),
     )
     if mesh is not None:
         out = _place_data_sharded(out, mesh, axis or DATA_AXIS)
@@ -1690,8 +1880,14 @@ class TiledGLMObjective:
     axis_name: Optional[str] = None
     interpret: bool = False
     # "bf16x2w" (default): hi+lo bf16 data split with both half-width
-    # matmuls fused into one full-width MXU tile (~1e-5 rel err, fastest);
-    # "highest": full f32 emulation (~1e-7).
+    # matmuls fused into one full-width MXU tile (~1e-5 rel err a product,
+    # fastest); "highest": full f32 emulation (~1e-7), a variant for tests
+    # and ``benchmark/proof.py`` that no driver selects. The split's
+    # rounding is independent from column to column and averages out over
+    # the rows, except for a column every row reads: such a DENSE column
+    # is not in the schedules at all (module docstring), its term is
+    # float32 under either variant, which is what lets the GAME driver's
+    # fixed effect run the default.
     mxu: str = "bf16x2w"
     # Positional-expansion algorithm: "compare" (sublane-iota equality,
     # the round-2 build) or "mxu" (squared-distance matmul + relu — the
@@ -1720,7 +1916,8 @@ class TiledGLMObjective:
         return jnp.zeros((batch.dim,), w.dtype).at[: w.shape[0]].set(w)
 
     def _z_pass(self, w_padded: Array, batch: TiledSparseBatch) -> Array:
-        """raw row-sums [num_rows] of the tiled bilinear product."""
+        """raw row-sums [num_rows]: the tiled bilinear product, the spilled
+        entries and the batch's dense columns."""
         b = batch
         p = b.params
         w2d = w_padded.reshape((b.num_feat_blocks, p.s_hi, p.s_lo))
@@ -1730,7 +1927,14 @@ class TiledGLMObjective:
             name=MARGIN_KERNEL,
         ).reshape(-1)
         with jax.named_scope("objective.spill"):
-            return b.z_sched.apply_spill(raw, w_padded)
+            raw = b.z_sched.apply_spill(raw, w_padded)
+        if b.dense_vals is None:
+            return raw
+        # the dense columns, exactly: elementwise float32 and a sum over K,
+        # no matmul for the caller's default precision to round
+        with jax.named_scope("objective.dense"):
+            w_dense = jnp.take(w_padded, b.dense_cols)
+            return raw + jnp.sum(b.dense_vals * w_dense[:, None], axis=0)
 
     def _grad_pass(
         self, c_rows: Array, batch: TiledSparseBatch,
@@ -1746,7 +1950,13 @@ class TiledGLMObjective:
             name=GRADIENT_KERNEL,
         ).reshape(-1)
         with jax.named_scope("objective.spill"):
-            return b.g_sched.apply_spill(g, c_rows, vals=spill_vals)
+            g = b.g_sched.apply_spill(g, c_rows, vals=spill_vals)
+        if b.dense_vals is None:
+            return g
+        with jax.named_scope("objective.dense"):
+            # (``vals``: the hessian-diagonal pass squares the values)
+            dense = b.dense_vals if vals is None else b.dense_vals**2
+            return g.at[b.dense_cols].add(jnp.sum(dense * c_rows, axis=1))
 
     # -- margins -----------------------------------------------------------
 
@@ -1760,9 +1970,10 @@ class TiledGLMObjective:
     def scores(self, coef: Array, batch: TiledSparseBatch) -> Array:
         """x_i . coef in padded row space, for ORIGINAL-space ``coef``: the
         raw row sums of the margin pass (kernel ``photon_tiled_margin`` +
-        the spilled entries), what ``models.glm.compute_scores`` returns
-        for the rows the schedule holds. No normalisation, no offsets and
-        no ``psum``: under ``shard_map`` each device scores its own rows.
+        the spilled entries + the dense columns' side term), what
+        ``models.glm.compute_scores`` returns for the rows the batch
+        holds. No normalisation, no offsets and no ``psum``: under
+        ``shard_map`` each device scores its own rows.
         (:meth:`margins` takes NORMALISED-space coefficients.)"""
         with jax.named_scope("objective.scores"):
             return self._z_pass(self._pad(coef, batch), batch)

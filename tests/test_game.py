@@ -473,8 +473,15 @@ class TestFixedEffectCoordinateTiled:
         shards = 2 if case == "data_mesh" else 1
         assert builds == {"scatter": [0, 0], "tiled": [2 * shards, 0]}
         first, second = (self._builds(s) for s in spans["tiled"])
-        if case != "data_mesh":  # (the mesh layout's build files no span)
-            assert [s.attrs["cache"] for s in first] == ["miss", "miss"]
+        # (a span a schedule on one device, one for the mesh layout's 2 x 2)
+        assert [s.attrs["cache"] for s in first] == ["miss"] * (
+            1 if case == "data_mesh" else 2
+        )
+        # the shard's seven always-present features, the intercept among
+        # them, are no tile's: a float32 side array of the batch
+        dense = ds.shards["globalShard"].intercept_index
+        assert {s.attrs["dense_columns"] for s in first} == {7}
+        assert dense in np.asarray(coord.__dict__["_tiled"].dense_cols)
         assert second == [] and not any(map(self._builds, spans["scatter"]))
         for per_update in spans["tiled"]:
             dispatch, = [s for s in per_update if s.name == "fit.dispatch"]
@@ -550,11 +557,14 @@ class TestFixedEffectCoordinateTiled:
             assert s.parent_id is None or by_id[s.parent_id].name != "cd.update"
         waits = [s for s in first if s.name == "cd.prefetch_wait"]
         assert "global" in {s.attrs["coordinate"] for s in waits}
-        updates = {
-            s.attrs["coordinate"]: s.attrs.get("kernel")
-            for s in first if s.name == "cd.update"
-        }
-        assert updates == {"global": "tiled", "per-user": None}
+        for name in ("cd.update", "cd.score"):  # kernel, and its variant
+            said = {
+                (s.attrs["coordinate"], s.attrs.get("kernel"), s.attrs.get("mxu"))
+                for s in first if s.name == name
+            }
+            assert said == {
+                ("global", "tiled", "bf16x2w"), ("per-user", None, None)
+            }
         assert any(
             line.startswith("coordinate global: ")
             and line.endswith(", kernel=tiled") for line in cd.logger.lines
@@ -585,9 +595,11 @@ class TestFixedEffectCoordinateTiled:
         coord.prepare()
         base = coord.__dict__["_tiled"]
         residual = jnp.asarray(rng.normal(size=ds.num_rows), jnp.float32)
-        offsets, weights = jax.jit(
-            lambda r: coord._tiled_batch(r)[-2:]
-        )(residual)
+        def row_vectors(r):
+            batch = coord._tiled_batch(r)
+            return batch.offsets, batch.weights
+
+        offsets, weights = jax.jit(row_vectors)(residual)
         n, total = ds.num_rows, base.labels.shape[0]
         assert offsets.shape == weights.shape == (total,) and total > n
         np.testing.assert_array_equal(
@@ -675,9 +687,10 @@ class TestDriverResolvesTheFixedEffectKernel:
         _, build = self._coords(tmp_path, rng, rate=rate)
         fe = build()["global"]
         assert isinstance(fe.problem.objective, TiledGLMObjective)
-        # the cd cell's fixed_value_gap holds the solve's value to 5e-7 of
-        # a float32 evaluation; "bf16x2w" read 8.1e-7 there (PERF.md, PR 28)
-        assert fe.problem.objective.mxu == "highest"
+        # the objective's default variant, as every other tiled user: the
+        # intercept, whose rounding "highest" was once paid for, is a
+        # float32 side term of the batch (PERF.md section 6, PR 32)
+        assert fe.problem.objective.mxu == fe.mxu == "bf16x2w"
         assert fe.kernel == "tiled" and fe.down_sampling_rate == rate
 
     def test_the_projection_problem_stays_scatter(self, tmp_path, rng, on_tpu):

@@ -31,12 +31,16 @@ from photon_ml_tpu.training import train_generalized_linear_model
 PARAMS = TileParams(s_hi=8, s_lo=8, chunk=32)  # window 64, tiny for tests
 
 
-def random_problem(rng, n=203, d=150, k=6):
+def random_problem(rng, n=203, d=150, k=6, intercept=False):
+    """``intercept``: one more column, d - 1, with an entry in every row."""
     rows, labels = [], []
     for _ in range(n):
         nnz = int(rng.integers(1, k + 1))
-        ix = rng.choice(d, size=nnz, replace=False).tolist()
+        ix = rng.choice(d - intercept, size=nnz, replace=False).tolist()
         vs = rng.normal(size=nnz).tolist()
+        if intercept:
+            ix.append(d - 1)
+            vs.append(float(rng.normal()))
         labels.append(float(rng.uniform() > 0.5))
         rows.append((ix, vs))
     return make_sparse_batch(rows, labels, weights=rng.uniform(0.5, 2.0, n)), d
@@ -66,6 +70,88 @@ class TestShardedTiledBatch:
             np.count_nonzero(np.asarray(tb.g_sched.vals))
             + np.count_nonzero(np.asarray(tb.g_sched.spill_vals))
         ) == nnz
+
+    def test_a_dense_column_is_split_over_the_shards_rows(self, rng):
+        """Found over the WHOLE batch, kept as one [K, rows] segment a
+        shard along axis 0, so the ``P(axis)`` split of every leaf hands a
+        device its own rows' values (row r of the batch at position r)."""
+        batch, d = random_problem(rng, intercept=True)
+        n_shards = 4
+        tb = build_sharded_tiled_batch(batch, d, n_shards, params=PARAMS)
+        R = tb.meta.num_rows
+        assert tb.dense_cols.tolist() == [d - 1] * n_shards
+        assert tb.dense_vals.shape == (n_shards, R)
+        live = np.asarray(batch.weights) > 0
+        at, slot = np.where(np.asarray(batch.indices) == d - 1)
+        want = np.zeros(n_shards * R, np.float32)
+        want[at] = np.asarray(batch.values)[at, slot]
+        want[: len(live)][~live] = 0  # built-out rows hold no entry
+        np.testing.assert_array_equal(
+            np.asarray(tb.dense_vals).reshape(-1), want
+        )
+        nnz = int(np.count_nonzero(np.asarray(batch.values)[live]))
+        for sched in (tb.z_sched, tb.g_sched):
+            assert (
+                np.count_nonzero(np.asarray(sched.vals))
+                + np.count_nonzero(np.asarray(sched.spill_vals))
+                + int(live.sum())
+            ) == nnz
+        # without one, the batch is the pytree it was
+        plain = build_sharded_tiled_batch(
+            random_problem(rng)[0], d, n_shards, params=PARAMS
+        )
+        assert plain.dense_cols is None and plain.dense_vals is None
+
+    @pytest.mark.parametrize(
+        "method", ["value_and_gradient", "hessian_vector", "hessian_diagonal"]
+    )
+    def test_a_dense_column_on_a_four_device_mesh(self, rng, method):
+        """Each device adds its own rows' side term; the gradient's joins
+        ``vector_sum`` before the one ``psum`` (no collective of its
+        own: the same count as without a dense column)."""
+        batch, d = random_problem(rng, intercept=True)
+        mesh = make_mesh((4,), devices=jax.devices()[:4])
+        tb = build_sharded_tiled_batch(batch, d, 4, params=PARAMS, mesh=mesh)
+        assert tb.dense_vals.sharding.spec == P(DATA_AXIS)
+        obj = TiledGLMObjective(
+            LOGISTIC, d, axis_name=DATA_AXIS, interpret=True
+        )
+        oracle = GLMObjective(LOGISTIC, d)
+        w = jnp.asarray(rng.normal(size=d).astype(np.float32))
+        v = jnp.asarray(rng.normal(size=d).astype(np.float32))
+        l2 = jnp.float32(0.3)
+
+        def on_mesh(f):
+            return jax.jit(shard_map(
+                f, mesh=mesh, in_specs=(P(), P(), P(DATA_AXIS), P()),
+                out_specs=P(), check_vma=False,
+            ))
+
+        if method == "value_and_gradient":
+            f = on_mesh(lambda w, v, b, l2: obj.value_and_gradient(w, b, l2))
+            value, got = f(w, v, tb, l2)
+            ov, want = oracle.value_and_gradient(w, batch, l2)
+            np.testing.assert_allclose(float(value), float(ov), rtol=1e-5)
+            plain = build_sharded_tiled_batch(
+                random_problem(rng)[0], d, 4, params=PARAMS, mesh=mesh
+            )
+            psums = [
+                str(f.trace(w, v, b, l2).jaxpr).count("psum")
+                for b in (tb, plain)
+            ]
+            assert psums[0] == psums[1] > 0
+        elif method == "hessian_vector":
+            got = on_mesh(obj.hessian_vector)(w, v, tb, l2)
+            want = oracle.hessian_vector(w, v, batch, l2)
+        else:
+            got = on_mesh(
+                lambda w, v, b, l2: obj.hessian_diagonal(w, b, l2)
+            )(w, v, tb, l2)
+            want = oracle.hessian_diagonal(w, batch, l2)
+        scale = float(np.max(np.abs(np.asarray(want))))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-4 * scale
+        )
 
     def test_per_shard_blocks_monotone(self, rng):
         batch, d = random_problem(rng)

@@ -74,13 +74,16 @@ class TestSchedule:
     def test_entries_preserved(self, rng):
         batch, d = random_problem(rng)
         tb = tiled_batch_from_sparse(batch, d, params=PARAMS)
-        # every nonzero entry appears exactly once in each schedule
-        # (chunk slots + spill tail together)
+        # every nonzero entry appears exactly once in each pass: chunk
+        # slots + spill tail + the dense side array (the intercept's)
         nnz = int(np.count_nonzero(np.asarray(batch.values)))
+        dense = np.count_nonzero(tb.dense_vals)
+        assert dense == np.count_nonzero(np.asarray(batch.weights))
         for sched in (tb.z_sched, tb.g_sched):
             assert (
                 np.count_nonzero(sched.vals)
                 + np.count_nonzero(sched.spill_vals)
+                + dense
             ) == nnz
         # monotone output blocks
         z_out = np.asarray(tb.z_sched.step_out)
@@ -460,3 +463,313 @@ class TestWideMxuVariant:
                 float(jnp.linalg.norm(hv1 - hv0) / jnp.linalg.norm(hv0))
                 < 1e-4
             )
+
+
+def _coo_problem(rng, n=150, d=200, k=5, dense=(), padding=9):
+    """A SparseBatch of ``n`` rows, the last ``padding`` of them weight 0
+    (built out), ``k`` random entries a row over [0, d - 16) and one entry
+    a LIVE row in each column of ``dense``."""
+    from photon_ml_tpu.data.batch import SparseBatch
+
+    dense = list(dense)
+    indices = np.zeros((n, k + len(dense)), np.int32)
+    for i in range(n):
+        indices[i, :k] = rng.choice(d - 16, size=k, replace=False)
+    indices[:, k:] = dense
+    values = rng.normal(size=indices.shape).astype(np.float32)
+    weights = rng.uniform(0.5, 2.0, n).astype(np.float32)
+    if padding:
+        weights[-padding:] = 0.0
+    return SparseBatch(
+        indices=jnp.asarray(indices), values=jnp.asarray(values),
+        labels=jnp.asarray((rng.uniform(size=n) > 0.5).astype(np.float32)),
+        offsets=jnp.asarray(rng.normal(size=n).astype(np.float32)),
+        weights=jnp.asarray(weights),
+    )
+
+
+def _tiled_columns(tb):
+    """The feature ids a batch's g-schedule (kernel steps + spill) holds."""
+    win = tb.params.window
+    g = tb.g_sched
+    steps = g.step_out.shape[0]
+    ids = np.asarray(g.step_out)[:, None] * win + np.asarray(g.out_pos)[:steps]
+    held = set(ids[np.asarray(g.vals)[:steps] != 0].tolist())
+    return held | set(
+        np.asarray(g.spill_out)[np.asarray(g.spill_vals) != 0].tolist()
+    )
+
+
+class TestDenseColumns:
+    """A column with an entry in every live row leaves the tile schedule
+    and is applied in float32 beside the kernel (ops/tiled_sparse's module
+    docstring)."""
+
+    def test_the_builder_splits_a_column_in_every_live_row(self, rng):
+        d = 200
+        batch = _coo_problem(rng, d=d, dense=(d - 1, d - 7))
+        tb = tiled_batch_from_sparse(batch, d, params=PARAMS)
+        live = np.asarray(batch.weights) > 0
+        assert tb.dense_cols.tolist() == [d - 7, d - 1]
+        assert tb.dense_vals.shape == (2, tb.num_rows)
+        assert tb.dense_vals.dtype == jnp.float32
+        values = np.asarray(batch.values)
+        for k, slot in ((0, -1), (1, -2)):
+            want = np.zeros(tb.num_rows, np.float32)
+            want[: len(live)] = np.where(live, values[:, slot], 0.0)
+            np.testing.assert_array_equal(np.asarray(tb.dense_vals[k]), want)
+        assert not _tiled_columns(tb) & {d - 1, d - 7}
+
+    @pytest.mark.parametrize("order", ["row_major", "shuffled"])
+    def test_the_split_zeroes_the_columns_entries_and_no_other(self, rng, order):
+        """``_split_dense_columns`` against the plain mask, in whatever
+        order the COO triple comes: the dense entries' values become 0
+        (no entry, to the schedule builders) in a COPY; with nothing
+        dense the values come back as the same object."""
+        from photon_ml_tpu.ops.tiled_sparse import _split_dense_columns
+
+        n, k, d = 5000, 7, 3000
+        feats = rng.integers(0, d - 1, size=(n, k)).astype(np.int64)
+        feats[:, 3] = d - 1
+        rows = np.repeat(np.arange(n, dtype=np.int64), k)
+        feats = feats.reshape(-1)
+        vals = rng.normal(size=n * k).astype(np.float32)
+        if order == "shuffled":
+            perm = rng.permutation(n * k)
+            rows, feats, vals = rows[perm], feats[perm], vals[perm]
+        before = vals.copy()
+        out, cols, dense = _split_dense_columns(rows, feats, vals, n, d, 8)
+        np.testing.assert_array_equal(vals, before)  # the caller's: untouched
+        column = feats == d - 1
+        assert cols.tolist() == [d - 1] and dense.shape == (1, n)
+        np.testing.assert_array_equal(out, np.where(column, 0, vals))
+        want = np.zeros(n, np.float32)
+        want[rows[column]] = vals[column]
+        np.testing.assert_array_equal(dense[0], want)
+        feats[np.flatnonzero(column)[0]] = 0  # one row short: nothing dense
+        again, cols, dense = _split_dense_columns(rows, feats, vals, n, d, 8)
+        assert again is vals and cols.shape == (0,) and dense.shape == (0, n)
+
+    def test_a_zero_value_is_no_entry_to_either_schedule_builder(self, rng):
+        """The native builder and its numpy oracle pass over an entry
+        whose value is 0: both build what they build of the compacted
+        triple, array for array."""
+        from photon_ml_tpu.ops import tiled_sparse as ts
+
+        n, d, nnz = 400, 260, 5000
+        rows = rng.integers(0, n, nnz).astype(np.int64)
+        feats = rng.integers(0, d, nnz).astype(np.int64)
+        vals = rng.normal(size=nnz).astype(np.float32)
+        vals[rng.uniform(size=nnz) < 0.2] = 0.0
+        live = vals != 0
+        params = TileParams(s_hi=8, s_lo=8, chunk=32, spill_cap=8)
+        kw = dict(
+            params=params, sort_by_feature_block=True,
+            num_out_blocks=(d + 63) // 64,
+        )
+        want = ts._build_schedule_np(rows[live], feats[live], vals[live], **kw)
+        native = ts._build_schedule_native(rows, feats, vals, **kw)
+        assert native is not None
+        saved = ts._tile_lib_handle
+        ts._tile_lib_handle = False
+        try:
+            oracle = ts._build_schedule_np(rows, feats, vals, **kw)
+        finally:
+            ts._tile_lib_handle = saved
+        for a, b, c in zip(want, native, oracle):
+            np.testing.assert_array_equal(a, b)
+            np.testing.assert_array_equal(a, c)
+
+    @pytest.mark.parametrize("fault", ["misses_a_row", "twice_in_a_row"])
+    def test_a_column_short_of_a_live_row_stays_tiled(self, rng, fault):
+        """One live row without the column, or (the same entry count) the
+        column twice in one row and missing in another."""
+        d = 200
+        batch = _coo_problem(rng, d=d, dense=(d - 1,))
+        indices = np.asarray(batch.indices).copy()
+        indices[3, -1] = indices[4, 0] if fault == "twice_in_a_row" else 0
+        if fault == "twice_in_a_row":
+            indices[4, 0] = d - 1
+        batch = batch._replace(indices=jnp.asarray(indices))
+        tb = tiled_batch_from_sparse(batch, d, params=PARAMS)
+        assert tb.dense_cols is None and tb.dense_vals is None
+        assert d - 1 in _tiled_columns(tb)
+
+    def test_no_dense_column_builds_the_schedules_as_before(self, rng):
+        """K = 0: the batch is the pytree it was, and its schedules are,
+        byte for byte, what the schedule builder makes of the whole COO
+        triple under the parameters resolved from all of it."""
+        from photon_ml_tpu.ops import tiled_sparse as ts
+
+        d = 200
+        batch = _coo_problem(rng, d=d)
+        tb = tiled_batch_from_sparse(batch, d, params=TileParams(8, 8))
+        assert tb.dense_cols is None and tb.dense_vals is None
+        assert len(jax.tree.leaves(tb)) == 22
+        rows, feats, vals, _ = ts._sparse_coo(batch)
+        blocks = tb.num_row_blocks, tb.num_feat_blocks
+        params = TileParams(8, 8).resolved(len(vals), blocks[0] * blocks[1])
+        assert params == tb.params
+        for sched, by_feat, out_blocks in (
+            (tb.z_sched, False, blocks[0]), (tb.g_sched, True, blocks[1]),
+        ):
+            want = ts._build_schedule_np(
+                rows, feats, vals, params=params,
+                sort_by_feature_block=by_feat, num_out_blocks=out_blocks,
+            )
+            for got, arr in zip(sched, want):
+                assert np.asarray(got).tobytes() == arr.tobytes()
+        again = tiled_batch_from_sparse(
+            batch, d, params=TileParams(8, 8), max_dense_columns=0
+        )
+        assert jax.tree.structure(again) == jax.tree.structure(tb)
+
+    def test_the_bound_leaves_a_further_dense_column_tiled(self, rng):
+        from photon_ml_tpu.ops.tiled_sparse import MAX_DENSE_COLUMNS
+
+        d = 200
+        dense = tuple(range(d - 10, d))
+        batch = _coo_problem(rng, d=d, dense=dense)
+        tb = tiled_batch_from_sparse(batch, d, params=PARAMS)
+        assert MAX_DENSE_COLUMNS == 8
+        assert tb.dense_cols.tolist() == list(dense[:8])
+        assert _tiled_columns(tb) & set(dense) == {d - 2, d - 1}
+        w = jnp.asarray(rng.normal(size=d).astype(np.float32))
+        v0, g0 = GLMObjective(LOGISTIC, d).value_and_gradient(w, batch, 0.1)
+        v1, g1 = TiledGLMObjective(
+            LOGISTIC, d, interpret=True
+        ).value_and_gradient(w, tb, 0.1)
+        np.testing.assert_allclose(float(v1), float(v0), rtol=1e-4)
+        np.testing.assert_allclose(
+            np.asarray(g1), np.asarray(g0), atol=1e-4 * float(jnp.max(jnp.abs(g0)))
+        )
+
+    @pytest.mark.parametrize(
+        "method",
+        ["value_and_gradient", "scores", "hessian_vector", "hessian_diagonal"],
+    )
+    def test_the_default_variant_matches_the_scatter_objective(
+        self, rng, method
+    ):
+        """Every method inherits the side term from the two passes: at
+        "bf16x2w", with a normalisation shift and factor, offsets and
+        weight-0 rows, two dense columns."""
+        from photon_ml_tpu.models.glm import compute_scores
+        from photon_ml_tpu.ops.normalization import NormalizationContext
+
+        d = 200
+        batch = _coo_problem(rng, d=d, dense=(d - 1, 11))
+        ctx = NormalizationContext(
+            factor=jnp.asarray(rng.uniform(0.5, 2.0, d).astype(np.float32)),
+            shift=jnp.asarray(rng.normal(size=d).astype(np.float32) * 0.1),
+        )
+        obj = GLMObjective(LOGISTIC, d, ctx)
+        tobj = TiledGLMObjective(LOGISTIC, d, norm=ctx, interpret=True)
+        assert tobj.mxu == "bf16x2w"
+        tb = tiled_batch_from_sparse(batch, d, params=PARAMS)
+        assert tb.dense_cols.tolist() == [11, d - 1]
+        w = jnp.asarray(rng.normal(size=d).astype(np.float32))
+        u = jnp.asarray(rng.normal(size=d).astype(np.float32))
+        if method == "value_and_gradient":
+            want, got = (
+                o.value_and_gradient(w, b, 0.2) for o, b in ((obj, batch), (tobj, tb))
+            )
+            np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-5)
+            want, got = want[1], got[1]
+        elif method == "scores":
+            # (original-space coefficients; a built-out row scores 0)
+            live = np.asarray(batch.weights) > 0
+            want = np.where(live, np.asarray(compute_scores(w, batch)), 0.0)
+            got = tobj.scores(w, tb)[: len(live)]
+        elif method == "hessian_vector":
+            want = obj.hessian_vector(w, u, batch, 0.2)
+            got = tobj.hessian_vector(w, u, tb, 0.2)
+        else:
+            want = obj.hessian_diagonal(w, batch, 0.1)
+            got = tobj.hessian_diagonal(w, tb, 0.1)
+        scale = float(np.max(np.abs(np.asarray(want))))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-4 * scale
+        )
+
+    def test_a_coefficient_bank_under_vmap(self, rng):
+        """The batched lambda grid's path: the kernel's ``custom_vmap``
+        rule takes the bank, the side term batches as plain jax.numpy."""
+        d = 200
+        batch = _coo_problem(rng, d=d, dense=(d - 1,))
+        tb = tiled_batch_from_sparse(batch, d, params=PARAMS)
+        tobj = TiledGLMObjective(LOGISTIC, d, interpret=True)
+        bank = jnp.asarray(rng.normal(size=(3, d)).astype(np.float32))
+        l2 = jnp.asarray([0.0, 0.1, 1.0], jnp.float32)
+        values, grads = jax.vmap(
+            lambda w, l: tobj.value_and_gradient(w, tb, l)
+        )(bank, l2)
+        obj = GLMObjective(LOGISTIC, d)
+        for i in range(3):
+            v0, g0 = obj.value_and_gradient(bank[i], batch, l2[i])
+            np.testing.assert_allclose(float(values[i]), float(v0), rtol=1e-5)
+            np.testing.assert_allclose(
+                np.asarray(grads[i]), np.asarray(g0),
+                atol=1e-4 * float(jnp.max(jnp.abs(g0))),
+            )
+
+    def test_a_tiled_in_intercept_shifts_every_margin_the_same_way(self, rng):
+        """What the side term is for (PERF.md section 6, PR 28 and 32): a
+        bfloat16 hi+lo pair does not hold 3.7182817, and every row reads
+        it, so with the intercept IN the tiles (the builder's bound at 0)
+        the margins' MEAN error against a float64 evaluation is the
+        coefficient's rounding (the pair holds 3.7182817 - 9.54e-7),
+        where any other column's averages out. With the column out the
+        mean error is float32's."""
+        n, d, k = 2048, 200, 5
+        batch = _coo_problem(rng, n=n, d=d, k=k, dense=(d - 1,), padding=0)
+        values = np.asarray(batch.values).copy()
+        values[:, -1] = 1.0
+        batch = batch._replace(
+            values=jnp.asarray(values), offsets=jnp.zeros(n, jnp.float32)
+        )
+        w = rng.normal(size=d).astype(np.float32) * 0.1
+        w[d - 1] = np.float32(3.7182817)
+        exact = np.einsum(
+            "nk,nk->n", values.astype(np.float64),
+            w.astype(np.float64)[np.asarray(batch.indices)],
+        )
+        tobj = TiledGLMObjective(LOGISTIC, d, interpret=True)
+
+        def mean_error(**kw):
+            tb = tiled_batch_from_sparse(batch, d, params=PARAMS, **kw)
+            z = np.asarray(tobj.margins(jnp.asarray(w), tb))[:n]
+            return abs(float(np.mean(z.astype(np.float64) - exact)))
+
+        assert mean_error() < 1e-7
+        assert mean_error(max_dense_columns=0) > 8e-7
+
+    def test_the_counter_and_the_span_say_it_engaged(self, rng):
+        from photon_ml_tpu.obs import trace as obs_trace
+        from photon_ml_tpu.obs.registry import default_registry
+
+        d = 200
+        batch = _coo_problem(rng, d=d, dense=(d - 1,))
+        counter = default_registry().counter("photon_tiled_entries_total")
+        paths = ("kernel", "spill", "dense")
+        before = [counter.value(path=p) for p in paths]
+        with obs_trace.tracing_scope(True):
+            obs_trace.tracer().clear()
+            tb = tiled_batch_from_sparse(
+                batch, d, params=TileParams(8, 8, 32, spill_cap=8)
+            )
+            spans = [
+                s for s in obs_trace.tracer().drain()
+                if s.name == "tiled.schedule_build"
+            ]
+        assert [s.attrs["dense_columns"] for s in spans] == [1, 1]
+        kernel, spill, dense = (
+            counter.value(path=p) - b for p, b in zip(paths, before)
+        )
+        live = int(np.count_nonzero(np.asarray(batch.weights)))
+        # two schedules a batch, each applying every entry once
+        assert dense == 2 * live and spill > 0
+        assert kernel + spill == 2 * 5 * live
+        assert spill == sum(
+            np.count_nonzero(s.spill_vals) for s in (tb.z_sched, tb.g_sched)
+        )
