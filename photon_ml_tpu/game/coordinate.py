@@ -714,11 +714,12 @@ class RandomEffectCoordinate(Coordinate):
             bank, tracker, variances = self.problem.update_bank(
                 model.bank, self.re_dataset, residual_offsets=offsets,
                 with_variances=True, defer_tracker=True,
+                coordinate=self.name,
             )
         else:
             bank, tracker = self.problem.update_bank(
                 model.bank, self.re_dataset, residual_offsets=offsets,
-                defer_tracker=True,
+                defer_tracker=True, coordinate=self.name,
             )
         return replace(model, bank=bank, variances=variances), tracker
 
@@ -742,7 +743,7 @@ class RandomEffectCoordinate(Coordinate):
                 jnp.float32,
             )
         )
-        self.problem.prepare(bank, self.re_dataset)
+        self.problem.prepare(bank, self.re_dataset, coordinate=self.name)
         device_row_view(self.re_dataset)
 
 

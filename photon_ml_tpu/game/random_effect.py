@@ -17,15 +17,20 @@ while_loop over [E_b, ...] blocks. Shard the entity axis over the mesh
 
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
+from photon_ml_tpu.game.random_effect_data import (
+    RandomEffectBucket,
+    RandomEffectDataset,
+)
+from photon_ml_tpu.obs.registry import default_registry
 from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.obs.trace import traced as obs_traced
 from photon_ml_tpu.ops.losses import PointwiseLoss
@@ -444,7 +449,38 @@ def _bucket_solver(
 
     n_reasons = max(CONVERGENCE_REASON_NAMES) + 1
 
-    def _fused(core):
+    def _update_block(core, bank, codes, ix, v, lab, off, w, l1, l2):
+        """One block's gather, solve, scatter and tracker reductions. A
+        code past the bank's last row is a PADDING lane (the last
+        sub-block of a split bucket, :meth:`RandomEffectOptimizationProblem
+        ._solver_blocks`): it starts from zero on weight-0 rows, its
+        scatter drops and it counts in no reduction, as the pod's pad
+        lanes do."""
+        real = codes < bank.shape[0]
+        sl = jnp.take(bank, codes, axis=0, mode="fill", fill_value=0)
+        new_sl, iters, reasons = core(sl, ix, v, lab, off, w, l1, l2)
+        with jax.named_scope("bank.scatter_back"):
+            bank = bank.at[codes].set(new_sl, mode="drop")
+        iters = jnp.where(real, iters, 0)
+        counts = jnp.bincount(
+            jnp.where(real, reasons, n_reasons), length=n_reasons + 1
+        )[:n_reasons]
+        return bank, jnp.sum(iters), jnp.max(iters), counts
+
+    def _donate():
+        from photon_ml_tpu.utils.backend import effective_platform
+
+        return (0,) if effective_platform() != "cpu" else ()
+
+    def _named(name):
+        """The function handed to jax.jit names the XLA module."""
+        def rename(f):
+            f.__name__ = f.__qualname__ = name
+            return f
+
+        return rename
+
+    def _fused(core, name="bank_fused"):
         """Single-dispatch bucket update: bank-row gather, solve, bank
         scatter, and the tracker reductions all inside ONE jit program —
         per-bucket host overhead (separate gather/scatter dispatches plus
@@ -456,54 +492,40 @@ def _bucket_solver(
         [E_total, D] bank per bucket — at the 1B-coefficient scale that
         copy would double peak bank memory and add a ~4 GB HBM pass per
         bucket. update_bank defensively copies the caller's bank ONCE
-        before the bucket chain so outside references stay valid."""
-        from photon_ml_tpu.utils.backend import effective_platform
+        before the bucket chain so outside references stay valid.
 
-        donate = (0,) if effective_platform() != "cpu" else ()
+        ``name`` names the XLA module (``jit_<name>``): a coordinate's
+        own where :func:`fused_for` is given one."""
 
         # photon: sharding(axes=[], donates=[0])
-        @partial(jax.jit, donate_argnums=donate)
+        @partial(jax.jit, donate_argnums=_donate())
+        @_named(name)
         def bank_fused(bank_full, codes, ix, v, lab, off, w, l1, l2):
-            sl = jnp.take(bank_full, codes, axis=0)
-            new_sl, iters, reasons = core(sl, ix, v, lab, off, w, l1, l2)
-            with jax.named_scope("bank.scatter_back"):
-                bank_full = bank_full.at[codes].set(new_sl)
-            return (
-                bank_full,
-                jnp.sum(iters),
-                jnp.max(iters),
-                jnp.bincount(reasons, length=n_reasons),
+            return _update_block(
+                core, bank_full, codes, ix, v, lab, off, w, l1, l2
             )
 
         return bank_fused
 
-    def _fused_scan(core):
+    def _fused_scan(core, name="bank_fused_scan"):
         """The fused bucket update folded over a STACK of same-shape
-        buckets by lax.scan — one dispatch for the whole group. Profiled
-        at the config-4 user-bank shape (round 5): the four
-        sequential per-bucket dispatches left ~125 ms of host gaps
-        between ~76 ms device programs; scanning removes the gaps. The
-        bank threads through the scan carry (donated, in-place
-        scatters)."""
-        from photon_ml_tpu.utils.backend import effective_platform
-
-        donate = (0,) if effective_platform() != "cpu" else ()
+        blocks by lax.scan — one dispatch for the whole group: a run of
+        same-shape buckets, or the equal sub-blocks of one bucket over
+        the dense budget. Profiled at the config-4 user-bank shape
+        (round 5): the four sequential per-bucket dispatches left
+        ~125 ms of host gaps between ~76 ms device programs; scanning
+        removes the gaps. The bank threads through the scan carry
+        (donated, in-place scatters), so only ONE block's dense staging
+        is live at a time."""
 
         # photon: sharding(axes=[], donates=[0])
-        @partial(jax.jit, donate_argnums=donate)
+        @partial(jax.jit, donate_argnums=_donate())
+        @_named(name)
         def bank_fused_scan(bank_full, codes_s, ix_s, v_s, lab_s, off_s,
                             w_s, l1, l2):
             def body(bank, args):
-                codes, ix, v, lab, off, w = args
-                sl = jnp.take(bank, codes, axis=0)
-                new_sl, iters, reasons = core(sl, ix, v, lab, off, w, l1, l2)
-                with jax.named_scope("bank.scatter_back"):
-                    bank = bank.at[codes].set(new_sl)
-                return bank, (
-                    jnp.sum(iters),
-                    jnp.max(iters),
-                    jnp.bincount(reasons, length=n_reasons),
-                )
+                bank, *stats = _update_block(core, bank, *args, l1, l2)
+                return bank, tuple(stats)
 
             bank_full, (it_sums, it_maxs, counts) = jax.lax.scan(
                 body, bank_full, (codes_s, ix_s, v_s, lab_s, off_s, w_s)
@@ -537,28 +559,77 @@ def _bucket_solver(
 
     from types import SimpleNamespace
 
-    solve_dense = _make_dense(False)
-    solve_dense_id = _make_dense(True)
-    solve_newton = _make_newton(False)
-    solve_newton_id = _make_newton(True)
+    cores = {
+        "sparse": bank_sparse,
+        "dense": _make_dense(False),
+        "dense_id": _make_dense(True),
+        "newton": _make_newton(False),
+        "newton_id": _make_newton(True),
+    }
+    fused_programs: dict = {}
+
+    def fused_for(kind, coordinate=None, scan=False):
+        """The fused (``scan``: scanned) update program of a solver kind,
+        its module named after ``coordinate`` when one is given
+        (``jit_bank_fused[_scan]_<coordinate>``, non-word characters as
+        ``_``): a device trace then tells one coordinate's bank from
+        another's. Two banks differ in shape and compile apart whatever
+        they are called, so the name costs no compile."""
+        key = (kind, coordinate, scan)
+        if key not in fused_programs:
+            name = "bank_fused_scan" if scan else "bank_fused"
+            if coordinate:
+                name += "_" + re.sub(r"\W", "_", coordinate)
+            build = _fused_scan if scan else _fused
+            fused_programs[key] = build(cores[kind], name)
+        return fused_programs[key]
+
     return SimpleNamespace(
-        sparse=bank_sparse,
-        dense=solve_dense,
-        dense_id=solve_dense_id,
-        newton=solve_newton,
-        newton_id=solve_newton_id,
-        fused_sparse=_fused(bank_sparse),
-        fused_dense=_fused(solve_dense),
-        fused_dense_id=_fused(solve_dense_id),
-        fused_newton=_fused(solve_newton),
-        fused_newton_id=_fused(solve_newton_id),
-        fused_scan_sparse=_fused_scan(bank_sparse),
-        fused_scan_dense=_fused_scan(solve_dense),
-        fused_scan_dense_id=_fused_scan(solve_dense_id),
-        fused_scan_newton=_fused_scan(solve_newton),
-        fused_scan_newton_id=_fused_scan(solve_newton_id),
+        **cores,
+        **{f"fused_{kind}": fused_for(kind) for kind in cores},
+        fused_for=fused_for,
         hdiag=hdiag,
     )
+
+
+class _SolverBlock(NamedTuple):
+    """What ONE solver program runs on the replicated bank: a whole
+    bucket, or one of the ``sub_blocks`` equal sub-blocks of a bucket
+    whose dense staging is over ``dense_bytes_budget``."""
+
+    bucket_index: int  # in ``dataset.buckets``
+    sub_block: int
+    sub_blocks: int  # how many the bucket was split into (1: whole)
+    kind: str
+    bucket: RandomEffectBucket  # the bucket itself, or the sub-block's view
+    num_real: int  # entities less the last sub-block's padding lanes
+
+
+def _split_bucket(
+    bucket: RandomEffectBucket, n_sub: int, pad_code: int
+) -> List[RandomEffectBucket]:
+    """``bucket`` as ``n_sub`` sub-blocks of one shape: views of its host
+    arrays, the last padded with weight-0 entities on no row whose code
+    ``pad_code`` lies past the bank (the fused programs' padding lanes)."""
+    e_sub = -(-bucket.num_entities // n_sub)
+    fill = {"entity_codes": pad_code, "row_index": -1}
+    out = []
+    for j in range(n_sub):
+        parts = {}
+        for name in ("entity_codes", "row_index", "indices", "values",
+                     "labels", "offsets", "weights"):
+            a = getattr(bucket, name)[j * e_sub:(j + 1) * e_sub]
+            if a.shape[0] < e_sub:
+                pad = np.full(
+                    (e_sub - a.shape[0],) + a.shape[1:], fill.get(name, 0),
+                    a.dtype,
+                )
+                a = np.concatenate([a, pad])
+            parts[name] = a
+        out.append(RandomEffectBucket(
+            identity_indices=bucket.identity_indices, **parts
+        ))
+    return out
 
 
 @dataclass
@@ -643,11 +714,12 @@ class RandomEffectOptimizationProblem:
         kind a dense staging of it runs, and how many of its entities ONE
         dense program may hold under ``dense_bytes_budget``. A cap of at
         least ``num_entities`` means the block runs whole; a smaller one
-        leaves the caller to run the sparse solver (a replicated bucket,
-        :meth:`_bucket_kind`) or to split the block into sub-blocks of at
-        most that many entities (a device's share on the pod path,
-        game/pod.py). ``("sparse", num_entities)`` where nothing dense
-        may run: the layout says so, or not one entity fits."""
+        has the caller split the block into equal sub-blocks of at most
+        that many entities (a replicated bucket, :meth:`_solver_blocks`;
+        a device's share on the pod path, game/pod.py), or run the sparse
+        solver where it cannot split (:meth:`_bucket_kind`).
+        ``("sparse", num_entities)`` where nothing dense may run: the
+        layout says so, or not one entity fits."""
         if self.layout == "sparse":
             return "sparse", num_entities
         newton = self._newton_eligible()
@@ -671,13 +743,59 @@ class RandomEffectOptimizationProblem:
         return (kind, int(cap)) if cap > 0 else ("sparse", num_entities)
 
     def _bucket_kind(self, bucket, d_local: int) -> str:
-        """Which solver program this bucket runs (host-side selection):
-        the dense kind where the WHOLE bucket fits the budget."""
+        """Which solver program this bucket runs as ONE block (host-side
+        selection): the dense kind where the WHOLE bucket fits the
+        budget. What a block that cannot be split runs: a streamed
+        segment, a bucket on the entity mesh or under a values
+        override; ``update_bank`` splits the others
+        (:meth:`_solver_blocks`)."""
         e_b, s_b, _ = bucket.indices.shape
         kind, cap = self.dense_block_plan(
             e_b, s_b, d_local, bucket.identity_indices
         )
         return kind if cap >= e_b else "sparse"
+
+    def _solver_blocks(
+        self, dataset: RandomEffectDataset, d_local: int, *, split: bool
+    ) -> List[_SolverBlock]:
+        """The dataset's buckets as the blocks the solver programs run,
+        in bucket order. With ``split`` a bucket whose dense staging is
+        over the budget becomes ``ceil(E_b / cap)`` equal sub-blocks of
+        the dense kind (:meth:`dense_block_plan`, the rule the pod splits
+        a device's share by): same shape, so ONE compiled program, and
+        consecutive, so they fold into one scanned dispatch that hands
+        the donated bank from one to the next. Without it (the entity
+        mesh, a values override: both address a bucket by its index) a
+        bucket is one block of :meth:`_bucket_kind`. The sub-block views
+        are cached on the dataset, keyed by the split."""
+        plans = []
+        for bucket in dataset.buckets:
+            e_b, s_b, _ = bucket.indices.shape
+            kind, cap = self.dense_block_plan(
+                e_b, s_b, d_local, bucket.identity_indices
+            )
+            if cap >= e_b:
+                plans.append((kind, 1))
+            elif split:
+                plans.append((kind, -(-e_b // cap)))
+            else:
+                plans.append(("sparse", 1))
+        n_subs = tuple(n for _, n in plans)
+        cache = dataset.__dict__.setdefault("_sub_block_cache", {})
+        if n_subs not in cache:
+            cache[n_subs] = [
+                _split_bucket(b, n, dataset.num_entities) if n > 1 else [b]
+                for b, n in zip(dataset.buckets, n_subs)
+            ]
+        blocks = []
+        for bi, (subs, (kind, n)) in enumerate(zip(cache[n_subs], plans)):
+            e_sub = subs[0].num_entities
+            left = dataset.buckets[bi].num_entities
+            for j, sub in enumerate(subs):
+                blocks.append(
+                    _SolverBlock(bi, j, n, kind, sub, min(e_sub, left - j * e_sub))
+                )
+        return blocks
 
     def _newton_eligible(self) -> bool:
         """The dual-space Newton solver needs l2 > 0 (Woodbury ridge), a
@@ -761,14 +879,14 @@ class RandomEffectOptimizationProblem:
                 routed = router.route(residual_offsets)
         return residual_offsets, routed, router
 
-    def _stacked_group_args(self, dataset, members, *, with_residuals):
-        """Device-stacked [B, ...] args for a same-shape bucket group,
-        built from the HOST arrays in one transfer per field and cached
-        on the dataset. Only the offset source the configuration needs is
-        stacked: stored offsets when ``with_residuals`` is False, row
-        indices (for the on-device residual gather) when True — never
-        both (a dead [B, E, S] buffer would otherwise pin HBM for the
-        dataset's lifetime).
+    def _stacked_group_args(self, dataset, blocks, *, with_residuals):
+        """Device-stacked [B, ...] args for a same-shape group of solver
+        blocks, built from the HOST arrays in one transfer per field and
+        cached on the dataset. Only the offset source the configuration
+        needs is stacked: stored offsets when ``with_residuals`` is
+        False, row indices (for the on-device residual gather) when True
+        — never both (a dead [B, E, S] buffer would otherwise pin HBM for
+        the dataset's lifetime).
 
         Accepted trade-off: a dataset that ALSO runs the per-bucket path
         (bank_variances / with_variances) holds its buckets in both this
@@ -776,11 +894,11 @@ class RandomEffectOptimizationProblem:
         co-occur within one update, and problems are variance-typed for
         their lifetime, so the overlap is rare in practice."""
         cache = dataset.__dict__.setdefault("_stacked_device_cache", {})
-        key = (tuple(members), bool(with_residuals))
+        key = (tuple(b[:3] for b in blocks), bool(with_residuals))
         hit = cache.get(key)
         if hit is not None:
             return hit
-        bs = [dataset.buckets[bi] for bi in members]
+        bs = [b.bucket for b in blocks]
         out = (
             jnp.asarray(np.stack([b.entity_codes for b in bs])),
             jnp.asarray(np.stack([b.indices for b in bs])),
@@ -815,46 +933,37 @@ class RandomEffectOptimizationProblem:
     def _bucket_plans(
         self,
         bank: Array,
-        dataset: RandomEffectDataset,
+        groups,
         *,
         has_values_override: bool,
         has_residual_offsets: bool,
         l1_d,
         l2_d,
-        groups=None,
+        coordinate: Optional[str] = None,
     ):
-        """(sig, thunk) plans for every DISTINCT bucket program of one
-        dataset; ``thunk()`` lowers the bucket's exact solver call and
-        returns the compiled executable. With ``groups`` (the update_bank
-        fold grouping) multi-member groups plan the SCAN program from
-        avals instead of per-bucket programs."""
+        """(sig, thunk) plans for every DISTINCT program of ``groups``
+        (:meth:`_block_groups`); ``thunk()`` lowers the exact solver call
+        and returns the compiled executable. A group of several blocks
+        plans the SCAN program from avals, a single block its own."""
         plans = []
-        if groups is not None:
-            singles = []
-            seen_scan_sigs = set()
-            for sig, members in groups:
-                if len(members) == 1:
-                    singles.append(members[0])
-                    continue
-                kind = sig[0]
-                bucket = dataset.buckets[members[0]]
+        seen_sigs = set()
+        for members in groups:
+            kind, bucket = members[0].kind, members[0].bucket
+            ixk = bucket.indices.shape
+            if len(members) > 1:
                 E, S = bucket.labels.shape
-                ixk = bucket.indices.shape
                 B = len(members)
-                scan_sig = (
-                    "scan", kind, bank.shape, (B,) + ixk
-                )
-                if scan_sig in seen_scan_sigs:
+                sig = ("scan", kind, coordinate, bank.shape, (B,) + ixk)
+                if sig in seen_sigs:
                     continue  # identical program; one compile suffices
-                seen_scan_sigs.add(scan_sig)
+                seen_sigs.add(sig)
 
                 def thunk(kind=kind, B=B, E=E, S=S, ixk=ixk, bank=bank):
                     sds = jax.ShapeDtypeStruct
                     f32, i32 = jnp.float32, jnp.int32
-                    fused_scan = getattr(
-                        self._solvers, f"fused_scan_{kind}"
-                    )
-                    return fused_scan.lower(
+                    return self._solvers.fused_for(
+                        kind, coordinate, scan=True
+                    ).lower(
                         bank,
                         sds((B, E), i32),
                         sds((B,) + ixk, i32),
@@ -865,19 +974,14 @@ class RandomEffectOptimizationProblem:
                         l1_d, l2_d,
                     ).compile()
 
-                plans.append((scan_sig, thunk))
-            buckets_iter = [(bi, dataset.buckets[bi]) for bi in singles]
-        else:
-            buckets_iter = list(enumerate(dataset.buckets))
-        seen_sigs = set()
-        for bi, bucket in buckets_iter:
-            kind = self._bucket_kind(bucket, bank.shape[1])
-            sig = (kind, bank.shape, bucket.indices.shape)
+                plans.append((sig, thunk))
+                continue
+            sig = (kind, coordinate, bank.shape, ixk)
             if sig in seen_sigs:
                 continue
             seen_sigs.add(sig)
 
-            def thunk(bi=bi, bucket=bucket, kind=kind, bank=bank):
+            def thunk(bucket=bucket, kind=kind, bank=bank):
                 (
                     ix_d, v_d, lab_d, w_d, off_d, rows_d, codes_d,
                 ) = self._bucket_device_args(
@@ -897,9 +1001,8 @@ class RandomEffectOptimizationProblem:
                     off_d = jax.ShapeDtypeStruct(
                         bucket.offsets.shape, jnp.float32
                     )
-                fused = getattr(self._solvers, f"fused_{kind}")
                 # lowering never executes; the loop calls the result
-                return fused.lower(
+                return self._solvers.fused_for(kind, coordinate).lower(
                     bank, codes_d, ix_d, v_d, lab_d, off_d, w_d,
                     l1_d, l2_d,
                 ).compile()
@@ -907,30 +1010,32 @@ class RandomEffectOptimizationProblem:
             plans.append((sig, thunk))
         return plans
 
-    def _bucket_groups(self, d_local, dataset, *, fold_eligible):
-        """Consecutive same-signature bucket runs -> [(sig, members)]
-        (the lax.scan fold grouping); singletons when folding is off."""
-        groups: List = []
-        if fold_eligible:
-            for bi, bucket in enumerate(dataset.buckets):
-                kind = self._bucket_kind(bucket, d_local)
-                sig = (kind, bucket.indices.shape)
-                if groups and groups[-1][0] == sig:
-                    groups[-1][1].append(bi)
-                else:
-                    groups.append((sig, [bi]))
-        else:
-            groups = [(None, [bi]) for bi in range(len(dataset.buckets))]
+    @staticmethod
+    def _block_groups(blocks, *, fold: bool):
+        """Consecutive runs of same-kind, same-shape blocks (the lax.scan
+        fold grouping: a bucket's sub-blocks always form one);
+        singletons when folding is off."""
+        groups: List[List[_SolverBlock]] = []
+        for block in blocks:
+            last = groups[-1][-1] if groups else None
+            if (
+                fold and last is not None and last.kind == block.kind
+                and last.bucket.indices.shape == block.bucket.indices.shape
+            ):
+                groups[-1].append(block)
+            else:
+                groups.append([block])
         return groups
 
     def prepare(
         self, bank: Array, dataset: RandomEffectDataset,
         *, has_residual_offsets: bool = True,
+        coordinate: Optional[str] = None,
     ) -> None:
         """Host-side staging for a FUTURE update_bank over ``dataset``:
-        device transfer of every bucket's static arrays (stacked group
+        device transfer of every block's static arrays (stacked group
         args on the fold path), residual routing tables on the mesh path,
-        and AOT compiles of the bucket programs. Idempotent — everything
+        and AOT compiles of the solver programs. Idempotent — everything
         lands in the same caches update_bank reads — and safe to run on a
         background thread while ANOTHER coordinate's solves occupy the
         device (the overlap prefetched-dispatch lever: coordinate k+1's
@@ -938,32 +1043,32 @@ class RandomEffectOptimizationProblem:
         serial gap between their dispatches)."""
         if not dataset.buckets:
             return
+        blocks = self._solver_blocks(
+            dataset, bank.shape[1], split=self.mesh is None
+        )
         # mirror update_bank's fold eligibility (variance-typed problems
-        # run the per-bucket path, so stage per-bucket device args — a
+        # run the per-block path, so stage per-block device args — a
         # stacked copy would pin HBM the update never reads)
-        fold_eligible = (
+        groups = self._block_groups(blocks, fold=(
             self.mesh is None
             and not self.compute_variances
-            and len(dataset.buckets) > 1
-        )
-        groups = self._bucket_groups(
-            bank.shape[1], dataset, fold_eligible=fold_eligible
-        )
-        for _sig, members in groups:
+            and len(blocks) > 1
+        ))
+        for members in groups:
             if len(members) > 1:
                 self._stacked_group_args(
                     dataset, members, with_residuals=has_residual_offsets
                 )
             else:
-                self._bucket_device_args(dataset.buckets[members[0]])
+                self._bucket_device_args(members[0].bucket)
         if self.mesh is None:
             l1, l2 = self.regularization.split(self.reg_weight)
             self._warm_solvers(self._bucket_plans(
-                bank, dataset,
+                bank, groups,
                 has_values_override=False,
                 has_residual_offsets=has_residual_offsets,
                 l1_d=jnp.float32(l1), l2_d=jnp.float32(l2),
-                groups=groups if fold_eligible else None,
+                coordinate=coordinate,
             ))
         elif has_residual_offsets:
             self._router_for(dataset)  # static routing tables, host-built
@@ -981,8 +1086,11 @@ class RandomEffectOptimizationProblem:
         l1_d, l2_d = jnp.float32(l1), jnp.float32(l2)
         plans = []
         for bank, dataset, has_override, has_resid in specs:
+            blocks = self._solver_blocks(
+                dataset, bank.shape[1], split=not has_override
+            )
             plans += self._bucket_plans(
-                bank, dataset,
+                bank, self._block_groups(blocks, fold=False),
                 has_values_override=has_override,
                 has_residual_offsets=has_resid,
                 l1_d=l1_d, l2_d=l2_d,
@@ -1029,10 +1137,11 @@ class RandomEffectOptimizationProblem:
         values_override: Optional[Sequence[Array]] = None,
         with_variances: bool = False,
         defer_tracker: bool = False,
+        coordinate: Optional[str] = None,
     ):
         """Solve every entity against its active data; returns the new bank
         and an aggregated tracker — plus the per-entity variance bank when
-        ``with_variances`` (the Hdiag pass runs inside the bucket loop with
+        ``with_variances`` (the Hdiag pass runs inside the block loop with
         the already-routed offsets in hand, so the mesh path pays no second
         residual all_to_all).
 
@@ -1045,20 +1154,25 @@ class RandomEffectOptimizationProblem:
         stay on device — the GAME CD loop folds every coordinate's
         tracker into ONE batched readback per iteration instead of one
         synchronous round trip per bank update.
+
+        ``coordinate``: the GAME coordinate this bank belongs to. It
+        names the solver programs' XLA modules, goes on the
+        ``bank.dispatch`` spans and labels
+        ``photon_bank_entities_total``, so that a trace and the registry
+        tell one coordinate's bank from another's.
         """
         l1, l2 = self.regularization.split(self.reg_weight)
         l1_d, l2_d = jnp.float32(l1), jnp.float32(l2)
-        # Per-bucket stat vectors [iter_sum, iter_max, *reason_counts] stay
+        # Per-block stat vectors [iter_sum, iter_max, *reason_counts] stay
         # ON DEVICE until one stacked fetch at the end: every device->host
         # readback is a synchronous round trip that drains the dispatch
         # queue, so the loop stays fully async and the tracker costs one
-        # sync total, not three per bucket.
-        n_codes = max(CONVERGENCE_REASON_NAMES) + 1
+        # sync total, not three per block.
         n_reals: List[int] = []
         stat_vecs: List[Array] = []
         if self.mesh is None and dataset.buckets:
             # one defensive copy so the fused updates can DONATE the bank
-            # (in-place scatter per bucket) while the caller's reference
+            # (in-place scatter per block) while the caller's reference
             # stays valid
             bank = jnp.array(bank, copy=True)
         with obs_span("bank.route_residuals"):
@@ -1068,30 +1182,42 @@ class RandomEffectOptimizationProblem:
         var_bank = jnp.zeros_like(bank) if with_variances else None
         if with_variances:
             from photon_ml_tpu.optim.problem import _VARIANCE_EPSILON
-        # Same-shape bucket RUNS fold into one lax.scan dispatch (the
+        # The blocks the solver programs run: a bucket, or the equal
+        # sub-blocks of one over the dense budget (the entity mesh and a
+        # values override address buckets by index and keep them whole).
+        foldable = self.mesh is None and values_override is None
+        blocks = self._solver_blocks(dataset, bank.shape[1], split=foldable)
+        # Same-shape block RUNS fold into one lax.scan dispatch (the
         # profiled ~125 ms of host gaps between per-bucket dispatches at
-        # the config-4 shape, round 5); per-bucket paths keep
+        # the config-4 shape, round 5); per-block paths keep
         # handling the mesh / values_override / variances cases.
-        fold_eligible = (
-            self.mesh is None
-            and values_override is None
-            and not with_variances
-            and len(dataset.buckets) > 1
-        )
-        groups = self._bucket_groups(
-            bank.shape[1], dataset, fold_eligible=fold_eligible
+        groups = self._block_groups(
+            blocks, fold=foldable and not with_variances and len(blocks) > 1
         )
         if self.mesh is None and dataset.buckets:
             self._warm_solvers(self._bucket_plans(
-                bank, dataset,
+                bank, groups,
                 has_values_override=values_override is not None,
                 has_residual_offsets=residual_offsets is not None,
-                l1_d=l1_d, l2_d=l2_d,
-                groups=groups if fold_eligible else None,
+                l1_d=l1_d, l2_d=l2_d, coordinate=coordinate,
             ))
-        for sig, members in groups:
+        solved = default_registry().counter(
+            "photon_bank_entities_total",
+            "entities the replicated bank updates solved, by coordinate "
+            "and solver kind",
+        )
+        for members in groups:
+            block = members[0]
+            kind, bucket = block.kind, block.bucket
+            n_real = sum(b.num_real for b in members)
+            # counted where the kind is decided, on the host
+            solved.inc(n_real, coordinate=coordinate or "", kind=kind)
+            dispatch_span = obs_span(
+                "bank.dispatch", kind=kind, entities=n_real,
+                capacity=bucket.capacity, sub_blocks=block.sub_blocks,
+                **({"coordinate": coordinate} if coordinate else {}),
+            )
             if len(members) > 1:
-                kind = sig[0]
                 (
                     codes_s, ix_s, v_s, lab_s, off_s, w_s, rows_s,
                 ) = self._stacked_group_args(
@@ -1105,26 +1231,19 @@ class RandomEffectOptimizationProblem:
                         0.0,
                     )
                 fused_scan = self._aot_cache.get(
-                    ("scan", kind, bank.shape, ix_s.shape)
-                ) or getattr(self._solvers, f"fused_scan_{kind}")
-                n_group = sum(
-                    dataset.buckets[bi].num_entities for bi in members
-                )
-                with obs_span(
-                    "bank.dispatch", kind=kind, entities=n_group,
-                    capacity=dataset.buckets[members[0]].capacity,
-                ):
+                    ("scan", kind, coordinate, bank.shape, ix_s.shape)
+                ) or self._solvers.fused_for(kind, coordinate, scan=True)
+                with dispatch_span:
                     bank, it_sum, it_max, counts = fused_scan(
                         bank, codes_s, ix_s, v_s, lab_s, off_s, w_s,
                         l1_d, l2_d,
                     )
-                n_reals.append(n_group)
+                n_reals.append(n_real)
                 stat_vecs.append(
                     jnp.concatenate([jnp.stack([it_sum, it_max]), counts])
                 )
                 continue
-            bi = members[0]
-            bucket = dataset.buckets[bi]
+            bi = block.bucket_index
             (
                 ix_d, v_d, lab_d, w_d, off_d, rows_d, codes_d,
             ) = self._bucket_device_args(
@@ -1143,19 +1262,13 @@ class RandomEffectOptimizationProblem:
                 off_d = self._bucket_offsets(
                     bi, bucket, rows_d, residual_offsets, routed, router
                 )
-            n_real = bucket.num_entities
-            kind = self._bucket_kind(bucket, bank.shape[1])
-            dispatch_span = obs_span(
-                "bank.dispatch", kind=kind, entities=n_real,
-                capacity=bucket.capacity,
-            )
             if self.mesh is None:
                 # fused path: gather + solve + scatter + tracker reductions
                 # in one dispatch; AOT-warmed programs run their compiled
                 # executable directly
                 fused = self._aot_cache.get(
-                    (kind, bank.shape, bucket.indices.shape)
-                ) or getattr(self._solvers, f"fused_{kind}")
+                    (kind, coordinate, bank.shape, bucket.indices.shape)
+                ) or self._solvers.fused_for(kind, coordinate)
                 with dispatch_span:
                     bank, it_sum, it_max, counts = fused(
                         bank, codes_d, ix_d, v_d, lab_d, off_d, w_d,
@@ -1177,17 +1290,23 @@ class RandomEffectOptimizationProblem:
                 bank = bank.at[codes_d].set(new_sl)
                 it_sum = jnp.sum(iters)
                 it_max = jnp.max(iters)
-                counts = jnp.bincount(reasons, length=n_codes)
+                counts = jnp.bincount(
+                    reasons, length=max(CONVERGENCE_REASON_NAMES) + 1
+                )
             if with_variances:
                 # Hdiag at the just-solved rows, same off_d — no re-route
-                sl_new = jnp.take(bank, codes_d, axis=0)
+                # (a sub-block's padding lanes: zero rows, dropped codes)
+                sl_new = jnp.take(
+                    bank, codes_d, axis=0, mode="fill", fill_value=0
+                )
                 if self.mesh is not None:
                     (sl_new,), _ = self._shard_entity_axis([sl_new])
                 hd = self._solvers.hdiag(
                     sl_new, ix_d, v_d, lab_d, off_d, w_d, l2_d
                 )
                 var_bank = var_bank.at[codes_d].set(
-                    1.0 / (hd[:n_real] + _VARIANCE_EPSILON)
+                    1.0 / (hd[:bucket.num_entities] + _VARIANCE_EPSILON),
+                    mode="drop",
                 )
             n_reals.append(n_real)
             stat_vecs.append(
