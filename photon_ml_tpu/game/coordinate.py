@@ -35,6 +35,7 @@ from photon_ml_tpu.game.model import (
 from photon_ml_tpu.game.random_effect import (
     RandomEffectOptimizationProblem,
     device_row_view,
+    score_plan,
     score_random_effect,
 )
 from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
@@ -684,6 +685,26 @@ class FixedEffectCoordinate(Coordinate):
             self.dataset.batch_for_shard(self.feature_shard_id)
 
 
+def _count_scored_rows(coordinate: str, block_rows: int, gather_rows: int):
+    """One scoring pass of a random effect into the registry: the rows
+    scored from the solver's blocks and those left to the gather (host
+    arithmetic on the scoring plan; nothing is read from the device)."""
+    counter = default_registry().counter(
+        "photon_re_score_rows_total",
+        "rows the random effects scored, by coordinate and path "
+        "(blocks | gather)",
+    )
+    for path, rows in (("blocks", block_rows), ("gather", gather_rows)):
+        if rows:
+            counter.inc(rows, coordinate=coordinate, path=path)
+
+
+def _score_replicated_bank(coordinate, bank, re_dataset, problem) -> Array:
+    plan = score_plan(re_dataset, problem)
+    _count_scored_rows(coordinate, plan.block_rows, plan.gather_rows)
+    return score_random_effect(bank, re_dataset, problem)
+
+
 @dataclass
 class RandomEffectCoordinate(Coordinate):
     """Per-entity block (RandomEffectCoordinate[InProjectedSpace])."""
@@ -723,8 +744,16 @@ class RandomEffectCoordinate(Coordinate):
             )
         return replace(model, bank=bank, variances=variances), tracker
 
+    @property
+    def score_kernel(self) -> str:
+        """How ``score()`` computes, "blocks" | "gather" |
+        "blocks+gather": for ``cd.score``."""
+        return score_plan(self.re_dataset, self.problem).kernel
+
     def score(self, model: RandomEffectModel) -> Array:
-        return score_random_effect(model.bank, self.re_dataset)
+        return _score_replicated_bank(
+            self.name, model.bank, self.re_dataset, self.problem
+        )
 
     def regularization_term(self, model: RandomEffectModel) -> float:
         return self.problem.regularization_term(model.bank)
@@ -744,7 +773,7 @@ class RandomEffectCoordinate(Coordinate):
             )
         )
         self.problem.prepare(bank, self.re_dataset, coordinate=self.name)
-        device_row_view(self.re_dataset)
+        score_plan(self.re_dataset, self.problem)
 
 
 @dataclass
@@ -836,12 +865,22 @@ class PodRandomEffectCoordinate(Coordinate):
             tracker,
         )
 
+    @property
+    def score_kernel(self) -> str:
+        """As :attr:`RandomEffectCoordinate.score_kernel`, of the pod
+        view's blocks (a sharded model's scoring)."""
+        return self.pod.pod_view(self.re_dataset).score_kernel
+
     def score(self, model) -> Array:
-        bank = getattr(model, "sharded_bank", None)
-        if bank is None:
-            return score_random_effect(model.bank, self.re_dataset)
-        self._count_hop("out")
-        return self.pod.score(bank, self.re_dataset)
+        if getattr(model, "sharded_bank", None) is None:
+            return _score_replicated_bank(
+                self.name, model.bank, self.re_dataset, self.problem
+            )
+        view = self._count_hop("out")
+        _count_scored_rows(
+            self.name, view.score_block_rows, view.score_gather_rows
+        )
+        return self.pod.score(model.sharded_bank, self.re_dataset)
 
     def regularization_term(self, model) -> float:
         from photon_ml_tpu.parallel import overlap

@@ -60,6 +60,10 @@ from photon_ml_tpu.game.random_effect import (
     LazyRandomEffectTracker,
     RandomEffectOptimizationProblem,
     RandomEffectTracker,
+    gather_scores,
+    score_block,
+    score_kernel_name,
+    scores_from_block,
 )
 from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
 from photon_ml_tpu.game.residual_routing import PodResidualRouter
@@ -407,37 +411,53 @@ def _build_chunk_score_program(mesh, axis: str, n_dev: int):
     return pod_chunk_score
 
 
-def _build_score_program(mesh, axis: str, n_dev: int, cap: int):
+def _build_score_program(mesh, axis: str, n_dev: int, cap: int, identity):
     """Hop 2 of the residual exchange, fused with the local scoring:
     each owner shard scores its received row slots against its LOCAL
     bank rows, then the reverse all_to_all lands each score back at the
     row that sent the residual — one dispatch, one collective, zero
-    host crossings."""
+    host crossings. The slots a dense solver block holds are scored
+    from that block (``blocks``: ``(lrow, ix, v, offslot)`` of each
+    ``_PodBlock``, what ``pod_update`` runs on; ``identity`` says which
+    carry the tiled arange) by ``random_effect.score_block`` and placed
+    by ``offslot``; ``rest`` (``(slots, lrow, valid, ix, v)``: a
+    compacted slot list, or every slot where no block scores) keeps the
+    element gather."""
     ax = axis
+    n_slots = n_dev * cap
 
     # photon: sharding(axes=[entity], in=[entity,*], out=[entity])
     @jax.jit
     @partial(
         jax.shard_map,
         mesh=mesh,
-        in_specs=(P(ax), P(ax), P(ax), P(ax), P(ax), P(ax)),
+        in_specs=(P(ax), P(ax), P(ax), P(ax)),
         out_specs=P(ax),
         check_vma=False,
     )
-    def pod_score(bank_l, slot_lrow, slot_ix, slot_v, slot_valid, send_pos):
+    def pod_score(bank_l, blocks, rest, send_pos):
         e_loc = bank_l.shape[0]
-        safe = jnp.minimum(slot_lrow, e_loc - 1)
-        w_rows = jnp.take(bank_l, safe, axis=0)
-        s = jnp.sum(
-            slot_v * jnp.take_along_axis(w_rows, slot_ix, axis=1), axis=-1
-        )
-        s = jnp.where(slot_valid, s, 0.0)
-        blocks = s.reshape(n_dev, cap)
+        s = jnp.zeros((n_slots,), jnp.float32)
+        for (lrow, ix, v, offslot), ident in zip(blocks, identity):
+            # (a padding lane's row lies past the shard: zeros, on no slot)
+            w = jnp.take(bank_l, lrow, axis=0, mode="fill", fill_value=0)
+            at = jnp.where(offslot >= 0, offslot, n_slots)
+            s = s.at[at.reshape(-1)].set(
+                score_block(w, ix, v, ident).reshape(-1), mode="drop"
+            )
+        if rest is not None:
+            slots, lrow, valid, ix, v = rest
+            g = gather_scores(bank_l, jnp.minimum(lrow, e_loc - 1), ix, v)
+            if slots is None:
+                s = jnp.where(valid, g, 0.0)
+            else:
+                s = s.at[slots].set(g, mode="drop")
+        blocks_out = s.reshape(n_dev, cap)
         back = lax.all_to_all(
-            blocks, ax, split_axis=0, concat_axis=0, tiled=False
+            blocks_out, ax, split_axis=0, concat_axis=0, tiled=False
         ).reshape(-1)
-        safe_p = jnp.minimum(send_pos, n_dev * cap - 1)
-        return jnp.where(send_pos < n_dev * cap, back[safe_p], 0.0)
+        safe_p = jnp.minimum(send_pos, n_slots - 1)
+        return jnp.where(send_pos < n_slots, back[safe_p], 0.0)
 
     return pod_score
 
@@ -475,6 +495,7 @@ class _PodBlock:
     kind: str
     num_real: int  # real entities across all shards (tracker accounting)
     sub_blocks: int  # how many blocks its capacity class was split into
+    identity: bool  # indices are the tiled arange (k == local_dim)
     lrow: Array
     valid: Array
     ix: Array
@@ -547,10 +568,6 @@ class _PodView:
         self.slot_v = put(slot_v)
         self.slot_lrow = put(slot_lrow)
         self.slot_valid = put(s_valid)
-        self._score = _cached_program(
-            ("score", _mesh_key(mesh), n_dev, cap),
-            lambda: _build_score_program(mesh, axis, n_dev, cap),
-        )
 
         # -- solver blocks: each bucket's entities split by hash; every
         # sample's residual offset arrives via its row's scoring slot
@@ -558,6 +575,8 @@ class _PodView:
         # slot's owner), so the solve needs no second exchange
         slot_of_row = self.router.slot_of_row
         d_local = dataset.local_dim
+        # [owner, slot]: the slots no dense block holds, left to the gather
+        unscored = slot_row >= 0
         for bucket in dataset.buckets:
             b_codes = np.asarray(bucket.entity_codes, np.int64)
             sh, lo, pos, e_blk = _owner_positions(b_codes, self.spec)
@@ -575,6 +594,13 @@ class _PodView:
                 gids >= 0, slot_of_row[np.maximum(gids, 0)], -1
             ).astype(np.int32)
             rows_total = n_dev * e_sub
+            if scores_from_block(kind, d_local):
+                # scored from the block: its slots leave the gather's list
+                held = offslot >= 0
+                unscored[
+                    np.broadcast_to(sh[:, None], held.shape)[held],
+                    offslot[held],
+                ] = False
             for j in range(n_sub):
                 m = (pos // e_sub) == j
                 dest = sh[m] * e_sub + pos[m] % e_sub
@@ -596,6 +622,7 @@ class _PodView:
                     kind=kind,
                     num_real=int(m.sum()),
                     sub_blocks=n_sub,
+                    identity=bucket.identity_indices,
                     lrow=put(b_lrow),
                     valid=put(b_valid),
                     ix=put(b_ix),
@@ -604,6 +631,51 @@ class _PodView:
                     w=put(b_w),
                     offslot=put(b_offslot),
                 ))
+
+        # -- scoring: the slots a DENSE block holds are scored from it
+        # (random_effect.score_plan's rule, on a device's share); passive
+        # rows and a sparse block's keep the gather, over a compacted
+        # slot list a device (padded to the fullest device's)
+        scored = [
+            b for b in self.blocks if scores_from_block(b.kind, d_local)
+        ]
+        self.score_gather_rows = int(unscored.sum())
+        self.score_block_rows = int(s_valid.sum()) - self.score_gather_rows
+        self._score_blocks = tuple(
+            (b.lrow, b.ix, b.v, b.offslot) for b in scored
+        )
+        if not scored:
+            self._score_rest = (
+                None, self.slot_lrow, self.slot_valid, self.slot_ix,
+                self.slot_v,
+            )
+        elif self.score_gather_rows:
+            n_slots = self.router.num_slots
+            width = int(unscored.sum(axis=1).max())
+            # a device's unscored slots first, in slot order
+            order = np.argsort(~unscored, axis=1, kind="stable")[:, :width]
+            live = np.take_along_axis(unscored, order, axis=1).reshape(-1)
+            flat = (order + np.arange(n_dev)[:, None] * n_slots).reshape(-1)
+            self._score_rest = (
+                put(np.where(live, order.reshape(-1), n_slots).astype(np.int32)),
+                put(np.where(live, slot_lrow[flat], e_loc).astype(np.int32)),
+                None,
+                put(np.where(live[:, None], slot_ix[flat], 0)),
+                put(np.where(live[:, None], slot_v[flat], 0.0)),
+            )
+        else:
+            self._score_rest = None
+        identity = tuple(b.identity for b in scored)
+        self._score = _cached_program(
+            ("score", _mesh_key(mesh), n_dev, cap, identity),
+            lambda: _build_score_program(mesh, axis, n_dev, cap, identity),
+        )
+
+    @property
+    def score_kernel(self) -> str:
+        return score_kernel_name(
+            self.score_block_rows, self.score_gather_rows
+        )
 
     def entities_by_kind(self) -> Dict[str, int]:
         """Real entities a bank update solves, by solver kind."""
@@ -909,8 +981,8 @@ class PodRandomEffectProblem:
         bank = self._coerce_bank(bank, dataset)
         with obs_span("pod.score"):
             rows = view._score(
-                bank.data, view.slot_lrow, view.slot_ix, view.slot_v,
-                view.slot_valid, view.router._send_pos,
+                bank.data, view._score_blocks, view._score_rest,
+                view.router._send_pos,
             )
         with obs_span("pod.replicate"):
             return _replicate(self.mesh, rows)[: view.num_rows]

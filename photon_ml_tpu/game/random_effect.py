@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -118,6 +118,48 @@ _SOLVER_CACHE_MAX = 16
 _BLOCK_ITEMSIZE = 4
 
 
+def _densify(ix, v, d_local):
+    """Batched densification of each entity's [S, k] sparse rows into a
+    dense X [E, S, D] block — as a fused compare-and-reduce over the
+    nnz axis rather than a scatter: TPU scatters and gathers serialize
+    per element while the VPU eats the k-reduction whole, with the exact
+    same result. XLA fuses the [E, S, k, D] broadcast; it is never
+    materialized."""
+    with jax.named_scope("bank.densify"):
+        d = jnp.arange(d_local, dtype=ix.dtype)
+        return jnp.sum(
+            v[..., :, None]
+            * (ix[..., :, None] == d[None, None, None, :]),
+            axis=2,
+        )
+
+
+def score_block(w, ix, v, identity=False):
+    """Scores [E, S] of a solver block's rows ``ix, v [E, S, k]`` against
+    its entities' bank rows ``w [E, D]``:
+    ``score[e, s] = sum_j v[e, s, j] * w[e, ix[e, s, j]]``, each entry
+    looked up as :func:`_densify` places it, by a compare against
+    ``arange(D)`` and a reduce, and not by an element gather: on a v5e
+    12.0 ms for [32768, 16, 32] x 1000 where the gather of the same
+    16.8M elements takes 247 (my chip run, PR 34; of the forms tried,
+    reducing over D first and then over k was the fastest, 23 ms for one
+    reduce over both and 30 for densify-then-multiply, and the only one
+    that writes no [E, S, D] block). The sum over D has one non-zero
+    term, so the lookup is exact and the sum over k is the gather's
+    own. ``identity``: the block's indices are the tiled arange
+    (k == D) and ``v`` multiplies ``w`` with no compare. Elementwise
+    float32 products and float32 sums: ``jax_default_matmul_precision``
+    does not reach it."""
+    with jax.named_scope("cd.score_block"):
+        if identity:
+            return jnp.sum(v * w[:, None, :], axis=-1)
+        d = jnp.arange(w.shape[1], dtype=ix.dtype)
+        looked = jnp.sum(
+            jnp.where(ix[..., None] == d, w[:, None, None, :], 0.0), axis=-1
+        )
+        return jnp.sum(v * looked, axis=-1)
+
+
 def _cached_bucket_solver(
     loss: PointwiseLoss,
     config: OptimizerConfig,
@@ -201,21 +243,6 @@ def _bucket_solver(
 
         res = jax.vmap(one)(bank, ix, v, lab, off, w)
         return res.coefficients, res.iterations, res.reason
-
-    def _densify(ix, v, d_local):
-        """Batched densification of each entity's [S, k] sparse rows into a
-        dense X [E, S, D] block — as a fused compare-and-reduce over the
-        nnz axis rather than a scatter: TPU scatters serialize per element
-        (measured 132 ms at E=20k, S=16, k=32, D=1000) while the VPU eats
-        the k-reduction whole (33 ms, exact same result). XLA fuses the
-        [E, S, k, D] broadcast; it is never materialized."""
-        with jax.named_scope("bank.densify"):
-            d = jnp.arange(d_local, dtype=ix.dtype)
-            return jnp.sum(
-                v[..., :, None]
-                * (ix[..., :, None] == d[None, None, None, :]),
-                axis=2,
-            )
 
     def _make_dense(identity):
         """DENSE per-entity layout: one compare-and-reduce densification
@@ -1420,27 +1447,202 @@ def device_row_view(dataset: RandomEffectDataset):
     return hit
 
 
+# The widest local space a block's rows are looked up in by compare: the
+# compare costs 0.71 ns an entry for every 1,000 dims (12.0 ms for 16.8M
+# entries at D = 1000), the element gather 14 ns whatever the width (my
+# chip runs, PR 34), so past ~19,000 dims the gather is the cheaper one.
+_SCORE_BLOCK_MAX_DIM = 16384
+
+
+def scores_from_block(kind: str, d_local: int) -> bool:
+    """Whether the rows of a solver block of ``kind`` are scored from the
+    block (:func:`score_block`): a block the dense solvers run, over a
+    local space narrow enough for the compare to beat the gather."""
+    return kind != "sparse" and d_local <= _SCORE_BLOCK_MAX_DIM
+
+
+class _ScorePlan(NamedTuple):
+    """How one dataset's rows are scored under one problem's block
+    split: the groups of dense solver blocks whose rows are scored from
+    the block (:func:`score_block`), and the rows no such block holds,
+    which keep the gather."""
+
+    groups: Tuple[List[_SolverBlock], ...]
+    # (rows, codes, valid, ix, v) device arrays of the rows left to the
+    # gather: a compacted row list (``valid`` None), the whole row view
+    # where no block scores (``rows`` None), or None where none is left
+    rest: Optional[tuple]
+    block_rows: int
+    gather_rows: int
+
+    @property
+    def kernel(self) -> str:
+        return score_kernel_name(self.block_rows, self.gather_rows)
+
+
+def score_kernel_name(block_rows: int, gather_rows: int) -> str:
+    """``blocks`` | ``gather`` | ``blocks+gather``: what a random
+    effect's ``cd.score`` span says of a scoring pass."""
+    if block_rows and gather_rows:
+        return "blocks+gather"
+    return "blocks" if block_rows else "gather"
+
+
+@lru_cache(maxsize=1)
+def _default_problem() -> "RandomEffectOptimizationProblem":
+    """What a caller that holds no problem scores through
+    (``RandomEffectModel.score``): the dataclass's default layout and
+    ``dense_bytes_budget``, so that no bucket is scored whole over it."""
+    from photon_ml_tpu.ops.losses import LOGISTIC
+
+    return RandomEffectOptimizationProblem(
+        LOGISTIC, OptimizerConfig(), RegularizationContext()
+    )
+
+
+def score_plan(
+    dataset: RandomEffectDataset,
+    problem: Optional["RandomEffectOptimizationProblem"] = None,
+) -> _ScorePlan:
+    """The :class:`_ScorePlan` of ``dataset`` under ``problem``, decided
+    from the data: a row that a DENSE solver block holds (the blocks
+    ``update_bank`` runs, :meth:`RandomEffectOptimizationProblem.
+    _solver_blocks`) is scored from that block; a passive row, a row of
+    a block the budget or the layout leaves to the sparse solver or whose
+    local space is too wide to compare against
+    (:func:`scores_from_block`), every row of a view without buckets
+    and every row under the entity mesh (its blocks are entity-sharded)
+    keep the gather. Cached on the dataset, keyed by the split."""
+    problem = problem or _default_problem()
+    blocks = []
+    if problem.mesh is None and dataset.buckets:
+        blocks = problem._solver_blocks(
+            dataset, dataset.local_dim, split=True
+        )
+    # the update's own fold rule, so that scoring finds the device arrays
+    # the update holds
+    fold = not problem.compute_variances and len(blocks) > 1
+    d_local = dataset.local_dim
+    key = (
+        tuple((b[:3], scores_from_block(b.kind, d_local)) for b in blocks),
+        fold,
+    )
+    cache = dataset.__dict__.setdefault("_score_plan_cache", {})
+    plan = cache.get(key)
+    if plan is not None:
+        return plan
+    groups = tuple(
+        members
+        for members in problem._block_groups(blocks, fold=fold)
+        if scores_from_block(members[0].kind, d_local)
+    )
+    codes = np.asarray(dataset.row_entity_codes)
+    left = codes >= 0
+    num_valid = int(left.sum())
+    if not groups:
+        rest = (None,) + device_row_view(dataset)
+    else:
+        for members in groups:
+            for block in members:
+                rows = block.bucket.row_index
+                left[rows[rows >= 0]] = False
+        rows = np.nonzero(left)[0]
+        rest = None
+        if len(rows):
+            rest = (
+                jnp.asarray(rows.astype(np.int32)),
+                jnp.asarray(codes[rows]),
+                None,
+                jnp.asarray(dataset.row_local_indices[rows]),
+                jnp.asarray(dataset.row_local_values[rows]),
+            )
+    gather_rows = int(left.sum())
+    plan = _ScorePlan(groups, rest, num_valid - gather_rows, gather_rows)
+    cache[key] = plan
+    return plan
+
+
 def score_random_effect(
     bank: Array,  # [E, D]
     dataset: RandomEffectDataset,
+    problem: Optional["RandomEffectOptimizationProblem"] = None,
 ) -> Array:
     """Row-aligned scores [n]: score_i = x_i(local) . bank[entity_i].
 
     Covers active AND passive rows (passive scoring with locally-projected
     features is equivalent to the reference's back-projected model scoring:
     features unseen in the entity's active data have zero coefficients,
-    RandomEffectCoordinate.scala:178-199)."""
-    return re_score(bank, *device_row_view(dataset))
+    RandomEffectCoordinate.scala:178-199).
+
+    ``problem``: the one whose ``update_bank`` solves ``dataset``; the
+    rows its dense blocks hold are scored from the device arrays it
+    already holds for them (:func:`score_plan`)."""
+    problem = problem or _default_problem()
+    plan = score_plan(dataset, problem)
+    blocks = []
+    for members in plan.groups:
+        if len(members) > 1:
+            codes, ix, v, _, _, _, rows = problem._stacked_group_args(
+                dataset, members, with_residuals=True
+            )
+        else:
+            ix, v, _, _, _, rows, codes = problem._bucket_device_args(
+                members[0].bucket
+            )
+        blocks.append((codes, ix, v, rows))
+    return re_score(
+        bank, tuple(blocks), plan.rest,
+        identity=tuple(m[0].bucket.identity_indices for m in plan.groups),
+        num_rows=int(dataset.row_entity_codes.shape[0]),
+    )
 
 
-@jax.jit
-def re_score(bank, codes, valid, ix, v):
+@partial(jax.jit, static_argnames=("identity", "num_rows"))
+def re_score(bank, blocks, rest, *, identity, num_rows):
     """One named program (module ``re_score``, scope ``cd.score``) a
-    device trace can place; the [n, D] rows are never written."""
+    device trace can place. ``blocks``: ``(codes, ix, v, rows)`` of each
+    group of solver blocks, stacked ``[B, E_sub, ...]`` for a folded
+    group, which is scanned one sub-block at a time so that only one's
+    temporaries are live: whole bank rows taken as ``_update_block``
+    takes them, :func:`score_block`, the ``[E, S]`` scores placed into
+    the row vector by ``rows`` (a padding slot, -1, and a padding
+    entity, whose code lies past the bank, drop). ``rest``: the rows no
+    block holds (:class:`_ScorePlan`), gathered an element at a time.
+    A row with no entity scores 0."""
+
+    def place(out, args, ident):
+        codes, ix, v, rows = args
+        w = jnp.take(bank, codes, axis=0, mode="fill", fill_value=0)
+        score = score_block(w, ix, v, ident)
+        at = jnp.where(rows >= 0, rows, num_rows)
+        return out.at[at.reshape(-1)].set(score.reshape(-1), mode="drop")
+
     with jax.named_scope("cd.score"):
-        w_rows = jnp.take(bank, codes, axis=0)  # [n, D]
-        score = jnp.sum(v * jnp.take_along_axis(w_rows, ix, axis=1), axis=-1)
-        return jnp.where(valid, score, 0.0)
+        out = jnp.zeros((num_rows,), jnp.float32)
+        for args, ident in zip(blocks, identity):
+            if args[0].ndim == 2:
+                out, _ = jax.lax.scan(
+                    lambda o, a, ident=ident: (place(o, a, ident), None),
+                    out, args,
+                )
+            else:
+                out = place(out, args, ident)
+        if rest is not None:
+            rows, codes, valid, ix, v = rest
+            score = gather_scores(bank, codes, ix, v)
+            if valid is not None:
+                score = jnp.where(valid, score, 0.0)
+            out = score if rows is None else out.at[rows].set(score)
+        return out
+
+
+def gather_scores(bank, codes, ix, v):
+    """``sum_j v[i, j] * bank[codes[i], ix[i, j]]`` by an element gather
+    (XLA fuses the row take into it; the [n, D] rows are never
+    written): 13-14 ns an element on a v5e (ledger, PR 33), so only for
+    rows no solver block holds."""
+    w_rows = jnp.take(bank, codes, axis=0)  # [n, D]
+    return jnp.sum(v * jnp.take_along_axis(w_rows, ix, axis=1), axis=-1)
 
 
 def dryrun_entity_bank(mesh) -> None:

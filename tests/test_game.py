@@ -557,13 +557,14 @@ class TestFixedEffectCoordinateTiled:
             assert s.parent_id is None or by_id[s.parent_id].name != "cd.update"
         waits = [s for s in first if s.name == "cd.prefetch_wait"]
         assert "global" in {s.attrs["coordinate"] for s in waits}
-        for name in ("cd.update", "cd.score"):  # kernel, and its variant
+        # kernel, and its variant; the random effect says how it scored
+        for name, re_kernel in (("cd.update", None), ("cd.score", "blocks")):
             said = {
                 (s.attrs["coordinate"], s.attrs.get("kernel"), s.attrs.get("mxu"))
                 for s in first if s.name == name
             }
             assert said == {
-                ("global", "tiled", "bf16x2w"), ("per-user", None, None)
+                ("global", "tiled", "bf16x2w"), ("per-user", re_kernel, None)
             }
         assert any(
             line.startswith("coordinate global: ")

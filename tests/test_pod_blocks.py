@@ -296,8 +296,8 @@ def test_one_pod_step_leaves_its_spans_module_names_and_counters(tmp_path):
         ).lower(bank.data, blk.lrow, blk.valid, blk.ix, blk.v, blk.lab, blk.w,
                 blk.offslot, slots, one, one),
         "pod_score": view._score.lower(
-            bank.data, view.slot_lrow, view.slot_ix, view.slot_v,
-            view.slot_valid, view.router._send_pos),
+            bank.data, view._score_blocks, view._score_rest,
+            view.router._send_pos),
         "pod_route_in": view.router._route_in.lower(
             view.router._pad_rows(jnp.zeros(n, jnp.float32)), view.router._send_pos),
         "pod_route_out": view.router._route_out.lower(slots, view.router._send_pos),

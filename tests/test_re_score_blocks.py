@@ -1,0 +1,316 @@
+"""The random effect scores from the blocks its solver holds
+(game/random_effect.score_block, re_score; game/pod.pod_score): every
+case against the element gather on the row view as the plain reference,
+float32, to 1e-6 of the largest score. The rows no dense block holds
+(passive rows, a sparse block's, a view without buckets, the entity mesh)
+keep the gather, and ``photon_re_score_rows_total`` says how many rode
+which path."""
+
+from dataclasses import replace
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from photon_ml_tpu.game.config import (
+    ProjectorType,
+    RandomEffectDataConfiguration,
+)
+from photon_ml_tpu.game.coordinate import (
+    PodRandomEffectCoordinate,
+    RandomEffectCoordinate,
+    _latent_view,
+)
+from photon_ml_tpu.game.data import EntityIndex, GameDataset, ShardData
+from photon_ml_tpu.game.pod import PodRandomEffectProblem, ShardedREBank
+from photon_ml_tpu.game.random_effect import (
+    RandomEffectOptimizationProblem,
+    re_score,
+    score_block,
+    score_plan,
+    score_random_effect,
+)
+from photon_ml_tpu.game.random_effect_data import build_random_effect_dataset
+from photon_ml_tpu.obs.registry import default_registry
+from photon_ml_tpu.ops.losses import LOGISTIC
+from photon_ml_tpu.optim.config import (
+    OptimizerConfig,
+    RegularizationContext,
+    RegularizationType,
+)
+from photon_ml_tpu.parallel.mesh import entity_mesh
+from photon_ml_tpu.utils.index_map import IndexMap, feature_key
+
+ATOL = 1e-6  # of the largest score
+
+
+def _dataset(seed=0, n=431, E=23, d=40, k=6, cap=None,
+             projector=ProjectorType.INDEX_MAP):
+    """A GameDataset and its RandomEffectDataset: an uneven entity
+    histogram (several capacity classes), weight-0 rows, rows with no
+    entity."""
+    rng = np.random.default_rng(seed)
+    codes = np.minimum(
+        rng.geometric(0.12, size=n) - 1, E - 1
+    ).astype(np.int32)
+    codes[::29] = -1
+    ix = rng.integers(0, d, size=(n, k)).astype(np.int32)
+    v = rng.normal(size=(n, k)).astype(np.float32)
+    w = np.ones(n, np.float32)
+    w[::17] = 0.0
+    imap = IndexMap.build(
+        (feature_key(f"f{i}", "") for i in range(d)), add_intercept=False
+    )
+    ds = GameDataset(
+        uids=[str(i) for i in range(n)],
+        labels=(rng.uniform(size=n) > 0.5).astype(np.float32),
+        offsets=np.zeros(n, np.float32), weights=w,
+        shards={"s": ShardData(
+            indices=ix, values=v, index_map=imap, intercept_index=None
+        )},
+        entity_codes={"user": codes},
+        entity_indexes={"user": EntityIndex.build(
+            "user", [f"e{i:03d}" for i in range(E)]
+        )},
+        num_real_rows=n,
+    )
+    red = build_random_effect_dataset(ds, RandomEffectDataConfiguration(
+        random_effect_type="user", feature_shard_id="s",
+        projector_type=projector, active_data_upper_bound=cap,
+    ))
+    return ds, red
+
+
+def _problem(**kw):
+    kw.setdefault("reg_weight", 0.5)
+    return RandomEffectOptimizationProblem(
+        LOGISTIC, OptimizerConfig(max_iter=5),
+        RegularizationContext(RegularizationType.L2), **kw
+    )
+
+
+def _bank(red, seed=1):
+    rng = np.random.default_rng(seed)
+    return rng.normal(
+        size=(red.num_entities, red.local_dim)
+    ).astype(np.float32)
+
+
+def _reference(bank, red):
+    """The gather on the row view, in numpy float32."""
+    codes = red.row_entity_codes
+    rows = bank[np.maximum(codes, 0)]
+    looked = np.take_along_axis(rows, red.row_local_indices, axis=1)
+    score = np.sum(red.row_local_values * looked, axis=-1, dtype=np.float32)
+    return np.where(codes >= 0, score, np.float32(0.0))
+
+
+def _close(got, want):
+    got = np.asarray(got)
+    assert got.dtype == np.float32 and got.shape == want.shape
+    assert np.max(np.abs(got - want)) <= ATOL * np.max(np.abs(want))
+    # a row with no entity, or of weight 0, scores exactly 0
+    assert not got[want == 0].any()
+
+
+def _counted(coordinate):
+    counter = default_registry().counter("photon_re_score_rows_total")
+    return tuple(
+        counter.value(coordinate=coordinate, path=path)
+        for path in ("blocks", "gather")
+    )
+
+
+def _split_dataset(n_sub, cap):
+    """A dataset whose largest bucket does not divide into ``n_sub``
+    equal sub-blocks, and the ``dense_bytes_budget`` under which it runs
+    as that many, the last one padded."""
+    for seed in range(20):
+        _, red = _dataset(seed=seed, n=900, E=61, cap=cap)
+        bucket = max(red.buckets, key=lambda b: b.num_entities)
+        if bucket.num_entities % n_sub:
+            break
+    per_entity = 4 * bucket.capacity * (red.local_dim + bucket.capacity)
+    return red, per_entity * -(-bucket.num_entities // n_sub)
+
+
+CASES = {
+    # name: (dataset arguments, problem arguments, kernel the plan says)
+    "every_row_active": ({}, {}, "blocks"),
+    "passive_rows": ({"cap": 8}, {}, "blocks+gather"),
+    "identity_projector": (
+        {"projector": ProjectorType.IDENTITY}, {}, "blocks"
+    ),
+    "variances_unfolded": ({}, {"compute_variances": True}, "blocks"),
+    "sparse_layout": ({}, {"layout": "sparse"}, "gather"),
+    # past the width where the compare still beats the gather
+    "too_wide_to_compare": (
+        {"projector": ProjectorType.IDENTITY, "d": 16385}, {}, "gather"
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_blocks_match_the_gather(case):
+    data_kw, problem_kw, kernel = CASES[case]
+    _, red = _dataset(**data_kw)
+    assert len(red.buckets) >= 2  # two capacity classes and more
+    problem, bank = _problem(**problem_kw), _bank(red)
+    plan = score_plan(red, problem)
+    assert plan.kernel == kernel
+    valid = int(np.count_nonzero(red.row_entity_codes >= 0))
+    assert plan.block_rows + plan.gather_rows == valid
+    if kernel == "blocks":
+        assert plan.rest is None and plan.block_rows == red.num_active_rows
+    if case == "passive_rows":
+        assert red.num_passive_rows > 0
+        assert plan.block_rows == red.num_active_rows
+        assert plan.gather_rows == red.num_passive_rows
+        assert plan.rest[0].shape == (red.num_passive_rows,)
+    _close(score_random_effect(jnp.asarray(bank), red, problem),
+           _reference(bank, red))
+
+
+@pytest.mark.parametrize("n_sub", [2, 3])
+@pytest.mark.parametrize("cap", [None, 8])
+def test_a_split_bucket_scores_sub_block_by_sub_block(n_sub, cap):
+    red, budget = _split_dataset(n_sub, cap)
+    problem = _problem(dense_bytes_budget=budget)
+    plan = score_plan(red, problem)
+    largest = max(
+        range(len(red.buckets)), key=lambda i: red.buckets[i].num_entities
+    )
+    (group,) = [g for g in plan.groups if g[0].bucket_index == largest]
+    assert len(group) == n_sub  # one stacked group, scanned
+    assert group[-1].num_real < group[-1].bucket.num_entities
+    bank = _bank(red)
+    _close(score_random_effect(jnp.asarray(bank), red, problem),
+           _reference(bank, red))
+    # the update's own stacked arrays: scoring uploaded no second copy
+    stacked = red.__dict__["_stacked_device_cache"]
+    before = set(stacked)
+    problem.update_bank(
+        jnp.asarray(bank), red,
+        residual_offsets=jnp.zeros((red.row_entity_codes.shape[0],)),
+    )
+    assert set(stacked) == before
+
+
+def test_an_identity_indices_bucket_multiplies_with_no_compare():
+    _, red = _dataset(projector=ProjectorType.IDENTITY)
+    rng = np.random.default_rng(3)
+    x_lat = rng.normal(
+        size=(red.row_entity_codes.shape[0], 5)
+    ).astype(np.float32)
+    view = _latent_view(red, x_lat)
+    assert all(b.identity_indices for b in view.buckets)
+    problem, bank = _problem(), _bank(view)
+    assert score_plan(view, problem).kernel == "blocks"
+    _close(score_random_effect(jnp.asarray(bank), view, problem),
+           _reference(bank, view))
+    # and the lookup itself, both ways, on one block
+    b = view.buckets[0]
+    w = jnp.asarray(bank[b.entity_codes])
+    ix, v = jnp.asarray(b.indices), jnp.asarray(b.values)
+    np.testing.assert_allclose(
+        score_block(w, ix, v, True), score_block(w, ix, v, False),
+        rtol=0, atol=ATOL * float(jnp.max(jnp.abs(w))) * 5,
+    )
+
+
+@pytest.mark.parametrize("how", ["no_buckets", "no_problem", "entity_mesh"])
+def test_who_holds_no_block_or_no_problem(how):
+    _, red = _dataset()
+    bank = _bank(red)
+    if how == "no_buckets":  # the driver's validation view, score_rows
+        red = replace(red, buckets=[])
+        plan = score_plan(red)
+        assert plan.kernel == "gather" and plan.rest[0] is None
+        got = score_random_effect(jnp.asarray(bank), red)
+    elif how == "no_problem":  # RandomEffectModel.score: default budget
+        assert score_plan(red).kernel == "blocks"
+        got = score_random_effect(jnp.asarray(bank), red)
+    else:  # its blocks are entity-sharded: the gather stays
+        problem = _problem(mesh=entity_mesh(2))
+        assert score_plan(red, problem).kernel == "gather"
+        got = score_random_effect(jnp.asarray(bank), red, problem)
+    _close(got, _reference(bank, red))
+
+
+@pytest.mark.parametrize("cap", [None, 8])
+def test_float32_exact_under_a_bfloat16_default_precision(cap):
+    _, red = _dataset(cap=cap)
+    problem, bank = _problem(), _bank(red)
+    with jax.default_matmul_precision("bfloat16"):
+        got = score_random_effect(jnp.asarray(bank), red, problem)
+    _close(got, _reference(bank, red))
+
+
+@pytest.mark.parametrize("cap", [None, 8])
+def test_the_coordinate_counts_rows_by_path_and_names_the_kernel(cap):
+    ds, red = _dataset(cap=cap)
+    name = f"per-user-cap-{cap}"
+    coord = RandomEffectCoordinate(name, ds, red, _problem())
+    model = replace(coord.initialize_model(), bank=jnp.asarray(_bank(red)))
+    before = _counted(name)
+    _close(coord.score(model), _reference(np.asarray(model.bank), red))
+    blocks, gather = (a - b for a, b in zip(_counted(name), before))
+    assert (blocks, gather) == (red.num_active_rows, red.num_passive_rows)
+    assert coord.score_kernel == ("blocks+gather" if cap else "blocks")
+
+
+def test_the_program_keeps_its_module_name():
+    _, red = _dataset(cap=8)
+    problem = _problem()
+    plan = score_plan(red, problem)
+    blocks = []
+    for members in plan.groups:
+        ix, v, _, _, _, rows, codes = problem._bucket_device_args(
+            members[0].bucket
+        )
+        blocks.append((codes, ix, v, rows))
+    lowered = re_score.lower(
+        jnp.asarray(_bank(red)), tuple(blocks), plan.rest,
+        identity=(False,) * len(blocks),
+        num_rows=red.row_entity_codes.shape[0],
+    )
+    assert "module @jit_re_score" in lowered.as_text()
+
+
+@pytest.mark.parametrize("n_dev", [1, 2, 4])
+@pytest.mark.parametrize("cap", [None, 8])
+def test_the_pod_scores_its_blocks_like_the_replicated_bank(n_dev, cap):
+    ds, red = _dataset(n=700, E=37, cap=cap)
+    mesh = entity_mesh(n_dev)
+    base, bank = _problem(), _bank(red)
+    pod = PodRandomEffectProblem(base, mesh)
+    view = pod.pod_view(red)
+    assert view.score_kernel == ("blocks+gather" if cap else "blocks")
+    assert view.score_block_rows == red.num_active_rows
+    assert view.score_gather_rows == red.num_passive_rows
+    sharded = ShardedREBank.from_global(mesh, pod.spec_for(red), bank)
+    got = pod.score(sharded, red)
+    _close(got, _reference(bank, red))
+    _close(got, np.asarray(score_random_effect(jnp.asarray(bank), red, base)))
+    # the coordinate counts what the view says
+    name = f"pod-{n_dev}-{cap}"
+    coord = PodRandomEffectCoordinate(name, ds, red, base, mesh=mesh)
+    model = coord.initialize_model()
+    before = _counted(name)
+    assert not np.asarray(coord.score(model)).any()  # the zero bank
+    assert tuple(
+        a - b for a, b in zip(_counted(name), before)
+    ) == (red.num_active_rows, red.num_passive_rows)
+
+
+def test_a_pod_block_left_to_the_sparse_solver_keeps_the_gather():
+    _, red = _dataset(n=700, E=37)
+    mesh = entity_mesh(2)
+    base, bank = _problem(layout="sparse"), _bank(red)
+    pod = PodRandomEffectProblem(base, mesh)
+    view = pod.pod_view(red)
+    assert view.score_kernel == "gather" and view._score_rest[0] is None
+    sharded = ShardedREBank.from_global(mesh, pod.spec_for(red), bank)
+    _close(pod.score(sharded, red), _reference(bank, red))
