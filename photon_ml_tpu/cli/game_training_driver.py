@@ -27,11 +27,13 @@ from photon_ml_tpu.game.config import (
     FactoredRandomEffectConfiguration,
     FeatureShardConfiguration,
     FixedEffectDataConfiguration,
+    MatrixFactorizationConfiguration,
     RandomEffectDataConfiguration,
 )
 from photon_ml_tpu.game.coordinate import (
     FactoredRandomEffectCoordinate,
     FixedEffectCoordinate,
+    MatrixFactorizationCoordinate,
     RandomEffectCoordinate,
 )
 from photon_ml_tpu.game.coordinate_descent import CoordinateDescent
@@ -174,6 +176,12 @@ class GameTrainingParams:
     factored_re_configs: Dict[str, FactoredRandomEffectConfiguration] = field(
         default_factory=dict
     )
+    # Matrix-factorization coordinates (name -> row/col effect types, rank,
+    # ALS sweeps a pass); each one's optimizer and L2 weight are its entry
+    # in random_effect_opt_configs
+    mf_configs: Dict[str, MatrixFactorizationConfiguration] = field(
+        default_factory=dict
+    )
     updating_sequence: Optional[List[str]] = None
     num_iterations: int = 1
     evaluator_types: List[EvaluatorType] = field(default_factory=list)
@@ -309,7 +317,7 @@ class GameTrainingParams:
         coords = set(self.fixed_effect_data_configs) | set(
             self.random_effect_data_configs
         )
-        if not coords:
+        if not coords and not self.mf_configs:
             raise ValueError("at least one coordinate configuration required")
         for name in self.fixed_effect_data_configs:
             if name not in self.fixed_effect_opt_configs:
@@ -322,6 +330,37 @@ class GameTrainingParams:
         for name in self.random_effect_data_configs:
             if name not in self.random_effect_opt_configs:
                 raise ValueError(f"missing optimization config for {name}")
+        for name in self.mf_configs:
+            if name in coords:
+                raise ValueError(
+                    f"matrix-factorization coordinate {name} shares its "
+                    "name with another coordinate"
+                )
+            if name not in self.random_effect_opt_configs:
+                raise ValueError(
+                    f"missing optimization config for {name}: a "
+                    "matrix-factorization coordinate reads its optimizer "
+                    "and L2 weight from "
+                    "--random-effect-optimization-configurations"
+                )
+            # what has not run with the ALS half-steps yet, refused by name
+            unsupported = [
+                what for what, on in (
+                    ("--entity-shards", self.entity_shards not in (None, 0)),
+                    ("--streaming", self.streaming),
+                    ("a regularization-weight grid (';' alternatives)", any(
+                        ";" in v for v in (
+                            *self.fixed_effect_opt_configs.values(),
+                            *self.random_effect_opt_configs.values(),
+                        )
+                    )),
+                ) if on
+            ]
+            if unsupported:
+                raise ValueError(
+                    f"matrix-factorization coordinate {name} does not "
+                    "support: " + ", ".join(unsupported)
+                )
         if self.diagnostic_reservoir_rows < 1:
             raise ValueError("diagnostic-reservoir-rows must be >= 1")
         if self.diagnostic_reservoir_bytes < 1:
@@ -480,6 +519,11 @@ class GameTrainingDriver:
             c.random_effect_type
             for c in self.params.random_effect_data_configs.values()
         ]
+        # a matrix-factorization coordinate's two id columns
+        for c in self.params.mf_configs.values():
+            for id_type in (c.row_effect_type, c.col_effect_type):
+                if id_type not in re_types:
+                    re_types.append(id_type)
         # sharded evaluators need their id columns too
         for et in self.params.evaluator_types:
             if et.id_type and et.id_type not in re_types:
@@ -663,6 +707,22 @@ class GameTrainingDriver:
                 coords[name] = RandomEffectCoordinate(
                     name=name, dataset=dataset, re_dataset=red, problem=problem
                 )
+        for name, mcfg in p.mf_configs.items():
+            ocfg = opt_combo[name]
+            coords[name] = MatrixFactorizationCoordinate(
+                name=name,
+                dataset=dataset,
+                row_effect_type=mcfg.row_effect_type,
+                col_effect_type=mcfg.col_effect_type,
+                num_latent_factors=mcfg.num_latent_factors,
+                # the half-steps are bank updates of a random-effect
+                # problem: its loss, optimizer, L2 weight and block plan
+                problem=RandomEffectOptimizationProblem(
+                    loss, ocfg.optimizer_config, ocfg.regularization,
+                    reg_weight=ocfg.reg_weight, mesh=mesh,
+                ),
+                num_inner_iterations=mcfg.num_inner_iterations,
+            )
         return coords
 
     def _fe_grid_lambdas(self, combos) -> Optional[List[float]]:
@@ -1501,6 +1561,10 @@ class GameTrainingDriver:
                         p.feature_name_and_term_set_path
                     ),
                 }
+                if p.mf_configs:
+                    run_manifest["mf_configs"] = {
+                        k: repr(v) for k, v in sorted(p.mf_configs.items())
+                    }
             # retrain warm start: the aligned parent model seeds the
             # FIRST (most-regularized) combo exactly like the cross-
             # combo warm start seeds the rest
@@ -1732,6 +1796,13 @@ def build_arg_parser() -> argparse.ArgumentParser:
     ap.add_argument("--random-effect-data-configurations", default="")
     ap.add_argument("--random-effect-optimization-configurations", default="")
     ap.add_argument("--factored-random-effect-optimization-configurations", default="")
+    ap.add_argument(
+        "--matrix-factorization-configurations", default="",
+        help="name:rowEffectType,colEffectType,numFactors,numInnerIterations"
+        " ('|'-separated), e.g. mf:userId,itemId,64,1; the coordinate's "
+        "optimizer and L2 weight are its entry in "
+        "--random-effect-optimization-configurations",
+    )
     ap.add_argument("--updating-sequence", default=None)
     ap.add_argument("--num-iterations", type=int, default=1)
     ap.add_argument("--evaluator-types", default=None)
@@ -1939,6 +2010,12 @@ def params_from_args(argv=None) -> GameTrainingParams:
             ns.random_effect_optimization_configurations
         ),
         factored_re_configs=factored,
+        mf_configs={
+            k: MatrixFactorizationConfiguration.parse(v)
+            for k, v in parse_keyed_map(
+                ns.matrix_factorization_configurations
+            ).items()
+        },
         updating_sequence=(
             ns.updating_sequence.split(",") if ns.updating_sequence else None
         ),

@@ -6,7 +6,7 @@ from photon_ml_tpu.game.config import (
     FactoredRandomEffectConfiguration,
     FeatureShardConfiguration,
     FixedEffectDataConfiguration,
-    MFOptimizationConfiguration,
+    MatrixFactorizationConfiguration,
     ProjectorType,
     RandomEffectDataConfiguration,
 )
@@ -56,7 +56,7 @@ __all__ = [
     "FactoredRandomEffectConfiguration",
     "FeatureShardConfiguration",
     "FixedEffectDataConfiguration",
-    "MFOptimizationConfiguration",
+    "MatrixFactorizationConfiguration",
     "ProjectorType",
     "RandomEffectDataConfiguration",
     "Coordinate",

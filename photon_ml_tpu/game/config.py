@@ -95,17 +95,37 @@ class RandomEffectDataConfiguration:
 
 
 @dataclass(frozen=True)
-class MFOptimizationConfiguration:
-    """Matrix factorization settings (MFOptimizationConfiguration.scala:50):
-    ``maxNumberIterations,numFactors``."""
+class MatrixFactorizationConfiguration:
+    """One matrix-factorization coordinate
+    (``--matrix-factorization-configurations``, after the reference's
+    MFOptimizationConfiguration.scala:50):
+    ``rowEffectType,colEffectType,numFactors,numInnerIterations``. The
+    coordinate's optimizer and L2 weight are its entry in
+    ``--random-effect-optimization-configurations``."""
 
-    max_iterations: int = 20
-    num_latent_factors: int = 8
+    row_effect_type: str  # id column of the row side, e.g. "userId"
+    col_effect_type: str
+    num_latent_factors: int
+    num_inner_iterations: int = 1  # ALS sweeps a coordinate-descent pass
 
     @classmethod
-    def parse(cls, s: str) -> "MFOptimizationConfiguration":
+    def parse(cls, s: str) -> "MatrixFactorizationConfiguration":
         parts = [p.strip() for p in s.split(",")]
-        return cls(max_iterations=int(parts[0]), num_latent_factors=int(parts[1]))
+        if len(parts) not in (3, 4) or not all(parts):
+            raise ValueError(
+                "expected 'rowEffectType,colEffectType,numFactors"
+                f"[,numInnerIterations]', got {s!r}"
+            )
+        config = cls(
+            row_effect_type=parts[0], col_effect_type=parts[1],
+            num_latent_factors=int(parts[2]),
+            num_inner_iterations=int(parts[3]) if len(parts) > 3 else 1,
+        )
+        if config.num_latent_factors < 1 or config.num_inner_iterations < 1:
+            raise ValueError(
+                f"numFactors and numInnerIterations must be >= 1: {s!r}"
+            )
+        return config
 
 
 @dataclass(frozen=True)

@@ -34,6 +34,7 @@ from photon_ml_tpu.game.model import (
 )
 from photon_ml_tpu.game.random_effect import (
     RandomEffectOptimizationProblem,
+    ValuesOverride,
     device_row_view,
     score_plan,
     score_random_effect,
@@ -42,6 +43,7 @@ from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
 from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.models.glm import compute_scores, create_model
 from photon_ml_tpu.obs.registry import default_registry
+from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.optim.problem import GLMOptimizationProblem
 
 Array = jnp.ndarray
@@ -1050,15 +1052,30 @@ def _latent_view(
     )
 
 
+def partner_factors(latent: Array, keys: Array) -> Array:
+    """The values of an ALS half-step's block (a :class:`ValuesOverride`
+    ``fn``): each rating's partner-side factor row, ``latent[keys]``
+    ``[E, S, K]``, zero on a padding slot (key -1). Runs inside the
+    block's solver program."""
+    return jnp.where(
+        (keys >= 0)[..., None],
+        jnp.take(latent, jnp.maximum(keys, 0), axis=0),
+        0.0,
+    )
+
+
 @dataclass
 class MatrixFactorizationCoordinate(Coordinate):
     """MF block trained by alternating least squares on residuals: row
     factors solve a K-dim GLM with features = colLatent[col_i] (a
-    random-effect solve in disguise), then columns symmetrically.
+    random-effect solve in disguise), then columns symmetrically, against
+    the row factors the same step just made.
 
     The reference trains factored models via FactoredRandomEffect and
     scores external MF models (MatrixFactorizationModel.scala); training
     in-tree here completes the GAME loop for MovieLens-style benchmarks.
+    The two half-steps run as ``update_bank(coordinate="<name>_row" |
+    "<name>_col")``: their programs, spans and counters carry those names.
     """
 
     name: str
@@ -1071,23 +1088,42 @@ class MatrixFactorizationCoordinate(Coordinate):
     seed: int = 0
 
     def initialize_model(self) -> MatrixFactorizationModel:
-        rng = np.random.default_rng(self.seed)
+        """The starting factors: N(0, 0.1^2) from the coordinate's own
+        constant ``seed``, so the same on every call; drawn and uploaded
+        once (10.6M normals at MovieLens-20M's counts and rank 64), and
+        never written to (``update_bank`` copies a bank before it donates
+        it)."""
+        model = self.__dict__.get("_initial_model")
+        if model is None:
+            rng = np.random.default_rng(self.seed)
+            R = self.dataset.entity_indexes[self.row_effect_type].num_entities
+            C = self.dataset.entity_indexes[self.col_effect_type].num_entities
+            K = self.num_latent_factors
+            model = MatrixFactorizationModel(
+                self.row_effect_type,
+                self.col_effect_type,
+                jnp.asarray(rng.normal(0, 0.1, size=(R, K)).astype(np.float32)),
+                jnp.asarray(rng.normal(0, 0.1, size=(C, K)).astype(np.float32)),
+            )
+            self.__dict__["_initial_model"] = model
+        return model
+
+    def _sides(self):
+        """(side, solved codes, partner codes, entities solved) of the two
+        half-steps, in the order they run."""
+        rows = self.dataset.entity_codes[self.row_effect_type]
+        cols = self.dataset.entity_codes[self.col_effect_type]
         R = self.dataset.entity_indexes[self.row_effect_type].num_entities
         C = self.dataset.entity_indexes[self.col_effect_type].num_entities
-        K = self.num_latent_factors
-        return MatrixFactorizationModel(
-            self.row_effect_type,
-            self.col_effect_type,
-            jnp.asarray(rng.normal(0, 0.1, size=(R, K)).astype(np.float32)),
-            jnp.asarray(rng.normal(0, 0.1, size=(C, K)).astype(np.float32)),
-        )
+        return (("row", rows, cols, R), ("col", cols, rows, C))
 
     def _side_structure(self, side: str, solve_codes, fixed_codes, num_solved):
         """Static ALS half-step structure: entity grouping, bucket
-        membership and per-bucket latent GATHER plans. Depends only on
-        the dataset's entity codes, so it is built once per side and
-        cached — per half-step only the latent VALUES change, and those
-        are gathered on device (see _als_side).
+        membership and each slot's partner code (the bucket's
+        ``override_keys``). Depends only on the dataset's entity codes,
+        so it is built once per side and cached — per half-step only the
+        partner side's factors change, and those are gathered on device,
+        inside each block's solver program (:func:`partner_factors`).
         """
         cache = getattr(self, "_als_structure_cache", None)
         if cache is None:
@@ -1096,8 +1132,6 @@ class MatrixFactorizationCoordinate(Coordinate):
         hit = cache.get(side)
         if hit is not None:
             return hit
-
-        import jax.numpy as jnp
 
         from photon_ml_tpu.game.config import (
             ProjectorType,
@@ -1130,39 +1164,14 @@ class MatrixFactorizationCoordinate(Coordinate):
             1 << np.ceil(np.log2(np.maximum(counts, 1))).astype(np.int64),
             0,
         )
-        # Merge sparse cap-classes upward: every distinct (E_b, S) bucket
-        # shape costs a multi-second trace + compile of the fused solver
-        # (9 programs made the MF first step 63 s in round 4; merging
-        # took them to 4), while padding a FEW entities to
-        # the next power of two only squares their tiny share of the
-        # Gram work. Keep a class only when it holds >= 25% of the
-        # active entities; everything else pads up to the next kept
-        # class (the largest class is always kept — entities can never
-        # pad DOWN without dropping samples).
-        active = caps > 0
-        if active.any():
-            classes, class_counts = np.unique(caps[active], return_counts=True)
-            total_active = int(class_counts.sum())
-            kept = {
-                int(s)
-                for s, c in zip(classes, class_counts)
-                if c >= 0.25 * total_active
-            }
-            kept.add(int(classes.max()))
-            # bound the padding: no entity pads more than 4x its own cap
-            # (heavy-tailed count distributions can otherwise leave every
-            # class under the 25% bar and collapse the merge onto the
-            # largest class — [E, S_max] blocks would blow host memory)
-            for s in sorted((int(c) for c in classes), reverse=True):
-                target = min((k for k in kept if k >= s), default=None)
-                if target is None or target > 4 * s:
-                    kept.add(s)
-            kept = np.asarray(sorted(kept), np.int64)
-            # next kept class >= each entity's cap
-            idx = np.searchsorted(kept, caps[active])
-            caps[active] = kept[idx]
+        # One class a power of two, as build_random_effect_dataset has
+        # them: at heavy-tailed activity (MovieLens-20M's: 10 classes of
+        # users, 18 of movies) three slots in ten hold no rating. Coarser
+        # classes would spare compiles and leave seven in ten empty
+        # there, and every half-step gathers a factor row for every slot,
+        # empty or not; the programs compile once, concurrently
+        # (:meth:`prepare`).
         buckets = []
-        gather_plans = []  # (partner_codes [E_b, S] device, ok [E_b, S] device)
         for S in sorted(set(int(c) for c in caps if c > 0)):
             members = np.nonzero(caps == S)[0]
             E_b = len(members)
@@ -1177,24 +1186,18 @@ class MatrixFactorizationCoordinate(Coordinate):
             buckets.append(RandomEffectBucket(
                 entity_codes=members.astype(np.int32),
                 row_index=b_rows,
-                indices=np.tile(
-                    np.arange(K, dtype=np.int32)[None, None, :], (E_b, S, 1)
-                ),
-                # zero-size placeholder: every update passes
-                # values_override (on-device gathers of the partner
-                # side's factors) and _bucket_device_args skips the
-                # stored values on that path, so nothing is pinned
+                # an identity block made by a values override stores
+                # neither indices nor values: X IS what
+                # :func:`partner_factors` gathers inside the program
+                indices=np.zeros((E_b, S, 0), np.int32),
                 values=np.zeros((E_b, S, 0), np.float32),
                 labels=np.where(ok, self.dataset.labels[safe], 0.0),
                 offsets=np.where(ok, self.dataset.offsets[safe], 0.0),
                 weights=np.where(ok, self.dataset.weights[safe], 0.0),
                 identity_indices=True,
-            ))
-            gather_plans.append((
-                jnp.asarray(
-                    np.where(ok, fixed_codes[safe], 0).astype(np.int32)
+                override_keys=np.where(ok, fixed_codes[safe], -1).astype(
+                    np.int32
                 ),
-                jnp.asarray(ok),
             ))
         view = RandomEffectDataset(
             config=RandomEffectDataConfiguration(
@@ -1208,10 +1211,9 @@ class MatrixFactorizationCoordinate(Coordinate):
                 np.arange(K, dtype=np.int32)[None, :], (num_solved, 1)
             ),
             # zero-length row-level placeholders: update_bank never
-            # reads them (scoring goes through
-            # MatrixFactorizationModel.score on the real dataset), and
-            # [n, K] zeros would pin ~0.5 GB host RAM per side for the
-            # coordinate's lifetime
+            # reads them (scoring goes through ``mf_score`` on the real
+            # dataset), and [n, K] zeros would pin ~0.5 GB host RAM per
+            # side for the coordinate's lifetime
             row_local_indices=np.zeros((0, K), np.int32),
             row_local_values=np.zeros((0, K), np.float32),
             row_entity_codes=np.where(real, solve_codes, -1).astype(np.int32),
@@ -1219,8 +1221,20 @@ class MatrixFactorizationCoordinate(Coordinate):
             num_active_rows=int(counts.sum()),
             num_passive_rows=0,
         )
-        cache[side] = (view, gather_plans)
-        return cache[side]
+        # counted on the host once a structure build: the slots the
+        # half-steps run, and how many of them hold no rating
+        slots = default_registry().counter(
+            "photon_mf_slots_total",
+            "slots of the ALS half-steps' blocks, by coordinate, side and "
+            "state (rating | padding)",
+        )
+        held = sum(b.row_index.size for b in buckets)
+        ratings = int(counts.sum())
+        for state, count in (("rating", ratings), ("padding", held - ratings)):
+            if count:
+                slots.inc(count, coordinate=self.name, side=side, state=state)
+        cache[side] = view
+        return view
 
     def _als_side(
         self,
@@ -1229,65 +1243,71 @@ class MatrixFactorizationCoordinate(Coordinate):
         fixed_codes: np.ndarray,
         fixed_latent: Array,  # [F, K]
         bank: Array,  # [S, K] current factors of the solved side
-        offsets_np: np.ndarray,
+        offsets,
         num_solved: int,
     ) -> Array:
-        import jax.numpy as jnp
-
-        view, gather_plans = self._side_structure(
-            side, solve_codes, fixed_codes, num_solved
+        view = self._side_structure(side, solve_codes, fixed_codes, num_solved)
+        blocks = self.problem._solver_blocks(
+            view, self.num_latent_factors, split=self.problem.mesh is None
         )
-        # latent feature views gathered ON DEVICE from the partner side's
-        # current factors — no host round trip, no [E, S, K] re-upload.
-        # Deferred per bucket (callables): only the bucket being solved
-        # holds its gathered values in HBM.
-        values = [
-            (lambda codes=codes, ok=ok: jnp.where(
-                ok[..., None], jnp.take(fixed_latent, codes, axis=0), 0.0
-            ))
-            for codes, ok in gather_plans
-        ]
-        new_bank, _ = self.problem.update_bank(
-            bank, view, residual_offsets=offsets_np, values_override=values
-        )
+        with obs_span(
+            "mf.half_step", side=side,
+            entities=sum(b.num_entities for b in view.buckets),
+            classes=len(view.buckets), sub_blocks=len(blocks),
+            kind="+".join(sorted({b.kind for b in blocks})),
+        ):
+            # the partner side's CURRENT factors, gathered on device a
+            # block at a time inside the block's own program
+            new_bank, _ = self.problem.update_bank(
+                bank, view, residual_offsets=offsets,
+                values_override=ValuesOverride(partner_factors, fixed_latent),
+                coordinate=f"{self.name}_{side}",
+            )
         return new_bank
 
     def update_model(self, model, residual=None):
-        offsets_np = self.dataset.offsets
-        if residual is not None:
-            offsets_np = jnp.asarray(offsets_np) + residual
-        rows = self.dataset.entity_codes[self.row_effect_type]
-        cols = self.dataset.entity_codes[self.col_effect_type]
-        R = self.dataset.entity_indexes[self.row_effect_type].num_entities
-        C = self.dataset.entity_indexes[self.col_effect_type].num_entities
-        row_latent, col_latent = model.row_latent, model.col_latent
         # With no residual the cached bucket offsets already hold the
         # dataset offsets — passing residual_offsets would re-gather and
         # re-upload [E, S] offsets per bucket every half-step for nothing
-        offsets_arg = None if residual is None else offsets_np
-        if not self.__dict__.get("_als_prewarmed"):
-            # cold start: AOT-compile BOTH sides' bucket programs in one
-            # threaded pool before the first half-step — per-side warming
-            # serialized the col side's compiles behind the row solves
-            # (and skipped single-bucket sides entirely)
-            row_view, _ = self._side_structure("row", rows, cols, R)
-            col_view, _ = self._side_structure("col", cols, rows, C)
-            self.problem.prewarm([
-                (row_latent, row_view, True, offsets_arg is not None),
-                (col_latent, col_view, True, offsets_arg is not None),
-            ])
-            self.__dict__["_als_prewarmed"] = True
+        offsets = None
+        if residual is not None:
+            offsets = jnp.asarray(self.dataset.offsets) + residual
+        latent = {"row": model.row_latent, "col": model.col_latent}
+        self.prepare(model)
         for _ in range(self.num_inner_iterations):
-            row_latent = self._als_side(
-                "row", rows, cols, col_latent, row_latent, offsets_arg, R
-            )
-            col_latent = self._als_side(
-                "col", cols, rows, row_latent, col_latent, offsets_arg, C
-            )
-        return replace(model, row_latent=row_latent, col_latent=col_latent), None
+            for side, solve_codes, fixed_codes, num in self._sides():
+                partner = "col" if side == "row" else "row"
+                latent[side] = self._als_side(
+                    side, solve_codes, fixed_codes, latent[partner],
+                    latent[side], offsets, num,
+                )
+        return replace(
+            model, row_latent=latent["row"], col_latent=latent["col"]
+        ), None
+
+    def prepare(self, model=None) -> None:
+        """Both sides' structures, and (once) BOTH sides' solver programs
+        AOT-compiled in one threaded pool — per-side warming serialized
+        the col side's compiles behind the row solves."""
+        if self.__dict__.get("_als_prewarmed"):
+            return
+        model = model if model is not None else self.initialize_model()
+        latent = {"row": model.row_latent, "col": model.col_latent}
+        specs = []
+        for side, solve_codes, fixed_codes, num in self._sides():
+            partner = "col" if side == "row" else "row"
+            specs.append((
+                latent[side],
+                self._side_structure(side, solve_codes, fixed_codes, num),
+                ValuesOverride(partner_factors, latent[partner]),
+                f"{self.name}_{side}",
+            ))
+        self.problem.prewarm(specs)
+        self.__dict__["_als_prewarmed"] = True
 
     def score(self, model: MatrixFactorizationModel) -> Array:
-        return model.score(self.dataset)
+        with obs_span("mf.score", coordinate=self.name):
+            return model.score(self.dataset)
 
     def regularization_term(self, model: MatrixFactorizationModel) -> float:
         from photon_ml_tpu.parallel import overlap

@@ -9,7 +9,7 @@ scoring), DatumScoringModel.scala.
 
 TPU-native: every model scores a GameDataset into a row-aligned [n] array;
 the RDD-of-models becomes a dense [E, D] coefficient bank; the MF cogroup
-becomes two row gathers + a dot.
+becomes two row gathers + a dot, a chunk of rows at a time.
 """
 
 from __future__ import annotations
@@ -17,7 +17,9 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from photon_ml_tpu.game.data import GameDataset
 from photon_ml_tpu.game.random_effect import score_random_effect
@@ -84,12 +86,64 @@ class MatrixFactorizationModel(DatumScoringModel):
         return self.row_latent.shape[1]
 
     def score(self, dataset: GameDataset) -> Array:
-        rows = dataset.entity_codes[self.row_effect_type]
-        cols = dataset.entity_codes[self.col_effect_type]
-        valid = jnp.asarray((rows >= 0) & (cols >= 0))
-        r = jnp.take(self.row_latent, jnp.maximum(jnp.asarray(rows), 0), axis=0)
-        c = jnp.take(self.col_latent, jnp.maximum(jnp.asarray(cols), 0), axis=0)
-        return jnp.where(valid, jnp.sum(r * c, axis=-1), 0.0)
+        rows, cols = mf_device_codes(
+            dataset, self.row_effect_type, self.col_effect_type
+        )
+        return mf_score(
+            self.row_latent, self.col_latent, rows, cols
+        )[: dataset.num_rows]
+
+
+# Rows one step of :func:`mf_score` takes the two factor rows of: the
+# [chunk, K] pair is all that is ever written (64 MiB each at K = 64),
+# never [n, K].
+MF_SCORE_CHUNK = 1 << 18
+
+
+def mf_device_codes(dataset: GameDataset, row_type: str, col_type: str):
+    """The two code columns of ``dataset`` on the device, padded with -1
+    to whole chunks of :data:`MF_SCORE_CHUNK`, ``[chunks, chunk]`` each;
+    uploaded once a dataset and pair of effect types."""
+    cache = dataset.__dict__.setdefault("_mf_device_codes", {})
+    key = (row_type, col_type)
+    if key not in cache:
+        n = dataset.num_rows
+        chunk = min(MF_SCORE_CHUNK, max(n, 1))
+        pad = -n % chunk
+
+        def put(codes):
+            codes = np.asarray(codes, np.int32)
+            return jnp.asarray(
+                np.concatenate([codes, np.full(pad, -1, np.int32)])
+                .reshape(-1, chunk)
+            )
+
+        cache[key] = (
+            put(dataset.entity_codes[row_type]),
+            put(dataset.entity_codes[col_type]),
+        )
+    return cache[key]
+
+
+@jax.jit
+def mf_score(row_latent, col_latent, rows, cols):
+    """``score_i = rowLatent[rows_i] . colLatent[cols_i]`` as ONE named
+    program (module ``mf_score``, scope ``cd.score``) over
+    ``[chunks, chunk]`` codes, a chunk a scan step: the gathered factor
+    rows of one chunk are the only [*, K] temporaries. A row without
+    either entity (code -1) scores 0. Elementwise float32 products and
+    sums: ``jax_default_matmul_precision`` does not reach it."""
+
+    def chunk_scores(_, codes):
+        r, c = codes
+        p = jnp.take(row_latent, jnp.maximum(r, 0), axis=0)
+        q = jnp.take(col_latent, jnp.maximum(c, 0), axis=0)
+        score = jnp.sum(p * q, axis=-1)
+        return None, jnp.where((r >= 0) & (c >= 0), score, 0.0)
+
+    with jax.named_scope("cd.score"):
+        _, out = jax.lax.scan(chunk_scores, None, (rows, cols))
+        return out.reshape(-1)
 
 
 @dataclass

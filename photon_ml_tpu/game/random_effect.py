@@ -20,11 +20,12 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass
 from functools import lru_cache, partial
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.scipy.linalg import cho_solve
 
 from photon_ml_tpu.game.random_effect_data import (
     RandomEffectBucket,
@@ -279,6 +280,141 @@ def _bucket_solver(
 
         return bank_dense
 
+    def _damped_newton(coef0, lab_e, off_e, w_e, l2, x_dot, xt_dot,
+                       newton_step):
+        """One entity's damped Newton solve, shared by the dual
+        (:func:`bank_newton`) and the primal (:func:`bank_primal`) kind:
+        the same stopping rule and the same line search, so that on a
+        block both can run they agree to rounding. ``x_dot(c)`` is
+        ``X c`` ([S]) and ``xt_dot(r)`` is ``X' r`` ([D]);
+        ``newton_step(c, z, cd, d2, g_vec)`` gives ``(u, step, z_step)``
+        = ``(X g, -H^-1 g, X step)`` in the kind's own space."""
+        max_iter = config.max_iter
+        tol = config.tolerance
+
+        def value(c, z):
+            return jnp.sum(w_e * loss.value(z, lab_e)) + 0.5 * l2 * jnp.vdot(c, c)
+
+        def grad_vec(z, c):
+            # Exact g = X^T cd + l2 c, materialized in coefficient
+            # space: the all-dual norm expansion (cd G cd + 2 l2 cd.Xc
+            # + l2^2 ||c||^2) cancels catastrophically in float32 once
+            # ||g|| is small relative to the individual terms,
+            # mis-reporting convergence — so spend one [D, S] matvec
+            # per iteration on the true gradient. The vector rides the
+            # loop carry: the NEXT iteration's Cauchy fallback needs
+            # exactly this gradient, so it costs no extra X pass.
+            cd = w_e * loss.d1(z, lab_e)
+            return xt_dot(cd) + l2 * c
+
+        z0 = x_dot(coef0) + off_e
+        f0 = value(coef0, z0)
+        g0_vec = grad_vec(z0, coef0)
+        g0_norm = jnp.linalg.norm(g0_vec)
+
+        # state: (c, z, f, g_vec, iter, reason). z is carried
+        # incrementally (z_t = z + alpha * z_step) — the only X touches
+        # per iteration are the ones that materialize the step and the
+        # exact gradient.
+        def cond(st):
+            return st[5] == NOT_CONVERGED
+
+        def body(st):
+            c, z, f, g_vec, it, _ = st
+            cd = w_e * loss.d1(z, lab_e)  # dual gradient weights [S]
+            d2 = w_e * loss.d2(z, lab_e)  # [S] >= 0 (convex)
+            u, step, z_step = newton_step(c, z, cd, d2, g_vec)
+
+            # Line search over 16 halving trials: 0-7 along the Newton
+            # step, 8-15 along the exact Cauchy (steepest-descent)
+            # step — the fallback for the rare entity whose float32 solve
+            # left the Newton step non-descent (ill-conditioned system at
+            # tiny l2). Every trial is pure z-space: the loss term
+            # moves along the precomputed step's image and the l2 term is
+            # a scalar quadratic in alpha, so no [D]-sized work or X
+            # pass happens per trial.
+            cc = jnp.vdot(c, c)
+            cs_n = jnp.vdot(c, step)
+            ss_n = jnp.vdot(step, step)
+            cg_dot = jnp.vdot(c, g_vec)
+            g_sq = jnp.vdot(g_vec, g_vec)  # exact, from the carry
+            g_hg = jnp.vdot(u, d2 * u) + l2 * g_sq
+            cauchy = g_sq / (g_hg + 1e-30)
+            cs_c = -cauchy * cg_dot
+            ss_c = cauchy * cauchy * g_sq
+            z_step_c = -cauchy * u
+
+            def trial(k):
+                newton = k < 8
+                a = jnp.exp2(-jnp.where(newton, k, k - 8).astype(z.dtype))
+                z_t = z + a * jnp.where(newton, z_step, z_step_c)
+                cs = jnp.where(newton, cs_n, cs_c)
+                ss = jnp.where(newton, ss_n, ss_c)
+                loss_t = jnp.sum(w_e * loss.value(z_t, lab_e))
+                return a, loss_t + 0.5 * l2 * (
+                    cc + 2.0 * a * cs + a * a * ss
+                )
+
+            def ls_cond(carry):
+                k, _, f_t, _ = carry
+                bad = (f_t > f) | ~jnp.isfinite(f_t)
+                return bad & (k < 16)
+
+            def ls_body(carry):
+                k, _, _, f_min = carry
+                k = k + 1
+                a, f_t = trial(k)
+                f_t = jnp.where(k < 16, f_t, jnp.inf)
+                return k, a, f_t, jnp.minimum(f_min, f_t)
+
+            with jax.named_scope("bank.newton.line_search"):
+                a0, f0_t = trial(jnp.int32(0))
+                k, alpha, f_t, f_min = jax.lax.while_loop(
+                    ls_cond, ls_body,
+                    (jnp.int32(0), a0, f0_t, f0_t),
+                )
+            # Strict decrease moves the iterate (monotone invariant);
+            # when NO trial decreases but the best trial was a float32
+            # near-tie, the entity is sitting on its optimum's noise
+            # plateau — report convergence WITHOUT moving instead of a
+            # bogus MaxIterations (and instead of accepting an uphill
+            # step, which could random-walk past the convergence test).
+            moved = (f_t <= f) & jnp.isfinite(f_t)
+            plateau = ~moved & (f_min <= f + 1e-6 * (1.0 + jnp.abs(f)))
+            newton_used = k < 8
+            # the carried g_vec IS the gradient at (c, z) — the
+            # fallback direction costs no extra X pass
+            used_step = jnp.where(newton_used, step, -cauchy * g_vec)
+            used_zstep = jnp.where(newton_used, z_step, z_step_c)
+            c2 = jnp.where(moved, c + alpha * used_step, c)
+            z2 = jnp.where(moved, z + alpha * used_zstep, z)
+            f2 = jnp.where(moved, f_t, f)
+            it2 = it + 1
+            g2_vec = grad_vec(z2, c2)
+            g_norm = jnp.linalg.norm(g2_vec)
+            reason = jnp.where(
+                moved,
+                check_convergence(
+                    it2, f, f2, g_norm, f0, g0_norm,
+                    max_iter=max_iter, tol=tol,
+                ),
+                jnp.where(
+                    plateau,
+                    FUNCTION_VALUES_WITHIN_TOLERANCE,
+                    LINE_SEARCH_STALLED,  # no decreasing step exists
+                ),
+            ).astype(jnp.int32)
+            return (c2, z2, f2, g2_vec, it2, reason)
+
+        init = (
+            coef0, z0, f0, g0_vec, jnp.zeros((), jnp.int32),
+            jnp.where(
+                g0_norm == 0.0, GRADIENT_WITHIN_TOLERANCE, NOT_CONVERGED
+            ).astype(jnp.int32),
+        )
+        c, _, _, _, it, reason = jax.lax.while_loop(cond, body, init)
+        return c, it, reason
+
     def _make_newton(identity):
         @jax.jit
         def bank_newton(bank, ix, v, lab, off, w, l1, l2):
@@ -300,49 +436,20 @@ def _bucket_solver(
             passes + one batched S x S solve; quadratic convergence replaces
             ~O(10) line-search evaluations per L-BFGS iteration with ~1
             halving check per Newton iteration. Requires l2 > 0 and a twice-
-            differentiable loss — update_bank selects it host-side.
+            differentiable loss — update_bank selects it host-side, for a
+            block with no more samples an entity than features
+            (:meth:`RandomEffectOptimizationProblem.dense_block_plan`;
+            :func:`bank_primal` takes the others).
             """
             del l1  # smooth path only (OWL-QN handles l1)
             _, s_b, _ = ix.shape
             X = v if identity else _densify(ix, v, bank.shape[1])
-            max_iter = config.max_iter
-            tol = config.tolerance
 
             def one(coef0, X_e, lab_e, off_e, w_e):
                 with jax.named_scope("bank.newton.gram"):
                     G = X_e @ X_e.T  # [S, S] sample Gram, one-time
 
-                def value(c, z):
-                    return jnp.sum(w_e * loss.value(z, lab_e)) + 0.5 * l2 * jnp.vdot(c, c)
-
-                def grad_vec(z, c):
-                    # Exact g = X^T cd + l2 c, materialized in coefficient
-                    # space: the all-dual norm expansion (cd G cd + 2 l2 cd.Xc
-                    # + l2^2 ||c||^2) cancels catastrophically in float32 once
-                    # ||g|| is small relative to the individual terms,
-                    # mis-reporting convergence — so spend one [D, S] matvec
-                    # per iteration on the true gradient. The vector rides the
-                    # loop carry: the NEXT iteration's Cauchy fallback needs
-                    # exactly this gradient, so it costs no extra X pass.
-                    cd = w_e * loss.d1(z, lab_e)
-                    return X_e.T @ cd + l2 * c
-
-                z0 = X_e @ coef0 + off_e
-                f0 = value(coef0, z0)
-                g0_vec = grad_vec(z0, coef0)
-                g0_norm = jnp.linalg.norm(g0_vec)
-
-                # state: (c, z, f, g_vec, iter, reason). z is carried
-                # incrementally (z_t = z + alpha * z_step, z_step computed in
-                # dual space) — the only X touches per iteration are the X^T
-                # applies that materialize the step and the exact gradient.
-                def cond(st):
-                    return st[5] == NOT_CONVERGED
-
-                def body(st):
-                    c, z, f, g_vec, it, _ = st
-                    cd = w_e * loss.d1(z, lab_e)  # dual gradient weights [S]
-                    d2 = w_e * loss.d2(z, lab_e)  # [S] >= 0 (convex)
+                def newton_step(c, z, cd, d2, g_vec):
                     zp = z - off_e  # = X c
                     u = G @ cd + l2 * zp  # = X g, no X pass
                     # t = (l2 I + D G)^-1 D u via the symmetrized SPD system
@@ -378,111 +485,93 @@ def _bucket_solver(
                     r = cd - t
                     step = -(X_e.T @ r) / l2 - c  # = -H^-1 g, ONE X pass
                     z_step = -(G @ r) / l2 - zp  # = X step, dual space
+                    return u, step, z_step
 
-                    # Line search over 16 halving trials: 0-7 along the Newton
-                    # step, 8-15 along the exact Cauchy (steepest-descent)
-                    # step — the fallback for the rare entity whose float32 CG
-                    # left the Newton step non-descent (ill-conditioned B at
-                    # tiny l2). Every trial is pure z-space: the loss term
-                    # moves along the precomputed dual step and the l2 term is
-                    # a scalar quadratic in alpha, so no [D]-sized work or X
-                    # pass happens per trial.
-                    cc = jnp.vdot(c, c)
-                    cs_n = jnp.vdot(c, step)
-                    ss_n = jnp.vdot(step, step)
-                    cg_dot = jnp.vdot(c, g_vec)
-                    g_sq = jnp.vdot(g_vec, g_vec)  # exact, from the carry
-                    g_hg = jnp.vdot(u, d2 * u) + l2 * g_sq
-                    cauchy = g_sq / (g_hg + 1e-30)
-                    cs_c = -cauchy * cg_dot
-                    ss_c = cauchy * cauchy * g_sq
-                    z_step_c = -cauchy * u
-
-                    def trial(k):
-                        newton = k < 8
-                        a = jnp.exp2(-jnp.where(newton, k, k - 8).astype(z.dtype))
-                        z_t = z + a * jnp.where(newton, z_step, z_step_c)
-                        cs = jnp.where(newton, cs_n, cs_c)
-                        ss = jnp.where(newton, ss_n, ss_c)
-                        loss_t = jnp.sum(w_e * loss.value(z_t, lab_e))
-                        return a, loss_t + 0.5 * l2 * (
-                            cc + 2.0 * a * cs + a * a * ss
-                        )
-
-                    def ls_cond(carry):
-                        k, _, f_t, _ = carry
-                        bad = (f_t > f) | ~jnp.isfinite(f_t)
-                        return bad & (k < 16)
-
-                    def ls_body(carry):
-                        k, _, _, f_min = carry
-                        k = k + 1
-                        a, f_t = trial(k)
-                        f_t = jnp.where(k < 16, f_t, jnp.inf)
-                        return k, a, f_t, jnp.minimum(f_min, f_t)
-
-                    with jax.named_scope("bank.newton.line_search"):
-                        a0, f0_t = trial(jnp.int32(0))
-                        k, alpha, f_t, f_min = jax.lax.while_loop(
-                            ls_cond, ls_body,
-                            (jnp.int32(0), a0, f0_t, f0_t),
-                        )
-                    # Strict decrease moves the iterate (monotone invariant);
-                    # when NO trial decreases but the best trial was a float32
-                    # near-tie, the entity is sitting on its optimum's noise
-                    # plateau — report convergence WITHOUT moving instead of a
-                    # bogus MaxIterations (and instead of accepting an uphill
-                    # step, which could random-walk past the convergence test).
-                    moved = (f_t <= f) & jnp.isfinite(f_t)
-                    plateau = ~moved & (f_min <= f + 1e-6 * (1.0 + jnp.abs(f)))
-                    newton_used = k < 8
-                    # the carried g_vec IS the gradient at (c, z) — the
-                    # fallback direction costs no extra X pass
-                    used_step = jnp.where(newton_used, step, -cauchy * g_vec)
-                    used_zstep = jnp.where(newton_used, z_step, z_step_c)
-                    c2 = jnp.where(moved, c + alpha * used_step, c)
-                    z2 = jnp.where(moved, z + alpha * used_zstep, z)
-                    f2 = jnp.where(moved, f_t, f)
-                    it2 = it + 1
-                    g2_vec = grad_vec(z2, c2)
-                    g_norm = jnp.linalg.norm(g2_vec)
-                    reason = jnp.where(
-                        moved,
-                        check_convergence(
-                            it2, f, f2, g_norm, f0, g0_norm,
-                            max_iter=max_iter, tol=tol,
-                        ),
-                        jnp.where(
-                            plateau,
-                            FUNCTION_VALUES_WITHIN_TOLERANCE,
-                            LINE_SEARCH_STALLED,  # no decreasing step exists
-                        ),
-                    ).astype(jnp.int32)
-                    return (c2, z2, f2, g2_vec, it2, reason)
-
-                init = (
-                    coef0, z0, f0, g0_vec, jnp.zeros((), jnp.int32),
-                    jnp.where(
-                        g0_norm == 0.0, GRADIENT_WITHIN_TOLERANCE, NOT_CONVERGED
-                    ).astype(jnp.int32),
+                return _damped_newton(
+                    coef0, lab_e, off_e, w_e, l2,
+                    lambda c: X_e @ c, lambda r: X_e.T @ r, newton_step,
                 )
-                c, _, _, _, it, reason = jax.lax.while_loop(cond, body, init)
-                return c, it, reason
 
             coefs, iters, reasons = jax.vmap(one)(bank, X, lab, off, w)
             return coefs, iters, reasons
 
         return bank_newton
 
+    def _make_primal(identity):
+        @jax.jit
+        def bank_primal(bank, ix, v, lab, off, w, l1, l2):
+            """Damped Newton in the PRIMAL (feature) space: the other
+            regime from :func:`bank_newton`, an entity with MORE samples
+            than features (S > D: a bias, D = 1, over every row of a
+            heavy user; an ALS half-step, D = the rank, over a movie's
+            tens of thousands of ratings). There the sample-space Gram
+            ``[S, S]`` is the large object and the normal equations the
+            small one:
+
+                (X' diag(w l'') X + l2 I) step = -(X' (w l') + l2 c),
+
+            ``[D, D]`` an entity, rebuilt each iteration (one pass over
+            X on the MXU) and solved by a Cholesky factorization. The
+            stopping rule and the line search are :func:`bank_newton`'s
+            (:func:`_damped_newton`). A squared loss is solved by the
+            first step and stops on the second. Every matmul names its
+            precision: the normal equations square the condition number,
+            so they are float32 (``HIGHEST``) whatever the process
+            default is."""
+            del l1  # smooth path only (OWL-QN handles l1)
+            X = v if identity else _densify(ix, v, bank.shape[1])
+            eye = jnp.eye(bank.shape[1], dtype=X.dtype)
+            hi = jax.lax.Precision.HIGHEST
+
+            def one(coef0, X_e, lab_e, off_e, w_e):
+                def x_dot(c):
+                    return jnp.dot(X_e, c, precision=hi)
+
+                def xt_dot(r):
+                    return jnp.dot(r, X_e, precision=hi)
+
+                def newton_step(c, z, cd, d2, g_vec):
+                    del c, z, cd
+                    with jax.named_scope("bank.primal.hessian"):
+                        H = jax.lax.dot_general(
+                            X_e * d2[:, None], X_e,
+                            (((0,), (0,)), ((), ())), precision=hi,
+                        ) + l2 * eye  # [D, D]
+                    with jax.named_scope("bank.primal.solve"):
+                        step = -cho_solve(
+                            (jnp.linalg.cholesky(H), True), g_vec
+                        )
+                    return x_dot(g_vec), step, x_dot(step)
+
+                return _damped_newton(
+                    coef0, lab_e, off_e, w_e, l2, x_dot, xt_dot, newton_step
+                )
+
+            return jax.vmap(one)(bank, X, lab, off, w)
+
+        return bank_primal
+
     n_reasons = max(CONVERGENCE_REASON_NAMES) + 1
 
-    def _update_block(core, bank, codes, ix, v, lab, off, w, l1, l2):
+    def _update_block(core, bank, codes, ix, v, lab, off, w, l1, l2,
+                      values_of=None, operand=None):
         """One block's gather, solve, scatter and tracker reductions. A
         code past the bank's last row is a PADDING lane (the last
         sub-block of a split bucket, :meth:`RandomEffectOptimizationProblem
         ._solver_blocks`): it starts from zero on weight-0 rows, its
         scatter drops and it counts in no reduction, as the pod's pad
-        lanes do."""
+        lanes do. With ``values_of`` (:class:`ValuesOverride`) the block
+        holds no values: ``v`` carries its keys, and its values are made
+        HERE, inside the program, so that only this block's are alive."""
+        if values_of is not None:
+            with jax.named_scope("bank.values_override"):
+                v = values_of(operand, v)
+            if ix.shape[-1] != v.shape[-1]:
+                # an identity block holds no indices either; only the
+                # sparse solver reads them
+                ix = jnp.broadcast_to(
+                    jnp.arange(v.shape[-1], dtype=ix.dtype), v.shape
+                )
         real = codes < bank.shape[0]
         sl = jnp.take(bank, codes, axis=0, mode="fill", fill_value=0)
         new_sl, iters, reasons = core(sl, ix, v, lab, off, w, l1, l2)
@@ -507,7 +596,7 @@ def _bucket_solver(
 
         return rename
 
-    def _fused(core, name="bank_fused"):
+    def _fused(core, name="bank_fused", values_of=None):
         """Single-dispatch bucket update: bank-row gather, solve, bank
         scatter, and the tracker reductions all inside ONE jit program —
         per-bucket host overhead (separate gather/scatter dispatches plus
@@ -522,19 +611,23 @@ def _bucket_solver(
         before the bucket chain so outside references stay valid.
 
         ``name`` names the XLA module (``jit_<name>``): a coordinate's
-        own where :func:`fused_for` is given one."""
+        own where :func:`fused_for` is given one. ``values_of``: the
+        program takes the override's operand last and the block's keys
+        in the values' place."""
 
         # photon: sharding(axes=[], donates=[0])
         @partial(jax.jit, donate_argnums=_donate())
         @_named(name)
-        def bank_fused(bank_full, codes, ix, v, lab, off, w, l1, l2):
+        def bank_fused(bank_full, codes, ix, v, lab, off, w, l1, l2,
+                       operand=None):
             return _update_block(
-                core, bank_full, codes, ix, v, lab, off, w, l1, l2
+                core, bank_full, codes, ix, v, lab, off, w, l1, l2,
+                values_of, operand,
             )
 
         return bank_fused
 
-    def _fused_scan(core, name="bank_fused_scan"):
+    def _fused_scan(core, name="bank_fused_scan", values_of=None):
         """The fused bucket update folded over a STACK of same-shape
         blocks by lax.scan — one dispatch for the whole group: a run of
         same-shape buckets, or the equal sub-blocks of one bucket over
@@ -543,15 +636,18 @@ def _bucket_solver(
         ~125 ms of host gaps between ~76 ms device programs; scanning
         removes the gaps. The bank threads through the scan carry
         (donated, in-place scatters), so only ONE block's dense staging
-        is live at a time."""
+        (under ``values_of``, one block's made values) is live at a
+        time."""
 
         # photon: sharding(axes=[], donates=[0])
         @partial(jax.jit, donate_argnums=_donate())
         @_named(name)
         def bank_fused_scan(bank_full, codes_s, ix_s, v_s, lab_s, off_s,
-                            w_s, l1, l2):
+                            w_s, l1, l2, operand=None):
             def body(bank, args):
-                bank, *stats = _update_block(core, bank, *args, l1, l2)
+                bank, *stats = _update_block(
+                    core, bank, *args, l1, l2, values_of, operand
+                )
                 return bank, tuple(stats)
 
             bank_full, (it_sums, it_maxs, counts) = jax.lax.scan(
@@ -592,23 +688,27 @@ def _bucket_solver(
         "dense_id": _make_dense(True),
         "newton": _make_newton(False),
         "newton_id": _make_newton(True),
+        "primal": _make_primal(False),
+        "primal_id": _make_primal(True),
     }
     fused_programs: dict = {}
 
-    def fused_for(kind, coordinate=None, scan=False):
+    def fused_for(kind, coordinate=None, scan=False, values_of=None):
         """The fused (``scan``: scanned) update program of a solver kind,
         its module named after ``coordinate`` when one is given
         (``jit_bank_fused[_scan]_<coordinate>``, non-word characters as
         ``_``): a device trace then tells one coordinate's bank from
         another's. Two banks differ in shape and compile apart whatever
-        they are called, so the name costs no compile."""
-        key = (kind, coordinate, scan)
+        they are called, so the name costs no compile. ``values_of``:
+        the program that makes a block's values itself
+        (:class:`ValuesOverride`)."""
+        key = (kind, coordinate, scan, values_of)
         if key not in fused_programs:
             name = "bank_fused_scan" if scan else "bank_fused"
             if coordinate:
                 name += "_" + re.sub(r"\W", "_", coordinate)
             build = _fused_scan if scan else _fused
-            fused_programs[key] = build(cores[kind], name)
+            fused_programs[key] = build(cores[kind], name, values_of)
         return fused_programs[key]
 
     return SimpleNamespace(
@@ -617,6 +717,27 @@ def _bucket_solver(
         fused_for=fused_for,
         hdiag=hdiag,
     )
+
+
+class ValuesOverride(NamedTuple):
+    """Feature values a bank update MAKES instead of reading: the solver
+    program calls ``fn(operand, keys)`` on the device for each block it
+    runs, ``keys`` being the block's :attr:`RandomEffectBucket
+    .override_keys` ``[E, S]``, and solves on the ``[E, S, k]`` it
+    returns. An ALS half-step: ``operand`` the partner side's factors,
+    ``keys`` each rating's partner code. The values live only inside the
+    program of one block (one sub-block of a split bucket), never beside
+    another's. ``fn`` is part of the compiled program's identity: pass a
+    module-level function, not a fresh closure."""
+
+    fn: object
+    operand: object  # pytree of device arrays, the same for every block
+
+    @property
+    def sig(self) -> tuple:
+        return (self.fn,) + tuple(
+            tuple(a.shape) for a in jax.tree.leaves(self.operand)
+        )
 
 
 class _SolverBlock(NamedTuple):
@@ -639,12 +760,15 @@ def _split_bucket(
     arrays, the last padded with weight-0 entities on no row whose code
     ``pad_code`` lies past the bank (the fused programs' padding lanes)."""
     e_sub = -(-bucket.num_entities // n_sub)
-    fill = {"entity_codes": pad_code, "row_index": -1}
+    fill = {"entity_codes": pad_code, "row_index": -1, "override_keys": -1}
+    names = ["entity_codes", "row_index", "indices", "values", "labels",
+             "offsets", "weights"]
+    if bucket.override_keys is not None:
+        names.append("override_keys")
     out = []
     for j in range(n_sub):
         parts = {}
-        for name in ("entity_codes", "row_index", "indices", "values",
-                     "labels", "offsets", "weights"):
+        for name in names:
             a = getattr(bucket, name)[j * e_sub:(j + 1) * e_sub]
             if a.shape[0] < e_sub:
                 pad = np.full(
@@ -746,24 +870,44 @@ class RandomEffectOptimizationProblem:
         a device's share on the pod path, game/pod.py), or run the sparse
         solver where it cannot split (:meth:`_bucket_kind`).
         ``("sparse", num_entities)`` where nothing dense may run: the
-        layout says so, or not one entity fits."""
+        layout says so, or not one entity fits.
+
+        Which Newton: the DUAL kind (``newton``) holds an entity's
+        sample-space Gram, ``capacity ** 2`` floats built once with
+        ``capacity ** 2 * d_local`` multiply-adds; the PRIMAL kind
+        (``primal``) its normal equations, ``d_local ** 2`` floats
+        rebuilt each iteration with ``capacity * d_local ** 2``. They
+        break even at ``capacity == d_local``, in floats and in
+        multiply-adds alike, so a block with MORE samples an entity than
+        features (``capacity > d_local``) runs the primal kind and every
+        other the dual one: 16 rows of 1,000 features stay dual, a bias
+        over 9,254 ratings or a rank-64 factor over 67,310 is primal
+        (its Gram alone would be 16 GiB)."""
         if self.layout == "sparse":
             return "sparse", num_entities
         newton = self._newton_eligible()
+        primal = newton and capacity > d_local
         # "_id": indices that are the tiled arange (k == local_dim, the MF
         # latent view): X IS values, no [E, S, k, D] densify broadcast
-        kind = ("newton" if newton else "dense") + ("_id" if identity else "")
+        kind = (
+            "primal" if primal else "newton" if newton else "dense"
+        ) + ("_id" if identity else "")
         if self.layout == "dense":
             return kind, num_entities
-        # X [E, S, D], plus the Newton path's Gram G [E, S, S] when that
-        # solver would actually run (the CG solve is matrix-free — no
-        # second S x S block) — when S > D the Grams, not X, dominate the
-        # footprint, but charging them to a bucket that can only take the
-        # plain dense solver would wrongly force the slow sparse path.
-        # Identity-indices buckets pay no X at all (X IS values).
-        floats = 0 if identity else capacity * d_local
-        if newton:
-            floats += capacity * capacity
+        if primal:
+            # the X the program really holds (densified, stored or made by
+            # a values override) and the [D, D] system
+            floats = capacity * d_local + d_local * d_local
+        else:
+            # X [E, S, D], plus the dual Newton path's Gram G [E, S, S]
+            # when that solver would actually run (the CG solve is
+            # matrix-free — no second S x S block); charging a Gram to a
+            # bucket that can only take the plain dense solver would
+            # wrongly force the slow sparse path. Identity-indices
+            # buckets pay no X at all (X IS values).
+            floats = 0 if identity else capacity * d_local
+            if newton:
+                floats += capacity * capacity
         if floats == 0:
             return kind, num_entities
         cap = self.dense_bytes_budget // (floats * _BLOCK_ITEMSIZE)
@@ -773,9 +917,8 @@ class RandomEffectOptimizationProblem:
         """Which solver program this bucket runs as ONE block (host-side
         selection): the dense kind where the WHOLE bucket fits the
         budget. What a block that cannot be split runs: a streamed
-        segment, a bucket on the entity mesh or under a values
-        override; ``update_bank`` splits the others
-        (:meth:`_solver_blocks`)."""
+        segment, a bucket on the entity mesh; ``update_bank`` splits the
+        others (:meth:`_solver_blocks`)."""
         e_b, s_b, _ = bucket.indices.shape
         kind, cap = self.dense_block_plan(
             e_b, s_b, d_local, bucket.identity_indices
@@ -792,9 +935,9 @@ class RandomEffectOptimizationProblem:
         a device's share by): same shape, so ONE compiled program, and
         consecutive, so they fold into one scanned dispatch that hands
         the donated bank from one to the next. Without it (the entity
-        mesh, a values override: both address a bucket by its index) a
-        bucket is one block of :meth:`_bucket_kind`. The sub-block views
-        are cached on the dataset, keyed by the split."""
+        mesh addresses a bucket by its index) a bucket is one block of
+        :meth:`_bucket_kind`. The sub-block views are cached on the
+        dataset, keyed by the split."""
         plans = []
         for bucket in dataset.buckets:
             e_b, s_b, _ = bucket.indices.shape
@@ -842,9 +985,8 @@ class RandomEffectOptimizationProblem:
         """Device-resident (mesh-sharded if configured) static arrays for a
         bucket, transferred once and reused across update_bank calls. The
         cache holds a weakref: device copies die with the bucket.
-        ``with_values=False`` (the values_override path) skips uploading
-        the bucket's stored values — a caller that always overrides them
-        must not pin a dead [E, S, k] copy in HBM."""
+        ``with_values=False`` (the values_override path): the values'
+        place holds the bucket's ``override_keys`` [E, S] instead."""
         import weakref
 
         key = (id(bucket), with_values)
@@ -853,17 +995,16 @@ class RandomEffectOptimizationProblem:
             return hit[1]
         arrs = [
             jnp.asarray(bucket.indices),
-            jnp.asarray(bucket.values) if with_values else None,
+            jnp.asarray(
+                bucket.values if with_values else bucket.override_keys
+            ),
             jnp.asarray(bucket.labels),
             jnp.asarray(bucket.weights),
             jnp.asarray(bucket.offsets),
             jnp.asarray(bucket.row_index),
         ]
         if self.mesh is not None:
-            present = [a for a in arrs if a is not None]
-            present, _ = self._shard_entity_axis(present)
-            it = iter(present)
-            arrs = [next(it) if a is not None else None for a in arrs]
+            arrs, _ = self._shard_entity_axis(arrs)
         # entity codes stay unsharded: they index the full bank host-side
         arrs = arrs + [jnp.asarray(bucket.entity_codes)]
         cache = self._device_cache
@@ -906,7 +1047,8 @@ class RandomEffectOptimizationProblem:
                 routed = router.route(residual_offsets)
         return residual_offsets, routed, router
 
-    def _stacked_group_args(self, dataset, blocks, *, with_residuals):
+    def _stacked_group_args(self, dataset, blocks, *, with_residuals,
+                            with_values=True):
         """Device-stacked [B, ...] args for a same-shape group of solver
         blocks, built from the HOST arrays in one transfer per field and
         cached on the dataset. Only the offset source the configuration
@@ -921,7 +1063,10 @@ class RandomEffectOptimizationProblem:
         co-occur within one update, and problems are variance-typed for
         their lifetime, so the overlap is rare in practice."""
         cache = dataset.__dict__.setdefault("_stacked_device_cache", {})
-        key = (tuple(b[:3] for b in blocks), bool(with_residuals))
+        key = (
+            tuple(b[:3] for b in blocks), bool(with_residuals),
+            bool(with_values),
+        )
         hit = cache.get(key)
         if hit is not None:
             return hit
@@ -929,7 +1074,10 @@ class RandomEffectOptimizationProblem:
         out = (
             jnp.asarray(np.stack([b.entity_codes for b in bs])),
             jnp.asarray(np.stack([b.indices for b in bs])),
-            jnp.asarray(np.stack([b.values for b in bs])),
+            # under a values override, the blocks' keys in the values' place
+            jnp.asarray(np.stack([
+                b.values if with_values else b.override_keys for b in bs
+            ])),
             jnp.asarray(np.stack([b.labels for b in bs])),
             None
             if with_residuals
@@ -957,81 +1105,65 @@ class RandomEffectOptimizationProblem:
             rows_d >= 0, residual_offsets[jnp.maximum(rows_d, 0)], 0.0
         )
 
+    @staticmethod
+    def _program_sig(kind, coordinate, bank_shape, ix_shape, override,
+                     scan=False):
+        """What tells one compiled solver program from another in
+        ``_aot_cache``; ``ix_shape`` with the leading stack axis for a
+        scanned group."""
+        sig = (kind, coordinate, tuple(bank_shape), tuple(ix_shape))
+        if override is not None:
+            sig += override.sig
+        return (("scan",) + sig) if scan else sig
+
     def _bucket_plans(
         self,
         bank: Array,
         groups,
         *,
-        has_values_override: bool,
-        has_residual_offsets: bool,
+        override: Optional[ValuesOverride],
         l1_d,
         l2_d,
         coordinate: Optional[str] = None,
     ):
         """(sig, thunk) plans for every DISTINCT program of ``groups``
         (:meth:`_block_groups`); ``thunk()`` lowers the exact solver call
-        and returns the compiled executable. A group of several blocks
-        plans the SCAN program from avals, a single block its own."""
+        and returns the compiled executable. Everything lowers from
+        avals: nothing is uploaded or computed here."""
         plans = []
         seen_sigs = set()
+        sds = jax.ShapeDtypeStruct
+        f32, i32 = jnp.float32, jnp.int32
+        values_of = override.fn if override is not None else None
+        extra = () if override is None else (jax.tree.map(
+            lambda a: sds(a.shape, a.dtype), override.operand
+        ),)
         for members in groups:
             kind, bucket = members[0].kind, members[0].bucket
-            ixk = bucket.indices.shape
-            if len(members) > 1:
-                E, S = bucket.labels.shape
-                B = len(members)
-                sig = ("scan", kind, coordinate, bank.shape, (B,) + ixk)
-                if sig in seen_sigs:
-                    continue  # identical program; one compile suffices
-                seen_sigs.add(sig)
-
-                def thunk(kind=kind, B=B, E=E, S=S, ixk=ixk, bank=bank):
-                    sds = jax.ShapeDtypeStruct
-                    f32, i32 = jnp.float32, jnp.int32
-                    return self._solvers.fused_for(
-                        kind, coordinate, scan=True
-                    ).lower(
-                        bank,
-                        sds((B, E), i32),
-                        sds((B,) + ixk, i32),
-                        sds((B,) + ixk, f32),
-                        sds((B, E, S), f32),
-                        sds((B, E, S), f32),
-                        sds((B, E, S), f32),
-                        l1_d, l2_d,
-                    ).compile()
-
-                plans.append((sig, thunk))
-                continue
-            sig = (kind, coordinate, bank.shape, ixk)
+            scan = len(members) > 1
+            lead = (len(members),) if scan else ()
+            ixk = lead + bucket.indices.shape
+            sig = self._program_sig(
+                kind, coordinate, bank.shape, ixk, override, scan
+            )
             if sig in seen_sigs:
-                continue
+                continue  # identical program; one compile suffices
             seen_sigs.add(sig)
+            es = lead + bucket.labels.shape
+            # under an override the values' place holds the keys [E, S]
+            v_aval = (
+                sds(ixk[:-1] + bucket.values.shape[-1:], f32)
+                if override is None else sds(es, i32)
+            )
 
-            def thunk(bucket=bucket, kind=kind, bank=bank):
-                (
-                    ix_d, v_d, lab_d, w_d, off_d, rows_d, codes_d,
-                ) = self._bucket_device_args(
-                    bucket, with_values=not has_values_override
-                )
-                # COMPUTED operands (override gathers, residual
-                # offsets) lower from avals only — materializing them
-                # here would run every bucket's partner gather
-                # concurrently and break the one-bucket HBM cap the
-                # deferred values_override exists for
-                if has_values_override:
-                    k_dim = bucket.indices.shape[-1]
-                    v_d = jax.ShapeDtypeStruct(
-                        bucket.indices.shape[:2] + (k_dim,), jnp.float32
-                    )
-                if has_residual_offsets:
-                    off_d = jax.ShapeDtypeStruct(
-                        bucket.offsets.shape, jnp.float32
-                    )
-                # lowering never executes; the loop calls the result
-                return self._solvers.fused_for(kind, coordinate).lower(
-                    bank, codes_d, ix_d, v_d, lab_d, off_d, w_d,
-                    l1_d, l2_d,
+            def thunk(kind=kind, scan=scan, ixk=ixk, es=es, v_aval=v_aval,
+                      bank=bank):
+                return self._solvers.fused_for(
+                    kind, coordinate, scan=scan, values_of=values_of
+                ).lower(
+                    bank, sds(es[:-1], i32), sds(ixk, i32), v_aval,
+                    sds(es, f32), sds(es, f32), sds(es, f32), l1_d, l2_d,
+                    *extra,
                 ).compile()
 
             plans.append((sig, thunk))
@@ -1070,17 +1202,12 @@ class RandomEffectOptimizationProblem:
         serial gap between their dispatches)."""
         if not dataset.buckets:
             return
-        blocks = self._solver_blocks(
-            dataset, bank.shape[1], split=self.mesh is None
+        # update_bank's own groups (variance-typed problems run the
+        # per-block path, so stage per-block device args — a stacked copy
+        # would pin HBM the update never reads)
+        groups = self._update_groups(
+            dataset, bank.shape[1], with_variances=self.compute_variances
         )
-        # mirror update_bank's fold eligibility (variance-typed problems
-        # run the per-block path, so stage per-block device args — a
-        # stacked copy would pin HBM the update never reads)
-        groups = self._block_groups(blocks, fold=(
-            self.mesh is None
-            and not self.compute_variances
-            and len(blocks) > 1
-        ))
         for members in groups:
             if len(members) > 1:
                 self._stacked_group_args(
@@ -1092,35 +1219,40 @@ class RandomEffectOptimizationProblem:
             l1, l2 = self.regularization.split(self.reg_weight)
             self._warm_solvers(self._bucket_plans(
                 bank, groups,
-                has_values_override=False,
-                has_residual_offsets=has_residual_offsets,
+                override=None,
                 l1_d=jnp.float32(l1), l2_d=jnp.float32(l2),
                 coordinate=coordinate,
             ))
         elif has_residual_offsets:
             self._router_for(dataset)  # static routing tables, host-built
 
+    def _update_groups(self, dataset, d_local: int, *, with_variances=False):
+        """The groups of solver blocks ONE ``update_bank`` over
+        ``dataset`` dispatches (:meth:`_solver_blocks`,
+        :meth:`_block_groups`): split and folded on one device, a bucket
+        a block on the entity mesh, which addresses buckets by index."""
+        foldable = self.mesh is None
+        blocks = self._solver_blocks(dataset, d_local, split=foldable)
+        return self._block_groups(
+            blocks, fold=foldable and not with_variances and len(blocks) > 1
+        )
+
     def prewarm(self, specs) -> None:
-        """AOT-compile the bucket programs of SEVERAL (bank, dataset,
-        has_values_override, has_residual_offsets) quadruples in ONE
+        """AOT-compile the solver programs of SEVERAL (bank, dataset,
+        override, coordinate) updates in ONE
         threaded pool. The MF coordinate calls this before its first ALS
-        half-step so BOTH sides' programs — including single-bucket sides
-        that per-side warming used to skip — compile concurrently
-        instead of serializing across half-steps."""
+        half-step so BOTH sides' programs compile concurrently instead of
+        serializing across half-steps."""
         if self.mesh is not None:
             return
         l1, l2 = self.regularization.split(self.reg_weight)
         l1_d, l2_d = jnp.float32(l1), jnp.float32(l2)
         plans = []
-        for bank, dataset, has_override, has_resid in specs:
-            blocks = self._solver_blocks(
-                dataset, bank.shape[1], split=not has_override
-            )
+        for bank, dataset, override, coordinate in specs:
             plans += self._bucket_plans(
-                bank, self._block_groups(blocks, fold=False),
-                has_values_override=has_override,
-                has_residual_offsets=has_resid,
-                l1_d=l1_d, l2_d=l2_d,
+                bank, self._update_groups(dataset, bank.shape[1]),
+                override=override,
+                l1_d=l1_d, l2_d=l2_d, coordinate=coordinate,
             )
         self._warm_solvers(plans)
 
@@ -1161,7 +1293,7 @@ class RandomEffectOptimizationProblem:
         bank: Array,  # [E, D]
         dataset: RandomEffectDataset,
         residual_offsets: Optional[Array] = None,  # [n] replaces offsets
-        values_override: Optional[Sequence[Array]] = None,
+        values_override: Optional[ValuesOverride] = None,
         with_variances: bool = False,
         defer_tracker: bool = False,
         coordinate: Optional[str] = None,
@@ -1172,10 +1304,13 @@ class RandomEffectOptimizationProblem:
         the already-routed offsets in hand, so the mesh path pays no second
         residual all_to_all).
 
-        ``values_override``: device-resident per-bucket feature values
-        (aligned with ``dataset.buckets``) replacing each bucket's stored
-        values — the MF ALS path recomputes latent feature views on
-        device every half-step while the bucket STRUCTURE stays cached.
+        ``values_override``: feature values made on the device, inside
+        each block's solver program, from the block's ``override_keys``
+        (:class:`ValuesOverride`) — the MF ALS path gathers the partner
+        side's current factors every half-step while the bucket
+        STRUCTURE stays cached. A bucket over the dense budget splits
+        into sub-blocks as any other, so at most one sub-block's values
+        are alive.
 
         ``defer_tracker``: return a LazyRandomEffectTracker whose stats
         stay on device — the GAME CD loop folds every coordinate's
@@ -1210,22 +1345,22 @@ class RandomEffectOptimizationProblem:
         if with_variances:
             from photon_ml_tpu.optim.problem import _VARIANCE_EPSILON
         # The blocks the solver programs run: a bucket, or the equal
-        # sub-blocks of one over the dense budget (the entity mesh and a
-        # values override address buckets by index and keep them whole).
-        foldable = self.mesh is None and values_override is None
-        blocks = self._solver_blocks(dataset, bank.shape[1], split=foldable)
-        # Same-shape block RUNS fold into one lax.scan dispatch (the
-        # profiled ~125 ms of host gaps between per-bucket dispatches at
-        # the config-4 shape, round 5); per-block paths keep
-        # handling the mesh / values_override / variances cases.
-        groups = self._block_groups(
-            blocks, fold=foldable and not with_variances and len(blocks) > 1
+        # sub-blocks of one over the dense budget (the entity mesh
+        # addresses buckets by index and keeps them whole). Same-shape
+        # block RUNS fold into one lax.scan dispatch (the profiled
+        # ~125 ms of host gaps between per-bucket dispatches at the
+        # config-4 shape, round 5); per-block paths keep handling the
+        # mesh and the variances cases.
+        override = values_override
+        groups = self._update_groups(
+            dataset, bank.shape[1], with_variances=with_variances
         )
+        extra = () if override is None else (override.operand,)
+        values_of = override.fn if override is not None else None
         if self.mesh is None and dataset.buckets:
             self._warm_solvers(self._bucket_plans(
                 bank, groups,
-                has_values_override=values_override is not None,
-                has_residual_offsets=residual_offsets is not None,
+                override=override,
                 l1_d=l1_d, l2_d=l2_d, coordinate=coordinate,
             ))
         solved = default_registry().counter(
@@ -1250,6 +1385,7 @@ class RandomEffectOptimizationProblem:
                 ) = self._stacked_group_args(
                     dataset, members,
                     with_residuals=residual_offsets is not None,
+                    with_values=override is None,
                 )
                 if residual_offsets is not None:
                     off_s = jnp.where(
@@ -1257,13 +1393,17 @@ class RandomEffectOptimizationProblem:
                         residual_offsets[jnp.maximum(rows_s, 0)],
                         0.0,
                     )
-                fused_scan = self._aot_cache.get(
-                    ("scan", kind, coordinate, bank.shape, ix_s.shape)
-                ) or self._solvers.fused_for(kind, coordinate, scan=True)
+                fused_scan = self._aot_cache.get(self._program_sig(
+                    kind, coordinate, bank.shape, ix_s.shape, override,
+                    scan=True,
+                )) or self._solvers.fused_for(
+                    kind, coordinate, scan=True,
+                    values_of=values_of,
+                )
                 with dispatch_span:
                     bank, it_sum, it_max, counts = fused_scan(
                         bank, codes_s, ix_s, v_s, lab_s, off_s, w_s,
-                        l1_d, l2_d,
+                        l1_d, l2_d, *extra,
                     )
                 n_reals.append(n_real)
                 stat_vecs.append(
@@ -1274,17 +1414,12 @@ class RandomEffectOptimizationProblem:
             (
                 ix_d, v_d, lab_d, w_d, off_d, rows_d, codes_d,
             ) = self._bucket_device_args(
-                bucket, with_values=values_override is None
+                bucket, with_values=override is None
             )
-            if values_override is not None:
-                # entries may be callables: the gather for bucket i is
-                # then dispatched only when its solve runs, capping the
-                # override's extra HBM at one bucket's values
-                v_d = values_override[bi]
-                if callable(v_d):
-                    v_d = v_d()
-                if self.mesh is not None:
-                    (v_d,), _ = self._shard_entity_axis([v_d])
+            if override is not None and self.mesh is not None:
+                # the mesh path's solvers take values, not keys: this
+                # bucket's are made whole, beside no other's
+                v_d = override.fn(override.operand, v_d)
             if residual_offsets is not None:
                 off_d = self._bucket_offsets(
                     bi, bucket, rows_d, residual_offsets, routed, router
@@ -1293,13 +1428,17 @@ class RandomEffectOptimizationProblem:
                 # fused path: gather + solve + scatter + tracker reductions
                 # in one dispatch; AOT-warmed programs run their compiled
                 # executable directly
-                fused = self._aot_cache.get(
-                    (kind, coordinate, bank.shape, bucket.indices.shape)
-                ) or self._solvers.fused_for(kind, coordinate)
+                fused = self._aot_cache.get(self._program_sig(
+                    kind, coordinate, bank.shape, bucket.indices.shape,
+                    override,
+                )) or self._solvers.fused_for(
+                    kind, coordinate,
+                    values_of=values_of,
+                )
                 with dispatch_span:
                     bank, it_sum, it_max, counts = fused(
                         bank, codes_d, ix_d, v_d, lab_d, off_d, w_d,
-                        l1_d, l2_d,
+                        l1_d, l2_d, *extra,
                     )
             else:
                 # padded entities carry zero data: their solve converges at

@@ -44,6 +44,11 @@ class RandomEffectBucket:
     # MF latent view): the dense solvers then use X = values directly,
     # skipping the [E, S, k, D] densify broadcast entirely
     identity_indices: bool = False
+    # int32 [E_b, S_b], -1 pad: what a values override makes a slot's
+    # values from (game/random_effect.ValuesOverride; an ALS half-step:
+    # the partner entity's code). Such a bucket stores no values, and an
+    # identity one no indices: both are [E_b, S_b, 0]
+    override_keys: Optional[np.ndarray] = None
 
     @property
     def num_entities(self) -> int:
@@ -332,6 +337,13 @@ def build_random_effect_dataset(
                 labels=b_lab,
                 offsets=b_off,
                 weights=b_w,
+                # observed, not configured: a ONE-feature local space (an
+                # intercept-only shard: a bias per entity) IS the arange
+                # of itself, so X is values, to the bit (a sum of one
+                # term; a padding slot's value is zero)
+                identity_indices=bool(
+                    kk == D == 1 and not row_local_ix[br].any()
+                ),
             )
         )
 

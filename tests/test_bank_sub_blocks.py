@@ -38,7 +38,8 @@ from photon_ml_tpu.utils.index_map import IdentityIndexMap
 
 USERS, ROWS_A_USER = 44, 8  # 352 rows; 44 users = 3 sub-blocks of 15, one lane padded
 ITEMS, ROWS_AN_ITEM = 32, 11  # 11 rows an item pad to capacity 16
-D_RE, K_RE, D_FIXED, K_FIXED = 12, 4, 64, 6
+# (a local space of 16: capacity 16 is then no more than it, the dual kind)
+D_RE, K_RE, D_FIXED, K_FIXED = 16, 4, 64, 6
 N = USERS * ROWS_A_USER
 # what one entity costs the dense Newton solver: X [S, D] and the Gram [S, S]
 USER_BYTES = (ROWS_A_USER * D_RE + ROWS_A_USER * ROWS_A_USER) * 4

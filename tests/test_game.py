@@ -836,10 +836,10 @@ class TestMatrixFactorization:
             )
 
     def test_cap_class_merge_bounds_padding(self, rng):
-        """The MF bucket cap-class merge (fewer distinct solver programs)
-        must never pad an entity's sample capacity more than 4x — a
-        heavy-tailed count distribution where no class holds 25% of
-        entities must not collapse everything onto the largest class."""
+        """The MF capacity classes (a class a power of two) must never pad
+        an entity's sample capacity more than 4x — a heavy-tailed count
+        distribution where no class holds 25% of entities must not
+        collapse everything onto the largest class."""
         # entity i gets ~2^(i mod 10) ratings: every cap class ~10%
         counts = [2 ** (i % 10) for i in range(40)]
         rows = np.repeat(np.arange(40, dtype=np.int32), counts)
@@ -869,7 +869,7 @@ class TestMatrixFactorization:
         )
         row_codes = ds.entity_codes["userId"]
         col_codes = ds.entity_codes["itemId"]
-        view, _ = mf._side_structure("row", row_codes, col_codes, 40)
+        view = mf._side_structure("row", row_codes, col_codes, 40)
         per_entity = np.bincount(
             row_codes[(ds.weights > 0) & (row_codes >= 0)], minlength=40
         )

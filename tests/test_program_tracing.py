@@ -246,7 +246,7 @@ def test_a_cd_iteration_files_every_span_once_under_its_parent(rng):
     assert named["fit.dispatch"][0].attrs == {
         "kernel": "scatter", "fit_cache_hit": True}
     assert {s.attrs["kind"] for s in named["bank.dispatch"]} <= {
-        "newton", "dense", "sparse"}
+        "newton", "primal", "dense", "sparse"}
     assert all(
         s.attrs["entities"] > 0 and s.attrs["capacity"] > 0
         for s in named["bank.dispatch"]
