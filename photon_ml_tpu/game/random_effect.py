@@ -25,7 +25,6 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.scipy.linalg import cho_solve
 
 from photon_ml_tpu.game.random_effect_data import (
     RandomEffectBucket,
@@ -35,6 +34,7 @@ from photon_ml_tpu.obs.registry import default_registry
 from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.obs.trace import traced as obs_traced
 from photon_ml_tpu.ops.losses import PointwiseLoss
+from photon_ml_tpu.ops.spd_solve import solve_path, spd_solve
 from photon_ml_tpu.optim.common import (
     CONVERGENCE_REASON_NAMES,
     FUNCTION_VALUES_WITHIN_TOLERANCE,
@@ -50,6 +50,7 @@ from photon_ml_tpu.optim.config import (
 )
 from photon_ml_tpu.optim.lbfgs import minimize_lbfgs, minimize_owlqn
 from photon_ml_tpu.optim.tron import minimize_tron
+from photon_ml_tpu.utils.backend import effective_platform
 
 Array = jnp.ndarray
 
@@ -511,7 +512,9 @@ def _bucket_solver(
                 (X' diag(w l'') X + l2 I) step = -(X' (w l') + l2 c),
 
             ``[D, D]`` an entity, rebuilt each iteration (one pass over
-            X on the MXU) and solved by a Cholesky factorization. The
+            X on the MXU) and solved by a Cholesky factorization
+            (:func:`photon_ml_tpu.ops.spd_solve.spd_solve`: under this
+            ``vmap`` one kernel with the entity on the lanes). The
             stopping rule and the line search are :func:`bank_newton`'s
             (:func:`_damped_newton`). A squared loss is solved by the
             first step and stops on the second. Every matmul names its
@@ -538,9 +541,7 @@ def _bucket_solver(
                             (((0,), (0,)), ((), ())), precision=hi,
                         ) + l2 * eye  # [D, D]
                     with jax.named_scope("bank.primal.solve"):
-                        step = -cho_solve(
-                            (jnp.linalg.cholesky(H), True), g_vec
-                        )
+                        step = -spd_solve(H, g_vec)
                     return x_dot(g_vec), step, x_dot(step)
 
                 return _damped_newton(
@@ -584,8 +585,6 @@ def _bucket_solver(
         return bank, jnp.sum(iters), jnp.max(iters), counts
 
     def _donate():
-        from photon_ml_tpu.utils.backend import effective_platform
-
         return (0,) if effective_platform() != "cpu" else ()
 
     def _named(name):
@@ -1368,16 +1367,28 @@ class RandomEffectOptimizationProblem:
             "entities the replicated bank updates solved, by coordinate "
             "and solver kind",
         )
+        systems = default_registry().counter(
+            "photon_bank_primal_systems_total",
+            "[D, D] systems (D >= 2) the primal kind's Newton steps "
+            "factor, one an entity, by coordinate and the way "
+            "ops/spd_solve solves a batch of them",
+        )
+        solve = solve_path(bank.shape[1], effective_platform())
         for members in groups:
             block = members[0]
             kind, bucket = block.kind, block.bucket
             n_real = sum(b.num_real for b in members)
             # counted where the kind is decided, on the host
             solved.inc(n_real, coordinate=coordinate or "", kind=kind)
+            attrs = {"coordinate": coordinate} if coordinate else {}
+            # a bias (D = 1) has no system to factor
+            if kind.startswith("primal") and solve != "division":
+                systems.inc(n_real, coordinate=coordinate or "", solve=solve)
+                attrs["solve"] = solve
             dispatch_span = obs_span(
                 "bank.dispatch", kind=kind, entities=n_real,
                 capacity=bucket.capacity, sub_blocks=block.sub_blocks,
-                **({"coordinate": coordinate} if coordinate else {}),
+                **attrs,
             )
             if len(members) > 1:
                 (

@@ -29,7 +29,17 @@ def enable_compilation_cache() -> str:
     directory is set in code. Otherwise the cache lives at the fixed
     ``<checkout>/.jax_cache`` (git-ignored) — the directory is part of
     the cache key, so it is never a temp name, pid or time. Safe to call
-    multiple times."""
+    multiple times.
+
+    A Pallas kernel's serialized body, which the key covers, carries its
+    operations' source locations, by default with ten frames of the stack
+    of whoever traced the kernel first. The bank's programs are traced
+    from several places (``prewarm``, a prefetched ``prepare``,
+    ``update_bank``, each through the solver pool's threads), so such a
+    program's key could change from run to run and the program compile
+    again. With ONE frame, the operation's own line in the kernel's file,
+    the bytes are the same for every caller; the kernels keep their
+    names on the device (``photon_*``), which come with the frame."""
     import jax
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -40,6 +50,7 @@ def enable_compilation_cache() -> str:
     # cache anything that took meaningful compile time (the 1 s floor
     # skips the many tiny programs)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     return path
 
 
@@ -48,8 +59,12 @@ def effective_platform() -> str:
 
     Honors ``jax.default_device`` scopes (returns "cpu" inside one even
     when a TPU plugin is installed) and only initializes the backend the
-    caller is about to use anyway.
+    caller is about to use anyway. Safe under a trace (a batching rule
+    that picks a kernel asks from inside ``jit``): the probe array is
+    made eagerly.
     """
+    import jax
     import jax.numpy as jnp
 
-    return next(iter(jnp.zeros(()).devices())).platform
+    with jax.ensure_compile_time_eval():
+        return next(iter(jnp.zeros(()).devices())).platform

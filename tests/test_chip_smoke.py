@@ -85,3 +85,6 @@ class TestCompilationCachePlacement:
         expected = os.path.join(REPO_ROOT, ".jax_cache")
         assert backend.enable_compilation_cache() == expected
         assert calls["jax_compilation_cache_dir"] == expected
+        # a kernel's bytes, and so a program's key, must not follow the
+        # caller's stack (tests/test_spd_solve.py reads the bytes)
+        assert calls["jax_traceback_in_locations_limit"] == 1

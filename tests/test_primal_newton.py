@@ -26,6 +26,7 @@ from photon_ml_tpu.game.random_effect_data import (
 from photon_ml_tpu.obs import trace as obs_trace
 from photon_ml_tpu.obs.registry import default_registry
 from photon_ml_tpu.ops.losses import LINEAR, LOGISTIC
+from photon_ml_tpu.ops.spd_solve import MAX_LANE_DIM, solve_path
 from photon_ml_tpu.optim.config import (
     OptimizerConfig,
     OptimizerType,
@@ -303,3 +304,71 @@ def test_an_override_on_the_sparse_layout_still_solves(rng):
             bank0, ds, values_override=override)
         dense, _ = _problem().update_bank(bank0, ds, values_override=override)
     np.testing.assert_allclose(np.asarray(sparse), np.asarray(dense), atol=5e-4)
+
+
+def test_a_rank_64_half_step_block_is_the_ridge_solution_in_two_iterations(rng):
+    """The half-step's own shape: 64 features at capacity 256, squared
+    loss: the first Newton step solves it, the second confirms it."""
+    S, D = 256, 64
+    ix, v, lab, off, w = _block(rng, 5, S, D, identity=True)
+    got, iters, _ = _solve("primal_id", LINEAR, ix, v, lab, off, w, D, 0.7)
+    np.testing.assert_allclose(
+        got, _ridge(ix, v, lab, off, w, D, 0.7), atol=1e-5)
+    assert (iters == 2).all()
+
+
+@pytest.mark.parametrize("dim,platform,path", [
+    (1, "tpu", "division"), (1, "cpu", "division"),
+    (2, "tpu", "lanes"), (64, "tpu", "lanes"), (MAX_LANE_DIM, "tpu", "lanes"),
+    (MAX_LANE_DIM + 1, "tpu", "xla"), (1000, "tpu", "xla"),
+    (2, "cpu", "xla"), (64, "cpu", "xla"),
+])
+def test_the_shape_and_the_platform_name_the_way_a_batch_is_solved(
+        dim, platform, path):
+    assert solve_path(dim, platform) == path
+
+
+def test_the_kernels_bound_holds_twice_the_half_steps_rank():
+    assert MAX_LANE_DIM >= 128 and MAX_LANE_DIM % 8 == 0
+
+
+def _primal_bucket(rng, E, S, D):
+    ix, v, lab, off, w = _block(rng, E, S, D)
+    rows = np.arange(E * S, dtype=np.int32).reshape(E, S)
+    return RandomEffectBucket(
+        entity_codes=np.arange(E, dtype=np.int32), row_index=rows,
+        indices=ix, values=v, labels=lab, offsets=off, weights=w)
+
+
+def test_the_primal_systems_are_counted_under_the_solve_that_ran(rng):
+    """``photon_bank_primal_systems_total``: a primal block's entities
+    under the way their systems were solved (XLA's Cholesky on the CPU
+    these tests run on), the span saying the same; a bias (D = 1) has no
+    system to factor and counts nothing."""
+    systems = default_registry().counter("photon_bank_primal_systems_total")
+
+    def counted(coordinate):
+        return {
+            solve: systems.value(coordinate=coordinate, solve=solve)
+            for solve in ("lanes", "xla", "division")
+        }
+
+    S = 32
+    for coordinate, D, want in [
+        ("factor", 4, {"lanes": 0, "xla": 3, "division": 0}),
+        ("bias", 1, {"lanes": 0, "xla": 0, "division": 0}),
+    ]:
+        ds = _dataset([_primal_bucket(rng, 3, S, D)], 3, D, 3 * S)
+        assert _problem()._bucket_kind(ds.buckets[0], D) == "primal"
+        before = counted(coordinate)
+        with obs_trace.tracing_scope(True):
+            obs_trace.tracer().clear()
+            _problem().update_bank(
+                jnp.zeros((3, D), jnp.float32), ds, coordinate=coordinate)
+            (dispatch,) = [
+                s.attrs for s in obs_trace.tracer().drain()
+                if s.name == "bank.dispatch"
+            ]
+        after = counted(coordinate)
+        assert {k: after[k] - before[k] for k in after} == want
+        assert dispatch.get("solve") == ("xla" if D > 1 else None)

@@ -26,6 +26,8 @@ Sections (SURVEY §4: test on the real execution target):
   9. the GAME driver's own fixed-effect coordinate at chip_smoke.py's
      leg-B width: dispatches kernel=tiled, builds its schedules once,
      and lands where the scatter objective does
+ 10. ops/spd_solve under vmap: the lanes kernel (not XLA's Cholesky) at
+     a width that pads and at the ALS half-step's, vs a float64 solve
 
 Run with:  PHOTON_TPU_TESTS=1 python -m pytest tests/test_tiled_tpu.py -v
 """
@@ -460,6 +462,32 @@ def game_driver_fixed_effect_is_tiled():
         assert at_w <= scatter_at_w * (1 + 2e-2), (at_w, scatter_at_w)
 
 
+# ---- 10. the batched SPD solve: the lanes kernel vs float64 ------------
+def spd_solve_lanes():
+    from photon_ml_tpu.ops import spd_solve as mod
+
+    r = np.random.default_rng(10)
+    for D, E in ((21, 300), (64, 1000)):
+        assert mod.solve_path(D, mod.effective_platform()) == "lanes"
+        q, _ = np.linalg.qr(r.normal(size=(E, D, D)))
+        spectrum = np.geomspace(np.ones(E), np.geomspace(10.0, 1e4, E), D, axis=1)
+        H = ((q * spectrum[:, None, :]) @ np.swapaxes(q, 1, 2)).astype(np.float32)
+        g = r.normal(size=(E, D)).astype(np.float32)
+        solve = jax.jit(jax.vmap(mod.spd_solve))
+        assert "tpu_custom_call" in solve.lower(H, g).compile().as_text()
+        want = np.linalg.solve(H.astype(np.float64), g.astype(np.float64)[..., None])[..., 0]
+
+        def errors(x):
+            x = np.asarray(x)
+            return np.linalg.norm(x - want, axis=1) / np.linalg.norm(want, axis=1)
+
+        got = errors(solve(H, g))
+        cho = errors(jax.vmap(mod._cho_solve)(jnp.asarray(H), jnp.asarray(g)))
+        cond = np.linalg.cond(H.astype(np.float64))
+        room = 4 * np.maximum(cho, 0.1 * np.finfo(np.float32).eps * cond)
+        assert (got <= room).all(), ("spd_solve", D, got.max(), cho.max())
+
+
 # Every section runs even when an earlier one fails, so one call to the
 # chip reports all of them; the exit status says whether any failed.
 failed = []
@@ -485,6 +513,7 @@ _MARKERS = {
     "feature_sharded_1x1_mesh_fit": "TPU_FEATURE_SHARDED_OK",
     "game_cd_step": "TPU_GAME_CD_OK",
     "game_driver_fixed_effect_is_tiled": "TPU_GAME_DRIVER_FE_TILED_OK",
+    "spd_solve_lanes": "TPU_SPD_SOLVE_OK",
 }
 
 pytestmark = pytest.mark.skipif(
