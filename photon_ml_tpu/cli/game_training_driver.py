@@ -627,6 +627,17 @@ class GameTrainingDriver:
         (data, model) mesh keeps the scatter layout until its tiled one has
         run on the chip through this driver (PERF.md section 7), and
         ``fe_kernel`` is the batched lambda grid's seam to do the same."""
+        p = self.params
+        with obs_span(
+            "game.build_coordinates",
+            coordinates=len(p.fixed_effect_data_configs)
+            + len(p.random_effect_data_configs) + len(p.mf_configs),
+        ):
+            return self._coordinates_of(
+                dataset, re_datasets, opt_combo, fe_kernel
+            )
+
+    def _coordinates_of(self, dataset, re_datasets, opt_combo, fe_kernel):
         from photon_ml_tpu.parallel.mesh import MODEL_AXIS
 
         p = self.params

@@ -1132,7 +1132,21 @@ class MatrixFactorizationCoordinate(Coordinate):
         hit = cache.get(side)
         if hit is not None:
             return hit
+        with obs_span("mf.structure_build", side=side) as build_span:
+            view = self._build_side_structure(
+                side, solve_codes, fixed_codes, num_solved
+            )
+            build_span.set(
+                entities=sum(b.num_entities for b in view.buckets),
+                classes=len(view.buckets),
+                slots=sum(b.row_index.size for b in view.buckets),
+            )
+        cache[side] = view
+        return view
 
+    def _build_side_structure(
+        self, side: str, solve_codes, fixed_codes, num_solved
+    ):
         from photon_ml_tpu.game.config import (
             ProjectorType,
             RandomEffectDataConfiguration,
@@ -1233,7 +1247,6 @@ class MatrixFactorizationCoordinate(Coordinate):
         for state, count in (("rating", ratings), ("padding", held - ratings)):
             if count:
                 slots.inc(count, coordinate=self.name, side=side, state=state)
-        cache[side] = view
         return view
 
     def _als_side(

@@ -31,6 +31,7 @@ from photon_ml_tpu.game.random_effect_data import (
     RandomEffectDataset,
 )
 from photon_ml_tpu.obs.registry import default_registry
+from photon_ml_tpu.obs.trace import bound_to_current_span
 from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.obs.trace import traced as obs_traced
 from photon_ml_tpu.ops.losses import PointwiseLoss
@@ -1272,11 +1273,18 @@ class RandomEffectOptimizationProblem:
         ]
         if not fresh:
             return
+        # (the pool's jax.trace / jax.lower / jax.compile spans parent to
+        # this one and keep their own thread ids; the main thread waits)
         with (
-            obs_span("bank.warm_solvers", programs=len(fresh)),
+            obs_span(
+                "bank.warm_solvers", programs=len(fresh),
+                cached=len(plans) - len(fresh),
+            ),
             ThreadPoolExecutor(min(8, len(fresh))) as pool,
         ):
-            compiled = list(pool.map(lambda item: item[1](), fresh))
+            compiled = list(pool.map(
+                bound_to_current_span(lambda item: item[1]()), fresh
+            ))
         for (sig, _), exe in zip(fresh, compiled):
             # FIFO-bounded: the cache lives on the SHARED solver
             # namespace (process lifetime via _SOLVER_CACHE), so a

@@ -69,6 +69,7 @@ from photon_ml_tpu.obs.trace import (  # noqa: F401
     epoch,
     epoch_now,
     export_chrome_trace,
+    host_timings,
     new_trace_id,
     record_span,
     reset_tracer,
@@ -150,7 +151,6 @@ class ObsSession:
     def _register_process_views(self) -> None:
         from photon_ml_tpu.parallel import overlap
         from photon_ml_tpu.reliability import reliability_metrics
-        from photon_ml_tpu.utils.profiling import host_timings
 
         reg = self.registry
         reg.register_view("host_timings", host_timings)

@@ -21,24 +21,28 @@ Design constraints, in priority order:
   stay on in production without adding a contention point to the
   batcher's device section. ``drain()`` swaps the ring under the
   tracer's own lock (never taken by ``record``/``end``).
-- **Off by default, free when off.** ``tracing_enabled()`` is one
-  module-global read; every instrumentation site calls ``span()`` /
-  ``start_span()`` which return the no-op singleton when disabled.
-  What the training spans cost when they are on, measured on the chip
-  (TPU v5 lite, medians of 6 runs; ``PERF.md`` section 6, "What
-  tracing costs"): a coordinate-descent step of 8.90567 s takes
-  +0.002% with the ring on and +0.029% under the profiler, at 15 spans
-  a step; an L-BFGS iteration of 257.650 ms moves by less than its
-  run-to-run spread (0.014%) either way, at 4 spans a fit.
-- **One span system, two sinks.** ``span()`` / ``traced()`` (the
-  coarse, training-side API) file into the ring when tracing is on AND
-  open a profiler annotation named ``photon.<span name>`` through the
-  factory :func:`set_annotation_factory` was given, so that whenever a
-  ``jax.profiler`` session is live (``--profile-dir``) the span lies in
-  the same ``.xplane.pb`` as the device lines, on the profiler's
-  clock. This package never imports jax: ``utils/profiling.py``
-  installs the factory. ``start_span`` / ``record_span`` (the
-  request path) open no annotation and pay nothing new.
+- **The request path is off by default, free when off.**
+  ``tracing_enabled()`` is one module-global read; ``start_span()`` /
+  ``record_span()`` (what ``serving/`` calls, thousands a second) return
+  the no-op singleton / file nothing when disabled.
+- **The training side always records.** ``span()`` / ``traced()`` /
+  ``record_elapsed()`` (the coarse API: tens of spans a step, a few
+  thousand a set-up) file into the ring with NO switch, so that set-up
+  and every untraced step can be read after the fact on
+  ``time.perf_counter()``, the clock a benchmark cuts its window on.
+  What that costs, measured on the chip (TPU v5 lite, medians of 6
+  runs; ``PERF.md`` section 6): a coordinate-descent step of 8.90567 s
+  takes +0.002% with the ring on and +0.029% under the profiler, at 15
+  spans a step; an L-BFGS iteration of 257.650 ms moves by less than
+  its run-to-run spread (0.014%) either way, at 4 spans a fit.
+- **One span system, two sinks.** ``span()`` / ``traced()`` file into
+  the ring AND open a profiler annotation named ``photon.<span name>``
+  through the factory :func:`set_annotation_factory` was given, so that
+  whenever a ``jax.profiler`` session is live (``--profile-dir``) the
+  span lies in the same ``.xplane.pb`` as the device lines, on the
+  profiler's clock. This package never imports jax:
+  ``utils/profiling.py`` installs the factory. ``start_span`` /
+  ``record_span`` open no annotation and pay nothing new.
 
 Timestamps are ``time.perf_counter()`` pairs mapped onto the wall clock
 through one (wall, perf) epoch captured at import, so spans from one
@@ -75,6 +79,9 @@ __all__ = [
     "ANNOTATION_PREFIX",
     "start_span",
     "record_span",
+    "record_elapsed",
+    "bound_to_current_span",
+    "host_timings",
     "traced",
     "expand_spans",
     "TRACES_ATTR",
@@ -136,7 +143,8 @@ _PROC_NONCE = os.urandom(3).hex()  # photon: entropy(boot nonce; id uniqueness a
 _TRACE_PREFIX = f"t{os.getpid():x}.{_PROC_NONCE}-"  # photon: entropy(pid+nonce id prefix; cross-process uniqueness, not content)
 _SPAN_PREFIX = f"s{os.getpid():x}.{_PROC_NONCE}-"  # photon: entropy(pid+nonce id prefix; cross-process uniqueness, not content)
 
-# Enablement is a single module global: the disabled fast path is one
+# The REQUEST path's enablement (start_span / record_span; span() has no
+# switch) is a single module global: the disabled fast path is one
 # read + branch. set_tracing is the only writer (driver startup / test
 # scopes) — a torn read is impossible for a bool.
 _ENABLED = os.environ.get("PHOTON_TRACE", "").strip().lower() in (
@@ -494,8 +502,8 @@ def set_annotation_factory(factory: Optional[Callable[..., object]]) -> None:
 
 
 class _OpenSpan:
-    """What ``span()`` yields: the ring span's ids (None with the ring
-    off) and ``set()``, which reaches both sinks."""
+    """What ``span()`` yields: the ring span's ids and ``set()``, which
+    reaches both sinks."""
 
     __slots__ = ("span_id", "trace_id", "_span", "_annotation")
 
@@ -514,9 +522,42 @@ class _OpenSpan:
 
 
 def current_span():
-    """The innermost open ``span()`` here, or None (also when tracing
-    is off: only ring spans have ids to parent to)."""
+    """The innermost open ``span()`` of this thread or task, or None."""
     return _CURRENT.get()
+
+
+def bound_to_current_span(fn: Callable) -> Callable:
+    """``fn`` for another thread (a pool's worker), whose spans then
+    parent to the span open HERE, where the work was queued; they keep
+    the worker's thread id."""
+    parent = _CURRENT.get()
+
+    def run(*args, **kwargs):
+        token = _CURRENT.set(parent)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _CURRENT.reset(token)
+
+    return run
+
+
+def record_elapsed(
+    name: str, t0: float, t1: float, *, parent: Optional[Span] = None,
+    **attrs,
+) -> Span:
+    """The training side's span whose window already elapsed (a compile
+    stage ``jax.monitoring`` reports at its end, a cache's load): filed
+    always, under ``parent`` or else the innermost open span. Returns
+    the span, so that a child can be filed under it."""
+    if parent is None:
+        parent = _CURRENT.get()
+    return _TRACER.record(
+        name, t0, t1,
+        trace_id=parent.trace_id if parent is not None else None,
+        parent_id=parent.span_id if parent is not None else None,
+        attrs=attrs,
+    )
 
 
 @contextmanager
@@ -528,21 +569,20 @@ def span(
     **attrs,
 ):
     """``with span("cd.iteration", iteration=3) as s:`` — times the
-    block; ``s.set(objective=...)`` attaches result attrs. With the ring
-    on, the span is filed under the innermost open span (or the given
-    parent). Ring on or off, the block also runs inside the profiler
-    annotation ``photon.<name>`` carrying ``attrs``."""
-    s = NULL_SPAN
-    token = None
-    if _ENABLED:
-        if parent_id is None:
-            parent = _CURRENT.get()
-            if parent is not None:
-                parent_id = parent.span_id
-                if trace_id is None:
-                    trace_id = parent.trace_id
-        s = start_span(name, trace_id=trace_id, parent_id=parent_id, **attrs)
-        token = _CURRENT.set(s)
+    block; ``s.set(objective=...)`` attaches result attrs. The span is
+    filed, always, under the innermost open span (or the given parent),
+    and the block also runs inside the profiler annotation
+    ``photon.<name>`` carrying ``attrs``."""
+    if parent_id is None:
+        parent = _CURRENT.get()
+        if parent is not None:
+            parent_id = parent.span_id
+            if trace_id is None:
+                trace_id = parent.trace_id
+    s = _TRACER.start(
+        name, trace_id=trace_id, parent_id=parent_id, attrs=attrs
+    )
+    token = _CURRENT.set(s)
     annotation = (
         _ANNOTATE(ANNOTATION_PREFIX + name, **attrs)
         if _ANNOTATE is not None else None
@@ -554,26 +594,34 @@ def span(
     finally:
         if annotation is not None:
             annotation.__exit__(None, None, None)
-        if token is not None:
-            _CURRENT.reset(token)
+        _CURRENT.reset(token)
         s.end()
 
 
+# The host's one-off costs beside the device work: the tile-schedule
+# cache's hashes, loads, builds and stores (``schedule_cache.<bucket>``)
+# and the overlap layer's fetches and waits (``overlap.<what>``).
+HOST_TIMING_PREFIXES = ("schedule_cache.", "overlap.")
+
+
+def host_timings() -> Dict[str, float]:
+    """Seconds by span name of the ring's :data:`HOST_TIMING_PREFIXES`
+    spans: the ``host_timings`` view of an ``--obs-dir`` snapshot."""
+    out: Dict[str, float] = {}
+    for s in _TRACER.snapshot():
+        if s.t1 is not None and s.name.startswith(HOST_TIMING_PREFIXES):
+            out[s.name] = out.get(s.name, 0.0) + (s.t1 - s.t0)
+    return out
+
+
 def traced(name: str, **span_attrs):
-    """Decorator: the whole call becomes one span (streaming scan/stage
-    passes and other coarse phases). With the ring off it builds no span:
-    one flag read, and the profiler annotation (itself a flag test
-    outside a profiler session) where a factory is installed."""
+    """Decorator: the whole call becomes one :func:`span` (streaming
+    scan/stage passes and other coarse phases)."""
     import functools
 
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            if not _ENABLED:
-                if _ANNOTATE is None:
-                    return fn(*args, **kwargs)
-                with _ANNOTATE(ANNOTATION_PREFIX + name, **span_attrs):
-                    return fn(*args, **kwargs)
             with span(name, **span_attrs):
                 return fn(*args, **kwargs)
 

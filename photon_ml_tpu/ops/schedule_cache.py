@@ -153,11 +153,14 @@ def _bump(counter: str, n: int = 1) -> None:
 
 
 def _add_time(bucket: str, seconds: float) -> None:
+    """``seconds``, just elapsed, into the stats AND onto the span ring as
+    ``schedule_cache.<bucket>`` (the ``host_timings`` view sums those)."""
     with _stats_lock:
         setattr(_stats, bucket, getattr(_stats, bucket) + seconds)
-    from photon_ml_tpu.utils.profiling import record_host_timing
+    from photon_ml_tpu.obs.trace import record_elapsed
 
-    record_host_timing(f"schedule_cache.{bucket}", seconds)
+    t1 = time.perf_counter()
+    record_elapsed(f"schedule_cache.{bucket}", t1 - seconds, t1)
 
 
 def record_build_seconds(seconds: float) -> None:

@@ -682,7 +682,35 @@ def _build_schedule(
             num_out_blocks=num_out_blocks, digest=digest,
         )
         sp.set(cache="miss" if _sc.stats().builds > builds else "hit")
-        return _Schedule(*map(jnp.asarray, arrays))
+        return _upload_schedule(arrays)
+
+
+def _upload_schedule(arrays) -> _Schedule:
+    """A built schedule's host arrays onto the device, one at a time (an
+    iterable may make each only when asked), under ``tiled.upload``
+    (``bytes``)."""
+    from photon_ml_tpu.obs.trace import span
+
+    with span("tiled.upload") as sp:
+        on_device, total = [], 0
+        for a in arrays:
+            total += int(a.nbytes)
+            on_device.append(jnp.asarray(a))
+        sp.set(bytes=total)
+        return _Schedule(*on_device)
+
+
+def _dense_split_spanned(rows, feats, vals, num_rows, num_cols, limit):
+    """``_split_dense_columns`` under ``tiled.dense_split`` (``entries``
+    it read, ``dense_columns`` it found)."""
+    from photon_ml_tpu.obs.trace import span
+
+    with span("tiled.dense_split", entries=len(vals)) as sp:
+        out = _split_dense_columns(
+            rows, feats, vals, num_rows, num_cols, limit
+        )
+        sp.set(dense_columns=len(out[1]))
+    return out
 
 
 class TiledSparseBatch(NamedTuple):
@@ -772,7 +800,22 @@ def build_tiled_batch(
     """COO triples + per-row arrays -> tiled batch. Entries with zero value
     are dropped (they contribute nothing); up to ``max_dense_columns``
     dense columns go beside the schedules (``_split_dense_columns``), the
-    tile parameters resolved from what is left."""
+    tile parameters resolved from what is left. The whole build is the
+    span ``tiled.batch_build`` on the calling thread, which waits for the
+    two schedules' ``tiled.schedule_build`` on a pool's threads."""
+    from photon_ml_tpu.obs.trace import span
+
+    with span("tiled.batch_build", rows=int(labels.shape[0]), shards=1):
+        return _build_tiled_batch(
+            rows, feats, vals, labels, offsets, weights, dim,
+            params=params, max_dense_columns=max_dense_columns,
+        )
+
+
+def _build_tiled_batch(
+    rows, feats, vals, labels, offsets, weights, dim, *, params,
+    max_dense_columns,
+) -> TiledSparseBatch:
     nz = vals != 0
     if not nz.all():
         rows, feats, vals = rows[nz], feats[nz], vals[nz]
@@ -780,7 +823,7 @@ def build_tiled_batch(
     n = labels.shape[0]
     n_pad = max(((n + win - 1) // win) * win, win)
     d_pad = max(((dim + win - 1) // win) * win, win)
-    vals, dense_cols, dense_vals = _split_dense_columns(
+    vals, dense_cols, dense_vals = _dense_split_spanned(
         rows, feats, vals, n, dim, max_dense_columns
     )
     entries = len(vals) - int(np.count_nonzero(dense_vals))
@@ -790,6 +833,7 @@ def build_tiled_batch(
     # GIL — overlap them (halves the dominant host cost of cold training)
     from concurrent.futures import ThreadPoolExecutor
 
+    from photon_ml_tpu.obs.trace import bound_to_current_span
     from photon_ml_tpu.ops import schedule_cache as _sc
 
     # both passes key off the same COO triple: hash it once, up front
@@ -798,14 +842,15 @@ def build_tiled_batch(
         if _sc.resolve_cache_dir() is not None else None
     )
     builds = _sc.stats().builds
+    build = bound_to_current_span(_build_schedule)
     with ThreadPoolExecutor(2) as pool:
         fz = pool.submit(
-            _build_schedule, rows, feats, vals, params=params,
+            build, rows, feats, vals, params=params,
             sort_by_feature_block=False, num_out_blocks=n_pad // win,
             digest=digest, entries=entries, dense_columns=len(dense_cols),
         )
         fg = pool.submit(
-            _build_schedule, rows, feats, vals, params=params,
+            build, rows, feats, vals, params=params,
             sort_by_feature_block=True, num_out_blocks=d_pad // win,
             digest=digest, entries=entries, dense_columns=len(dense_cols),
         )
@@ -907,7 +952,18 @@ def _sparse_coo(batch) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
     """SparseBatch -> filtered COO triples (+ real row count): zero values
     and weight-0 (padding) rows dropped. Row-major order. Where nothing is
     dropped (no padding, no explicit zeros) no entry is gathered: at 34M
-    entries the masked copies were most of a schedule build's host time."""
+    entries the masked copies were most of a schedule build's host time.
+    Under ``tiled.coo`` (``entries``): the batch's arrays pulled to the
+    host and a row id made for every entry, 5 s of 168M entries."""
+    from photon_ml_tpu.obs.trace import span
+
+    with span("tiled.coo") as sp:
+        out = _sparse_coo_unspanned(batch)
+        sp.set(entries=len(out[2]))
+    return out
+
+
+def _sparse_coo_unspanned(batch):
     indices = np.asarray(batch.indices)
     values = np.asarray(batch.values)
     weights = np.asarray(batch.weights)
@@ -1001,14 +1057,13 @@ def _concat_cell_schedules(
         g_parts = list(pool.map(
             lambda p: _pad_schedule_np(p, gg, g_out_blocks, sg), g_parts
         ))
-    z_sched = _Schedule(*(
-        jnp.asarray(np.concatenate([p[i] for p in z_parts]))
-        for i in range(9)
-    ))
-    g_sched = _Schedule(*(
-        jnp.asarray(np.concatenate([p[i] for p in g_parts]))
-        for i in range(9)
-    ))
+    # (a generator: one concatenated host copy alive at a time)
+    z_sched = _upload_schedule(
+        np.concatenate([p[i] for p in z_parts]) for i in range(9)
+    )
+    g_sched = _upload_schedule(
+        np.concatenate([p[i] for p in g_parts]) for i in range(9)
+    )
     return z_sched, g_sched, np.concatenate([p[5] for p in g_parts])
 
 
@@ -1040,6 +1095,21 @@ def build_sharded_tiled_batch(
     each device holding its own rows' segment.
     """
     from photon_ml_tpu.obs.trace import span
+
+    with span(
+        "tiled.batch_build", rows=int(batch.labels.shape[0]),
+        shards=n_shards,
+    ):
+        return _build_sharded_tiled_batch(
+            batch, dim, n_shards, params=params, mesh=mesh, axis=axis,
+            max_dense_columns=max_dense_columns,
+        )
+
+
+def _build_sharded_tiled_batch(
+    batch, dim, n_shards, *, params, mesh, axis, max_dense_columns
+) -> TiledSparseBatch:
+    from photon_ml_tpu.obs.trace import span
     from photon_ml_tpu.ops import schedule_cache as _sc
 
     win = params.window
@@ -1047,7 +1117,7 @@ def build_sharded_tiled_batch(
     rows_per = -(-n // n_shards)
     R = max(((rows_per + win - 1) // win) * win, win)
     d_pad = max(((dim + win - 1) // win) * win, win)
-    vals, dense_cols, dense_vals = _split_dense_columns(
+    vals, dense_cols, dense_vals = _dense_split_spanned(
         rows, feats, vals, n, dim, max_dense_columns
     )
     entries = len(vals) - int(np.count_nonzero(dense_vals))
@@ -1085,7 +1155,10 @@ def build_sharded_tiled_batch(
         **_dense_leaves(dense_cols, dense_vals, R, n_shards),
     )
     if mesh is not None:
-        out = _place_data_sharded(out, mesh, axis or DATA_AXIS)
+        with span("tiled.upload", bytes=sum(
+            int(a.nbytes) for a in jax.tree.leaves(out)
+        )):
+            out = _place_data_sharded(out, mesh, axis or DATA_AXIS)
     return out
 
 

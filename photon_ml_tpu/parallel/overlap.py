@@ -49,6 +49,8 @@ import threading
 from contextlib import contextmanager
 from typing import Any, Callable, List, Optional, Sequence
 
+from photon_ml_tpu.obs.trace import bound_to_current_span, span as obs_span
+
 __all__ = [
     "overlap_enabled",
     "set_overlap",
@@ -184,16 +186,11 @@ class Deferred:
 def fetch_all(deferreds: Sequence[Optional[Deferred]]) -> None:
     """Materialize every pending Deferred with ONE batched device_get
     (one transfer round trip for the whole list)."""
-    import time
-
-    from photon_ml_tpu.utils.profiling import record_host_timing
-
     pending = [d for d in deferreds if d is not None and not d.done]
     if not pending:
         return
-    t0 = time.perf_counter()
-    host = device_get([d._device for d in pending])
-    record_host_timing("overlap_fetch_s", time.perf_counter() - t0)
+    with obs_span("overlap.fetch", arrays=len(pending)):
+        host = device_get([d._device for d in pending])
     for d, h in zip(pending, host):
         d._deliver(h)
 
@@ -254,31 +251,24 @@ class _InlineFuture:
 
 def submit(fn: Callable, *args, **kwargs):
     """Run ``fn`` on the prep worker (overlap on) or inline (overlap
-    off); returns a future either way."""
+    off); returns a future either way. The worker's spans parent to the
+    span open here, where the work was queued."""
     if not overlap_enabled():
         return _InlineFuture(fn, args, kwargs)
-    return _pool("prep").submit(fn, *args, **kwargs)
+    return _pool("prep").submit(bound_to_current_span(fn), *args, **kwargs)
 
 
 def wait(future) -> Any:
     """Block on a future from :func:`submit` (None passes through).
-    Wait time accrues to the ``overlap_prep_wait_s`` host-timing bucket —
-    ~0 means the prep fully hid under the device work."""
+    The wait is the span ``overlap.prep_wait`` (summed in the
+    ``host_timings`` view) — ~0 means the prep fully hid under the device
+    work."""
     if future is None:
         return None
     if isinstance(future, _InlineFuture):
         return future.result()
-    import time
-
-    from photon_ml_tpu.utils.profiling import record_host_timing
-
-    t0 = time.perf_counter()
-    try:
+    with obs_span("overlap.prep_wait"):
         return future.result()
-    finally:
-        record_host_timing(
-            "overlap_prep_wait_s", time.perf_counter() - t0
-        )
 
 
 # -- async artifact IO -------------------------------------------------------
@@ -315,14 +305,9 @@ def drain_io() -> None:
     Call before anything that requires the artifacts — preemption stop,
     checkpoint restore, run exit. The FIRST recorded worker failure
     re-raises here with its artifact name (later queued writes still
-    drained first — write order stays FIFO even across a failure). Wait
-    time accrues to the ``overlap_io_wait_s`` host-timing bucket."""
-    import time
-
-    from photon_ml_tpu.utils.profiling import record_host_timing
-
-    t0 = time.perf_counter()
-    try:
+    drained first — write order stays FIFO even across a failure). The
+    wait is the span ``overlap.io_wait``."""
+    with obs_span("overlap.io_wait"):
         while True:
             with _LOCK:
                 if not _IO_PENDING:
@@ -339,5 +324,3 @@ def drain_io() -> None:
             + (f" for {artifact!r}" if artifact else "")
             + f": {exc}"
         ) from exc
-    finally:
-        record_host_timing("overlap_io_wait_s", time.perf_counter() - t0)

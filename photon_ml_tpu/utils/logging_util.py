@@ -14,6 +14,8 @@ import time
 from contextlib import contextmanager
 from typing import Dict, Optional
 
+from photon_ml_tpu.obs.trace import span as obs_span
+
 
 class PhotonLogger:
     """File+console logger bound to a job output directory."""
@@ -75,9 +77,12 @@ class Timer:
 
     @contextmanager
     def time(self, name: str):
+        """The stage as a timer AND as the span ``driver.<name>``: on the
+        ring, and in a ``--profile-dir`` trace beside the device lines."""
         self.start(name)
         try:
-            yield
+            with obs_span("driver." + name):
+                yield
         finally:
             self.stop(name)
 

@@ -83,12 +83,28 @@ class TestTraceCore:
         assert names == [f"s{i}" for i in range(12, 20)]  # oldest evicted
 
     def test_disabled_tracing_is_free_and_silent(self):
+        """The switch is the request path's: off, ``start_span`` hands out
+        the one no-op span and ``record_span`` files nothing. The training
+        side's ``span()`` has no switch: it files, parents to the span
+        open around it, and ``current_span()`` is it."""
+        from photon_ml_tpu.obs.trace import current_span, record_span, span
+
         assert not tracing_enabled()
         t0 = len(tracer())
         s = start_span("noop")
         assert s is NULL_SPAN
         s.end()
+        record_span("noop.dispatch", 0.0, 1.0)
         assert len(tracer()) == t0
+        with span("outer") as outer:
+            assert current_span().span_id == outer.span_id
+            with span("inner") as inner:
+                assert current_span().span_id == inner.span_id
+        assert current_span() is None
+        filed = {s.name: s for s in tracer().snapshot()[t0:]}
+        assert filed["inner"].parent_id == filed["outer"].span_id
+        assert filed["inner"].trace_id == filed["outer"].trace_id
+        assert filed["outer"].parent_id is None
 
     def test_span_nesting_ids_and_wire_context(self):
         t = Tracer()

@@ -305,8 +305,13 @@ def test_a_one_lambda_train_files_its_spans_under_the_solve(rng):
     by_id = {s.span_id: s for s in spans}
     for name, parent in (
         ("glm.lambda_solve", None), ("fit.prepare", "glm.lambda_solve"),
-        # (the batch is tiled before the lambda sweep opens)
-        ("fit.dispatch", "glm.lambda_solve"), ("tiled.schedule_build", None),
+        # (the batch is tiled before the lambda sweep opens: its build is
+        # a root, and the two schedules' builds, on a pool's threads,
+        # parent to it)
+        ("fit.dispatch", "glm.lambda_solve"), ("tiled.batch_build", None),
+        ("tiled.schedule_build", "tiled.batch_build"),
+        ("tiled.dense_split", "tiled.batch_build"),
+        ("tiled.upload", "tiled.schedule_build"),
     ):
         assert name in named, sorted(named)
         for s in named[name]:
@@ -320,21 +325,75 @@ def test_a_one_lambda_train_files_its_spans_under_the_solve(rng):
         s.attrs["entries"] == n * k and s.attrs["cache"] in ("hit", "miss")
         for s in named["tiled.schedule_build"]
     )
+    build, = named["tiled.batch_build"]
+    assert build.attrs == {"rows": n, "shards": 1}
+    # the pool's spans keep their own threads; the caller waits in its own
+    assert all(
+        s.tid != build.tid and build.t0 <= s.t0 and s.t1 <= build.t1
+        for s in named["tiled.schedule_build"]
+    )
+    split, = named["tiled.dense_split"]
+    assert split.attrs == {"entries": n * k, "dense_columns": 0}
+    assert split.tid == build.tid
+    assert len(named["tiled.upload"]) == 2
+    assert all(s.attrs["bytes"] > 0 for s in named["tiled.upload"])
     scalars = training.grid_result_scalars(results)
     (iterations, _, _, evaluations), = scalars.values()
     assert evaluations >= iterations + 1
 
 
 def test_with_tracing_off_and_no_profiler_nothing_is_filed(rng):
+    """The switch is the REQUEST path's: off, ``start_span`` /
+    ``record_span`` file nothing. The training side has no switch:
+    ``span()`` files, parents to the open span, and is the current one."""
     assert not obs_trace.tracing_enabled()
-    before = len(obs_trace.tracer())
+    obs_trace.tracer().clear()
+    assert obs_trace.start_span("request") is obs_trace.NULL_SPAN
+    obs_trace.record_span("dispatch", 1.0, 2.0)
+    assert len(obs_trace.tracer()) == 0
+    assert obs_trace.current_span() is None
+    with obs_trace.span("outer", x=1) as outer:
+        outer.set(y=2)
+        assert obs_trace.current_span().span_id == outer.span_id
+        with obs_trace.span("inner") as inner:
+            assert obs_trace.current_span().span_id == inner.span_id
+        assert obs_trace.traced("noop.call")(lambda: 7)() == 7
+        late = obs_trace.record_elapsed("elapsed", 1.0, 2.5, why="told late")
+    assert obs_trace.current_span() is None
+    filed = {s.name: s for s in obs_trace.tracer().drain()}
+    assert sorted(filed) == ["elapsed", "inner", "noop.call", "outer"]
+    assert filed["outer"].parent_id is None
+    assert filed["outer"].attrs == {"x": 1, "y": 2}
+    for child in ("inner", "noop.call", "elapsed"):
+        assert filed[child].parent_id == outer.span_id
+        assert filed[child].trace_id == outer.trace_id
+    assert filed["elapsed"] is late and (late.t0, late.t1) == (1.0, 2.5)
+    assert all(s.t1 >= s.t0 for s in filed.values())
+    # and a whole CD iteration lands on the ring with nobody asking
     with overlap.overlap_scope(True):
         _cd(rng).run(num_iterations=1)
-    with obs_trace.span("noop", x=1) as s:
-        s.set(y=2)  # reaches neither sink: no ring, no profiler session
-        assert s.span_id is None and obs_trace.current_span() is None
-    assert obs_trace.traced("noop.call")(lambda: 7)() == 7
-    assert len(obs_trace.tracer()) == before
+    named = _by_name(obs_trace.tracer().drain())
+    assert set(CD_SPANS) <= set(named), sorted(named)
+
+
+def test_a_pool_workers_spans_parent_to_the_span_that_queued_them():
+    from concurrent.futures import ThreadPoolExecutor
+
+    def work(_=None):
+        with obs_trace.span("worker.task"):
+            pass
+
+    obs_trace.tracer().clear()
+    with obs_trace.span("queued.here") as here, ThreadPoolExecutor(2) as pool:
+        list(pool.map(obs_trace.bound_to_current_span(work), "ab"))
+        pool.submit(work).result()  # unbound: a root of its own trace
+    spans = obs_trace.tracer().drain()
+    tasks = [s for s in spans if s.name == "worker.task"]
+    queued, = [s for s in spans if s.name == "queued.here"]
+    assert all(s.tid != queued.tid for s in tasks)  # the worker's thread
+    assert [s.parent_id for s in tasks] == [here.span_id] * 2 + [None]
+    assert [s.trace_id for s in tasks[:2]] == [here.trace_id] * 2
+    assert tasks[2].trace_id != here.trace_id
 
 
 def test_a_span_opens_a_profiler_annotation_named_for_it():
@@ -364,7 +423,8 @@ def test_a_span_opens_a_profiler_annotation_named_for_it():
         assert opened == [
             ["photon.cd.update", {"coordinate": "global", "done": True}, "closed"]
         ]
-        assert not obs_trace.tracing_enabled() and s.span_id is None
+        # the ring's span beside it, switch or no switch
+        assert not obs_trace.tracing_enabled() and s.span_id is not None
     finally:
         obs_trace.set_annotation_factory(installed)
 
