@@ -1154,6 +1154,7 @@ class MatrixFactorizationCoordinate(Coordinate):
         from photon_ml_tpu.game.random_effect_data import (
             RandomEffectBucket,
             RandomEffectDataset,
+            observe_row_runs,
         )
 
         K = self.num_latent_factors
@@ -1212,6 +1213,7 @@ class MatrixFactorizationCoordinate(Coordinate):
                 override_keys=np.where(ok, fixed_codes[safe], -1).astype(
                     np.int32
                 ),
+                row_runs=observe_row_runs(b_rows),
             ))
         view = RandomEffectDataset(
             config=RandomEffectDataConfiguration(

@@ -29,6 +29,7 @@ import numpy as np
 from photon_ml_tpu.game.random_effect_data import (
     RandomEffectBucket,
     RandomEffectDataset,
+    RowRuns,
 )
 from photon_ml_tpu.obs.registry import default_registry
 from photon_ml_tpu.obs.trace import bound_to_current_span
@@ -54,6 +55,22 @@ from photon_ml_tpu.optim.tron import minimize_tron
 from photon_ml_tpu.utils.backend import effective_platform
 
 Array = jnp.ndarray
+
+
+def _named(base: str, coordinate: Optional[str] = None):
+    """The function handed to jax.jit names the XLA module: ``base``,
+    with the GAME coordinate's name after it when one is given (non-word
+    characters as ``_``), so that a device trace tells one coordinate's
+    programs from another's."""
+    name = base
+    if coordinate:
+        name += "_" + re.sub(r"\W", "_", coordinate)
+
+    def rename(f):
+        f.__name__ = f.__qualname__ = name
+        return f
+
+    return rename
 
 
 @dataclass
@@ -588,15 +605,7 @@ def _bucket_solver(
     def _donate():
         return (0,) if effective_platform() != "cpu" else ()
 
-    def _named(name):
-        """The function handed to jax.jit names the XLA module."""
-        def rename(f):
-            f.__name__ = f.__qualname__ = name
-            return f
-
-        return rename
-
-    def _fused(core, name="bank_fused", values_of=None):
+    def _fused(core, coordinate=None, values_of=None):
         """Single-dispatch bucket update: bank-row gather, solve, bank
         scatter, and the tracker reductions all inside ONE jit program —
         per-bucket host overhead (separate gather/scatter dispatches plus
@@ -610,14 +619,14 @@ def _bucket_solver(
         bucket. update_bank defensively copies the caller's bank ONCE
         before the bucket chain so outside references stay valid.
 
-        ``name`` names the XLA module (``jit_<name>``): a coordinate's
-        own where :func:`fused_for` is given one. ``values_of``: the
+        ``coordinate`` goes into the XLA module's name (:func:`_named`:
+        ``jit_bank_fused[_<coordinate>]``). ``values_of``: the
         program takes the override's operand last and the block's keys
         in the values' place."""
 
         # photon: sharding(axes=[], donates=[0])
         @partial(jax.jit, donate_argnums=_donate())
-        @_named(name)
+        @_named("bank_fused", coordinate)
         def bank_fused(bank_full, codes, ix, v, lab, off, w, l1, l2,
                        operand=None):
             return _update_block(
@@ -627,7 +636,7 @@ def _bucket_solver(
 
         return bank_fused
 
-    def _fused_scan(core, name="bank_fused_scan", values_of=None):
+    def _fused_scan(core, coordinate=None, values_of=None):
         """The fused bucket update folded over a STACK of same-shape
         blocks by lax.scan — one dispatch for the whole group: a run of
         same-shape buckets, or the equal sub-blocks of one bucket over
@@ -641,7 +650,7 @@ def _bucket_solver(
 
         # photon: sharding(axes=[], donates=[0])
         @partial(jax.jit, donate_argnums=_donate())
-        @_named(name)
+        @_named("bank_fused_scan", coordinate)
         def bank_fused_scan(bank_full, codes_s, ix_s, v_s, lab_s, off_s,
                             w_s, l1, l2, operand=None):
             def body(bank, args):
@@ -704,11 +713,8 @@ def _bucket_solver(
         (:class:`ValuesOverride`)."""
         key = (kind, coordinate, scan, values_of)
         if key not in fused_programs:
-            name = "bank_fused_scan" if scan else "bank_fused"
-            if coordinate:
-                name += "_" + re.sub(r"\W", "_", coordinate)
             build = _fused_scan if scan else _fused
-            fused_programs[key] = build(cores[kind], name, values_of)
+            fused_programs[key] = build(cores[kind], coordinate, values_of)
         return fused_programs[key]
 
     return SimpleNamespace(
@@ -717,6 +723,78 @@ def _bucket_solver(
         fused_for=fused_for,
         hdiag=hdiag,
     )
+
+
+_LANES = 128
+
+
+def _residual_windows(rows, starts, counts, capacity: int):
+    """``[E, capacity]`` windows of the row vector: entity e's is its
+    ``counts[e]`` values from row ``starts[e]`` on, zero after. ``rows``
+    is the vector as ``[n / 128, 128]`` (zero-padded so that the last
+    window's rows exist), so that a window is whole ROWS of it: the
+    ``capacity / 128 + 1`` rows (at least 2) from ``starts[e] // 128``,
+    each a row gather (0.4 ns a slot on a v5e where the element gather
+    takes 7.9 and a slice gather 1.2 us an entity: ``PERF.md`` section 6,
+    PR 38), every lane then turned left by ``starts[e] % 128`` (seven
+    roll-and-select stages, one a bit of the shift) and taken from its
+    own row or the next. Values are moved, never computed with: what
+    comes out is bit-equal to the element gather's."""
+    r = max(capacity // _LANES, 1)
+    first, shift = starts // _LANES, (starts % _LANES)[None, :, None]
+    # [r + 1, E, 128], the row the slow axis: the compiler's own layout
+    # (an [E, r + 1] index array, 2 wide, takes it 7 s to compile)
+    win = jnp.take(
+        rows, first[None, :] + jnp.arange(r + 1, dtype=jnp.int32)[:, None],
+        axis=0, mode="clip",
+    )
+    for bit in range(7):
+        on = ((shift >> bit) & 1).astype(bool)
+        win = jnp.where(on, jnp.roll(win, -(1 << bit), axis=2), win)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _LANES), 2)
+    win = jnp.where(lane < _LANES - shift, win[:r], win[1:])
+    win = win.transpose(1, 0, 2).reshape(-1, r * _LANES)[:, :capacity]
+    slot = jax.lax.broadcasted_iota(jnp.int32, win.shape, 1)
+    return jnp.where(slot < counts[:, None], win, 0.0)
+
+
+@lru_cache(maxsize=None)
+def _residual_program(coordinate: Optional[str]):
+    """The ONE program a bank update runs to turn the ``[n]`` residual
+    into every group's offsets (``RandomEffectOptimizationProblem
+    ._bucket_offsets``), module ``jit_bank_residual[_<coordinate>]``
+    (non-word characters as ``_``, as ``fused_for`` names the solver
+    programs). ``specs`` and ``capacities`` as
+    :meth:`RandomEffectOptimizationProblem._residual_args` makes them: a
+    group of runs reads windows (:func:`_residual_windows`; a folded
+    group's [B, E] starts as one [B * E]), any other the element gather
+    through its ``row_index``, a padding slot (-1) reading 0."""
+
+    @partial(jax.jit, static_argnames=("capacities",))
+    @_named("bank_residual", coordinate)
+    def bank_residual(residual, specs, *, capacities):
+        # the vector as rows of 128, zero-padded past the widest window
+        # that starts at its last row and the row after that
+        reach = max(capacities) + 2 * _LANES
+        rows = jnp.pad(
+            residual, (0, -(residual.shape[0] + reach) % _LANES + reach)
+        ).reshape(-1, _LANES)
+        out = []
+        for spec, capacity in zip(specs, capacities):
+            if capacity:
+                starts, counts = spec
+                win = _residual_windows(
+                    rows, starts.reshape(-1), counts.reshape(-1), capacity
+                )
+                out.append(win.reshape(starts.shape + (capacity,)))
+            else:
+                (at,) = spec
+                out.append(
+                    jnp.where(at >= 0, residual[jnp.maximum(at, 0)], 0.0)
+                )
+        return out
+
+    return bank_residual
 
 
 class ValuesOverride(NamedTuple):
@@ -765,20 +843,26 @@ def _split_bucket(
              "offsets", "weights"]
     if bucket.override_keys is not None:
         names.append("override_keys")
+
+    def part(a, j, fill=0):
+        a = a[j * e_sub:(j + 1) * e_sub]
+        if a.shape[0] < e_sub:
+            pad = np.full((e_sub - a.shape[0],) + a.shape[1:], fill, a.dtype)
+            a = np.concatenate([a, pad])
+        return a
+
     out = []
     for j in range(n_sub):
-        parts = {}
-        for name in names:
-            a = getattr(bucket, name)[j * e_sub:(j + 1) * e_sub]
-            if a.shape[0] < e_sub:
-                pad = np.full(
-                    (e_sub - a.shape[0],) + a.shape[1:], fill.get(name, 0),
-                    a.dtype,
-                )
-                a = np.concatenate([a, pad])
-            parts[name] = a
+        parts = {
+            name: part(getattr(bucket, name), j, fill.get(name, 0))
+            for name in names
+        }
+        # a slice of runs is runs, and a padding entity an empty one
+        runs = None if bucket.row_runs is None else RowRuns(
+            *(part(a, j) for a in bucket.row_runs)
+        )
         out.append(RandomEffectBucket(
-            identity_indices=bucket.identity_indices, **parts
+            identity_indices=bucket.identity_indices, row_runs=runs, **parts
         ))
     return out
 
@@ -981,36 +1065,55 @@ class RandomEffectOptimizationProblem:
     def _use_dense(self, bucket, d_local: int) -> bool:
         return self._bucket_kind(bucket, d_local) != "sparse"
 
-    def _bucket_device_args(self, bucket, with_values=True) -> List[Array]:  # photon: entropy(id-keyed device-array memo; weakref-pinned, never serialized)
-        """Device-resident (mesh-sharded if configured) static arrays for a
-        bucket, transferred once and reused across update_bank calls. The
-        cache holds a weakref: device copies die with the bucket.
-        ``with_values=False`` (the values_override path): the values'
-        place holds the bucket's ``override_keys`` [E, S] instead."""
+    def _bucket_cached(self, bucket, what, make):  # photon: entropy(id-keyed device-array memo; weakref-pinned, never serialized)
+        """``make()``'s device arrays for ``bucket``, made once a
+        ``what``. The cache holds a weakref: device copies die with the
+        bucket."""
         import weakref
 
-        key = (id(bucket), with_values)
+        key = (id(bucket), what)
         hit = self._device_cache.get(key)
         if hit is not None and hit[0]() is bucket:
             return hit[1]
-        arrs = [
-            jnp.asarray(bucket.indices),
-            jnp.asarray(
-                bucket.values if with_values else bucket.override_keys
-            ),
-            jnp.asarray(bucket.labels),
-            jnp.asarray(bucket.weights),
-            jnp.asarray(bucket.offsets),
-            jnp.asarray(bucket.row_index),
-        ]
-        if self.mesh is not None:
-            arrs, _ = self._shard_entity_axis(arrs)
-        # entity codes stay unsharded: they index the full bank host-side
-        arrs = arrs + [jnp.asarray(bucket.entity_codes)]
+        arrs = make()
         cache = self._device_cache
         ref = weakref.ref(bucket, lambda _, k=key, c=cache: c.pop(k, None))
         self._device_cache[key] = (ref, arrs)
         return arrs
+
+    def _bucket_device_args(self, bucket, with_values=True) -> List[Array]:
+        """Device-resident (mesh-sharded if configured) static arrays for a
+        bucket, transferred once and reused across update_bank calls.
+        ``with_values=False`` (the values_override path): the values'
+        place holds the bucket's ``override_keys`` [E, S] instead. The
+        bucket's ``row_index`` is not among them: who reads it (the
+        residual's slot path, ``re_score``) asks :meth:`_bucket_rows`."""
+
+        def make():
+            arrs = [
+                jnp.asarray(bucket.indices),
+                jnp.asarray(
+                    bucket.values if with_values else bucket.override_keys
+                ),
+                jnp.asarray(bucket.labels),
+                jnp.asarray(bucket.weights),
+                jnp.asarray(bucket.offsets),
+            ]
+            if self.mesh is not None:
+                arrs, _ = self._shard_entity_axis(arrs)
+            # entity codes stay unsharded: they index the full bank
+            # host-side
+            return arrs + [jnp.asarray(bucket.entity_codes)]
+
+        return self._bucket_cached(bucket, with_values, make)
+
+    def _bucket_rows(self, bucket) -> Array:
+        """``bucket.row_index`` [E, S] on the device, uploaded when first
+        asked for (a bucket of runs whose rows nothing scores from, an ALS
+        half-step's, never uploads it)."""
+        return self._bucket_cached(
+            bucket, "rows", lambda: jnp.asarray(bucket.row_index)
+        )
 
     def _shard_entity_axis(self, arrays):
         """Pad arrays' leading (entity) dim to the mesh axis size and place
@@ -1051,11 +1154,15 @@ class RandomEffectOptimizationProblem:
                             with_values=True):
         """Device-stacked [B, ...] args for a same-shape group of solver
         blocks, built from the HOST arrays in one transfer per field and
-        cached on the dataset. Only the offset source the configuration
-        needs is stacked: stored offsets when ``with_residuals`` is
-        False, row indices (for the on-device residual gather) when True
-        — never both (a dead [B, E, S] buffer would otherwise pin HBM for
-        the dataset's lifetime).
+        cached on the dataset: codes, indices, values (under a values
+        override the blocks' keys), labels, stored offsets, weights. The
+        stored offsets are stacked only when ``with_residuals`` is False;
+        under a residual their place holds None and the offsets come from
+        :meth:`_bucket_offsets`, which uploads what IT reads: [B, E]
+        starts and counts for a group of runs, the [B, E, S]
+        ``row_index`` (:meth:`_stacked_rows`) otherwise — never both (a
+        dead [B, E, S] buffer would otherwise pin HBM for the dataset's
+        lifetime).
 
         Accepted trade-off: a dataset that ALSO runs the per-bucket path
         (bank_variances / with_variances) holds its buckets in both this
@@ -1083,27 +1190,87 @@ class RandomEffectOptimizationProblem:
             if with_residuals
             else jnp.asarray(np.stack([b.offsets for b in bs])),
             jnp.asarray(np.stack([b.weights for b in bs])),
-            jnp.asarray(np.stack([b.row_index for b in bs]))
-            if with_residuals
-            else None,
         )
         cache[key] = out
         return out
 
+    @staticmethod
+    def _stacked_rows(dataset, blocks) -> Array:
+        """The group's ``row_index`` stacked [B, E, S] on the device,
+        uploaded when first asked for and cached on the dataset beside
+        :meth:`_stacked_group_args`' (the residual's slot path and
+        ``re_score`` read the same copy)."""
+        cache = dataset.__dict__.setdefault("_stacked_device_cache", {})
+        key = (tuple(b[:3] for b in blocks), "rows")
+        if key not in cache:
+            cache[key] = jnp.asarray(
+                np.stack([b.bucket.row_index for b in blocks])
+            )
+        return cache[key]
+
+    def _residual_args(self, dataset, groups):
+        """What :func:`_residual_program` reads for ``groups`` of solver
+        blocks, on the device, cached on the dataset: ``(starts, counts)``
+        [E] (a folded group: [B, E]) where every block of the group
+        observed runs (:class:`RowRuns`), else ``(rows,)``, the group's
+        ``row_index``; and the static capacities that say which, 0 for
+        the slot path."""
+        cache = dataset.__dict__.setdefault("_residual_device_cache", {})
+        key = tuple(tuple(b[:3] for b in members) for members in groups)
+        if key not in cache:
+            specs, capacities = [], []
+            for members in groups:
+                runs = [b.bucket.row_runs for b in members]
+                if all(r is not None for r in runs):
+                    fields = [np.stack(field) for field in zip(*runs)]
+                    if len(members) == 1:
+                        fields = [field[0] for field in fields]
+                    specs.append(tuple(jnp.asarray(f) for f in fields))
+                    capacities.append(members[0].bucket.capacity)
+                else:
+                    specs.append((
+                        self._stacked_rows(dataset, members)
+                        if len(members) > 1
+                        else self._bucket_rows(members[0].bucket),
+                    ))
+                    capacities.append(0)
+            cache[key] = (tuple(specs), tuple(capacities))
+        return cache[key]
+
     def _bucket_offsets(
-        self, bi, bucket, rows_d, residual_offsets, routed, router
-    ):
-        """One bucket's per-sample offsets from the routed residuals."""
-        if routed is not None:
-            # mesh path: slice this bucket's slab out of the routed
-            # per-device buffers — already entity-sharded
-            return router.bucket_slab(routed, bi, bucket.capacity)
-        # single device: per-row gather stays on device — the
-        # KeyValueScore residual currency never leaves it
-        # (SURVEY §7.9; round 2 gathered on host per bucket)
-        return jnp.where(
-            rows_d >= 0, residual_offsets[jnp.maximum(rows_d, 0)], 0.0
+        self, dataset, groups, residual_offsets, coordinate=None
+    ) -> List[Array]:
+        """Every group's per-sample offsets from the residual on ONE
+        device, [E, S] (a folded group: [B, E, S]), in the groups' order
+        (the mesh path slices each bucket's slab out of the routed
+        buffers instead, ``router.bucket_slab``). One program a dataset
+        (:func:`_residual_program`, module
+        ``jit_bank_residual_<coordinate>``), which stays on the device
+        (the KeyValueScore residual currency never leaves it, SURVEY
+        §7.9) and reads what :meth:`_residual_args` uploaded: windows of
+        the row vector at [E] starts for a group whose entities' rows are
+        runs, the element gather through the uploaded ``row_index`` for
+        any other. Which of the two a group's slots took is counted here,
+        on the host, where it is decided."""
+        specs, capacities = self._residual_args(dataset, groups)
+        slots = default_registry().counter(
+            "photon_bank_residual_slots_total",
+            "slots of the replicated bank's blocks whose offsets were "
+            "read from the residual, by coordinate and path (windows: an "
+            "entity's rows are a run; slots: the element gather)",
         )
+        taken = {"windows": 0, "slots": 0}
+        for members, capacity in zip(groups, capacities):
+            taken["windows" if capacity else "slots"] += (
+                sum(b.num_real for b in members) * members[0].bucket.capacity
+            )
+        for path, count in taken.items():
+            if count:
+                slots.inc(count, coordinate=coordinate or "", path=path)
+        with obs_span("bank.residual", groups=len(groups), **taken):
+            return _residual_program(coordinate)(
+                residual_offsets, specs, capacities=capacities
+            )
 
     @staticmethod
     def _program_sig(kind, coordinate, bank_shape, ix_shape, override,
@@ -1216,6 +1383,8 @@ class RandomEffectOptimizationProblem:
             else:
                 self._bucket_device_args(members[0].bucket)
         if self.mesh is None:
+            if has_residual_offsets:
+                self._residual_args(dataset, groups)
             l1, l2 = self.regularization.split(self.reg_weight)
             self._warm_solvers(self._bucket_plans(
                 bank, groups,
@@ -1382,7 +1551,12 @@ class RandomEffectOptimizationProblem:
             "ops/spd_solve solves a batch of them",
         )
         solve = solve_path(bank.shape[1], effective_platform())
-        for members in groups:
+        offsets = None
+        if residual_offsets is not None and routed is None and groups:
+            offsets = self._bucket_offsets(
+                dataset, groups, residual_offsets, coordinate
+            )
+        for gi, members in enumerate(groups):
             block = members[0]
             kind, bucket = block.kind, block.bucket
             n_real = sum(b.num_real for b in members)
@@ -1400,18 +1574,14 @@ class RandomEffectOptimizationProblem:
             )
             if len(members) > 1:
                 (
-                    codes_s, ix_s, v_s, lab_s, off_s, w_s, rows_s,
+                    codes_s, ix_s, v_s, lab_s, off_s, w_s,
                 ) = self._stacked_group_args(
                     dataset, members,
                     with_residuals=residual_offsets is not None,
                     with_values=override is None,
                 )
-                if residual_offsets is not None:
-                    off_s = jnp.where(
-                        rows_s >= 0,
-                        residual_offsets[jnp.maximum(rows_s, 0)],
-                        0.0,
-                    )
+                if offsets is not None:
+                    off_s = offsets[gi]
                 fused_scan = self._aot_cache.get(self._program_sig(
                     kind, coordinate, bank.shape, ix_s.shape, override,
                     scan=True,
@@ -1429,9 +1599,8 @@ class RandomEffectOptimizationProblem:
                     jnp.concatenate([jnp.stack([it_sum, it_max]), counts])
                 )
                 continue
-            bi = block.bucket_index
             (
-                ix_d, v_d, lab_d, w_d, off_d, rows_d, codes_d,
+                ix_d, v_d, lab_d, w_d, off_d, codes_d,
             ) = self._bucket_device_args(
                 bucket, with_values=override is None
             )
@@ -1439,10 +1608,14 @@ class RandomEffectOptimizationProblem:
                 # the mesh path's solvers take values, not keys: this
                 # bucket's are made whole, beside no other's
                 v_d = override.fn(override.operand, v_d)
-            if residual_offsets is not None:
-                off_d = self._bucket_offsets(
-                    bi, bucket, rows_d, residual_offsets, routed, router
+            if routed is not None:
+                # mesh path: slice this bucket's slab out of the routed
+                # per-device buffers — already entity-sharded
+                off_d = router.bucket_slab(
+                    routed, block.bucket_index, bucket.capacity
                 )
+            elif offsets is not None:
+                off_d = offsets[gi]
             if self.mesh is None:
                 # fused path: gather + solve + scatter + tracker reductions
                 # in one dispatch; AOT-warmed programs run their compiled
@@ -1551,14 +1724,25 @@ class RandomEffectOptimizationProblem:
             dataset, residual_offsets
         )
         variances = jnp.zeros_like(bank)
+        offsets = None
+        if residual_offsets is not None and routed is None and dataset.buckets:
+            # a bucket a group, whole
+            offsets = self._bucket_offsets(
+                dataset,
+                self._block_groups(
+                    self._solver_blocks(dataset, bank.shape[1], split=False),
+                    fold=False,
+                ),
+                residual_offsets,
+            )
         for bi, bucket in enumerate(dataset.buckets):
             (
-                ix_d, v_d, lab_d, w_d, off_d, rows_d, codes_d,
+                ix_d, v_d, lab_d, w_d, off_d, codes_d,
             ) = self._bucket_device_args(bucket)
-            if residual_offsets is not None:
-                off_d = self._bucket_offsets(
-                    bi, bucket, rows_d, residual_offsets, routed, router
-                )
+            if routed is not None:
+                off_d = router.bucket_slab(routed, bi, bucket.capacity)
+            elif offsets is not None:
+                off_d = offsets[bi]
             n_real = bucket.num_entities
             sl = bank[codes_d]
             if self.mesh is not None:
@@ -1740,13 +1924,15 @@ def score_random_effect(
     blocks = []
     for members in plan.groups:
         if len(members) > 1:
-            codes, ix, v, _, _, _, rows = problem._stacked_group_args(
+            codes, ix, v, _, _, _ = problem._stacked_group_args(
                 dataset, members, with_residuals=True
             )
+            rows = problem._stacked_rows(dataset, members)
         else:
-            ix, v, _, _, _, rows, codes = problem._bucket_device_args(
+            ix, v, _, _, _, codes = problem._bucket_device_args(
                 members[0].bucket
             )
+            rows = problem._bucket_rows(members[0].bucket)
         blocks.append((codes, ix, v, rows))
     return re_score(
         bank, tuple(blocks), plan.rest,

@@ -18,7 +18,7 @@ bucket (SURVEY P2: entities are the expert-parallel analog).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import numpy as np
 
@@ -28,6 +28,29 @@ from photon_ml_tpu.game.config import (
 )
 from photon_ml_tpu.game.data import GameDataset, ShardData
 from photon_ml_tpu.obs.trace import span as obs_span
+
+
+class RowRuns(NamedTuple):
+    """A bucket whose every entity's real slots hold CONSECUTIVE rows in
+    slot order (``row_index[e, j] == starts[e] + j`` for ``j <
+    counts[e]``, -1 after): the bucket's ``row_index`` in 2 x [E_b]."""
+
+    starts: np.ndarray  # int32 [E_b]; 0 for an entity with no real slot
+    counts: np.ndarray  # int32 [E_b]
+
+
+def observe_row_runs(row_index: np.ndarray) -> Optional[RowRuns]:
+    """The :class:`RowRuns` of ``row_index`` [E_b, S_b], or None where
+    some entity's rows are not a run: read from the data alone (a table
+    grouped by the entity has them; a shuffled one, or an entity whose
+    reservoir cap dropped a middle row, does not)."""
+    counts = (row_index >= 0).sum(axis=1).astype(np.int32)
+    starts = np.where(counts > 0, row_index[:, 0], 0).astype(np.int32)
+    slot = np.arange(row_index.shape[1], dtype=np.int32)[None, :]
+    runs = np.where(slot < counts[:, None], starts[:, None] + slot, -1)
+    if not np.array_equal(runs, row_index):
+        return None
+    return RowRuns(starts, counts)
 
 
 @dataclass
@@ -50,6 +73,10 @@ class RandomEffectBucket:
     # the partner entity's code). Such a bucket stores no values, and an
     # identity one no indices: both are [E_b, S_b, 0]
     override_keys: Optional[np.ndarray] = None
+    # observed where the bucket is built (:func:`observe_row_runs`), not
+    # configured: where set, ``update_bank`` reads the residual as [E_b]
+    # windows of the row vector and uploads no ``row_index`` for it
+    row_runs: Optional[RowRuns] = None
 
     @property
     def num_entities(self) -> int:
@@ -359,6 +386,7 @@ def _build_random_effect_dataset(
                 identity_indices=bool(
                     kk == D == 1 and not row_local_ix[br].any()
                 ),
+                row_runs=observe_row_runs(b_rows),
             )
         )
 

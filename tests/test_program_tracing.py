@@ -200,6 +200,7 @@ CD_SPANS = {
     "fit.dispatch": "cd.update",
     "bank.update": "cd.update",
     "bank.route_residuals": "bank.update",
+    "bank.residual": "bank.update",
     "bank.dispatch": "bank.update",
 }
 
@@ -277,6 +278,61 @@ def test_a_cold_bank_update_files_its_warm_up_and_the_compiles(rng):
     assert len(named["bank.warm_solvers"]) == 1
     assert named["bank.warm_solvers"][0].attrs["programs"] >= 1
     assert "jax.trace" in named and all(s.t1 >= s.t0 for s in named["jax.trace"])
+
+
+@pytest.mark.parametrize("path", ["windows", "slots"])
+def test_a_warm_bank_update_runs_one_residual_program_and_compiles_nothing(
+    path, monkeypatch
+):
+    """Under a residual a bank update turns the row vector into every
+    block's offsets in ONE named program (no eager dispatch a block), on
+    the windows of a grouped table and on the slots of a shuffled one."""
+    from photon_ml_tpu.game import random_effect as re_mod
+    from test_residual_windows import _build, _grouped_codes, _problem
+
+    codes = _grouped_codes(seed=11)
+    if path == "slots":
+        codes = np.random.default_rng(11).permutation(codes)
+    red = _build(codes)
+    held = sum(
+        b.row_index.size for b in red.buckets
+        if (b.row_runs is not None) == (path == "windows")
+    )
+    assert held > 0.9 * sum(b.row_index.size for b in red.buckets)
+    calls = []
+    program_for = re_mod._residual_program
+
+    def counting(coordinate):
+        program = program_for(coordinate)
+
+        def call(*args, **kwargs):
+            calls.append(coordinate)
+            return program(*args, **kwargs)
+
+        return call
+
+    monkeypatch.setattr(re_mod, "_residual_program", counting)
+    problem = _problem()
+    bank = jnp.zeros((red.num_entities, red.local_dim), jnp.float32)
+    residual = jnp.ones((red.row_entity_codes.shape[0],), jnp.float32)
+    name = f"trace-{path}"
+    problem.update_bank(bank, red, residual_offsets=residual, coordinate=name)
+    calls.clear()
+    with obs_trace.tracing_scope(True):
+        obs_trace.tracer().clear()
+        problem.update_bank(
+            bank, red, residual_offsets=residual, coordinate=name
+        )
+        spans = obs_trace.tracer().drain()
+    assert calls == [name]
+    named = _by_name(spans)
+    (span,) = named["bank.residual"]
+    assert span.attrs[path] == held and span.attrs["groups"] >= 3
+    by_id = {s.span_id: s for s in spans}
+    assert by_id[span.parent_id].name == "bank.update"
+    # nothing traced, lowered or compiled: no eager operation at a block's
+    # shape, and the program itself is warm
+    assert not {"jax.trace", "jax.lower", "jax.compile"} & set(named)
 
 
 def test_a_one_lambda_train_files_its_spans_under_the_solve(rng):

@@ -267,10 +267,12 @@ def test_the_program_keeps_its_module_name():
     plan = score_plan(red, problem)
     blocks = []
     for members in plan.groups:
-        ix, v, _, _, _, rows, codes = problem._bucket_device_args(
+        ix, v, _, _, _, codes = problem._bucket_device_args(
             members[0].bucket
         )
-        blocks.append((codes, ix, v, rows))
+        blocks.append(
+            (codes, ix, v, problem._bucket_rows(members[0].bucket))
+        )
     lowered = re_score.lower(
         jnp.asarray(_bank(red)), tuple(blocks), plan.rest,
         identity=(False,) * len(blocks),
