@@ -675,21 +675,22 @@ class GameTrainingDriver:
         for name, dcfg in p.random_effect_data_configs.items():
             ocfg = opt_combo[name]
             red = re_datasets[name]
+            factored = name in p.factored_re_configs
             problem = RandomEffectOptimizationProblem(
                 loss,
                 ocfg.optimizer_config,
                 ocfg.regularization,
                 reg_weight=ocfg.reg_weight,
-                # the pod layer owns placement on the entity-sharded path
-                mesh=None if pod_mesh is not None else mesh,
+                # the pod layer owns placement on the entity-sharded path;
+                # a factored random effect runs on the replicated bank (its
+                # projection fit reads the blocks one device holds)
+                mesh=None if pod_mesh is not None or factored else mesh,
                 # plain RE coordinates attach per-entity variances; the
                 # factored path persists in the ORIGINAL space where the
                 # latent-space Hdiag does not transform diagonally
-                compute_variances=(
-                    p.compute_variance and name not in p.factored_re_configs
-                ),
+                compute_variances=p.compute_variance and not factored,
             )
-            if name in p.factored_re_configs:
+            if factored:
                 fcfg = p.factored_re_configs[name]
                 coords[name] = FactoredRandomEffectCoordinate(
                     name=name,

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu.data.batch import SparseBatch
+from photon_ml_tpu.game import factored
 from photon_ml_tpu.game.config import FactoredRandomEffectConfiguration
 from photon_ml_tpu.game.data import GameDataset
 from photon_ml_tpu.game.model import (
@@ -35,7 +36,7 @@ from photon_ml_tpu.game.model import (
 from photon_ml_tpu.game.random_effect import (
     RandomEffectOptimizationProblem,
     ValuesOverride,
-    device_row_view,
+    score_kernel_name,
     score_plan,
     score_random_effect,
 )
@@ -901,15 +902,70 @@ class PodRandomEffectCoordinate(Coordinate):
         self.pod.prepare(self.re_dataset)
 
 
+class FactoredRandomEffectTracker:
+    """What one factored update did, inner iteration by inner iteration:
+    the latent bank update's tracker (``latent``, device-resident until
+    read) and the projection fit's iterations, evaluations and stop
+    reason (``projection``). Everything stays on the device until the
+    coordinate descent's one batched readback (``deferreds``); the
+    readback fills the ``fre.projection`` spans' attrs and counts the
+    fits' evaluations into ``photon_fre_projection_evals_total``."""
+
+    def __init__(self, coordinate: str, latent: list, fits: list, spans: list):
+        from photon_ml_tpu.parallel import overlap
+
+        self.coordinate = coordinate
+        self.latent = latent
+        self._spans = spans
+        self._fits = overlap.Deferred(
+            [(r.iterations, r.evaluations, r.reason) for r in fits],
+            self._finalize,
+        )
+
+    def _finalize(self, fetched) -> list:
+        from photon_ml_tpu.optim.common import CONVERGENCE_REASON_NAMES
+
+        fits = [
+            {
+                "iterations": int(it), "evaluations": int(ev),
+                "reason": CONVERGENCE_REASON_NAMES.get(int(reason), "?"),
+            }
+            for it, ev, reason in fetched
+        ]
+        for sp, fit in zip(self._spans, fits):
+            sp.set_after(**fit)
+        default_registry().counter(
+            "photon_fre_projection_evals_total",
+            "objective evaluations of the factored random effects' "
+            "projection fits, by coordinate",
+        ).inc(sum(f["evaluations"] for f in fits), coordinate=self.coordinate)
+        return fits
+
+    @property
+    def deferreds(self) -> list:
+        return [getattr(t, "deferred", None) for t in self.latent] + [
+            self._fits
+        ]
+
+    @property
+    def projection(self) -> list:
+        return self._fits.result()
+
+
 @dataclass
 class FactoredRandomEffectCoordinate(Coordinate):
-    """Random effects in a LEARNED latent projection: alternate
-    (1) per-entity solves in latent space and (2) a distributed fit of the
-    shared projection matrix (FactoredRandomEffectCoordinate.scala:99-289).
+    """Random effects in a LEARNED latent projection
+    (FactoredRandomEffectCoordinate.scala:99-289): ``num_inner_iterations``
+    times, (1) every entity's latent coefficients with ``z' B`` as its
+    features, one ``update_bank`` under a values override that makes
+    them inside each block's program (:mod:`photon_ml_tpu.game.factored`),
+    then (2) the shared projection ``B`` fitted with them held, over the
+    same blocks. ``projection_problem`` is the projection's GLM problem
+    over ``vec(B)``: its optimizer configuration and regularization (at
+    ``reg_weight_projection``) are the fit's; its objective is not run.
 
-    Model state: RandomEffectModel whose re_dataset is a latent-space view,
-    plus the projection matrix B [d, L] kept on this coordinate's model via
-    the MatrixFactorization-style composition below.
+    Model state: a :class:`FactoredRandomEffectModel` (latent bank
+    ``[E, L]`` and projection ``[d, L]``), both on the device throughout.
     """
 
     name: str
@@ -937,68 +993,112 @@ class FactoredRandomEffectCoordinate(Coordinate):
             feature_shard_id=self.re_dataset.config.feature_shard_id,
         )
 
-    def _latent_rows(self, projection: Array) -> Tuple[Array, Array]:
-        """Project every row into latent space: dense [n, L] values with
-        identity local indices."""
-        _, _, ix, v = device_row_view(self.re_dataset)
-        # x_lat = sum_s v_s * B[ix_s]  -> [n, L]
-        return jnp.einsum("nk,nkl->nl", v, jnp.take(projection, ix, axis=0))
+    def _view(self) -> RandomEffectDataset:
+        return factored.latent_view(
+            self.re_dataset, self.config.latent_space_dimension
+        )
 
     def update_model(self, model, residual=None):
-        offsets_np = self.dataset.offsets
+        offsets = jnp.asarray(self.dataset.offsets)
         if residual is not None:
-            offsets_np = jnp.asarray(offsets_np) + residual
-        bank = model.bank
-        projection = model.projection
-        L = self.config.latent_space_dimension
-        tracker = None
-        for _ in range(self.config.num_inner_iterations):
-            # (1) latent-space per-entity solves over re-projected buckets
-            x_lat = np.asarray(self._latent_rows(projection))
-            lat_view = _latent_view(self.re_dataset, x_lat)
-            bank, tracker = self.problem.update_bank(
-                bank, lat_view, residual_offsets=offsets_np
-            )
-            # (2) distributed projection-matrix fit with per-row features
-            # outer(x_i, w_e(i)) flattened to d*L (updateLatentProjection
-            # Matrix analog: a plain GLM over vec(B)).
-            projection = self._update_projection(bank, projection, offsets_np)
-        new_model = replace(model, bank=bank, projection=projection)
-        return new_model, tracker
+            if len(residual.sharding.device_set) > 1:
+                # a data mesh's residual, replicated over its devices: the
+                # bank here is one device's (its programs are compiled for
+                # one), so the vector comes to it through the host, once
+                # an update; on one device it never leaves
+                from photon_ml_tpu.parallel import overlap
 
-    def _update_projection(
-        self, bank: Array, projection: Array, offsets_np: np.ndarray
-    ) -> Array:
-        d = self.re_dataset.local_dim
-        L = self.config.latent_space_dimension
-        codes, valid, ix, v = device_row_view(self.re_dataset)
-        w_rows = jnp.take(bank, codes, axis=0)  # [n, L]
-        n, k = ix.shape
-        # flattened sparse features: index (j*L + l), value v_s * w_l
-        flat_ix = (ix[:, :, None] * L + jnp.arange(L)[None, None, :]).reshape(n, k * L)
-        flat_v = (v[:, :, None] * w_rows[:, None, :]).reshape(n, k * L)
-        batch = SparseBatch(
-            indices=flat_ix.astype(jnp.int32),
-            values=jnp.where(valid[:, None], flat_v, 0.0),
-            labels=jnp.asarray(self.dataset.labels),
-            offsets=jnp.asarray(offsets_np),
-            weights=jnp.asarray(self.dataset.weights),
+                residual = jnp.asarray(overlap.device_get(residual))
+            offsets = offsets + residual  # device-resident
+        view = self._view()
+        bank, projection = model.bank, model.projection
+        inner = self.config.num_inner_iterations
+        default_registry().counter(
+            "photon_fre_inner_iterations_total",
+            "inner iterations (latent bank update + projection fit) of the "
+            "factored random effects, by coordinate",
+        ).inc(inner, coordinate=self.name)
+        # the residual does not change across inner iterations: every
+        # block's offsets are made from it once
+        groups, offsets = self.problem.group_offsets(
+            view, offsets, override=factored.latent_override(projection),
+            coordinate=self.name,
         )
-        coefficients, _ = self.projection_problem.run(
-            batch,
-            initial=projection.reshape(-1),
-            reg_weight=self.reg_weight_projection,
+        arrays = factored.group_arrays(self.problem, view, groups)
+        opt = self.projection_problem
+        l1, l2 = opt.regularization.split(self.reg_weight_projection)
+        latent, fits, spans = [], [], []
+        for i in range(inner):
+            with obs_span("fre.latent", coordinate=self.name, inner=i + 1):
+                bank, tracker = self.problem.update_bank(
+                    bank, view,
+                    values_override=factored.latent_override(projection),
+                    defer_tracker=True, coordinate=self.name,
+                    group_offsets=offsets,
+                )
+            with obs_span(
+                "fre.projection", coordinate=self.name, inner=i + 1
+            ) as sp:
+                fit = factored.fit_projection(
+                    projection, bank, arrays, offsets,
+                    loss=self.problem.loss, config=opt.config, l1=l1, l2=l2,
+                    coordinate=self.name,
+                )
+            projection = fit.coefficients.reshape(projection.shape)
+            latent.append(tracker)
+            fits.append(fit)
+            spans.append(sp)
+        return (
+            replace(model, bank=bank, projection=projection),
+            FactoredRandomEffectTracker(self.name, latent, fits, spans),
         )
-        return coefficients.means.reshape(d, L)
+
+    @property
+    def score_kernel(self) -> str:
+        """How ``score()`` computes: for ``cd.score``."""
+        plan = score_plan(
+            self._view(), self.problem, staged=self.re_dataset.local_dim
+        )
+        return score_kernel_name(plan.block_rows, plan.gather_rows)
 
     def score(self, model) -> Array:
-        x_lat = self._latent_rows(model.projection)  # [n, L]
-        codes, valid, _, _ = device_row_view(self.re_dataset)
-        w_rows = jnp.take(model.bank, codes, axis=0)
-        return jnp.where(valid, jnp.sum(x_lat * w_rows, axis=-1), 0.0)
+        scores, plan = factored.score_factored(
+            model.bank, model.projection, self.re_dataset, self.problem
+        )
+        _count_scored_rows(self.name, plan.block_rows, plan.gather_rows)
+        return scores
 
     def regularization_term(self, model) -> float:
-        return self.problem.regularization_term(model.bank)
+        from photon_ml_tpu.parallel import overlap
+
+        return float(
+            overlap.device_get(self.regularization_term_device(model))
+        )
+
+    def regularization_term_device(self, model) -> Array:
+        """The latent bank's term and the projection's own ``l2/2 |B|^2
+        (+ l1 |B|_1)`` at ``reg_weight_projection``."""
+        B = model.projection
+        l1, l2 = self.projection_problem.regularization.split(
+            self.reg_weight_projection
+        )
+        term = self.problem.regularization_term_device(model.bank)
+        term = term + 0.5 * l2 * jnp.sum(B * B)
+        if l1:
+            term = term + l1 * jnp.sum(jnp.abs(B))
+        return term
+
+    def prepare(self, model=None) -> None:
+        """The latent bank's :meth:`RandomEffectOptimizationProblem.prepare`
+        (block copies, residual arrays, AOT solver programs) under its
+        values override, and the scoring plan."""
+        model = model if model is not None else self.initialize_model()
+        view = self._view()
+        override = factored.latent_override(model.projection)
+        self.problem.prepare(
+            model.bank, view, coordinate=self.name, override=override
+        )
+        score_plan(view, self.problem, staged=override.staged)
 
 
 @dataclass
@@ -1013,43 +1113,9 @@ class FactoredRandomEffectModel(DatumScoringModel):
     feature_shard_id: str
 
     def score(self, dataset: GameDataset) -> Array:
-        codes, valid, ix, v = device_row_view(self.re_dataset)
-        x_lat = jnp.einsum("nk,nkl->nl", v, jnp.take(self.projection, ix, axis=0))
-        w_rows = jnp.take(self.bank, codes, axis=0)
-        return jnp.where(valid, jnp.sum(x_lat * w_rows, axis=-1), 0.0)
-
-
-def _latent_view(
-    base: RandomEffectDataset, x_lat: np.ndarray
-) -> RandomEffectDataset:
-    """Re-project a RandomEffectDataset's rows into latent space: dense
-    identity-local features of width L, same entity grouping/buckets."""
-    from dataclasses import replace as dc_replace
-
-    L = x_lat.shape[1]
-    n = base.row_local_indices.shape[0]
-    row_ix = np.tile(np.arange(L, dtype=np.int32)[None, :], (n, 1))
-    buckets = []
-    for b in base.buckets:
-        safe = np.maximum(b.row_index, 0)
-        bix = np.tile(
-            np.arange(L, dtype=np.int32)[None, None, :],
-            (b.num_entities, b.capacity, 1),
-        )
-        bv = x_lat[safe].astype(np.float32)
-        bv = np.where((b.row_index >= 0)[:, :, None], bv, 0.0)
-        buckets.append(
-            dc_replace(b, indices=bix, values=bv, identity_indices=True)
-        )
-    return dc_replace(
-        base,
-        local_dim=L,
-        projection=np.tile(np.arange(L, dtype=np.int32)[None, :], (base.num_entities, 1)),
-        row_local_indices=row_ix,
-        row_local_values=x_lat.astype(np.float32),
-        buckets=buckets,
-        random_projection=None,
-    )
+        return factored.score_factored(
+            self.bank, self.projection, self.re_dataset
+        )[0]
 
 
 def partner_factors(latent: Array, keys: Array) -> Array:

@@ -38,6 +38,13 @@ from photon_ml_tpu.utils.logging_util import PhotonLogger
 Array = jnp.ndarray
 
 
+def _deferreds(tracker) -> list:
+    """The device-resident halves of a coordinate's tracker: one
+    ``deferred``, or (a factored random effect's) several ``deferreds``."""
+    many = getattr(tracker, "deferreds", None)
+    return many if many is not None else [getattr(tracker, "deferred", None)]
+
+
 # The CD loop's own device arithmetic, as named programs (the function
 # handed to jax.jit names the XLA module) with a scope each, so that a
 # device trace places it in this layer.
@@ -354,8 +361,8 @@ class CoordinateDescent:
                     overlap.fetch_all(
                         [objective_d]
                         + [
-                            getattr(trackers[name][-1], "deferred", None)
-                            for name in seq
+                            d for name in seq
+                            for d in _deferreds(trackers[name][-1])
                         ]
                         + list(solved.values())
                     )

@@ -580,14 +580,20 @@ def _bucket_solver(
         ._solver_blocks`): it starts from zero on weight-0 rows, its
         scatter drops and it counts in no reduction, as the pod's pad
         lanes do. With ``values_of`` (:class:`ValuesOverride`) the block
-        holds no values: ``v`` carries its keys, and its values are made
-        HERE, inside the program, so that only this block's are alive."""
+        holds no values: ``v`` carries its keys (or, an override that
+        reads ``rows``, the block's own sparse values beside ``ix``), and
+        its values are made HERE, inside the program, so that only this
+        block's are alive."""
         if values_of is not None:
+            # (fn, "rows"): an override that reads the block's rows
+            rows = isinstance(values_of, tuple)
             with jax.named_scope("bank.values_override"):
-                v = values_of(operand, v)
-            if ix.shape[-1] != v.shape[-1]:
-                # an identity block holds no indices either; only the
-                # sparse solver reads them
+                v = values_of[0](operand, ix, v) if rows else values_of(
+                    operand, v
+                )
+            if rows or ix.shape[-1] != v.shape[-1]:
+                # the made values are an identity block; only the sparse
+                # solver reads its indices
                 ix = jnp.broadcast_to(
                     jnp.arange(v.shape[-1], dtype=ix.dtype), v.shape
                 )
@@ -799,23 +805,47 @@ def _residual_program(coordinate: Optional[str]):
 
 class ValuesOverride(NamedTuple):
     """Feature values a bank update MAKES instead of reading: the solver
-    program calls ``fn(operand, keys)`` on the device for each block it
-    runs, ``keys`` being the block's :attr:`RandomEffectBucket
-    .override_keys` ``[E, S]``, and solves on the ``[E, S, k]`` it
-    returns. An ALS half-step: ``operand`` the partner side's factors,
-    ``keys`` each rating's partner code. The values live only inside the
+    program calls ``fn`` on the device for each block it runs and solves
+    on the ``[E, S, k]`` identity block it returns. ``reads``: what of
+    the block ``fn`` reads. ``"keys"``: ``fn(operand, keys)``, ``keys``
+    the block's :attr:`RandomEffectBucket.override_keys` ``[E, S]`` (an
+    ALS half-step: ``operand`` the partner side's factors, ``keys`` each
+    rating's partner code). ``"rows"``: ``fn(operand, ix, v)``, the
+    block's own sparse rows ``[E, S, k']`` (a factored random effect:
+    ``operand`` the projection, the values each row's latent features).
+    ``staged``: floats a slot ``fn`` stages beside what it returns (a
+    densified row: its width), charged to the dense budget so that a
+    block's staging stays under it. The values live only inside the
     program of one block (one sub-block of a split bucket), never beside
     another's. ``fn`` is part of the compiled program's identity: pass a
     module-level function, not a fresh closure."""
 
     fn: object
     operand: object  # pytree of device arrays, the same for every block
+    reads: str = "keys"
+    staged: int = 0
+
+    @property
+    def values_of(self):
+        """What the fused programs are built from: ``fn`` (it reads
+        keys), or ``(fn, "rows")``."""
+        return self.fn if self.reads == "keys" else (self.fn, self.reads)
 
     @property
     def sig(self) -> tuple:
-        return (self.fn,) + tuple(
+        return (self.fn, self.reads, self.staged) + tuple(
             tuple(a.shape) for a in jax.tree.leaves(self.operand)
         )
+
+
+def _staged(override: Optional[ValuesOverride]) -> int:
+    return 0 if override is None else override.staged
+
+
+def _holds_values(override: Optional[ValuesOverride]) -> bool:
+    """Whether a block's device arrays hold its stored values (no
+    override, or one that reads the block's rows) or its keys."""
+    return override is None or override.reads == "rows"
 
 
 class _SolverBlock(NamedTuple):
@@ -942,7 +972,8 @@ class RandomEffectOptimizationProblem:
         return router
 
     def dense_block_plan(
-        self, num_entities: int, capacity: int, d_local: int, identity: bool
+        self, num_entities: int, capacity: int, d_local: int, identity: bool,
+        staged: int = 0,
     ) -> Tuple[str, int]:
         """The one rule for a block of ``num_entities`` entities at
         ``capacity`` samples and ``d_local`` features each: the solver
@@ -966,7 +997,9 @@ class RandomEffectOptimizationProblem:
         features (``capacity > d_local``) runs the primal kind and every
         other the dual one: 16 rows of 1,000 features stay dual, a bias
         over 9,254 ratings or a rank-64 factor over 67,310 is primal
-        (its Gram alone would be 16 GiB)."""
+        (its Gram alone would be 16 GiB). ``staged``: floats a slot a
+        values override stages beside its values
+        (:attr:`ValuesOverride.staged`), charged to every kind."""
         if self.layout == "sparse":
             return "sparse", num_entities
         newton = self._newton_eligible()
@@ -992,6 +1025,7 @@ class RandomEffectOptimizationProblem:
             floats = 0 if identity else capacity * d_local
             if newton:
                 floats += capacity * capacity
+        floats += capacity * staged
         if floats == 0:
             return kind, num_entities
         cap = self.dense_bytes_budget // (floats * _BLOCK_ITEMSIZE)
@@ -1010,7 +1044,8 @@ class RandomEffectOptimizationProblem:
         return kind if cap >= e_b else "sparse"
 
     def _solver_blocks(
-        self, dataset: RandomEffectDataset, d_local: int, *, split: bool
+        self, dataset: RandomEffectDataset, d_local: int, *, split: bool,
+        staged: int = 0,
     ) -> List[_SolverBlock]:
         """The dataset's buckets as the blocks the solver programs run,
         in bucket order. With ``split`` a bucket whose dense staging is
@@ -1020,13 +1055,14 @@ class RandomEffectOptimizationProblem:
         consecutive, so they fold into one scanned dispatch that hands
         the donated bank from one to the next. Without it (the entity
         mesh addresses a bucket by its index) a bucket is one block of
-        :meth:`_bucket_kind`. The sub-block views are cached on the
-        dataset, keyed by the split."""
+        :meth:`_bucket_kind`. ``staged``: a values override's
+        (:attr:`ValuesOverride.staged`). The sub-block views are cached
+        on the dataset, keyed by the split."""
         plans = []
         for bucket in dataset.buckets:
             e_b, s_b, _ = bucket.indices.shape
             kind, cap = self.dense_block_plan(
-                e_b, s_b, d_local, bucket.identity_indices
+                e_b, s_b, d_local, bucket.identity_indices, staged
             )
             if cap >= e_b:
                 plans.append((kind, 1))
@@ -1272,6 +1308,24 @@ class RandomEffectOptimizationProblem:
                 residual_offsets, specs, capacities=capacities
             )
 
+    def group_offsets(
+        self, dataset, residual_offsets, *,
+        override: Optional[ValuesOverride] = None,
+        coordinate: Optional[str] = None,
+    ):
+        """``(groups, offsets)``: the groups of solver blocks an
+        ``update_bank`` over ``dataset`` under ``override`` runs on one
+        device, and their offsets from the ``[n]`` residual (one
+        :func:`_residual_program` run), which ``update_bank`` takes as
+        ``group_offsets``."""
+        groups = self._update_groups(
+            dataset, dataset.local_dim, staged=_staged(override)
+        )
+        residual_offsets = jnp.asarray(residual_offsets, jnp.float32)
+        return groups, self._bucket_offsets(
+            dataset, groups, residual_offsets, coordinate
+        )
+
     @staticmethod
     def _program_sig(kind, coordinate, bank_shape, ix_shape, override,
                      scan=False):
@@ -1301,7 +1355,7 @@ class RandomEffectOptimizationProblem:
         seen_sigs = set()
         sds = jax.ShapeDtypeStruct
         f32, i32 = jnp.float32, jnp.int32
-        values_of = override.fn if override is not None else None
+        values_of = override.values_of if override is not None else None
         extra = () if override is None else (jax.tree.map(
             lambda a: sds(a.shape, a.dtype), override.operand
         ),)
@@ -1317,10 +1371,10 @@ class RandomEffectOptimizationProblem:
                 continue  # identical program; one compile suffices
             seen_sigs.add(sig)
             es = lead + bucket.labels.shape
-            # under an override the values' place holds the keys [E, S]
+            # under an override of keys the values' place holds them [E, S]
             v_aval = (
                 sds(ixk[:-1] + bucket.values.shape[-1:], f32)
-                if override is None else sds(es, i32)
+                if _holds_values(override) else sds(es, i32)
             )
 
             def thunk(kind=kind, scan=scan, ixk=ixk, es=es, v_aval=v_aval,
@@ -1357,6 +1411,7 @@ class RandomEffectOptimizationProblem:
         self, bank: Array, dataset: RandomEffectDataset,
         *, has_residual_offsets: bool = True,
         coordinate: Optional[str] = None,
+        override: Optional[ValuesOverride] = None,
     ) -> None:
         """Host-side staging for a FUTURE update_bank over ``dataset``:
         device transfer of every block's static arrays (stacked group
@@ -1366,42 +1421,53 @@ class RandomEffectOptimizationProblem:
         background thread while ANOTHER coordinate's solves occupy the
         device (the overlap prefetched-dispatch lever: coordinate k+1's
         host prep runs under coordinate k's device work instead of as a
-        serial gap between their dispatches)."""
+        serial gap between their dispatches). ``override``: the values
+        override the update will run under (:class:`ValuesOverride`)."""
         if not dataset.buckets:
             return
         # update_bank's own groups (variance-typed problems run the
         # per-block path, so stage per-block device args — a stacked copy
         # would pin HBM the update never reads)
         groups = self._update_groups(
-            dataset, bank.shape[1], with_variances=self.compute_variances
+            dataset, bank.shape[1], with_variances=self.compute_variances,
+            staged=_staged(override),
         )
+        with_values = _holds_values(override)
         for members in groups:
             if len(members) > 1:
                 self._stacked_group_args(
-                    dataset, members, with_residuals=has_residual_offsets
+                    dataset, members, with_residuals=has_residual_offsets,
+                    with_values=with_values,
                 )
             else:
-                self._bucket_device_args(members[0].bucket)
+                self._bucket_device_args(
+                    members[0].bucket, with_values=with_values
+                )
         if self.mesh is None:
             if has_residual_offsets:
                 self._residual_args(dataset, groups)
             l1, l2 = self.regularization.split(self.reg_weight)
             self._warm_solvers(self._bucket_plans(
                 bank, groups,
-                override=None,
+                override=override,
                 l1_d=jnp.float32(l1), l2_d=jnp.float32(l2),
                 coordinate=coordinate,
             ))
         elif has_residual_offsets:
             self._router_for(dataset)  # static routing tables, host-built
 
-    def _update_groups(self, dataset, d_local: int, *, with_variances=False):
+    def _update_groups(
+        self, dataset, d_local: int, *, with_variances=False, staged=0
+    ):
         """The groups of solver blocks ONE ``update_bank`` over
         ``dataset`` dispatches (:meth:`_solver_blocks`,
         :meth:`_block_groups`): split and folded on one device, a bucket
-        a block on the entity mesh, which addresses buckets by index."""
+        a block on the entity mesh, which addresses buckets by index.
+        ``staged``: a values override's (:attr:`ValuesOverride.staged`)."""
         foldable = self.mesh is None
-        blocks = self._solver_blocks(dataset, d_local, split=foldable)
+        blocks = self._solver_blocks(
+            dataset, d_local, split=foldable, staged=staged
+        )
         return self._block_groups(
             blocks, fold=foldable and not with_variances and len(blocks) > 1
         )
@@ -1419,7 +1485,9 @@ class RandomEffectOptimizationProblem:
         plans = []
         for bank, dataset, override, coordinate in specs:
             plans += self._bucket_plans(
-                bank, self._update_groups(dataset, bank.shape[1]),
+                bank, self._update_groups(
+                    dataset, bank.shape[1], staged=_staged(override)
+                ),
                 override=override,
                 l1_d=l1_d, l2_d=l2_d, coordinate=coordinate,
             )
@@ -1473,6 +1541,7 @@ class RandomEffectOptimizationProblem:
         with_variances: bool = False,
         defer_tracker: bool = False,
         coordinate: Optional[str] = None,
+        group_offsets: Optional[List[Array]] = None,
     ):
         """Solve every entity against its active data; returns the new bank
         and an aggregated tracker — plus the per-entity variance bank when
@@ -1498,6 +1567,11 @@ class RandomEffectOptimizationProblem:
         ``bank.dispatch`` spans and labels
         ``photon_bank_entities_total``, so that a trace and the registry
         tell one coordinate's bank from another's.
+
+        ``group_offsets``: the offsets of this update's groups already
+        made from the residual (:meth:`group_offsets`), in the residual's
+        place: a caller that runs several updates under one residual
+        makes them once.
         """
         l1, l2 = self.regularization.split(self.reg_weight)
         l1_d, l2_d = jnp.float32(l1), jnp.float32(l2)
@@ -1529,10 +1603,11 @@ class RandomEffectOptimizationProblem:
         # mesh and the variances cases.
         override = values_override
         groups = self._update_groups(
-            dataset, bank.shape[1], with_variances=with_variances
+            dataset, bank.shape[1], with_variances=with_variances,
+            staged=_staged(override),
         )
         extra = () if override is None else (override.operand,)
-        values_of = override.fn if override is not None else None
+        values_of = override.values_of if override is not None else None
         if self.mesh is None and dataset.buckets:
             self._warm_solvers(self._bucket_plans(
                 bank, groups,
@@ -1551,8 +1626,11 @@ class RandomEffectOptimizationProblem:
             "ops/spd_solve solves a batch of them",
         )
         solve = solve_path(bank.shape[1], effective_platform())
-        offsets = None
-        if residual_offsets is not None and routed is None and groups:
+        offsets = group_offsets
+        if (
+            offsets is None and residual_offsets is not None
+            and routed is None and groups
+        ):
             offsets = self._bucket_offsets(
                 dataset, groups, residual_offsets, coordinate
             )
@@ -1577,8 +1655,8 @@ class RandomEffectOptimizationProblem:
                     codes_s, ix_s, v_s, lab_s, off_s, w_s,
                 ) = self._stacked_group_args(
                     dataset, members,
-                    with_residuals=residual_offsets is not None,
-                    with_values=override is None,
+                    with_residuals=offsets is not None,
+                    with_values=_holds_values(override),
                 )
                 if offsets is not None:
                     off_s = offsets[gi]
@@ -1602,7 +1680,7 @@ class RandomEffectOptimizationProblem:
             (
                 ix_d, v_d, lab_d, w_d, off_d, codes_d,
             ) = self._bucket_device_args(
-                bucket, with_values=override is None
+                bucket, with_values=_holds_values(override)
             )
             if override is not None and self.mesh is not None:
                 # the mesh path's solvers take values, not keys: this
@@ -1845,6 +1923,7 @@ def _default_problem() -> "RandomEffectOptimizationProblem":
 def score_plan(
     dataset: RandomEffectDataset,
     problem: Optional["RandomEffectOptimizationProblem"] = None,
+    staged: int = 0,
 ) -> _ScorePlan:
     """The :class:`_ScorePlan` of ``dataset`` under ``problem``, decided
     from the data: a row that a DENSE solver block holds (the blocks
@@ -1854,12 +1933,14 @@ def score_plan(
     local space is too wide to compare against
     (:func:`scores_from_block`), every row of a view without buckets
     and every row under the entity mesh (its blocks are entity-sharded)
-    keep the gather. Cached on the dataset, keyed by the split."""
+    keep the gather. ``staged``: the values override's the update runs
+    under (:attr:`ValuesOverride.staged`), so that the blocks are its.
+    Cached on the dataset, keyed by the split."""
     problem = problem or _default_problem()
     blocks = []
     if problem.mesh is None and dataset.buckets:
         blocks = problem._solver_blocks(
-            dataset, dataset.local_dim, split=True
+            dataset, dataset.local_dim, split=True, staged=staged
         )
     # the update's own fold rule, so that scoring finds the device arrays
     # the update holds
@@ -1921,6 +2002,17 @@ def score_random_effect(
     already holds for them (:func:`score_plan`)."""
     problem = problem or _default_problem()
     plan = score_plan(dataset, problem)
+    return re_score(
+        bank, score_blocks(problem, dataset, plan), plan.rest,
+        identity=tuple(m[0].bucket.identity_indices for m in plan.groups),
+        num_rows=int(dataset.row_entity_codes.shape[0]),
+    )
+
+
+def score_blocks(problem, dataset, plan: _ScorePlan) -> tuple:
+    """``(codes, ix, v, rows)`` on the device for each group of ``plan``
+    (stacked ``[B, ...]`` for a folded group): the copies ``problem``'s
+    bank update holds, and the groups' ``row_index``."""
     blocks = []
     for members in plan.groups:
         if len(members) > 1:
@@ -1934,11 +2026,7 @@ def score_random_effect(
             )
             rows = problem._bucket_rows(members[0].bucket)
         blocks.append((codes, ix, v, rows))
-    return re_score(
-        bank, tuple(blocks), plan.rest,
-        identity=tuple(m[0].bucket.identity_indices for m in plan.groups),
-        num_rows=int(dataset.row_entity_codes.shape[0]),
-    )
+    return tuple(blocks)
 
 
 @partial(jax.jit, static_argnames=("identity", "num_rows"))

@@ -520,6 +520,12 @@ class _OpenSpan:
         if self._annotation is not None:
             self._annotation.set_metadata(**attrs)
 
+    def set_after(self, **attrs) -> None:
+        """Attach result attrs that arrive after the span closed (a count
+        the device reports at a later readback): the ring span's; the
+        profiler annotation is gone by then."""
+        self._span.set(**attrs)
+
 
 def current_span():
     """The innermost open ``span()`` of this thread or task, or None."""

@@ -21,7 +21,6 @@ from photon_ml_tpu.game.config import (
 from photon_ml_tpu.game.coordinate import (
     PodRandomEffectCoordinate,
     RandomEffectCoordinate,
-    _latent_view,
 )
 from photon_ml_tpu.game.data import EntityIndex, GameDataset, ShardData
 from photon_ml_tpu.game.pod import PodRandomEffectProblem, ShardedREBank
@@ -198,13 +197,39 @@ def test_a_split_bucket_scores_sub_block_by_sub_block(n_sub, cap):
     assert set(stacked) == before
 
 
+def _identity_view(base, x_lat):
+    """``base``'s rows as dense identity-local features ``x_lat`` [n, L]
+    (every bucket's indices the tiled ``arange(L)``): the identity blocks
+    a values override's solvers run."""
+    L = x_lat.shape[1]
+    n = base.row_local_indices.shape[0]
+    buckets = []
+    for b in base.buckets:
+        ok = (b.row_index >= 0)[:, :, None]
+        buckets.append(replace(
+            b,
+            indices=np.broadcast_to(
+                np.arange(L, dtype=np.int32), b.row_index.shape + (L,)
+            ).copy(),
+            values=np.where(ok, x_lat[np.maximum(b.row_index, 0)], 0.0)
+            .astype(np.float32),
+            identity_indices=True,
+        ))
+    return replace(
+        base, local_dim=L,
+        projection=np.tile(np.arange(L, dtype=np.int32), (base.num_entities, 1)),
+        row_local_indices=np.tile(np.arange(L, dtype=np.int32), (n, 1)),
+        row_local_values=x_lat, buckets=buckets, random_projection=None,
+    )
+
+
 def test_an_identity_indices_bucket_multiplies_with_no_compare():
     _, red = _dataset(projector=ProjectorType.IDENTITY)
     rng = np.random.default_rng(3)
     x_lat = rng.normal(
         size=(red.row_entity_codes.shape[0], 5)
     ).astype(np.float32)
-    view = _latent_view(red, x_lat)
+    view = _identity_view(red, x_lat)
     assert all(b.identity_indices for b in view.buckets)
     problem, bank = _problem(), _bank(view)
     assert score_plan(view, problem).kernel == "blocks"
