@@ -770,34 +770,50 @@ def _residual_program(coordinate: Optional[str]):
     into every group's offsets (``RandomEffectOptimizationProblem
     ._bucket_offsets``), module ``jit_bank_residual[_<coordinate>]``
     (non-word characters as ``_``, as ``fused_for`` names the solver
-    programs). ``specs`` and ``capacities`` as
-    :meth:`RandomEffectOptimizationProblem._residual_args` makes them: a
-    group of runs reads windows (:func:`_residual_windows`; a folded
-    group's [B, E] starts as one [B * E]), any other the element gather
-    through its ``row_index``, a padding slot (-1) reading 0."""
+    programs). ``specs``, ``keys`` and ``paths`` as
+    :meth:`RandomEffectOptimizationProblem._residual_args` makes them, a
+    ``(path, capacity)`` a group: ``windows``, a group of runs, reads
+    windows of the row vector (:func:`_residual_windows`; a folded
+    group's [B, E] starts as one [B * E]); ``sorted`` reads windows of
+    the residual sorted by ``keys``, the dataset's entity order, ONE
+    ``lax.sort`` in this program for every such group; ``slots`` the
+    element gather through the group's ``row_index``, a padding slot (-1)
+    reading 0. Values are moved, never computed with: all three give the
+    same bits."""
 
-    @partial(jax.jit, static_argnames=("capacities",))
+    @partial(jax.jit, static_argnames=("paths",))
     @_named("bank_residual", coordinate)
-    def bank_residual(residual, specs, *, capacities):
-        # the vector as rows of 128, zero-padded past the widest window
-        # that starts at its last row and the row after that
-        reach = max(capacities) + 2 * _LANES
-        rows = jnp.pad(
-            residual, (0, -(residual.shape[0] + reach) % _LANES + reach)
-        ).reshape(-1, _LANES)
+    def bank_residual(residual, specs, keys=None, *, paths):
+        vectors = {"windows": residual}
+        if keys is not None:
+            # keys are unique: a stable sort would order nothing more
+            _, vectors["sorted"] = jax.lax.sort(
+                (keys, residual), num_keys=1, is_stable=False
+            )
+        # each vector read as windows as rows of 128, zero-padded past the
+        # widest window that starts at its last row and the row after that
+        reach = max(capacity for _, capacity in paths) + 2 * _LANES
+        rows = {
+            path: jnp.pad(
+                vector, (0, -(vector.shape[0] + reach) % _LANES + reach)
+            ).reshape(-1, _LANES)
+            for path, vector in vectors.items()
+            if any(p == path for p, _ in paths)
+        }
         out = []
-        for spec, capacity in zip(specs, capacities):
-            if capacity:
-                starts, counts = spec
-                win = _residual_windows(
-                    rows, starts.reshape(-1), counts.reshape(-1), capacity
-                )
-                out.append(win.reshape(starts.shape + (capacity,)))
-            else:
+        for spec, (path, capacity) in zip(specs, paths):
+            if path == "slots":
                 (at,) = spec
                 out.append(
                     jnp.where(at >= 0, residual[jnp.maximum(at, 0)], 0.0)
                 )
+            else:
+                starts, counts = spec
+                win = _residual_windows(
+                    rows[path], starts.reshape(-1), counts.reshape(-1),
+                    capacity,
+                )
+                out.append(win.reshape(starts.shape + (capacity,)))
         return out
 
     return bank_residual
@@ -861,40 +877,49 @@ class _SolverBlock(NamedTuple):
     num_real: int  # entities less the last sub-block's padding lanes
 
 
+def _sub_block(a: np.ndarray, n_sub: int, j: int, fill=0) -> np.ndarray:
+    """Sub-block ``j`` of ``n_sub`` equal ones of ``a`` along its entity
+    axis, the last padded with ``fill``."""
+    e_sub = -(-a.shape[0] // n_sub)
+    a = a[j * e_sub:(j + 1) * e_sub]
+    if a.shape[0] < e_sub:
+        pad = np.full((e_sub - a.shape[0],) + a.shape[1:], fill, a.dtype)
+        a = np.concatenate([a, pad])
+    return a
+
+
+def _sub_runs(runs: Optional[RowRuns], n_sub: int, j: int):
+    """Sub-block ``j`` of ``n_sub`` of a bucket's runs: a slice of runs is
+    runs, and a padding entity an empty one."""
+    if runs is None or n_sub == 1:
+        return runs
+    return RowRuns(*(_sub_block(a, n_sub, j) for a in runs))
+
+
 def _split_bucket(
     bucket: RandomEffectBucket, n_sub: int, pad_code: int
 ) -> List[RandomEffectBucket]:
     """``bucket`` as ``n_sub`` sub-blocks of one shape: views of its host
     arrays, the last padded with weight-0 entities on no row whose code
     ``pad_code`` lies past the bank (the fused programs' padding lanes)."""
-    e_sub = -(-bucket.num_entities // n_sub)
     fill = {"entity_codes": pad_code, "row_index": -1, "override_keys": -1}
     names = ["entity_codes", "row_index", "indices", "values", "labels",
              "offsets", "weights"]
     if bucket.override_keys is not None:
         names.append("override_keys")
-
-    def part(a, j, fill=0):
-        a = a[j * e_sub:(j + 1) * e_sub]
-        if a.shape[0] < e_sub:
-            pad = np.full((e_sub - a.shape[0],) + a.shape[1:], fill, a.dtype)
-            a = np.concatenate([a, pad])
-        return a
-
-    out = []
-    for j in range(n_sub):
-        parts = {
-            name: part(getattr(bucket, name), j, fill.get(name, 0))
-            for name in names
-        }
-        # a slice of runs is runs, and a padding entity an empty one
-        runs = None if bucket.row_runs is None else RowRuns(
-            *(part(a, j) for a in bucket.row_runs)
+    return [
+        RandomEffectBucket(
+            identity_indices=bucket.identity_indices,
+            row_runs=_sub_runs(bucket.row_runs, n_sub, j),
+            **{
+                name: _sub_block(
+                    getattr(bucket, name), n_sub, j, fill.get(name, 0)
+                )
+                for name in names
+            },
         )
-        out.append(RandomEffectBucket(
-            identity_indices=bucket.identity_indices, row_runs=runs, **parts
-        ))
-    return out
+        for j in range(n_sub)
+    ]
 
 
 @dataclass
@@ -1246,31 +1271,61 @@ class RandomEffectOptimizationProblem:
 
     def _residual_args(self, dataset, groups):
         """What :func:`_residual_program` reads for ``groups`` of solver
-        blocks, on the device, cached on the dataset: ``(starts, counts)``
-        [E] (a folded group: [B, E]) where every block of the group
-        observed runs (:class:`RowRuns`), else ``(rows,)``, the group's
-        ``row_index``; and the static capacities that say which, 0 for
-        the slot path."""
+        blocks, on the device, cached on the dataset: ``(specs, keys,
+        paths)``. A group's spec is ``(starts, counts)`` [E] (a folded
+        group: [B, E]) where every block of it observed runs in the row
+        vector (:class:`RowRuns`; path ``windows``) or, failing that, in
+        the residual sorted into the dataset's entity order
+        (:attr:`RandomEffectDataset.entity_order`, observed here when
+        first read; path ``sorted``), else ``(rows,)``, the group's
+        ``row_index`` (path ``slots``: no order, as where a row is held
+        twice or a bucket's real slots are not a prefix). ``keys``: the
+        order's keys on the device, uploaded once and cached here, where
+        some group is ``sorted``; else None. ``paths``, static:
+        ``(path, capacity)`` a group."""
         cache = dataset.__dict__.setdefault("_residual_device_cache", {})
         key = tuple(tuple(b[:3] for b in members) for members in groups)
-        if key not in cache:
-            specs, capacities = [], []
-            for members in groups:
-                runs = [b.bucket.row_runs for b in members]
-                if all(r is not None for r in runs):
-                    fields = [np.stack(field) for field in zip(*runs)]
-                    if len(members) == 1:
-                        fields = [field[0] for field in fields]
-                    specs.append(tuple(jnp.asarray(f) for f in fields))
-                    capacities.append(members[0].bucket.capacity)
-                else:
-                    specs.append((
-                        self._stacked_rows(dataset, members)
-                        if len(members) > 1
-                        else self._bucket_rows(members[0].bucket),
-                    ))
-                    capacities.append(0)
-            cache[key] = (tuple(specs), tuple(capacities))
+        if key in cache:
+            return cache[key]
+
+        def runs_of(members):
+            runs = [b.bucket.row_runs for b in members]
+            if all(r is not None for r in runs):
+                return "windows", runs
+            order = dataset.entity_order
+            if order is None:
+                return "slots", None
+            runs = [
+                _sub_runs(order.runs[b.bucket_index], b.sub_blocks,
+                          b.sub_block)
+                for b in members
+            ]
+            if all(r is not None for r in runs):
+                return "sorted", runs
+            return "slots", None
+
+        specs, paths = [], []
+        for members in groups:
+            path, runs = runs_of(members)
+            if runs is None:
+                specs.append((
+                    self._stacked_rows(dataset, members)
+                    if len(members) > 1
+                    else self._bucket_rows(members[0].bucket),
+                ))
+                paths.append((path, 0))
+                continue
+            fields = [np.stack(field) for field in zip(*runs)]
+            if len(members) == 1:
+                fields = [field[0] for field in fields]
+            specs.append(tuple(jnp.asarray(f) for f in fields))
+            paths.append((path, members[0].bucket.capacity))
+        keys = None
+        if any(path == "sorted" for path, _ in paths):
+            if "entity_order" not in cache:
+                cache["entity_order"] = jnp.asarray(dataset.entity_order.keys)
+            keys = cache["entity_order"]
+        cache[key] = (tuple(specs), keys, tuple(paths))
         return cache[key]
 
     def _bucket_offsets(
@@ -1285,19 +1340,23 @@ class RandomEffectOptimizationProblem:
         (the KeyValueScore residual currency never leaves it, SURVEY
         §7.9) and reads what :meth:`_residual_args` uploaded: windows of
         the row vector at [E] starts for a group whose entities' rows are
-        runs, the element gather through the uploaded ``row_index`` for
-        any other. Which of the two a group's slots took is counted here,
-        on the host, where it is decided."""
-        specs, capacities = self._residual_args(dataset, groups)
+        runs, windows of the residual sorted into the dataset's entity
+        order for a group whose rows are a run there, the element gather
+        through the uploaded ``row_index`` for any other. Which path a
+        group's slots took is counted here, on the host, where it is
+        decided."""
+        specs, keys, paths = self._residual_args(dataset, groups)
         slots = default_registry().counter(
             "photon_bank_residual_slots_total",
             "slots of the replicated bank's blocks whose offsets were "
             "read from the residual, by coordinate and path (windows: an "
-            "entity's rows are a run; slots: the element gather)",
+            "entity's rows are a run; sorted: they are a run once the "
+            "residual is sorted into the dataset's entity order; slots: "
+            "the element gather)",
         )
-        taken = {"windows": 0, "slots": 0}
-        for members, capacity in zip(groups, capacities):
-            taken["windows" if capacity else "slots"] += (
+        taken = {"windows": 0, "sorted": 0, "slots": 0}
+        for members, (path, _) in zip(groups, paths):
+            taken[path] += (
                 sum(b.num_real for b in members) * members[0].bucket.capacity
             )
         for path, count in taken.items():
@@ -1305,7 +1364,7 @@ class RandomEffectOptimizationProblem:
                 slots.inc(count, coordinate=coordinate or "", path=path)
         with obs_span("bank.residual", groups=len(groups), **taken):
             return _residual_program(coordinate)(
-                residual_offsets, specs, capacities=capacities
+                residual_offsets, specs, keys, paths=paths
             )
 
     def group_offsets(

@@ -18,6 +18,7 @@ bucket (SURVEY P2: entities are the expert-parallel analog).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import cached_property
 from typing import List, NamedTuple, Optional
 
 import numpy as np
@@ -33,7 +34,10 @@ from photon_ml_tpu.obs.trace import span as obs_span
 class RowRuns(NamedTuple):
     """A bucket whose every entity's real slots hold CONSECUTIVE rows in
     slot order (``row_index[e, j] == starts[e] + j`` for ``j <
-    counts[e]``, -1 after): the bucket's ``row_index`` in 2 x [E_b]."""
+    counts[e]``, -1 after): the bucket's ``row_index`` in 2 x [E_b].
+    The same pair says where an entity's rows sit in the residual SORTED
+    into the dataset's entity order (:func:`observe_entity_order`), for a
+    bucket whose rows are no runs in the row vector itself."""
 
     starts: np.ndarray  # int32 [E_b]; 0 for an entity with no real slot
     counts: np.ndarray  # int32 [E_b]
@@ -51,6 +55,54 @@ def observe_row_runs(row_index: np.ndarray) -> Optional[RowRuns]:
     if not np.array_equal(runs, row_index):
         return None
     return RowRuns(starts, counts)
+
+
+class EntityOrder(NamedTuple):
+    """The order that makes every entity's rows a run in a SORTED copy of
+    the residual, for the buckets that observe no runs in the row vector
+    (:func:`observe_entity_order`)."""
+
+    keys: np.ndarray  # int32 [n]: each row's position in the order
+    # a bucket: its RowRuns in the sorted vector; None for a bucket of runs
+    # in the row vector or one whose real slots are not a prefix
+    runs: List[Optional[RowRuns]]
+
+
+def observe_entity_order(buckets, num_rows: int) -> Optional[EntityOrder]:
+    """The ENTITY ORDER of the rows held by the buckets that observe no
+    runs (``row_runs`` None), read from their ``row_index`` alone. The
+    order puts those buckets' rows bucket after bucket, entity after
+    entity, each entity's in slot order; ``keys`` int32 [num_rows] is each
+    row's position in it (a row no such bucket holds comes after the
+    last, in row order), so that sorting the residual by ``keys`` makes
+    every such entity's rows a run, at the bucket's ``runs``. A bucket
+    whose real slots are not a prefix of its slots gets none; None where
+    no bucket has them or a row is held twice (no permutation then)."""
+    runs, held, at = [], [], 0
+    for bucket in buckets:
+        real = bucket.row_index >= 0
+        counts = real.sum(axis=1, dtype=np.int32)
+        slot = np.arange(real.shape[1], dtype=np.int32)[None, :]
+        if bucket.row_runs is not None or not np.array_equal(
+            real, slot < counts[:, None]
+        ):
+            runs.append(None)
+            continue
+        starts = at + np.cumsum(counts) - counts
+        runs.append(RowRuns(
+            np.where(counts > 0, starts, 0).astype(np.int32), counts
+        ))
+        held.append(bucket.row_index[real])
+        at += int(counts.sum())
+    if not held:
+        return None
+    keys = np.full(num_rows, -1, np.int32)
+    keys[np.concatenate(held)] = np.arange(at, dtype=np.int32)
+    rest = keys < 0
+    if int(rest.sum()) != num_rows - at:  # a row held twice
+        return None
+    keys[rest] = np.arange(at, num_rows, dtype=np.int32)
+    return EntityOrder(keys, runs)
 
 
 @dataclass
@@ -106,6 +158,20 @@ class RandomEffectDataset:
     num_passive_rows: int
     # RANDOM projector only: [d_global, D] projection matrix
     random_projection: Optional[np.ndarray] = None
+
+    @cached_property
+    def entity_order(self) -> Optional[EntityOrder]:
+        """The buckets' :class:`EntityOrder` over the ``[n]`` rows,
+        observed when a replicated bank update first reads the residual
+        (``game/random_effect._residual_args``) and kept: a dataset that
+        never does (the pod's, a streamed segment's) never builds it."""
+        with obs_span(
+            "re.entity_order", rows=self.row_entity_codes.shape[0],
+            buckets=len(self.buckets),
+        ):
+            return observe_entity_order(
+                self.buckets, self.row_entity_codes.shape[0]
+            )
 
     @property
     def intercept_local_index(self) -> Optional[int]:

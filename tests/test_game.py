@@ -1278,7 +1278,8 @@ class TestBucketScanFold:
                 indices=b.indices, values=b.values, labels=b.labels,
                 offsets=b.offsets, weights=b.weights,
             ))
-        data = SimpleNamespace(buckets=buckets)
+        # rows drawn with repeats are no permutation: no entity order
+        data = SimpleNamespace(buckets=buckets, entity_order=None)
         residual = jnp.asarray(
             rng.normal(size=n_rows).astype(np.float32) * 0.1
         )

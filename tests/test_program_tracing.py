@@ -280,20 +280,25 @@ def test_a_cold_bank_update_files_its_warm_up_and_the_compiles(rng):
     assert "jax.trace" in named and all(s.t1 >= s.t0 for s in named["jax.trace"])
 
 
-@pytest.mark.parametrize("path", ["windows", "slots"])
+@pytest.mark.parametrize("path", ["windows", "sorted", "slots"])
 def test_a_warm_bank_update_runs_one_residual_program_and_compiles_nothing(
     path, monkeypatch
 ):
     """Under a residual a bank update turns the row vector into every
     block's offsets in ONE named program (no eager dispatch a block), on
-    the windows of a grouped table and on the slots of a shuffled one."""
+    the windows of a grouped table, and on a shuffled one's sorted
+    windows or, its entity order taken away, its slots."""
     from photon_ml_tpu.game import random_effect as re_mod
-    from test_residual_windows import _build, _grouped_codes, _problem
+    from test_residual_windows import (
+        _build, _grouped_codes, _problem, _without_runs,
+    )
 
     codes = _grouped_codes(seed=11)
-    if path == "slots":
+    if path != "windows":
         codes = np.random.default_rng(11).permutation(codes)
     red = _build(codes)
+    if path == "slots":
+        red = _without_runs(red)
     held = sum(
         b.row_index.size for b in red.buckets
         if (b.row_runs is not None) == (path == "windows")

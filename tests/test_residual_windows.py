@@ -1,8 +1,11 @@
 """The residual reaches a bank block as windows of the row vector where an
 entity's rows are a run (game/random_effect_data.observe_row_runs,
-game/random_effect._residual_program): the build observes runs from the
-data alone, the windows are the element gather's values to the bit, an
-update on a grouped table equals the same update on the slot path, and
+game/random_effect._residual_program), and as windows of the residual
+sorted into the dataset's entity order where they are not
+(observe_entity_order): runs and the order are observed from the data
+alone, the order only where a bank update reads the residual, both
+window paths are the element gather's values to the bit, an update on
+either equals the same update on the slot path, and
 ``photon_bank_residual_slots_total`` says which path the slots took."""
 
 from dataclasses import replace
@@ -24,6 +27,8 @@ from photon_ml_tpu.game.random_effect_data import (
     RandomEffectBucket,
     RowRuns,
     build_random_effect_dataset,
+    EntityOrder,
+    observe_entity_order,
     observe_row_runs,
 )
 from photon_ml_tpu.obs.registry import default_registry
@@ -99,7 +104,7 @@ def _slots_counted(coordinate):
     counter = default_registry().counter("photon_bank_residual_slots_total")
     return tuple(
         counter.value(coordinate=coordinate, path=path)
-        for path in ("windows", "slots")
+        for path in ("windows", "sorted", "slots")
     )
 
 
@@ -247,7 +252,7 @@ def test_windows_equal_the_element_gather_bitwise(case):
         jnp.asarray(residual),
         ((jnp.asarray(runs.starts), jnp.asarray(runs.counts)),
          (jnp.asarray(rows),)),
-        capacities=(S, 0),
+        paths=(("windows", S), ("slots", 0)),
     )
     want = _gathered(residual, rows)
     assert windows.shape == slots.shape == (E, S)
@@ -276,7 +281,7 @@ def test_a_sub_block_split_keeps_the_runs_and_the_values(n_sub):
         for field in zip(*(sub.row_runs for sub in subs))
     )
     (windows,) = _residual_program("t")(
-        jnp.asarray(residual), (stacked,), capacities=(S,)
+        jnp.asarray(residual), (stacked,), paths=(("windows", S),)
     )
     want = np.stack([_gathered(residual, sub.row_index) for sub in subs])
     np.testing.assert_array_equal(_bits(windows), _bits(want))
@@ -286,11 +291,13 @@ def test_a_sub_block_split_keeps_the_runs_and_the_values(n_sub):
 
 
 def _without_runs(red):
-    """The same dataset with the run observation forced off (every
-    bucket on the slot path)."""
-    return replace(
+    """The same dataset with the run and order observations forced off
+    (every bucket on the slot path)."""
+    off = replace(
         red, buckets=[replace(b, row_runs=None) for b in red.buckets]
     )
+    off.entity_order = None
+    return off
 
 
 @pytest.mark.parametrize("case", ["whole_buckets", "split_and_folded"])
@@ -317,20 +324,16 @@ def test_update_bank_on_runs_equals_the_slot_path_bitwise(case):
     got, _ = problem.update_bank(
         bank, red, residual_offsets=residual, coordinate=name
     )
-    windows, slots = (
-        a - b for a, b in zip(_slots_counted(name), before)
-    )
+    counted = tuple(a - b for a, b in zip(_slots_counted(name), before))
     held = sum(b.row_index.size for b in red.buckets)
-    assert (windows, slots) == (held, 0)
+    assert counted == (held, 0, 0)
     # the same table on the slot path, through a problem of its own
     off = _without_runs(red)
     want, _ = _problem(**kw).update_bank(
         bank, off, residual_offsets=residual, coordinate=name
     )
-    windows, slots = (
-        a - b for a, b in zip(_slots_counted(name), before)
-    )
-    assert (windows, slots) == (held, held)
+    counted = tuple(a - b for a, b in zip(_slots_counted(name), before))
+    assert counted == (held, 0, held)
     np.testing.assert_array_equal(_bits(got), _bits(want))
     # and the variances' pass reads the same offsets
     np.testing.assert_array_equal(
@@ -347,6 +350,7 @@ def test_a_mixed_dataset_counts_each_path_by_its_slots():
     mixed = replace(
         red, buckets=[replace(first, row_runs=None)] + red.buckets[1:]
     )
+    mixed.entity_order = None
     rng = np.random.default_rng(2)
     bank = jnp.zeros((red.num_entities, red.local_dim), jnp.float32)
     residual = jnp.asarray(
@@ -356,8 +360,11 @@ def test_a_mixed_dataset_counts_each_path_by_its_slots():
     got, _ = _problem().update_bank(
         bank, mixed, residual_offsets=residual, coordinate="mixed"
     )
-    windows, slots = (a - b for a, b in zip(_slots_counted("mixed"), before))
+    windows, sorted_, slots = (
+        a - b for a, b in zip(_slots_counted("mixed"), before)
+    )
     held = sum(b.row_index.size for b in red.buckets)
+    assert sorted_ == 0
     assert slots == first.row_index.size and windows + slots == held
     want, _ = _problem().update_bank(bank, red, residual_offsets=residual)
     np.testing.assert_array_equal(_bits(got), _bits(want))
@@ -388,8 +395,9 @@ def test_a_bucket_of_runs_uploads_no_row_index_for_the_residual():
 
 def test_the_als_structures_observe_runs_on_the_grouped_side_only():
     """A rating table grouped by user: the row half-step reads windows,
-    the column half-step, whose movies' rows lie all over it, slots; and
-    the pass equals the same pass with the observation forced off."""
+    the column half-step, whose movies' rows lie all over it, windows of
+    the residual sorted into the movies' order; and the pass equals the
+    same pass with the observations forced off."""
     from photon_ml_tpu.game.coordinate import MatrixFactorizationCoordinate
     from photon_ml_tpu.ops.losses import LINEAR
 
@@ -414,6 +422,8 @@ def test_the_als_structures_observe_runs_on_the_grouped_side_only():
     col = mf._side_structure("col", *sides["col"])
     assert all(b.row_runs is not None for b in row.buckets)
     assert all(b.row_runs is None for b in col.buckets)
+    # observed where the update first reads the residual, not at the build
+    assert "entity_order" not in col.__dict__
     residual = jnp.asarray(rng.normal(size=len(users)).astype(np.float32))
     before = {c: _slots_counted(c) for c in ("mf_row", "mf_col")}
     got, _ = mf.update_model(mf.initialize_model(), residual)
@@ -421,12 +431,15 @@ def test_the_als_structures_observe_runs_on_the_grouped_side_only():
         c: tuple(a - b for a, b in zip(_slots_counted(c), before[c]))
         for c in before
     }
+    assert row.entity_order is None and col.entity_order is not None
     assert counted == {
-        "mf_row": (sum(b.row_index.size for b in row.buckets), 0),
-        "mf_col": (0, sum(b.row_index.size for b in col.buckets)),
+        "mf_row": (sum(b.row_index.size for b in row.buckets), 0, 0),
+        "mf_col": (0, sum(b.row_index.size for b in col.buckets), 0),
     }
     off = coordinate()
-    off._als_structure_cache = {"row": _without_runs(row), "col": col}
+    off._als_structure_cache = {
+        "row": _without_runs(row), "col": _without_runs(col),
+    }
     want, _ = off.update_model(off.initialize_model(), residual)
     np.testing.assert_array_equal(_bits(got.row_latent), _bits(want.row_latent))
     np.testing.assert_array_equal(_bits(got.col_latent), _bits(want.col_latent))
@@ -436,7 +449,186 @@ def test_the_program_is_named_for_its_coordinate():
     text = _residual_program("per-user").lower(
         jnp.zeros((10,), jnp.float32),
         ((jnp.zeros((2,), jnp.int32), jnp.ones((2,), jnp.int32)),),
-        capacities=(4,),
+        jnp.arange(10, dtype=jnp.int32), paths=(("sorted", 4),),
     ).as_text()
     assert "module @jit_bank_residual_per_user" in text
+    assert "sort" in text  # the sort is inside the named program
     assert re_mod._residual_program("per-user") is _residual_program("per-user")
+
+
+# ---- the sorted path: one sort into the entity order, then windows ------
+
+
+def test_the_entity_order_is_read_from_row_index_alone():
+    loose = np.array([[4, 1, -1], [0, -1, -1], [-1, -1, -1]], np.int32)
+    grouped = np.array([[2, 3], [6, -1]], np.int32)  # runs: not in it
+    holed = np.array([[5, -1, 7]], np.int32)  # a hole: not in it
+    order = observe_entity_order(
+        [_bucket_of(loose), _bucket_of(grouped), _bucket_of(holed)], 10
+    )
+    assert isinstance(order, EntityOrder) and order.keys.dtype == np.int32
+    # rows 4, 1, 0 in slot order; the rest after them, in row order
+    np.testing.assert_array_equal(order.keys, [2, 1, 3, 4, 0, 5, 6, 7, 8, 9])
+    runs = order.runs[0]
+    np.testing.assert_array_equal(runs.starts, [0, 2, 0])
+    np.testing.assert_array_equal(runs.counts, [2, 1, 0])
+    assert order.runs[1] is None and order.runs[2] is None
+    # a row held twice is no permutation: no order
+    twice = np.array([[4, 8], [1, -1]], np.int32)
+    assert observe_entity_order(
+        [_bucket_of(loose), _bucket_of(twice)], 10
+    ) is None
+    # nothing without runs: no order
+    assert observe_entity_order([_bucket_of(grouped)], 10) is None
+
+
+def _shuffled_codes(counts, seed=0):
+    return np.random.default_rng(seed).permutation(
+        np.repeat(np.arange(len(counts)), counts)
+    )
+
+
+def _gather_groups(groups, residual):
+    """The element gather of every group's slots, on the host."""
+    return [
+        np.stack([_gathered(residual, b.bucket.row_index) for b in members])
+        if len(members) > 1
+        else _gathered(residual, members[0].bucket.row_index)
+        for members in groups
+    ]
+
+
+SORTED_CASES = {
+    # name: (rows an entity, reservoir cap, sub-blocks of the widest class)
+    "one_group_a_class_padding_slots": (
+        [3, 5, 7, 2, 6, 1, 4, 8, 5, 3, 7, 6], None, 1
+    ),
+    "folded_group_an_entity_with_no_real_slot": (
+        [5] * 11 + [2, 3], None, 3
+    ),
+    "rows_held_by_no_block": ([12] * 10 + [3, 20, 9], 8, 1),
+    "capacities_over_and_under_128": ([200, 300, 130, 60, 90, 7, 1], None, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SORTED_CASES))
+def test_the_sorted_path_equals_the_element_gather_bitwise(case):
+    counts, cap, n_sub = SORTED_CASES[case]
+    red = _build(_shuffled_codes(counts, seed=len(counts)), cap=cap, seed=7)
+    n = red.row_entity_codes.shape[0]
+    assert "entity_order" not in red.__dict__
+    kw = {}
+    if n_sub > 1:
+        bucket = max(red.buckets, key=lambda b: b.num_entities)
+        per_entity = 4 * bucket.capacity * (red.local_dim + bucket.capacity)
+        kw["dense_bytes_budget"] = (
+            per_entity * -(-bucket.num_entities // n_sub)
+        )
+    rng = np.random.default_rng(len(case))
+    residual = rng.normal(size=n).astype(np.float32)
+    residual[rng.integers(0, n, size=5)] = -0.0  # a sign a sum would lose
+    name = f"sorted-{case}"
+    before = _slots_counted(name)
+    groups, got = _problem(**kw).group_offsets(
+        red, jnp.asarray(residual), coordinate=name
+    )
+    windows, sorted_, slots = (
+        a - b for a, b in zip(_slots_counted(name), before)
+    )
+    read = sum(
+        sum(b.num_real for b in members) * members[0].bucket.capacity
+        for members in groups
+    )
+    # only an entity of ONE row is a run in a shuffled table
+    assert slots == 0 and windows + sorted_ == read and sorted_ > 0
+    keys = red.entity_order.keys
+    assert sorted(keys.tolist()) == list(range(n))
+    for have, want in zip(got, _gather_groups(groups, residual)):
+        np.testing.assert_array_equal(_bits(have), _bits(want))
+    if n_sub > 1:
+        assert max(len(members) for members in groups) == n_sub
+        assert any(
+            b.num_real < b.bucket.num_entities for m in groups for b in m
+        )
+    if cap is not None:
+        assert red.num_passive_rows > 0
+        held = sum(int((b.row_index >= 0).sum()) for b in red.buckets)
+        free = np.ones(n, bool)
+        for b in red.buckets:
+            free[b.row_index[b.row_index >= 0]] = False
+        # rows held by no block sort after the last held one, in row order
+        np.testing.assert_array_equal(
+            keys[free], held + np.arange(free.sum())
+        )
+    if "128" in case:
+        assert {b.capacity for b in red.buckets} >= {64, 256, 512}
+
+
+def test_the_three_paths_count_every_slot_once_and_upload_their_own():
+    """Windows for a grouped class, the sorted windows for a shuffled one,
+    the gather for a shuffled one with a hole among its real slots (no
+    run in the order): the three counts sum to every slot read, the
+    offsets are the gather's bits, and only the gathered bucket uploads
+    its ``row_index``."""
+    grouped = np.repeat(np.arange(20), 3)  # capacity 4, runs
+    loose = _shuffled_codes([20] * 12 + [40] * 8, seed=1) + 20
+    red = _build(np.concatenate([grouped, loose]))
+    by_cap = {b.capacity: i for i, b in enumerate(red.buckets)}
+    assert red.buckets[by_cap[4]].row_runs is not None
+    buckets = list(red.buckets)
+    holed = buckets[by_cap[64]]
+    assert (holed.row_index < 0).any(axis=1).all()
+    # each entity's padding slot first: a hole before its real slots
+    buckets[by_cap[64]] = replace(
+        holed, row_index=np.roll(holed.row_index, 1, axis=1)
+    )
+    red = replace(red, buckets=buckets)
+    n = red.row_entity_codes.shape[0]
+    residual = np.random.default_rng(4).normal(size=n).astype(np.float32)
+    problem = _problem()
+    before = _slots_counted("three")
+    groups, got = problem.group_offsets(
+        red, jnp.asarray(residual), coordinate="three"
+    )
+    counted = tuple(a - b for a, b in zip(_slots_counted("three"), before))
+    size = {b.capacity: b.row_index.size for b in red.buckets}
+    assert counted == (size[4], size[32], size[64])
+    assert sum(counted) == sum(size.values())
+    for have, want in zip(got, _gather_groups(groups, residual)):
+        np.testing.assert_array_equal(_bits(have), _bits(want))
+    uploaded = [what for _, what in problem._device_cache]
+    assert uploaded.count("rows") == 1
+    cache = red.__dict__["_residual_device_cache"]
+    np.testing.assert_array_equal(
+        np.asarray(cache["entity_order"]), red.entity_order.keys
+    )
+    assert red.entity_order.runs[by_cap[64]] is None
+
+
+@pytest.mark.parametrize(
+    "case", ["built", "updated", "updated_under_a_residual",
+             "variances_under_a_residual"],
+)
+def test_the_entity_order_is_observed_only_where_the_residual_is_read(case):
+    """A dataset observes its entity order the first time a replicated
+    bank update reads the residual through it, and keeps it: a build, or
+    an update with no residual (a streamed segment's), builds none."""
+    red = _build(_shuffled_codes([5, 9, 3, 12, 7, 6], seed=3))
+    n = red.row_entity_codes.shape[0]
+    bank = jnp.zeros((red.num_entities, red.local_dim), jnp.float32)
+    residual = jnp.asarray(
+        np.random.default_rng(3).normal(size=n), jnp.float32
+    )
+    problem = _problem()
+    if case == "updated":
+        problem.update_bank(bank, red)
+    elif case == "updated_under_a_residual":
+        problem.update_bank(bank, red, residual_offsets=residual)
+    elif case == "variances_under_a_residual":
+        problem.bank_variances(bank, red, residual)
+    read = case.endswith("under_a_residual")
+    assert ("entity_order" in red.__dict__) == read
+    if read:
+        order = red.entity_order
+        assert order is red.entity_order  # observed once
+        assert sorted(order.keys.tolist()) == list(range(n))

@@ -148,6 +148,11 @@ def test_the_random_effect_dataset_build_is_a_span_with_its_sizes(ring, rng):
 
 @pytest.fixture(scope="module")
 def mf_descent(tmp_path_factory):
+    from photon_ml_tpu.game import random_effect as re_mod
+
+    # programs an earlier test of this process compiled for the same
+    # shapes would leave the pool nothing to warm
+    re_mod._SOLVER_CACHE.clear()
     obs_trace.tracer().clear()
     dataset, _ = _ratings()
     coords, cd = _descent(tmp_path_factory.mktemp("mf_spans"), dataset)
