@@ -1057,7 +1057,8 @@ class FactoredRandomEffectCoordinate(Coordinate):
     def score_kernel(self) -> str:
         """How ``score()`` computes: for ``cd.score``."""
         plan = score_plan(
-            self._view(), self.problem, staged=self.re_dataset.local_dim
+            self._view(), self.problem, staged=self.re_dataset.local_dim,
+            passive_apart=False,
         )
         return score_kernel_name(plan.block_rows, plan.gather_rows)
 
@@ -1098,7 +1099,9 @@ class FactoredRandomEffectCoordinate(Coordinate):
         self.problem.prepare(
             model.bank, view, coordinate=self.name, override=override
         )
-        score_plan(view, self.problem, staged=override.staged)
+        score_plan(
+            view, self.problem, staged=override.staged, passive_apart=False
+        )
 
 
 @dataclass

@@ -275,7 +275,9 @@ def score_factored(
     score with no problem splits by the default budget)."""
     problem = problem or _default_problem()
     view = latent_view(re_dataset, bank.shape[1])
-    plan = score_plan(view, problem, staged=projection.shape[0])
+    plan = score_plan(
+        view, problem, staged=projection.shape[0], passive_apart=False
+    )
     blocks = score_blocks(problem, view, plan)
     return fre_score(
         bank, projection, blocks, plan.rest,

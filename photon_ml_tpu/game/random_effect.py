@@ -1953,6 +1953,10 @@ class _ScorePlan(NamedTuple):
     rest: Optional[tuple]
     block_rows: int
     gather_rows: int
+    # (rows, codes, ix, v) device arrays of the PASSIVE rows, where the
+    # plan keeps them apart from ``rest`` (:func:`score_plan`'s
+    # ``passive_apart``); they count among ``gather_rows``
+    passive: Optional[tuple] = None
 
     @property
     def kernel(self) -> str:
@@ -1983,6 +1987,7 @@ def score_plan(
     dataset: RandomEffectDataset,
     problem: Optional["RandomEffectOptimizationProblem"] = None,
     staged: int = 0,
+    passive_apart: bool = True,
 ) -> _ScorePlan:
     """The :class:`_ScorePlan` of ``dataset`` under ``problem``, decided
     from the data: a row that a DENSE solver block holds (the blocks
@@ -1994,7 +1999,12 @@ def score_plan(
     and every row under the entity mesh (its blocks are entity-sharded)
     keep the gather. ``staged``: the values override's the update runs
     under (:attr:`ValuesOverride.staged`), so that the blocks are its.
-    Cached on the dataset, keyed by the split."""
+    On the replicated bank the passive rows (valid rows that no bucket
+    holds: the reservoir cap's leftovers) go to ``passive`` instead of
+    ``rest``, for a program of their own (:func:`re_score_passive`);
+    ``passive_apart=False`` leaves them in ``rest``, for a caller that
+    scores every gathered row itself (the factored coordinate). Cached
+    on the dataset, keyed by the split."""
     problem = problem or _default_problem()
     blocks = []
     if problem.mesh is None and dataset.buckets:
@@ -2005,9 +2015,13 @@ def score_plan(
     # the update holds
     fold = not problem.compute_variances and len(blocks) > 1
     d_local = dataset.local_dim
+    apart = bool(
+        passive_apart and problem.mesh is None and dataset.num_passive_rows
+    )
     key = (
         tuple((b[:3], scores_from_block(b.kind, d_local)) for b in blocks),
         fold,
+        apart,
     )
     cache = dataset.__dict__.setdefault("_score_plan_cache", {})
     plan = cache.get(key)
@@ -2021,7 +2035,20 @@ def score_plan(
     codes = np.asarray(dataset.row_entity_codes)
     left = codes >= 0
     num_valid = int(left.sum())
-    if not groups:
+    passive, passive_rows = None, 0
+    if apart:
+        held = np.zeros(left.shape, bool)
+        for bucket in dataset.buckets:
+            held[bucket.row_index[bucket.row_index >= 0]] = True
+        rows = np.nonzero(left & ~held)[0]
+        left &= held
+        passive_rows = len(rows)
+        passive = (
+            jnp.asarray(rows.astype(np.int32)), jnp.asarray(codes[rows]),
+            jnp.asarray(dataset.row_local_indices[rows]),
+            jnp.asarray(dataset.row_local_values[rows]),
+        )
+    if not groups and not apart:
         rest = (None,) + device_row_view(dataset)
     else:
         for members in groups:
@@ -2038,8 +2065,10 @@ def score_plan(
                 jnp.asarray(dataset.row_local_indices[rows]),
                 jnp.asarray(dataset.row_local_values[rows]),
             )
-    gather_rows = int(left.sum())
-    plan = _ScorePlan(groups, rest, num_valid - gather_rows, gather_rows)
+    gather_rows = int(left.sum()) + passive_rows
+    plan = _ScorePlan(
+        groups, rest, num_valid - gather_rows, gather_rows, passive
+    )
     cache[key] = plan
     return plan
 
@@ -2058,14 +2087,18 @@ def score_random_effect(
 
     ``problem``: the one whose ``update_bank`` solves ``dataset``; the
     rows its dense blocks hold are scored from the device arrays it
-    already holds for them (:func:`score_plan`)."""
+    already holds for them (:func:`score_plan`), the passive rows by
+    :func:`re_score_passive`."""
     problem = problem or _default_problem()
     plan = score_plan(dataset, problem)
-    return re_score(
+    scores = re_score(
         bank, score_blocks(problem, dataset, plan), plan.rest,
         identity=tuple(m[0].bucket.identity_indices for m in plan.groups),
         num_rows=int(dataset.row_entity_codes.shape[0]),
     )
+    if plan.passive is not None:
+        scores = re_score_passive(scores, bank, *plan.passive)
+    return scores
 
 
 def score_blocks(problem, dataset, plan: _ScorePlan) -> tuple:
@@ -2125,6 +2158,20 @@ def re_score(bank, blocks, rest, *, identity, num_rows):
                 score = jnp.where(valid, score, 0.0)
             out = score if rows is None else out.at[rows].set(score)
         return out
+
+
+@jax.jit
+def re_score_passive(scores, bank, rows, codes, ix, v):
+    """The passive rows' scores put into ``scores``: one named program
+    (module ``re_score_passive``, scope ``cd.score``) apart from
+    :func:`re_score`, so that a device trace tells what the rows the
+    solves never see cost. A passive row is scored through its entity's
+    own map: the row was remapped with the features the map lacks
+    dropped, which is the reference's own behaviour (a feature outside an
+    entity's map has no coefficient, RandomEffectCoordinate.scala:
+    178-199). By the element gather: no solver block holds such a row."""
+    with jax.named_scope("cd.score"):
+        return scores.at[rows].set(gather_scores(bank, codes, ix, v))
 
 
 def gather_scores(bank, codes, ix, v):

@@ -17,6 +17,7 @@ bucket (SURVEY P2: entities are the expert-parallel analog).
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from functools import cached_property
 from typing import List, NamedTuple, Optional
@@ -28,6 +29,8 @@ from photon_ml_tpu.game.config import (
     RandomEffectDataConfiguration,
 )
 from photon_ml_tpu.game.data import GameDataset, ShardData
+from photon_ml_tpu.obs.registry import default_registry
+from photon_ml_tpu.obs.trace import record_elapsed
 from photon_ml_tpu.obs.trace import span as obs_span
 
 
@@ -266,6 +269,7 @@ def _build_random_effect_dataset(
     proj_type = config.projector_type
     random_projection = None
     intercept_local: Optional[int] = None
+    local_dims = None  # features of each entity's own space, where it has one
 
     if proj_type == ProjectorType.IDENTITY:
         D = max(dim, 1)
@@ -308,6 +312,8 @@ def _build_random_effect_dataset(
         # building AND every later lookup: a per-key "kept" mask (active
         # membership / Pearson top-k / intercept) defines the map, the
         # inverse positions remap every row — no searchsorted anywhere.
+        # The span ``re.index_map_build``, sized by its attrs.
+        t_map = time.perf_counter()
         ratio = config.features_to_samples_ratio
         srow_of_entry = np.repeat(np.arange(len(srows)), k)
         slot_of_entry = np.tile(np.arange(k), len(srows))
@@ -405,6 +411,10 @@ def _build_random_effect_dataset(
         es = e_slot[entry_kept]
         row_local_ix[er, es] = local_u[inv_live[entry_kept]].astype(np.int32)
         row_local_v[er, es] = e_val[entry_kept]
+        record_elapsed(
+            "re.index_map_build", t_map, time.perf_counter(), entities=E,
+            distinct_keys=U, max_local_dim=D,
+        )
 
     # --- bucketed active data (power-of-two capacities) ------------------
     # one flat scatter per bucket instead of per-entity/per-row fills
@@ -456,6 +466,10 @@ def _build_random_effect_dataset(
             )
         )
 
+    _count_built(
+        config.random_effect_type, buckets, acounts, local_dims, D,
+        num_passive, int((counts > cap).sum()) if cap is not None else 0,
+    )
     ds = RandomEffectDataset(
         config=config,
         num_entities=E,
@@ -471,3 +485,57 @@ def _build_random_effect_dataset(
     )
     ds._intercept_local = intercept_local
     return ds
+
+
+def _count_built(
+    effect_type: str, buckets, active_counts, local_dims, d_local: int,
+    passive_rows: int, capped: int,
+) -> None:
+    """One dataset build into the registry, by random-effect type: its
+    active and passive rows, the entities the reservoir cap cut, the
+    capacity classes (``photon_re_capacity_classes_total`` over
+    ``photon_re_dataset_builds_total``), and the slots of the blocks the
+    solves stage, ``[E_b, S_b, D]`` a bucket, that hold a row on a
+    dimension of its entity's own space (``held``) or no row or no such
+    dimension (``padding``). ``local_dims`` None: every entity's space is
+    all ``d_local`` wide. Host arithmetic on what the build made."""
+    registry = default_registry()
+    labels = {"type": effect_type}
+    rows = registry.counter(
+        "photon_re_rows_total",
+        "rows of the random-effect datasets built, by type and state "
+        "(active | passive)",
+    )
+    for state, count in (
+        ("active", int(active_counts.sum())), ("passive", passive_rows)
+    ):
+        if count:
+            rows.inc(count, state=state, **labels)
+    if capped:
+        registry.counter(
+            "photon_re_capped_entities_total",
+            "entities whose active rows the reservoir cap cut, by type",
+        ).inc(capped, **labels)
+    registry.counter(
+        "photon_re_dataset_builds_total",
+        "random-effect datasets built, by type",
+    ).inc(1, **labels)
+    registry.counter(
+        "photon_re_capacity_classes_total",
+        "capacity classes (buckets) of the random-effect datasets built, "
+        "by type",
+    ).inc(len(buckets), **labels)
+    dims = (
+        np.full(active_counts.shape, d_local, np.int64)
+        if local_dims is None else local_dims.astype(np.int64)
+    )
+    held = int(np.sum(active_counts.astype(np.int64) * dims))
+    staged = sum(b.row_index.size for b in buckets) * d_local
+    slots = registry.counter(
+        "photon_re_bank_slots_total",
+        "slots of the blocks the random-effect solves stage, by type and "
+        "state (held | padding)",
+    )
+    for state, count in (("held", held), ("padding", staged - held)):
+        if count:
+            slots.inc(count, state=state, **labels)

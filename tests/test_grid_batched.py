@@ -265,8 +265,8 @@ class TestCompileAndReadbackContract:
         problem.run_grid(batch, LAMBDAS)  # compile once
         with jtu.count_jit_and_pmap_lowerings() as count:
             _, result = problem.run_grid(batch, [5.0, 0.5, 0.05, 2.0])
-        assert count[0] == 0, (
-            f"same-shape grid re-lowered {count[0]} program(s)"
+        assert count() == 0, (
+            f"same-shape grid re-lowered {count()} program(s)"
         )
         assert result.coefficients.shape == (4, 48)
 

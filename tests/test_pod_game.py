@@ -392,6 +392,73 @@ class TestShardedCDParity:
         )
 
 
+def _driver_pod_cd(tmp_path, ds, red, entity_shards=4):
+    """The descent the pod cell runs, through the GAME driver's own
+    coordinates: ``--entity-shards`` of the eight virtual devices, a
+    data-parallel fixed effect over the same devices, the users' bank
+    hash-partitioned over them."""
+    from photon_ml_tpu.cli import game_training_driver as gtd
+
+    driver = gtd.GameTrainingDriver(gtd.params_from_args([
+        "--task-type", "LOGISTIC_REGRESSION",
+        "--feature-shard-id-to-feature-section-keys-map", "s:features",
+        "--fixed-effect-data-configurations", "global:s,1",
+        "--fixed-effect-optimization-configurations",
+        "global:10,1e-7,1.0,1,LBFGS,L2",
+        "--random-effect-data-configurations",
+        "per-user:user,s,1,none,none,none,IDENTITY",
+        "--random-effect-optimization-configurations",
+        "per-user:20,1e-5,1.0,1,LBFGS,L2",
+        "--updating-sequence", "global,per-user",
+        "--num-iterations", "1",
+        "--entity-shards", str(entity_shards),
+        "--train-input-dirs", str(tmp_path / "unused"),
+        "--output-dir", str(tmp_path / "out"),
+    ]))
+    p = driver.params
+    combo = gtd.expand_config_grid(
+        {**p.fixed_effect_opt_configs, **p.random_effect_opt_configs}
+    )[0]
+    coords = driver._build_coordinates(ds, {"per-user": red}, combo)
+    assert isinstance(coords["per-user"], PodRandomEffectCoordinate)
+    return CoordinateDescent(
+        coords, ds, p.task_type, update_sequence=p.updating_sequence
+    )
+
+
+def _cd_step(cd):
+    """One step as the benchmark's cells take it: ``run(1)`` from zero,
+    closed on the models, the trackers read."""
+    result = cd.run(1)
+    jax.block_until_ready([
+        result.model.get_model("global").model.coefficients.means,
+        result.model.get_model("per-user").sharded_bank.data,
+    ])
+    assert np.isfinite(result.objective_history[-1])
+    int(result.trackers["global"][-1].iterations)
+    float(result.trackers["per-user"][-1].iterations_mean)
+    return result
+
+
+class TestWarmPodDescent:
+    def test_zero_lowerings_in_warm_pod_steps(self, tmp_path):
+        """Once one step has run, two more steps of the entity-mesh
+        descent on 4 of the 8 devices lower NOTHING: no program of the
+        pod path (router, bank, scoring, fixed effect, objective) takes a
+        static argument, a shape or a cache key that is new on a later
+        step. A step that lowers inside the timed window costs the pod
+        cell its compile time on every step."""
+        import jax._src.test_util as jtu
+
+        ds, red = _synthetic_re(n=192, E=23)
+        cd = _driver_pod_cd(tmp_path, ds, red)
+        _cd_step(cd)  # warm
+        with jtu.count_jit_and_pmap_lowerings() as count:
+            _cd_step(cd)
+            _cd_step(cd)
+        assert count() == 0, count()
+
+
 class TestWeakScalingBytes:
     def test_per_device_bank_bytes_bounded_at_8_shards(self):
         """Acceptance: at N=8, per-device RE bank + optimizer-state

@@ -167,7 +167,8 @@ def test_blocks_match_the_gather(case):
         assert red.num_passive_rows > 0
         assert plan.block_rows == red.num_active_rows
         assert plan.gather_rows == red.num_passive_rows
-        assert plan.rest[0].shape == (red.num_passive_rows,)
+        assert plan.rest is None
+        assert plan.passive[0].shape == (red.num_passive_rows,)
     _close(score_random_effect(jnp.asarray(bank), red, problem),
            _reference(bank, red))
 

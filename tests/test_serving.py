@@ -395,7 +395,7 @@ class TestCompileAndReadbackContract:
                 futs = [mb.submit(r) for r in reqs]
                 for f in futs:
                     f.result()
-        assert count[0] == 0, f"request path lowered {count[0]} program(s)"
+        assert count() == 0, f"request path lowered {count()} program(s)"
         after = programs.stats()
         assert after["compile_count"] == before["compile_count"]
         assert after["cold_dispatch_compiles"] == 0
@@ -712,8 +712,8 @@ class TestHotSwap:
         with jtu.count_jit_and_pmap_lowerings() as count:
             res = sm.swap_to_bank(staged)
         assert res.ok and res.donated
-        assert count[0] == 0, (
-            f"donated swap lowered {count[0]} program(s) after warmup"
+        assert count() == 0, (
+            f"donated swap lowered {count()} program(s) after warmup"
         )
 
     def test_exhausted_load_budget_rolls_back(self, two_generations):

@@ -438,7 +438,7 @@ class TestUnifiedContracts:
         with jtu.count_jit_and_pmap_lowerings() as count:
             _run_unified(game_data, n_ent=2,
                          lambdas=[0.2, 0.7, 1.5, 3.0], num_iterations=2)
-        assert count[0] == 0, count[0]
+        assert count() == 0, count()
 
     def test_sharding_inventory_shrank(self):
         """SUBTRACTIVE success metric: the unified program REPLACED
