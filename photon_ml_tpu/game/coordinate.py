@@ -688,23 +688,31 @@ class FixedEffectCoordinate(Coordinate):
             self.dataset.batch_for_shard(self.feature_shard_id)
 
 
-def _count_scored_rows(coordinate: str, block_rows: int, gather_rows: int):
+def _count_scored_rows(
+    coordinate: str, block_rows: int, gather_rows: int, chunk_rows: int = 0
+):
     """One scoring pass of a random effect into the registry: the rows
-    scored from the solver's blocks and those left to the gather (host
-    arithmetic on the scoring plan; nothing is read from the device)."""
+    scored from the solver's blocks, the passive rows scored from their
+    entity chunks, and those left to the gather (host arithmetic on the
+    scoring plan; nothing is read from the device)."""
     counter = default_registry().counter(
         "photon_re_score_rows_total",
         "rows the random effects scored, by coordinate and path "
-        "(blocks | gather)",
+        "(blocks | chunks | gather)",
     )
-    for path, rows in (("blocks", block_rows), ("gather", gather_rows)):
+    for path, rows in (
+        ("blocks", block_rows), ("chunks", chunk_rows),
+        ("gather", gather_rows),
+    ):
         if rows:
             counter.inc(rows, coordinate=coordinate, path=path)
 
 
 def _score_replicated_bank(coordinate, bank, re_dataset, problem) -> Array:
     plan = score_plan(re_dataset, problem)
-    _count_scored_rows(coordinate, plan.block_rows, plan.gather_rows)
+    _count_scored_rows(
+        coordinate, plan.block_rows, plan.gather_rows, plan.chunk_rows
+    )
     return score_random_effect(bank, re_dataset, problem)
 
 
@@ -750,8 +758,20 @@ class RandomEffectCoordinate(Coordinate):
     @property
     def score_kernel(self) -> str:
         """How ``score()`` computes, "blocks" | "gather" |
-        "blocks+gather": for ``cd.score``."""
+        "blocks+chunks" | ...: for ``cd.score``."""
         return score_plan(self.re_dataset, self.problem).kernel
+
+    @property
+    def score_attrs(self) -> dict:
+        """The passive rows' chunks and their padding slots, where the
+        plan scores them from chunks: for ``cd.score``."""
+        plan = score_plan(self.re_dataset, self.problem)
+        if not plan.chunk_rows:
+            return {}
+        return {
+            "passive_chunks": plan.passive_chunks,
+            "passive_padding": plan.passive_padding,
+        }
 
     def score(self, model: RandomEffectModel) -> Array:
         return _score_replicated_bank(

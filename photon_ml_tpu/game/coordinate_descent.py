@@ -175,8 +175,10 @@ class CoordinateDescent:
 
     def _score(self, name: str, model) -> Array:
         """One scoring pass under its ``cd.score`` span, which says how a
-        fixed effect scored (``kernel``: tiled | gather; on the kernel,
-        its ``mxu`` variant)."""
+        coordinate scored (``kernel``: tiled | gather for a fixed effect,
+        on the kernel its ``mxu`` variant; blocks | chunks | gather
+        joined by ``+`` for a random effect, with the coordinate's
+        ``score_attrs``)."""
         coord = self.coordinates[name]
         with obs_span("cd.score", coordinate=name) as sp:
             score = coord.score(model)
@@ -185,6 +187,9 @@ class CoordinateDescent:
                 sp.set(kernel=kernel)
             if kernel == "tiled":
                 sp.set(mxu=coord.mxu)
+            attrs = getattr(coord, "score_attrs", None)
+            if attrs:
+                sp.set(**attrs)
         return score
 
     def run(

@@ -1955,20 +1955,74 @@ class _ScorePlan(NamedTuple):
     gather_rows: int
     # (rows, codes, ix, v) device arrays of the PASSIVE rows, where the
     # plan keeps them apart from ``rest`` (:func:`score_plan`'s
-    # ``passive_apart``); they count among ``gather_rows``
+    # ``passive_apart``): per-entity chunks (:func:`_passive_chunks`),
+    # counted as ``chunk_rows``, or flat rows that keep the gather,
+    # counted among ``gather_rows``
     passive: Optional[tuple] = None
+    chunk_rows: int = 0
+    passive_chunks: int = 0
+    passive_padding: int = 0  # chunk slots on no row
 
     @property
     def kernel(self) -> str:
-        return score_kernel_name(self.block_rows, self.gather_rows)
+        return score_kernel_name(
+            self.block_rows, self.gather_rows, self.chunk_rows
+        )
 
 
-def score_kernel_name(block_rows: int, gather_rows: int) -> str:
-    """``blocks`` | ``gather`` | ``blocks+gather``: what a random
-    effect's ``cd.score`` span says of a scoring pass."""
-    if block_rows and gather_rows:
-        return "blocks+gather"
-    return "blocks" if block_rows else "gather"
+def score_kernel_name(
+    block_rows: int, gather_rows: int, chunk_rows: int = 0
+) -> str:
+    """``blocks`` | ``chunks`` | ``gather``, or those that scored rows
+    joined by ``+`` (``blocks+chunks``, ``blocks+gather``, ...): what a
+    random effect's ``cd.score`` span says of a scoring pass."""
+    paths = (
+        ("blocks", block_rows), ("chunks", chunk_rows),
+        ("gather", gather_rows),
+    )
+    return "+".join(p for p, rows in paths if rows) or "gather"
+
+
+def _passive_chunks(dataset: RandomEffectDataset, rows: np.ndarray,
+                    capacity: int, budget: int) -> tuple:
+    """The passive ``rows`` of ``dataset`` in the shape of a solver
+    block: grouped by entity (a stable sort of their codes), each
+    entity's rows cut into chunks of ``capacity``, the last padded with
+    slots on no row (``rows`` -1, ``v`` 0). ``(rows [C, S], codes [C],
+    ix [C, S, k], v [C, S, k])`` host arrays; stacked ``[B, C / B, ...]``
+    in equal sub-blocks, the last padded with chunks of an entity past
+    the bank, where the compare's live arrays (the chunks' bank rows and
+    their looked-up entries) would pass ``budget`` bytes."""
+    codes = dataset.row_entity_codes[rows]
+    order = np.argsort(codes, kind="stable")
+    rows, codes = rows[order], codes[order]
+    entities, first, counts = np.unique(
+        codes, return_index=True, return_counts=True
+    )
+    per = -(-counts // capacity)  # chunks an entity
+    pos = np.arange(len(rows)) - np.repeat(first, counts)
+    chunk = np.repeat(np.cumsum(per) - per, counts) + pos // capacity
+    slot = pos % capacity
+    num_chunks = int(per.sum())
+    k = dataset.row_local_indices.shape[1]
+    c_rows = np.full((num_chunks, capacity), -1, np.int32)
+    c_ix = np.zeros(
+        (num_chunks, capacity, k), dataset.row_local_indices.dtype
+    )
+    c_v = np.zeros((num_chunks, capacity, k), np.float32)
+    c_rows[chunk, slot] = rows
+    c_ix[chunk, slot] = dataset.row_local_indices[rows]
+    c_v[chunk, slot] = dataset.row_local_values[rows]
+    out = (c_rows, np.repeat(entities, per).astype(np.int32), c_ix, c_v)
+    live = _BLOCK_ITEMSIZE * num_chunks * (dataset.local_dim + capacity * k)
+    n_sub = -(-live // budget)
+    if n_sub <= 1:
+        return out
+    fills = (-1, dataset.num_entities, 0, 0)
+    return tuple(
+        np.stack([_sub_block(a, n_sub, j, fill) for j in range(n_sub)])
+        for a, fill in zip(out, fills)
+    )
 
 
 @lru_cache(maxsize=1)
@@ -2001,10 +2055,13 @@ def score_plan(
     under (:attr:`ValuesOverride.staged`), so that the blocks are its.
     On the replicated bank the passive rows (valid rows that no bucket
     holds: the reservoir cap's leftovers) go to ``passive`` instead of
-    ``rest``, for a program of their own (:func:`re_score_passive`);
-    ``passive_apart=False`` leaves them in ``rest``, for a caller that
-    scores every gathered row itself (the factored coordinate). Cached
-    on the dataset, keyed by the split."""
+    ``rest``, for a program of their own (:func:`re_score_passive`): as
+    per-entity chunks of the widest bucket's capacity, scored from the
+    chunk like a block's rows, where the bank is narrow enough for the
+    compare (:func:`scores_from_block`'s width), else as flat rows that
+    keep the gather; ``passive_apart=False`` leaves them in ``rest``,
+    for a caller that scores every gathered row itself (the factored
+    coordinate). Cached on the dataset, keyed by the split."""
     problem = problem or _default_problem()
     blocks = []
     if problem.mesh is None and dataset.buckets:
@@ -2018,10 +2075,14 @@ def score_plan(
     apart = bool(
         passive_apart and problem.mesh is None and dataset.num_passive_rows
     )
+    chunked = apart and bool(dataset.buckets) and (
+        d_local <= _SCORE_BLOCK_MAX_DIM
+    )
     key = (
         tuple((b[:3], scores_from_block(b.kind, d_local)) for b in blocks),
         fold,
         apart,
+        chunked and problem.dense_bytes_budget,
     )
     cache = dataset.__dict__.setdefault("_score_plan_cache", {})
     plan = cache.get(key)
@@ -2035,7 +2096,7 @@ def score_plan(
     codes = np.asarray(dataset.row_entity_codes)
     left = codes >= 0
     num_valid = int(left.sum())
-    passive, passive_rows = None, 0
+    passive, passive_rows, chunks = None, 0, {}
     if apart:
         held = np.zeros(left.shape, bool)
         for bucket in dataset.buckets:
@@ -2043,11 +2104,22 @@ def score_plan(
         rows = np.nonzero(left & ~held)[0]
         left &= held
         passive_rows = len(rows)
-        passive = (
-            jnp.asarray(rows.astype(np.int32)), jnp.asarray(codes[rows]),
-            jnp.asarray(dataset.row_local_indices[rows]),
-            jnp.asarray(dataset.row_local_values[rows]),
-        )
+        if chunked:
+            passive = _passive_chunks(
+                dataset, rows, max(b.capacity for b in dataset.buckets),
+                problem.dense_bytes_budget,
+            )
+            chunks = dict(
+                passive_chunks=int(passive[1].size),
+                passive_padding=int(passive[0].size) - passive_rows,
+            )
+        else:
+            passive = (
+                rows.astype(np.int32), codes[rows],
+                dataset.row_local_indices[rows],
+                dataset.row_local_values[rows],
+            )
+        passive = tuple(jnp.asarray(a) for a in passive)
     if not groups and not apart:
         rest = (None,) + device_row_view(dataset)
     else:
@@ -2065,9 +2137,11 @@ def score_plan(
                 jnp.asarray(dataset.row_local_indices[rows]),
                 jnp.asarray(dataset.row_local_values[rows]),
             )
-    gather_rows = int(left.sum()) + passive_rows
+    chunk_rows = passive_rows if chunked else 0
+    gather_rows = int(left.sum()) + passive_rows - chunk_rows
     plan = _ScorePlan(
-        groups, rest, num_valid - gather_rows, gather_rows, passive
+        groups, rest, num_valid - gather_rows - chunk_rows, gather_rows,
+        passive, chunk_rows, **chunks,
     )
     cache[key] = plan
     return plan
@@ -2121,36 +2195,39 @@ def score_blocks(problem, dataset, plan: _ScorePlan) -> tuple:
     return tuple(blocks)
 
 
+def _place_scores(out, bank, args, identity):
+    """``out`` with the rows of one block put in: ``args`` ``(codes, ix,
+    v, rows)``, stacked ``[B, E_sub, ...]`` for sub-blocks, which are
+    scanned one at a time so that only one's temporaries are live. Whole
+    bank rows taken as ``_update_block`` takes them, :func:`score_block`,
+    the ``[E, S]`` scores placed into the row vector by ``rows`` (a
+    padding slot, -1, and a padding entity, whose code lies past the
+    bank, drop)."""
+
+    def place(out, args):
+        codes, ix, v, rows = args
+        w = jnp.take(bank, codes, axis=0, mode="fill", fill_value=0)
+        score = score_block(w, ix, v, identity)
+        at = jnp.where(rows >= 0, rows, out.shape[0])
+        return out.at[at.reshape(-1)].set(score.reshape(-1), mode="drop")
+
+    if args[0].ndim == 2:
+        return jax.lax.scan(lambda o, a: (place(o, a), None), out, args)[0]
+    return place(out, args)
+
+
 @partial(jax.jit, static_argnames=("identity", "num_rows"))
 def re_score(bank, blocks, rest, *, identity, num_rows):
     """One named program (module ``re_score``, scope ``cd.score``) a
     device trace can place. ``blocks``: ``(codes, ix, v, rows)`` of each
     group of solver blocks, stacked ``[B, E_sub, ...]`` for a folded
-    group, which is scanned one sub-block at a time so that only one's
-    temporaries are live: whole bank rows taken as ``_update_block``
-    takes them, :func:`score_block`, the ``[E, S]`` scores placed into
-    the row vector by ``rows`` (a padding slot, -1, and a padding
-    entity, whose code lies past the bank, drop). ``rest``: the rows no
-    block holds (:class:`_ScorePlan`), gathered an element at a time.
-    A row with no entity scores 0."""
-
-    def place(out, args, ident):
-        codes, ix, v, rows = args
-        w = jnp.take(bank, codes, axis=0, mode="fill", fill_value=0)
-        score = score_block(w, ix, v, ident)
-        at = jnp.where(rows >= 0, rows, num_rows)
-        return out.at[at.reshape(-1)].set(score.reshape(-1), mode="drop")
-
+    group, each scored from the block (:func:`_place_scores`). ``rest``:
+    the rows no block holds (:class:`_ScorePlan`), gathered an element
+    at a time. A row with no entity scores 0."""
     with jax.named_scope("cd.score"):
         out = jnp.zeros((num_rows,), jnp.float32)
         for args, ident in zip(blocks, identity):
-            if args[0].ndim == 2:
-                out, _ = jax.lax.scan(
-                    lambda o, a, ident=ident: (place(o, a, ident), None),
-                    out, args,
-                )
-            else:
-                out = place(out, args, ident)
+            out = _place_scores(out, bank, args, ident)
         if rest is not None:
             rows, codes, valid, ix, v = rest
             score = gather_scores(bank, codes, ix, v)
@@ -2169,16 +2246,22 @@ def re_score_passive(scores, bank, rows, codes, ix, v):
     own map: the row was remapped with the features the map lacks
     dropped, which is the reference's own behaviour (a feature outside an
     entity's map has no coefficient, RandomEffectCoordinate.scala:
-    178-199). By the element gather: no solver block holds such a row."""
+    178-199). Per-entity chunks (``rows [C, S]``, or ``[B, C / B, S]``
+    in sub-blocks; :func:`_passive_chunks`) are scored from the chunk as
+    a solver block's rows are (:func:`_place_scores`); flat rows
+    (``rows [n]``, a bank too wide for the compare) by the element
+    gather."""
     with jax.named_scope("cd.score"):
-        return scores.at[rows].set(gather_scores(bank, codes, ix, v))
+        if rows.ndim == 1:
+            return scores.at[rows].set(gather_scores(bank, codes, ix, v))
+        return _place_scores(scores, bank, (codes, ix, v, rows), False)
 
 
 def gather_scores(bank, codes, ix, v):
     """``sum_j v[i, j] * bank[codes[i], ix[i, j]]`` by an element gather
     (XLA fuses the row take into it; the [n, D] rows are never
     written): 13-14 ns an element on a v5e (ledger, PR 33), so only for
-    rows no solver block holds."""
+    rows no solver block or passive chunk holds."""
     w_rows = jnp.take(bank, codes, axis=0)  # [n, D]
     return jnp.sum(v * jnp.take_along_axis(w_rows, ix, axis=1), axis=-1)
 

@@ -26,6 +26,7 @@ from photon_ml_tpu.game.config import (
     ProjectorType,
     RandomEffectDataConfiguration,
 )
+from photon_ml_tpu.game.coordinate import _count_scored_rows
 from photon_ml_tpu.game.data import EntityIndex, GameDataset, ShardData
 from photon_ml_tpu.game.random_effect import (
     RandomEffectOptimizationProblem,
@@ -292,11 +293,33 @@ def test_an_identity_datasets_score_plan_is_unchanged_all_blocks():
 
 
 def test_passive_rows_are_scored_by_a_program_of_their_own(solved):
+    """From per-member chunks of the widest bucket's capacity, each
+    passive row in one slot, filed under ``chunks`` and not ``gather``."""
     red, problem = solved["red"], _problem()
     plan = score_plan(red, problem)
-    assert plan.passive[0].shape == (red.num_passive_rows,)
-    assert plan.gather_rows == red.num_passive_rows + (
+    rows, codes, ix, v = (np.asarray(a) for a in plan.passive)
+    S = max(b.capacity for b in red.buckets)
+    assert S == CAP
+    C = codes.shape[0]
+    assert rows.shape == (C, S) and ix.shape == v.shape == (C, S, ix.shape[2])
+    held = rows[rows >= 0]
+    passive = np.nonzero(_passive(solved))[0]
+    assert sorted(held.tolist()) == passive.tolist()  # each exactly once
+    assert plan.kernel == "blocks+chunks"
+    assert plan.chunk_rows == red.num_passive_rows
+    assert plan.gather_rows == (
         0 if plan.rest is None else plan.rest[0].shape[0])
+    valid = int(np.count_nonzero(red.row_entity_codes >= 0))
+    assert plan.block_rows + plan.chunk_rows + plan.gather_rows == valid
+    counter = default_registry().counter("photon_re_score_rows_total")
+    paths = ("blocks", "chunks", "gather")
+    before = [counter.value(coordinate="passive", path=p) for p in paths]
+    _count_scored_rows(
+        "passive", plan.block_rows, plan.gather_rows, plan.chunk_rows)
+    assert [
+        counter.value(coordinate="passive", path=p) - b
+        for p, b in zip(paths, before)
+    ] == [plan.block_rows, red.num_passive_rows, plan.gather_rows]
     text = jax.jit(
         lambda b: score_random_effect(b, red, problem)
     ).lower(jnp.asarray(solved["bank"])).as_text()

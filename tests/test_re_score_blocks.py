@@ -1,10 +1,12 @@
 """The random effect scores from the blocks its solver holds
 (game/random_effect.score_block, re_score; game/pod.pod_score): every
 case against the element gather on the row view as the plain reference,
-float32, to 1e-6 of the largest score. The rows no dense block holds
-(passive rows, a sparse block's, a view without buckets, the entity mesh)
-keep the gather, and ``photon_re_score_rows_total`` says how many rode
-which path."""
+float32, to 1e-6 of the largest score. The passive rows of a replicated
+bank are scored from per-entity chunks of a block's shape
+(re_score_passive) where the bank is narrow enough for the compare. The
+rows no dense block or chunk holds (a sparse block's, a view without
+buckets, the entity mesh, a bank too wide) keep the gather, and
+``photon_re_score_rows_total`` says how many rode which path."""
 
 from dataclasses import replace
 
@@ -23,10 +25,13 @@ from photon_ml_tpu.game.coordinate import (
     RandomEffectCoordinate,
 )
 from photon_ml_tpu.game.data import EntityIndex, GameDataset, ShardData
+from photon_ml_tpu.game import random_effect
 from photon_ml_tpu.game.pod import PodRandomEffectProblem, ShardedREBank
 from photon_ml_tpu.game.random_effect import (
     RandomEffectOptimizationProblem,
+    gather_scores,
     re_score,
+    re_score_passive,
     score_block,
     score_plan,
     score_random_effect,
@@ -46,17 +51,19 @@ ATOL = 1e-6  # of the largest score
 
 
 def _dataset(seed=0, n=431, E=23, d=40, k=6, cap=None,
-             projector=ProjectorType.INDEX_MAP):
+             projector=ProjectorType.INDEX_MAP, codes=None):
     """A GameDataset and its RandomEffectDataset: an uneven entity
-    histogram (several capacity classes), weight-0 rows, rows with no
-    entity."""
+    histogram (several capacity classes; ``codes``, where given, in its
+    place), weight-0 rows, rows with no entity, entries of value 0."""
     rng = np.random.default_rng(seed)
-    codes = np.minimum(
-        rng.geometric(0.12, size=n) - 1, E - 1
-    ).astype(np.int32)
+    if codes is None:
+        codes = np.minimum(
+            rng.geometric(0.12, size=n) - 1, E - 1
+        ).astype(np.int32)
     codes[::29] = -1
     ix = rng.integers(0, d, size=(n, k)).astype(np.int32)
     v = rng.normal(size=(n, k)).astype(np.float32)
+    v[::7, -2:] = 0.0
     w = np.ones(n, np.float32)
     w[::17] = 0.0
     imap = IndexMap.build(
@@ -118,7 +125,7 @@ def _counted(coordinate):
     counter = default_registry().counter("photon_re_score_rows_total")
     return tuple(
         counter.value(coordinate=coordinate, path=path)
-        for path in ("blocks", "gather")
+        for path in ("blocks", "chunks", "gather")
     )
 
 
@@ -138,7 +145,10 @@ def _split_dataset(n_sub, cap):
 CASES = {
     # name: (dataset arguments, problem arguments, kernel the plan says)
     "every_row_active": ({}, {}, "blocks"),
-    "passive_rows": ({"cap": 8}, {}, "blocks+gather"),
+    "passive_rows": ({"cap": 8}, {}, "blocks+chunks"),
+    # a bank past the compare's width (the limit patched down): the
+    # passive rows keep the flat gather, as every other row does
+    "passive_rows_too_wide": ({"cap": 8}, {}, "gather"),
     "identity_projector": (
         {"projector": ProjectorType.IDENTITY}, {}, "blocks"
     ),
@@ -151,26 +161,148 @@ CASES = {
 }
 
 
+def _passive_rows(red):
+    held = np.zeros(red.row_entity_codes.shape, bool)
+    for b in red.buckets:
+        held[b.row_index[b.row_index >= 0]] = True
+    return np.nonzero((red.row_entity_codes >= 0) & ~held)[0]
+
+
+def _check_chunks(red, plan):
+    """The passive rows' chunks: ``[C, S, ...]`` at the widest bucket's
+    capacity, every passive row in exactly one slot, a chunk's rows its
+    entity's, and the padding slots on no row with values 0."""
+    rows, codes, ix, v = (np.asarray(a) for a in plan.passive)
+    S = max(b.capacity for b in red.buckets)
+    k = red.row_local_indices.shape[1]
+    C = codes.shape[0]
+    assert rows.shape == (C, S) and ix.shape == v.shape == (C, S, k)
+    held = rows[rows >= 0]
+    passive = _passive_rows(red)
+    assert len(held) == len(set(held.tolist())) == len(passive)
+    assert set(held.tolist()) == set(passive.tolist())
+    on = rows >= 0
+    assert np.all(red.row_entity_codes[np.maximum(rows, 0)][on]
+                  == np.broadcast_to(codes[:, None], rows.shape)[on])
+    np.testing.assert_array_equal(ix[on], red.row_local_indices[rows[on]])
+    np.testing.assert_array_equal(v[on], red.row_local_values[rows[on]])
+    assert not v[~on].any()
+    # an entity's padding is under one chunk
+    assert plan.passive_chunks == C
+    assert plan.passive_padding == rows.size - len(passive)
+    per = np.bincount(codes, minlength=red.num_entities)
+    want = -(-np.bincount(red.row_entity_codes[passive],
+                          minlength=red.num_entities) // S)
+    np.testing.assert_array_equal(per, want)
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_blocks_match_the_gather(case):
+def test_blocks_match_the_gather(case, monkeypatch):
     data_kw, problem_kw, kernel = CASES[case]
     _, red = _dataset(**data_kw)
     assert len(red.buckets) >= 2  # two capacity classes and more
+    if case == "passive_rows_too_wide":
+        monkeypatch.setattr(
+            random_effect, "_SCORE_BLOCK_MAX_DIM", red.local_dim - 1
+        )
     problem, bank = _problem(**problem_kw), _bank(red)
     plan = score_plan(red, problem)
     assert plan.kernel == kernel
     valid = int(np.count_nonzero(red.row_entity_codes >= 0))
-    assert plan.block_rows + plan.gather_rows == valid
+    assert plan.block_rows + plan.chunk_rows + plan.gather_rows == valid
     if kernel == "blocks":
         assert plan.rest is None and plan.block_rows == red.num_active_rows
     if case == "passive_rows":
         assert red.num_passive_rows > 0
         assert plan.block_rows == red.num_active_rows
-        assert plan.gather_rows == red.num_passive_rows
-        assert plan.rest is None
+        assert plan.chunk_rows == red.num_passive_rows
+        assert plan.gather_rows == 0 and plan.rest is None
+        _check_chunks(red, plan)
+    if case == "passive_rows_too_wide":
+        assert plan.chunk_rows == 0 and plan.gather_rows == valid
         assert plan.passive[0].shape == (red.num_passive_rows,)
+        assert plan.rest[0].shape == (red.num_active_rows,)
     _close(score_random_effect(jnp.asarray(bank), red, problem),
            _reference(bank, red))
+
+
+def _one_entity_codes(n=150, E=23, over=41):
+    """Every entity under the cap of 8 but entity 0, which holds
+    ``over`` rows."""
+    codes = np.concatenate([
+        np.zeros(over, np.int32),
+        1 + np.arange(n - over, dtype=np.int32) % (E - 1),
+    ])
+    return np.random.default_rng(0).permutation(codes)
+
+
+PASSIVE_CASES = {
+    # name: (dataset arguments, bank, split into sub-blocks)
+    "uneven_entities": ({"cap": 8}, "normal", False),
+    "zero_bank": ({"cap": 8}, "zeros", False),
+    "one_entity": (
+        {"cap": 8, "n": 150, "codes": _one_entity_codes()}, "normal", False
+    ),
+    # the compare's live arrays over the budget: scanned sub-blocks
+    "sub_blocks": ({"cap": 8}, "normal", True),
+}
+
+
+def _uneven_split_budget(red, plan):
+    """A ``dense_bytes_budget`` that splits ``plan``'s chunks into a
+    number of sub-blocks that does not divide them, and that number."""
+    C, S, k = plan.passive[2].shape
+    n_sub = next(n for n in range(2, C) if C % n)
+    live = 4 * C * (red.local_dim + S * k)
+    return -(-live // n_sub), n_sub
+
+
+@pytest.mark.parametrize("case", sorted(PASSIVE_CASES))
+def test_passive_chunks_score_as_the_gather(case):
+    """``re_score_passive`` on the chunks against the element gather on
+    the flat passive rows (the path a bank too wide keeps), float32, and
+    every other row of the vector left as it was."""
+    data_kw, which, split = PASSIVE_CASES[case]
+    _, red = _dataset(**data_kw)
+    plan = score_plan(red, _problem())
+    assert plan.kernel == "blocks+chunks"
+    _check_chunks(red, plan)
+    if split:
+        budget, n_sub = _uneven_split_budget(red, plan)
+        plan = score_plan(red, _problem(dense_bytes_budget=budget))
+        rows, codes = (np.asarray(a) for a in plan.passive[:2])
+        assert rows.ndim == 3 and rows.shape[0] == n_sub
+        # the last sub-block padded with chunks of an entity past the bank
+        assert (codes[-1] == red.num_entities).any()
+        assert not (rows[codes == red.num_entities] >= 0).any()
+    passive = _passive_rows(red)
+    counts = np.bincount(red.row_entity_codes[passive])
+    S = max(b.capacity for b in red.buckets)
+    # an entity whose passive rows fill no whole number of chunks
+    assert np.any(counts % S)
+    if case == "one_entity":
+        assert np.count_nonzero(counts) == 1
+    # rows with entries of value 0 among the passive rows
+    assert (red.row_local_values[passive] == 0).any(axis=1).any()
+    bank = _bank(red)
+    if which == "zeros":
+        bank = np.zeros_like(bank)
+    before = np.full(red.row_entity_codes.shape, -7.0, np.float32)
+    got = np.asarray(re_score_passive(
+        jnp.asarray(before), jnp.asarray(bank), *plan.passive
+    ))
+    want = before.copy()
+    want[passive] = gather_scores(
+        jnp.asarray(bank), jnp.asarray(red.row_entity_codes[passive]),
+        jnp.asarray(red.row_local_indices[passive]),
+        jnp.asarray(red.row_local_values[passive]),
+    )
+    assert got.dtype == np.float32
+    np.testing.assert_allclose(
+        got, want, rtol=1e-6, atol=ATOL * np.max(np.abs(want[passive]))
+    )
+    if which == "zeros":
+        assert not got[passive].any()
 
 
 @pytest.mark.parametrize("n_sub", [2, 3])
@@ -282,9 +414,16 @@ def test_the_coordinate_counts_rows_by_path_and_names_the_kernel(cap):
     model = replace(coord.initialize_model(), bank=jnp.asarray(_bank(red)))
     before = _counted(name)
     _close(coord.score(model), _reference(np.asarray(model.bank), red))
-    blocks, gather = (a - b for a, b in zip(_counted(name), before))
-    assert (blocks, gather) == (red.num_active_rows, red.num_passive_rows)
-    assert coord.score_kernel == ("blocks+gather" if cap else "blocks")
+    blocks, chunks, gather = (a - b for a, b in zip(_counted(name), before))
+    assert (blocks, chunks, gather) == (
+        red.num_active_rows, red.num_passive_rows, 0
+    )
+    assert coord.score_kernel == ("blocks+chunks" if cap else "blocks")
+    plan = score_plan(red, coord.problem)
+    assert coord.score_attrs == ({
+        "passive_chunks": plan.passive_chunks,
+        "passive_padding": plan.passive_padding,
+    } if cap else {})
 
 
 def test_the_program_keeps_its_module_name():
@@ -330,7 +469,7 @@ def test_the_pod_scores_its_blocks_like_the_replicated_bank(n_dev, cap):
     assert not np.asarray(coord.score(model)).any()  # the zero bank
     assert tuple(
         a - b for a, b in zip(_counted(name), before)
-    ) == (red.num_active_rows, red.num_passive_rows)
+    ) == (red.num_active_rows, 0, red.num_passive_rows)
 
 
 def test_a_pod_block_left_to_the_sparse_solver_keeps_the_gather():
