@@ -17,7 +17,7 @@ from __future__ import annotations
 import threading
 from dataclasses import dataclass, replace
 from functools import partial
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,8 +37,10 @@ from photon_ml_tpu.game.random_effect import (
     RandomEffectOptimizationProblem,
     ValuesOverride,
     score_kernel_name,
+    score_places,
     score_plan,
     score_random_effect,
+    slots_by_path,
 )
 from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
 from photon_ml_tpu.models.coefficients import Coefficients
@@ -708,11 +710,30 @@ def _count_scored_rows(
             counter.inc(rows, coordinate=coordinate, path=path)
 
 
+def _placed(re_dataset, problem) -> Dict[str, int]:
+    """The slots of the blocks a replicated bank scores from, by the path
+    their scores are placed on, a path with none left out (host
+    arithmetic on the scoring plan and the paths it observes)."""
+    plan = score_plan(re_dataset, problem)
+    _, _, paths = score_places(problem, re_dataset, plan)
+    taken = slots_by_path(plan.groups, paths)
+    return {path: slots for path, slots in taken.items() if slots}
+
+
 def _score_replicated_bank(coordinate, bank, re_dataset, problem) -> Array:
     plan = score_plan(re_dataset, problem)
     _count_scored_rows(
         coordinate, plan.block_rows, plan.gather_rows, plan.chunk_rows
     )
+    counter = default_registry().counter(
+        "photon_re_score_placed_slots_total",
+        "slots of the blocks the random effects scored from, by "
+        "coordinate and the path their scores were placed on (windows: an "
+        "entity's rows are a run; sorted: they are a run of the dataset's "
+        "entity order, sorted back; slots: the element scatter)",
+    )
+    for path, slots in _placed(re_dataset, problem).items():
+        counter.inc(slots, coordinate=coordinate, path=path)
     return score_random_effect(bank, re_dataset, problem)
 
 
@@ -763,15 +784,21 @@ class RandomEffectCoordinate(Coordinate):
 
     @property
     def score_attrs(self) -> dict:
-        """The passive rows' chunks and their padding slots, where the
+        """The paths the blocks' scores were placed on, joined by ``+``,
+        and the passive rows' chunks and their padding slots, where the
         plan scores them from chunks: for ``cd.score``."""
         plan = score_plan(self.re_dataset, self.problem)
-        if not plan.chunk_rows:
-            return {}
-        return {
-            "passive_chunks": plan.passive_chunks,
-            "passive_padding": plan.passive_padding,
-        }
+        attrs = {}
+        if plan.groups:
+            attrs["placed"] = "+".join(
+                _placed(self.re_dataset, self.problem)
+            )
+        if plan.chunk_rows:
+            attrs.update(
+                passive_chunks=plan.passive_chunks,
+                passive_padding=plan.passive_padding,
+            )
+        return attrs
 
     def score(self, model: RandomEffectModel) -> Array:
         return _score_replicated_bank(
@@ -786,7 +813,8 @@ class RandomEffectCoordinate(Coordinate):
 
     def prepare(self, model=None) -> None:
         """Stage bucket device transfers / stacked group args / AOT
-        programs + the row view the score pass reads."""
+        programs + the row view and the placement specs the score pass
+        reads."""
         bank = (
             model.bank
             if model is not None
@@ -796,7 +824,10 @@ class RandomEffectCoordinate(Coordinate):
             )
         )
         self.problem.prepare(bank, self.re_dataset, coordinate=self.name)
-        score_plan(self.re_dataset, self.problem)
+        score_places(
+            self.problem, self.re_dataset,
+            score_plan(self.re_dataset, self.problem),
+        )
 
 
 @dataclass

@@ -229,7 +229,8 @@ def fre_score(bank, projection, blocks, rest, *, num_rows):
     group of solver blocks (:func:`score_blocks`; a folded group one
     sub-block at a time) the block's latent features
     (:func:`factored_values`) against its entities' bank rows, the
-    ``[E, S]`` scores placed by ``rows`` as ``re_score`` places them.
+    ``[E, S]`` scores placed by ``rows``, the element scatter of
+    ``re_score``'s ``slots`` path.
     ``rest``: rows no block holds (a passive row; none where every row is
     active), from ``B``'s rows at their feature ids. A row with no entity
     scores 0."""

@@ -764,15 +764,48 @@ def _residual_windows(rows, starts, counts, capacity: int):
     return jnp.where(slot < counts[:, None], win, 0.0)
 
 
+def _add_windows(rows, score, starts, counts):
+    """``rows`` with entity e's ``counts[e]`` scores ``score[e]`` ADDED
+    from row ``starts[e]`` of the vector on: the inverse of
+    :func:`_residual_windows`, on the vector as ``[n / 128 + pad, 128]``.
+    Each entity's ``[capacity]`` scores, zero past its real slots, as the
+    ``capacity / 128 + 1`` lane rows from ``starts[e] // 128``: every lane
+    turned RIGHT by ``starts[e] % 128`` (the same seven roll-and-select
+    stages, the sign flipped) and taken from its own row or the one
+    before, then whole rows added in (a row scatter: on a v5e 12.0 ms for
+    138,493 runs over 28.45M slots, where the element scatter takes 193;
+    ``PERF.md`` section 6). Entities whose
+    runs meet share a lane row, so rows are added into zeros, never set;
+    each row of the vector then holds one score and zeros, and what comes
+    out is the element scatter's bits but for the sign of a zero."""
+    e, capacity = score.shape
+    r = max(-(-capacity // _LANES), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
+    win = jnp.where(slot < counts[:, None], score, 0.0)
+    win = jnp.pad(win, ((0, 0), (0, (r + 1) * _LANES - capacity)))
+    # [r + 1, E, 128], the row the slow axis, as _residual_windows has it
+    win = win.reshape(e, r + 1, _LANES).transpose(1, 0, 2)
+    shift = (starts % _LANES)[None, :, None]
+    for bit in range(7):
+        on = ((shift >> bit) & 1).astype(bool)
+        win = jnp.where(on, jnp.roll(win, 1 << bit, axis=2), win)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _LANES), 2)
+    before = jnp.pad(win[:-1], ((1, 0), (0, 0), (0, 0)))
+    win = jnp.where(lane >= shift, win, before)
+    at = starts // _LANES + jnp.arange(r + 1, dtype=jnp.int32)[:, None]
+    return rows.at[at].add(win)
+
+
 @lru_cache(maxsize=None)
 def _residual_program(coordinate: Optional[str]):
     """The ONE program a bank update runs to turn the ``[n]`` residual
     into every group's offsets (``RandomEffectOptimizationProblem
     ._bucket_offsets``), module ``jit_bank_residual[_<coordinate>]``
     (non-word characters as ``_``, as ``fused_for`` names the solver
-    programs). ``specs``, ``keys`` and ``paths`` as
+    programs). ``specs`` and ``paths`` as
     :meth:`RandomEffectOptimizationProblem._residual_args` makes them, a
-    ``(path, capacity)`` a group: ``windows``, a group of runs, reads
+    ``(path, capacity)`` a group, ``keys`` the order's
+    (:func:`_order_on_device`): ``windows``, a group of runs, reads
     windows of the row vector (:func:`_residual_windows`; a folded
     group's [B, E] starts as one [B * E]); ``sorted`` reads windows of
     the residual sorted by ``keys``, the dataset's entity order, ONE
@@ -817,6 +850,35 @@ def _residual_program(coordinate: Optional[str]):
         return out
 
     return bank_residual
+
+
+def slots_by_path(groups, paths) -> Dict[str, int]:
+    """The slots of ``groups`` of solver blocks by their path of
+    ``paths`` (:meth:`RandomEffectOptimizationProblem._residual_args`'),
+    ``windows``, ``sorted`` and ``slots`` in that order: a block's real
+    entities times its capacity, what the residual's read and the scores'
+    placement count."""
+    taken = {"windows": 0, "sorted": 0, "slots": 0}
+    for members, (path, _) in zip(groups, paths):
+        taken[path] += (
+            sum(b.num_real for b in members) * members[0].bucket.capacity
+        )
+    return taken
+
+
+def _order_on_device(dataset, which: str, paths):
+    """The dataset's entity order on the device where some group of
+    ``paths`` is ``sorted``, else None: ``which`` ``keys``, each row's
+    position in the order (what the residual is sorted by), or ``rows``,
+    the row at each position (what the scores are sorted back by). Each
+    uploaded once and cached beside the groups' specs."""
+    if not any(path == "sorted" for path, _ in paths):
+        return None
+    cache = dataset.__dict__.setdefault("_residual_device_cache", {})
+    name = {"keys": "entity_order", "rows": "entity_order_rows"}[which]
+    if name not in cache:
+        cache[name] = jnp.asarray(getattr(dataset.entity_order, which))
+    return cache[name]
 
 
 class ValuesOverride(NamedTuple):
@@ -1270,19 +1332,20 @@ class RandomEffectOptimizationProblem:
         return cache[key]
 
     def _residual_args(self, dataset, groups):
-        """What :func:`_residual_program` reads for ``groups`` of solver
-        blocks, on the device, cached on the dataset: ``(specs, keys,
-        paths)``. A group's spec is ``(starts, counts)`` [E] (a folded
-        group: [B, E]) where every block of it observed runs in the row
-        vector (:class:`RowRuns`; path ``windows``) or, failing that, in
-        the residual sorted into the dataset's entity order
+        """Where each of ``groups`` of solver blocks finds its slots in a
+        row-aligned vector, on the device, cached on the dataset:
+        ``(specs, paths)``, what :func:`_residual_program` reads the
+        residual's offsets through and :func:`re_score` puts the scores
+        back through. A group's spec is ``(starts, counts)`` [E] (a
+        folded group: [B, E]) where every block of it observed runs in
+        the row vector (:class:`RowRuns`; path ``windows``) or, failing
+        that, in the vector sorted into the dataset's entity order
         (:attr:`RandomEffectDataset.entity_order`, observed here when
         first read; path ``sorted``), else ``(rows,)``, the group's
         ``row_index`` (path ``slots``: no order, as where a row is held
-        twice or a bucket's real slots are not a prefix). ``keys``: the
-        order's keys on the device, uploaded once and cached here, where
-        some group is ``sorted``; else None. ``paths``, static:
-        ``(path, capacity)`` a group."""
+        twice or a bucket's real slots are not a prefix). ``paths``,
+        static: ``(path, capacity)`` a group. The order itself is
+        :func:`_order_on_device`'s."""
         cache = dataset.__dict__.setdefault("_residual_device_cache", {})
         key = tuple(tuple(b[:3] for b in members) for members in groups)
         if key in cache:
@@ -1320,12 +1383,7 @@ class RandomEffectOptimizationProblem:
                 fields = [field[0] for field in fields]
             specs.append(tuple(jnp.asarray(f) for f in fields))
             paths.append((path, members[0].bucket.capacity))
-        keys = None
-        if any(path == "sorted" for path, _ in paths):
-            if "entity_order" not in cache:
-                cache["entity_order"] = jnp.asarray(dataset.entity_order.keys)
-            keys = cache["entity_order"]
-        cache[key] = (tuple(specs), keys, tuple(paths))
+        cache[key] = (tuple(specs), tuple(paths))
         return cache[key]
 
     def _bucket_offsets(
@@ -1345,7 +1403,8 @@ class RandomEffectOptimizationProblem:
         through the uploaded ``row_index`` for any other. Which path a
         group's slots took is counted here, on the host, where it is
         decided."""
-        specs, keys, paths = self._residual_args(dataset, groups)
+        specs, paths = self._residual_args(dataset, groups)
+        keys = _order_on_device(dataset, "keys", paths)
         slots = default_registry().counter(
             "photon_bank_residual_slots_total",
             "slots of the replicated bank's blocks whose offsets were "
@@ -1354,11 +1413,7 @@ class RandomEffectOptimizationProblem:
             "residual is sorted into the dataset's entity order; slots: "
             "the element gather)",
         )
-        taken = {"windows": 0, "sorted": 0, "slots": 0}
-        for members, (path, _) in zip(groups, paths):
-            taken[path] += (
-                sum(b.num_real for b in members) * members[0].bucket.capacity
-            )
+        taken = slots_by_path(groups, paths)
         for path, count in taken.items():
             if count:
                 slots.inc(count, coordinate=coordinate or "", path=path)
@@ -1504,7 +1559,8 @@ class RandomEffectOptimizationProblem:
                 )
         if self.mesh is None:
             if has_residual_offsets:
-                self._residual_args(dataset, groups)
+                _, paths = self._residual_args(dataset, groups)
+                _order_on_device(dataset, "keys", paths)
             l1, l2 = self.regularization.split(self.reg_weight)
             self._warm_solvers(self._bucket_plans(
                 bank, groups,
@@ -2161,73 +2217,131 @@ def score_random_effect(
 
     ``problem``: the one whose ``update_bank`` solves ``dataset``; the
     rows its dense blocks hold are scored from the device arrays it
-    already holds for them (:func:`score_plan`), the passive rows by
+    already holds for them (:func:`score_plan`) and placed through the
+    specs its residual reads (:func:`score_places`), the passive rows by
     :func:`re_score_passive`."""
     problem = problem or _default_problem()
     plan = score_plan(dataset, problem)
+    specs, order, paths = score_places(problem, dataset, plan)
     scores = re_score(
-        bank, score_blocks(problem, dataset, plan), plan.rest,
+        bank, score_blocks(problem, dataset, plan, specs), plan.rest, order,
         identity=tuple(m[0].bucket.identity_indices for m in plan.groups),
-        num_rows=int(dataset.row_entity_codes.shape[0]),
+        num_rows=int(dataset.row_entity_codes.shape[0]), paths=paths,
     )
     if plan.passive is not None:
         scores = re_score_passive(scores, bank, *plan.passive)
     return scores
 
 
-def score_blocks(problem, dataset, plan: _ScorePlan) -> tuple:
-    """``(codes, ix, v, rows)`` on the device for each group of ``plan``
+def score_places(problem, dataset, plan: _ScorePlan):
+    """How each group of ``plan`` puts its ``[E, S]`` scores into the row
+    vector: ``(specs, order, paths)``, the specs and ``(path, capacity)``
+    a group that the bank update's residual reads through
+    (:meth:`RandomEffectOptimizationProblem._residual_args`, the same
+    rule and the same cached device arrays: ``windows``, ``sorted`` or
+    ``slots``), and ``order``, the rows of the dataset's entity order
+    (:func:`_order_on_device`) where some group is ``sorted``."""
+    specs, paths = problem._residual_args(dataset, plan.groups)
+    return specs, _order_on_device(dataset, "rows", paths), paths
+
+
+def score_blocks(problem, dataset, plan: _ScorePlan, specs=None) -> tuple:
+    """``(codes, ix, v, *place)`` on the device for each group of ``plan``
     (stacked ``[B, ...]`` for a folded group): the copies ``problem``'s
-    bank update holds, and the groups' ``row_index``."""
+    bank update holds, and where the group's scores go, its spec of
+    ``specs`` (:func:`score_places`) where given, else its
+    ``row_index``."""
     blocks = []
-    for members in plan.groups:
+    for i, members in enumerate(plan.groups):
         if len(members) > 1:
             codes, ix, v, _, _, _ = problem._stacked_group_args(
                 dataset, members, with_residuals=True
             )
-            rows = problem._stacked_rows(dataset, members)
         else:
             ix, v, _, _, _, codes = problem._bucket_device_args(
                 members[0].bucket
             )
-            rows = problem._bucket_rows(members[0].bucket)
-        blocks.append((codes, ix, v, rows))
+        if specs is not None:
+            place = specs[i]
+        elif len(members) > 1:
+            place = (problem._stacked_rows(dataset, members),)
+        else:
+            place = (problem._bucket_rows(members[0].bucket),)
+        blocks.append((codes, ix, v) + tuple(place))
     return tuple(blocks)
 
 
-def _place_scores(out, bank, args, identity):
+def _place_scores(out, bank, args, identity, path="slots"):
     """``out`` with the rows of one block put in: ``args`` ``(codes, ix,
-    v, rows)``, stacked ``[B, E_sub, ...]`` for sub-blocks, which are
+    v, *place)``, stacked ``[B, E_sub, ...]`` for sub-blocks, which are
     scanned one at a time so that only one's temporaries are live. Whole
     bank rows taken as ``_update_block`` takes them, :func:`score_block`,
-    the ``[E, S]`` scores placed into the row vector by ``rows`` (a
-    padding slot, -1, and a padding entity, whose code lies past the
-    bank, drop)."""
+    and the ``[E, S]`` scores put in on ``path``: ``slots``, ``place``
+    ``(rows,)``, the ``[n]`` ``out`` set by ``rows`` (a padding slot, -1,
+    and a padding entity, whose code lies past the bank, drop);
+    ``windows`` and ``sorted``, ``place`` ``(starts, counts)``, ``out``
+    a vector as lane rows that each entity's run is added into
+    (:func:`_add_windows`; a padding entity has no real slot)."""
 
-    def place(out, args):
-        codes, ix, v, rows = args
+    def put(out, args):
+        codes, ix, v, *place = args
         w = jnp.take(bank, codes, axis=0, mode="fill", fill_value=0)
         score = score_block(w, ix, v, identity)
+        if path != "slots":
+            return _add_windows(out, score, *place)
+        (rows,) = place
         at = jnp.where(rows >= 0, rows, out.shape[0])
         return out.at[at.reshape(-1)].set(score.reshape(-1), mode="drop")
 
     if args[0].ndim == 2:
-        return jax.lax.scan(lambda o, a: (place(o, a), None), out, args)[0]
-    return place(out, args)
+        return jax.lax.scan(lambda o, a: (put(o, a), None), out, args)[0]
+    return put(out, args)
 
 
-@partial(jax.jit, static_argnames=("identity", "num_rows"))
-def re_score(bank, blocks, rest, *, identity, num_rows):
+@partial(jax.jit, static_argnames=("identity", "num_rows", "paths"))
+def re_score(bank, blocks, rest, order=None, *, identity, num_rows, paths):
     """One named program (module ``re_score``, scope ``cd.score``) a
-    device trace can place. ``blocks``: ``(codes, ix, v, rows)`` of each
-    group of solver blocks, stacked ``[B, E_sub, ...]`` for a folded
-    group, each scored from the block (:func:`_place_scores`). ``rest``:
-    the rows no block holds (:class:`_ScorePlan`), gathered an element
-    at a time. A row with no entity scores 0."""
+    device trace can place. ``blocks``: ``(codes, ix, v, *place)`` of
+    each group of solver blocks, stacked ``[B, E_sub, ...]`` for a folded
+    group, each scored from the block and placed on its ``(path,
+    capacity)`` of ``paths`` (:func:`_place_scores`), which
+    the dataset observes as the residual's read does
+    (:func:`score_places`): ``windows``, a group whose entities' rows are
+    runs of the row vector, adds each run into that vector held as lane
+    rows; ``sorted``, a group whose rows are runs of the dataset's entity
+    order, adds them into ONE vector in that order, which ONE unstable
+    sort by ``order`` (each position's row: unique keys, so a
+    permutation) takes back to row order, the rows no such group holds
+    coming back as 0; ``slots``, any other, the element scatter by
+    ``row_index``. Rows are added, not set, because entities whose runs
+    meet share a lane row; the three vectors hold each row's score in one
+    of them and zeros in the others, so they are summed. ``rest``: the
+    rows no block holds (:class:`_ScorePlan`), gathered an element at a
+    time. A row with no entity scores 0. Every row is the element
+    scatter's bits, but for the sign of a zero."""
     with jax.named_scope("cd.score"):
-        out = jnp.zeros((num_rows,), jnp.float32)
-        for args, ident in zip(blocks, identity):
-            out = _place_scores(out, bank, args, ident)
+        # a run's lane rows reach past the last row by up to a window
+        reach = max((c for _, c in paths), default=0) + 2 * _LANES
+        lane_rows = -(-(num_rows + reach) // _LANES)
+        into = {
+            path: jnp.zeros(
+                (num_rows,) if path == "slots" else (lane_rows, _LANES),
+                jnp.float32,
+            )
+            for path, _ in paths
+        }
+        for args, ident, (path, _) in zip(blocks, identity, paths):
+            into[path] = _place_scores(into[path], bank, args, ident, path)
+        out = into.pop("slots", None)
+        for path, rows in into.items():
+            placed = rows.reshape(-1)[:num_rows]
+            if path == "sorted":
+                _, placed = jax.lax.sort(
+                    (order, placed), num_keys=1, is_stable=False
+                )
+            out = placed if out is None else out + placed
+        if out is None:
+            out = jnp.zeros((num_rows,), jnp.float32)
         if rest is not None:
             rows, codes, valid, ix, v = rest
             score = gather_scores(bank, codes, ix, v)

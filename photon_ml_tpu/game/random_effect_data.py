@@ -63,9 +63,13 @@ def observe_row_runs(row_index: np.ndarray) -> Optional[RowRuns]:
 class EntityOrder(NamedTuple):
     """The order that makes every entity's rows a run in a SORTED copy of
     the residual, for the buckets that observe no runs in the row vector
-    (:func:`observe_entity_order`)."""
+    (:func:`observe_entity_order`), and its inverse, which brings a vector
+    written in that order back to row order (a bank's scores,
+    ``game/random_effect.re_score``): sorting by ``keys`` takes the row
+    vector into the order, sorting by ``rows`` takes it back."""
 
     keys: np.ndarray  # int32 [n]: each row's position in the order
+    rows: np.ndarray  # int32 [n]: the row at each position of the order
     # a bucket: its RowRuns in the sorted vector; None for a bucket of runs
     # in the row vector or one whose real slots are not a prefix
     runs: List[Optional[RowRuns]]
@@ -78,7 +82,9 @@ def observe_entity_order(buckets, num_rows: int) -> Optional[EntityOrder]:
     entity, each entity's in slot order; ``keys`` int32 [num_rows] is each
     row's position in it (a row no such bucket holds comes after the
     last, in row order), so that sorting the residual by ``keys`` makes
-    every such entity's rows a run, at the bucket's ``runs``. A bucket
+    every such entity's rows a run, at the bucket's ``runs``; ``rows``,
+    its inverse, is those buckets' rows as they come then the rest, made
+    with the keys and never sorted for. A bucket
     whose real slots are not a prefix of its slots gets none; None where
     no bucket has them or a row is held twice (no permutation then)."""
     runs, held, at = [], [], 0
@@ -99,13 +105,14 @@ def observe_entity_order(buckets, num_rows: int) -> Optional[EntityOrder]:
         at += int(counts.sum())
     if not held:
         return None
+    held = np.concatenate(held).astype(np.int32)
     keys = np.full(num_rows, -1, np.int32)
-    keys[np.concatenate(held)] = np.arange(at, dtype=np.int32)
-    rest = keys < 0
-    if int(rest.sum()) != num_rows - at:  # a row held twice
+    keys[held] = np.arange(at, dtype=np.int32)
+    rest = np.flatnonzero(keys < 0).astype(np.int32)
+    if len(rest) != num_rows - at:  # a row held twice
         return None
     keys[rest] = np.arange(at, num_rows, dtype=np.int32)
-    return EntityOrder(keys, runs)
+    return EntityOrder(keys, np.concatenate([held, rest]), runs)
 
 
 @dataclass
@@ -165,9 +172,10 @@ class RandomEffectDataset:
     @cached_property
     def entity_order(self) -> Optional[EntityOrder]:
         """The buckets' :class:`EntityOrder` over the ``[n]`` rows,
-        observed when a replicated bank update first reads the residual
-        (``game/random_effect._residual_args``) and kept: a dataset that
-        never does (the pod's, a streamed segment's) never builds it."""
+        observed when a replicated bank update first reads the residual,
+        or its blocks' scores are first placed into the row vector
+        (``game/random_effect._residual_args``), and kept: a dataset that
+        does neither (the pod's, a streamed segment's) never builds it."""
         with obs_span(
             "re.entity_order", rows=self.row_entity_codes.shape[0],
             buckets=len(self.buckets),

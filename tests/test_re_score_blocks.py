@@ -6,7 +6,11 @@ bank are scored from per-entity chunks of a block's shape
 (re_score_passive) where the bank is narrow enough for the compare. The
 rows no dense block or chunk holds (a sparse block's, a view without
 buckets, the entity mesh, a bank too wide) keep the gather, and
-``photon_re_score_rows_total`` says how many rode which path."""
+``photon_re_score_rows_total`` says how many rode which path. A block's
+scores reach the row vector through the order the residual is read in
+(windows of the row vector, of the entity order sorted back, or the
+element scatter), each row equal to the element scatter's, and
+``photon_re_score_placed_slots_total`` says which."""
 
 from dataclasses import replace
 
@@ -33,6 +37,8 @@ from photon_ml_tpu.game.random_effect import (
     re_score,
     re_score_passive,
     score_block,
+    score_blocks,
+    score_places,
     score_plan,
     score_random_effect,
 )
@@ -51,21 +57,27 @@ ATOL = 1e-6  # of the largest score
 
 
 def _dataset(seed=0, n=431, E=23, d=40, k=6, cap=None,
-             projector=ProjectorType.INDEX_MAP, codes=None):
+             projector=ProjectorType.INDEX_MAP, codes=None,
+             sparse_rows=True):
     """A GameDataset and its RandomEffectDataset: an uneven entity
     histogram (several capacity classes; ``codes``, where given, in its
-    place), weight-0 rows, rows with no entity, entries of value 0."""
+    place), weight-0 rows (every 17th) and rows with no entity (every
+    29th; ``sparse_rows`` False: neither, but those ``codes`` has),
+    entries of value 0."""
     rng = np.random.default_rng(seed)
     if codes is None:
         codes = np.minimum(
             rng.geometric(0.12, size=n) - 1, E - 1
         ).astype(np.int32)
-    codes[::29] = -1
+    n, E = len(codes), max(E, int(codes.max()) + 1)
+    if sparse_rows:
+        codes[::29] = -1
     ix = rng.integers(0, d, size=(n, k)).astype(np.int32)
     v = rng.normal(size=(n, k)).astype(np.float32)
     v[::7, -2:] = 0.0
     w = np.ones(n, np.float32)
-    w[::17] = 0.0
+    if sparse_rows:
+        w[::17] = 0.0
     imap = IndexMap.build(
         (feature_key(f"f{i}", "") for i in range(d)), add_intercept=False
     )
@@ -420,10 +432,13 @@ def test_the_coordinate_counts_rows_by_path_and_names_the_kernel(cap):
     )
     assert coord.score_kernel == ("blocks+chunks" if cap else "blocks")
     plan = score_plan(red, coord.problem)
-    assert coord.score_attrs == ({
+    _, _, paths = score_places(coord.problem, red, plan)
+    placed = [p for p in ("windows", "sorted", "slots")
+              if any(q == p for q, _ in paths)]
+    assert coord.score_attrs == {"placed": "+".join(placed), **({
         "passive_chunks": plan.passive_chunks,
         "passive_padding": plan.passive_padding,
-    } if cap else {})
+    } if cap else {})}
 
 
 def test_the_program_keeps_its_module_name():
@@ -442,6 +457,7 @@ def test_the_program_keeps_its_module_name():
         jnp.asarray(_bank(red)), tuple(blocks), plan.rest,
         identity=(False,) * len(blocks),
         num_rows=red.row_entity_codes.shape[0],
+        paths=(("slots", 0),) * len(blocks),
     )
     assert "module @jit_re_score" in lowered.as_text()
 
@@ -481,3 +497,196 @@ def test_a_pod_block_left_to_the_sparse_solver_keeps_the_gather():
     assert view.score_kernel == "gather" and view._score_rest[0] is None
     sharded = ShardedREBank.from_global(mesh, pod.spec_for(red), bank)
     _close(pod.score(sharded, red), _reference(bank, red))
+
+
+# ---- where a block's scores reach the row vector -----------------------
+
+
+def _entity_rows(counts, *, grouped, seed=0):
+    """Codes of rows holding ``counts[e]`` rows of entity e: grouped by
+    entity (each entity's rows a run, the entities in a shuffled order,
+    a row with no entity after every third), or shuffled whole, with
+    rows of no entity among them."""
+    rng = np.random.default_rng(seed)
+    if not grouped:
+        codes = np.repeat(np.arange(len(counts)), counts)
+        codes = np.concatenate([codes, np.full(len(counts) // 3, -1)])
+        return rng.permutation(codes).astype(np.int32)
+    runs = []
+    for i, e in enumerate(rng.permutation(len(counts))):
+        runs.append(np.full(counts[e], e))
+        if i % 3 == 2:
+            runs.append(np.array([-1]))
+    return np.concatenate(runs).astype(np.int32)
+
+
+def _with_a_hole(red, capacity):
+    """``red`` with the bucket of ``capacity``'s slots turned by one, so
+    that each entity's padding slot comes before its real ones: no run
+    in the row vector or in the entity order (its scores keep the
+    element scatter)."""
+    buckets = []
+    for b in red.buckets:
+        if b.capacity == capacity:
+            assert (b.row_index < 0).any(axis=1).all()
+            b = replace(b, row_runs=None, **{
+                name: np.roll(getattr(b, name), 1, axis=1)
+                for name in ("row_index", "indices", "values", "labels",
+                             "offsets", "weights")
+            })
+        buckets.append(b)
+    return replace(red, buckets=buckets)
+
+
+# 1, 128 and over 128 rows an entity among them: capacities 1 to 512,
+# three entities in the widest class
+_COUNTS = [1, 1, 3, 128, 5, 300, 7, 1, 260, 2, 60, 9, 4, 100, 1, 33, 6, 400]
+
+PLACE_CASES = {
+    # name: (rows grouped by entity, weight-0 rows and rows of no entity
+    #        every 17th and 29th, reservoir cap, widest class folded into
+    #        two sub-blocks, a bucket turned to have a hole, the bank,
+    #        the paths the groups take). An entity of one row is a run
+    #        in any table.
+    "grouped": (True, False, None, False, None, "normal", {"windows"}),
+    "shuffled": (
+        False, False, None, False, None, "normal", {"windows", "sorted"}
+    ),
+    "grouped_folded": (True, False, None, True, None, "normal", {"windows"}),
+    "shuffled_folded": (
+        False, False, None, True, None, "normal", {"windows", "sorted"}
+    ),
+    # a weight-0 row or a capped entity's left-out row is no bucket's and
+    # breaks its entity's run: its bucket reads the entity order, and the
+    # row rides ``rest``
+    "grouped_sparse_rows_capped": (
+        True, True, 64, False, None, "normal", {"windows", "sorted"}
+    ),
+    "shuffled_sparse_rows_capped": (
+        False, True, 64, False, None, "normal", {"windows", "sorted"}
+    ),
+    "three_paths": (
+        True, False, None, False, 8, "normal", {"windows", "slots"}
+    ),
+    "three_paths_shuffled": (
+        False, False, None, False, 8, "normal", {"windows", "sorted", "slots"}
+    ),
+    "zero_bank": (
+        False, True, 64, False, None, "zeros", {"windows", "sorted"}
+    ),
+    # a diverged entity's row: its padding slots score NaN (0 x inf),
+    # which stays off the rows its run shares a lane row with
+    "an_infinite_bank_row": (
+        False, True, 64, False, None, "inf", {"windows", "sorted"}
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLACE_CASES))
+def test_every_path_places_the_element_scatters_scores(case):
+    """``re_score`` on the paths the data gives (``windows``, ``sorted``,
+    ``slots``) against the same program with every group on the element
+    scatter, and against the gather on the row view: equal row for row
+    (``==``, so that the sign of a zero is all that may differ), rows of
+    no entity and rows no bucket holds among them."""
+    grouped, sparse, cap, folded, holed, which, want_paths = (
+        PLACE_CASES[case]
+    )
+    codes = _entity_rows(_COUNTS, grouped=grouped, seed=len(case))
+    # one entry a row: a row's score is one product on every path, so the
+    # gather's is the blocks' to the bit (the sum over k takes another
+    # order in the two programs, ulps apart)
+    _, red = _dataset(codes=codes, k=1, cap=cap, sparse_rows=sparse)
+    if holed is not None:
+        red = _with_a_hole(red, holed)
+    kw = {}
+    if folded:  # the widest class's three entities as two sub-blocks of
+        capacity = max(b.capacity for b in red.buckets)  # the primal kind
+        d = red.local_dim
+        kw["dense_bytes_budget"] = 2 * 4 * (capacity * d + d * d)
+    problem = _problem(**kw)
+    plan = score_plan(red, problem, passive_apart=False)
+    capacities = {m[0].bucket.capacity for m in plan.groups}
+    assert capacities >= ({1, 64} if cap else {1, 128, 512})
+    if folded:  # a folded group, its last sub-block's padding entity on
+        widest = max(plan.groups, key=len)  # no slot at all
+        assert len(widest) == 2 and widest[0].bucket.capacity == 512
+        assert widest[-1].num_real < widest[-1].bucket.num_entities
+    if cap is not None:
+        assert red.num_passive_rows > 0 and plan.rest is not None
+    specs, order, paths = score_places(problem, red, plan)
+    assert {path for path, _ in paths} == want_paths
+    assert (order is not None) == ("sorted" in want_paths)
+    bank = np.zeros_like(_bank(red)) if which == "zeros" else _bank(red)
+    if which == "inf":  # an entity of 5 to 8 rows: 3 to 0 padding slots
+        (of_8,) = [b for b in red.buckets if b.capacity == 8]
+        bank[of_8.entity_codes[0]] = np.inf
+    bank = jnp.asarray(bank)
+    identity = tuple(m[0].bucket.identity_indices for m in plan.groups)
+    n = red.row_entity_codes.shape[0]
+    got = np.asarray(re_score(
+        bank, score_blocks(problem, red, plan, specs), plan.rest, order,
+        identity=identity, num_rows=n, paths=paths,
+    ))
+    scattered = np.asarray(re_score(
+        bank, score_blocks(problem, red, plan), plan.rest,
+        identity=identity, num_rows=n, paths=(("slots", 0),) * len(paths),
+    ))
+    codes = red.row_entity_codes
+    gathered = np.where(codes >= 0, np.asarray(gather_scores(
+        bank, jnp.asarray(np.maximum(codes, 0)),
+        jnp.asarray(red.row_local_indices), jnp.asarray(red.row_local_values),
+    )), np.float32(0.0))
+    assert got.dtype == np.float32 and got.shape == (n,)
+    # ``==`` row for row, a NaN where the other has one
+    np.testing.assert_array_equal(got, scattered)
+    np.testing.assert_array_equal(got, gathered)
+    assert not got[codes < 0].any()
+    if which == "zeros":
+        assert not got.any()
+    else:
+        assert (got != 0).sum() > n // 2
+    if which == "inf":
+        assert 0 < (~np.isfinite(got)).sum() <= 8
+    # the whole pass, the passive rows on their chunks, as the gather
+    np.testing.assert_array_equal(
+        score_random_effect(bank, red, problem), gathered
+    )
+
+
+def test_the_coordinate_counts_placed_slots_by_path_and_names_them():
+    """``photon_re_score_placed_slots_total`` counts a block's real
+    entities times its capacity, once a pass, under the path its scores
+    took, and ``cd.score``'s ``placed`` names those paths."""
+    codes = _entity_rows(_COUNTS, grouped=True, seed=5)
+    ds, red = _dataset(codes=codes, cap=64, sparse_rows=False)
+    red = _with_a_hole(red, 8)
+    coord = RandomEffectCoordinate("placed", ds, red, _problem())
+    counter = default_registry().counter(
+        "photon_re_score_placed_slots_total"
+    )
+
+    def counted():
+        return {
+            path: counter.value(coordinate="placed", path=path)
+            for path in ("windows", "sorted", "slots")
+        }
+
+    plan = score_plan(red, coord.problem)
+    want = {"windows": 0, "sorted": 0, "slots": 0}
+    for members in plan.groups:
+        bucket = members[0].bucket
+        path = "slots" if bucket.capacity == 8 else (
+            "windows" if bucket.row_runs is not None else "sorted"
+        )
+        want[path] += sum(b.num_real for b in members) * bucket.capacity
+    assert want["windows"] and want["sorted"] and want["slots"]
+    before = counted()
+    model = replace(coord.initialize_model(), bank=jnp.asarray(_bank(red)))
+    coord.score(model)
+    coord.score(model)
+    after = counted()
+    assert {p: after[p] - before[p] for p in want} == {
+        p: 2 * slots for p, slots in want.items()
+    }
+    assert coord.score_attrs["placed"] == "windows+sorted+slots"
